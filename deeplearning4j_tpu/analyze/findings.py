@@ -87,7 +87,7 @@ RULES: Dict[str, Rule] = _catalog(
     Rule("numerics.ce_tail_f32", "info",
          "bf16 compute with the softmax-CE tail left in f32 — on a "
          "large vocab this is the single largest f32 tensor in the "
-         "step (PROFILE.md; set MixedPrecision.softmax_dtype)"),
+         "step (set MixedPrecision.softmax_dtype)"),
     # -- config/composition passes (analyze/configpass.py) --------------
     Rule("config.mapping_unknown", "error",
          "data_set_feature/label_mapping names a variable that does "

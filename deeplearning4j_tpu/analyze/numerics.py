@@ -13,7 +13,7 @@ Three hazard families (tentpole pass 3):
   accumulating in bf16/f16;
 - non-finite-prone patterns: ``log``/``divide`` with no positivity /
   zero guard between the value and the op;
-- policy hints: the PROFILE.md f32-CE-tail delta (bf16 compute with
+- policy hints: the f32 CE tail (bf16 compute with
   ``MixedPrecision.softmax_dtype`` unset).
 """
 from __future__ import annotations
@@ -169,7 +169,7 @@ def check_lowp_accumulation(sd, facts: GraphFacts) -> List[Finding]:
 
 
 def check_ce_tail_policy(sd, facts: GraphFacts, mp) -> List[Finding]:
-    """The PROFILE.md f32-CE delta as a hint: bf16 compute, a softmax-CE
+    """The f32 CE tail as a hint: bf16 compute, a softmax-CE
     loss in the live graph, and no softmax_dtype policy — the
     [batch..., vocab] f32 tail is the step's largest tensor."""
     if mp is None or getattr(mp, "softmax_dtype", None) is not None:
@@ -188,7 +188,7 @@ def check_ce_tail_policy(sd, facts: GraphFacts, mp) -> List[Finding]:
                 "numerics.ce_tail_f32", opn,
                 f"loss op {opn!r} ({node.op}) runs its log-softmax "
                 f"tail in f32 under bf16 compute (vocab {vocab}) — "
-                f"the largest f32 tensor in the step (PROFILE.md)",
+                f"the largest f32 tensor in the step",
                 fix_hint="MixedPrecision(softmax_dtype='bfloat16') "
                          "keeps the tail bf16; the scalar loss still "
                          "accumulates f32 "

@@ -46,8 +46,7 @@ class MixedPrecision:
     ``softmax_dtype`` (alias ``ce_tail_dtype``) relaxes the one upcast
     that dominates LM steps: by default the softmax-CE losses run their
     log-softmax tail in f32 even under bf16 compute, which on a 32k
-    vocab materializes the largest f32 tensor in the step (PROFILE.md
-    round 5 names it the top delta to hand-written JAX). Setting
+    vocab materializes the largest f32 tensor in the step. Setting
     ``softmax_dtype="bfloat16"`` keeps that [batch..., vocab] tail in
     bf16 — the per-example losses still reduce to the scalar loss in
     f32, so the training signal accumulates at full precision. Default

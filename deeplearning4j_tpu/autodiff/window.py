@@ -1,8 +1,8 @@
 """Fused multi-step training windows: K train steps per compiled dispatch.
 
-BENCH_r05 showed the per-step fit tier is host-dispatch-bound on small
-models (lenet_mnist ~1.4 ms/step at ~1.8% MFU): the device finishes the
-step long before the host can enqueue the next one, and the scanned
+On small models the per-step fit tier pays one host dispatch per step
+for a step the device finishes quickly (how much of the step that is
+on the current chip is not measured yet — PERF.md), and the scanned
 whole-epoch tier that fixes this was only reachable with zero listeners
 and a fully device-cached dataset. This module makes the fused path work
 under PRODUCTION constraints:

@@ -16,11 +16,17 @@ The signature deliberately covers only the *placeholder/stacked-window*
 argument: parameter, optimizer-state and constant shapes are fixed for
 a given graph version, and the jit cache key that owns this dispatcher
 already pins the version — placeholder shapes are the only axis a fit
-or serving loop varies. ``Compiled`` itself re-validates every input
-aval and raises on mismatch — ``TypeError`` for shape/dtype,
-``ValueError`` for sharding — so a stale hit (e.g. resharded inputs
-under a mesh) degrades to the lazy path instead of executing the wrong
-program.
+or serving loop varies.
+
+Only a shape nobody predicted takes the lazy path. ``Compiled``
+re-validates every input aval and raises — ``TypeError`` for
+shape/dtype/weak-type, ``ValueError`` for sharding or layout — and
+that error is NOT absorbed here: a predicted shape whose executable
+rejects the live arguments means the abstract arguments it was lowered
+from were wrong (a missing sharding, a weak type, a key of another
+implementation). Re-dispatching through lazy ``jit`` would turn a
+warmed program into a compile under the first request, silently; the
+fix belongs at the lowering site.
 """
 from __future__ import annotations
 
@@ -53,17 +59,7 @@ class AOTDispatch:
         if self.aot:
             compiled = self.aot.get(ph_shape_sig(args[self.ph_arg]))
             if compiled is not None:
-                try:
-                    return compiled(*args)
-                except (TypeError, ValueError):
-                    # input aval/sharding mismatch at the executable
-                    # boundary (checked BEFORE execution or donation):
-                    # fall back to lazy jit, which specializes freely.
-                    # jax raises TypeError for aval (shape/dtype)
-                    # mismatches but ValueError for sharding mismatches
-                    # (mesh-committed inputs against an executable
-                    # lowered from unsharded specs)
-                    pass
+                return compiled(*args)
         return self.jit_fn(*args)
 
     # keep the jit AOT surface reachable (SameDiff.precompile uses it)
@@ -72,30 +68,22 @@ class AOTDispatch:
 
 
 class AOTOutput:
-    """An AOT-compiled inference executable paired with its lazy jit
-    twin, stored under ``output()``'s exact cache key.
+    """An AOT-compiled inference executable, stored under ``output()``'s
+    exact cache key (the type marks the entry as prebuilt).
 
     Unlike :class:`AOTDispatch` (one jit fn, MANY placeholder shapes),
     an output cache key already pins the placeholder signature — there
-    is exactly one predicted shape set, so the executable is tried
-    first unconditionally. ``Compiled`` re-validates input avals and
-    raises on mismatch — ``TypeError`` for a differently-typed PRNG
-    key, ``ValueError`` for resharded params — which degrades to the
-    lazy jit path instead of executing the wrong program.
+    is exactly one predicted shape set, so the executable is always the
+    one called.
     """
 
-    __slots__ = ("jit_fn", "compiled")
+    __slots__ = ("compiled",)
 
-    def __init__(self, jit_fn: Callable, compiled: Any):
-        self.jit_fn = jit_fn
+    def __init__(self, compiled: Any):
         self.compiled = compiled
 
     def __call__(self, params, consts, ph, key):
-        try:
-            return self.compiled(params, consts, ph, key)
-        except (TypeError, ValueError):
-            # TypeError = aval mismatch, ValueError = sharding mismatch
-            return self.jit_fn(params, consts, ph, key)
+        return self.compiled(params, consts, ph, key)
 
 
 __all__ = ["AOTDispatch", "AOTOutput", "ph_shape_sig"]

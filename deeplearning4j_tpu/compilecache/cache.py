@@ -116,7 +116,6 @@ COMPILE_STATS = CompileStats()
 
 _install_lock = threading.Lock()
 _installed = False
-_install_failed = False
 _tls = threading.local()
 
 
@@ -164,26 +163,13 @@ def install_compile_watcher() -> CompileStats:
     Called automatically by cache configuration, ``precompile()`` and
     serving warmup — call it directly only to observe purely-lazy
     compilation."""
-    global _installed, _install_failed
+    global _installed
     with _install_lock:
-        if _installed or _install_failed:
-            return COMPILE_STATS
-        try:
-            from jax import monitoring as _mon
-            _mon.register_event_listener(_on_event)
-            _mon.register_event_duration_secs_listener(_on_duration)
+        if not _installed:
+            from jax import monitoring
+            monitoring.register_event_listener(_on_event)
+            monitoring.register_event_duration_secs_listener(_on_duration)
             _installed = True
-        except Exception as exc:
-            # an all-zero COMPILE_STATS is indistinguishable from a
-            # perfectly warm cache downstream (bench warm_cache_hits,
-            # ui/report's Compilation section) — warn ONCE instead of
-            # silently reporting success-shaped zeros
-            _install_failed = True
-            import warnings
-            warnings.warn(
-                f"compile-watcher registration failed ({exc!r}); "
-                f"compile accounting is disabled and COMPILE_STATS "
-                f"will read zero", stacklevel=2)
     return COMPILE_STATS
 
 
@@ -192,47 +178,36 @@ def configure_cache(cache_dir: Optional[str],
                     min_compile_time: Optional[float] = None) -> None:
     """Apply persistent-cache settings to the LIVE jax process.
 
-    ``cache_dir=None``/``""`` disables the cache. ``min_entry_size``
-    (bytes; -1 = cache everything) and ``min_compile_time`` (seconds;
-    0 = cache everything) gate which executables are worth persisting —
-    production defaults skip sub-second compiles, tests set both to the
-    cache-everything values. Installs the compile watcher whenever a
-    cache is enabled, so hit/miss accounting is always live alongside.
+    Which directory is decided in one place,
+    ``Environment.apply_compilation_cache()``; this function only
+    applies it. ``cache_dir=None``/``""`` disables the cache.
+    ``min_entry_size`` (bytes; -1 = cache everything) and
+    ``min_compile_time`` (seconds; 0 = cache everything) gate which
+    executables are worth persisting — production defaults skip
+    sub-second compiles, tests set both to the cache-everything values.
+    Installs the compile watcher whenever a cache is enabled, so
+    hit/miss accounting is always live alongside.
     """
     import jax
     target = cache_dir or None
-    dir_changed = jax.config.jax_compilation_cache_dir != target
-    jax.config.update("jax_compilation_cache_dir", target)
+    if jax.config.jax_compilation_cache_dir != target:
+        jax.config.update("jax_compilation_cache_dir", target)
+        # jax initializes its cache object AT MOST ONCE, on the first
+        # compile — if anything compiled before this call the cache
+        # latched its old state and the config update above would never
+        # take effect. Reset to pristine so the next compile re-reads
+        # the config. Skipped when the dir is already the live value
+        # (the admission knobs are read per-put), so repeated applies —
+        # every fit() and serving warmup calls this — don't tear down
+        # and re-create the cache backend.
+        from jax._src import compilation_cache
+        compilation_cache.reset_cache()
     if min_entry_size is not None:
         jax.config.update("jax_persistent_cache_min_entry_size_bytes",
                           int(min_entry_size))
     if min_compile_time is not None:
         jax.config.update("jax_persistent_cache_min_compile_time_secs",
                           float(min_compile_time))
-    if dir_changed:
-        try:
-            # jax initializes its cache object AT MOST ONCE, on the
-            # first compile — if anything compiled before this call
-            # (importing the framework compiles a few eager helpers),
-            # the cache latched "disabled" and the config update above
-            # would silently never take effect. Reset to pristine so the
-            # next compile re-reads the config — this is what makes a
-            # LATE set() actually work. Skipped when the dir is already
-            # the live value (the admission knobs are read per-put), so
-            # repeated applies — serving warmup calls this once per
-            # bucket — don't tear down and re-create the cache backend.
-            from jax._src import compilation_cache as _cc
-            _cc.reset_cache()
-        except Exception as exc:
-            # without the reset, a cache object latched "disabled" by a
-            # pre-config compile stays disabled — the exact late-set()
-            # bug this module exists to fix — so say so instead of
-            # silently recompiling everything on every restart
-            import warnings
-            warnings.warn(
-                f"compilation-cache reset failed ({exc!r}); if anything "
-                f"compiled before this call the persistent cache may "
-                f"remain disabled for this process", stacklevel=2)
     if cache_dir:
         install_compile_watcher()
 

@@ -19,6 +19,14 @@ from typing import Any, Callable, Dict, Optional
 
 _TRUE = ("1", "true", "yes", "on")
 
+# Where the persistent compilation cache lives when
+# $JAX_COMPILATION_CACHE_DIR does not say: one fixed directory in the
+# checkout, next to pyproject.toml — never a temporary name, a pid or a
+# time, because a cache that moves between runs never hits.
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    ".jax_cache")
+
 
 def _as_bool(v: str) -> bool:
     return str(v).strip().lower() in _TRUE
@@ -70,11 +78,12 @@ PROPERTIES: Dict[str, PropertySpec] = {
         "Whether the XLA client preallocates the memory pool at startup.",
         startup_only=True),
     "compilation_cache_dir": PropertySpec(
-        "JAX_COMPILATION_CACHE_DIR", str, "",
+        "JAX_COMPILATION_CACHE_DIR", str, DEFAULT_CACHE_DIR,
         "Persistent XLA compilation cache directory (first-compile "
-        "latency amortization across process restarts). Applied LIVE "
-        "through jax.config — set() works after import, '' disables "
-        "(docs/cold_start.md)."),
+        "latency amortization across process restarts). The environment "
+        "variable places it from outside; unset, it is .jax_cache/ in "
+        "the checkout. Applied LIVE through jax.config by fit(), "
+        "precompile() and serving warmup (docs/cold_start.md)."),
     "compilation_cache_min_entry_size": PropertySpec(
         "JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES", int, 0,
         "Smallest executable (bytes) worth persisting to the "
@@ -153,12 +162,8 @@ class Environment:
             # accepting the write. ``for_restart=True`` opts into the
             # write-the-env-var behavior for child processes / the next
             # start.
-            try:
-                import jax._src.xla_bridge as _xb
-                backend_up = bool(getattr(_xb, "_backends", None))
-            except Exception:
-                backend_up = True      # unknown -> assume live
-            if backend_up and not for_restart:
+            from jax._src import xla_bridge
+            if xla_bridge._backends and not for_restart:
                 raise RuntimeError(
                     f"property {name!r} (${spec.key}) is read once at "
                     f"backend initialization and the backend is already "
@@ -239,14 +244,16 @@ class Environment:
 
     def apply_compilation_cache(self) -> "Environment":
         """Push the resolved compilation-cache properties into the live
-        JAX config. Properties still at their catalog default are left
-        alone (a direct ``jax.config.update`` by the user wins), so this
-        is safe to call from every startup path — ``SameDiff
-        .precompile()``, serving warmup and the ``cold_start`` bench all
-        do, making ``$JAX_COMPILATION_CACHE_DIR`` set after import (or a
-        programmatic ``set()``) take effect at the next compile."""
-        for n in ("compilation_cache_dir",
-                  "compilation_cache_min_entry_size",
+        JAX config — the ONE place the cache directory is decided:
+        ``$JAX_COMPILATION_CACHE_DIR`` where set, else
+        :data:`DEFAULT_CACHE_DIR`. Every path that compiles a model
+        program calls this first (``SameDiff.fit()``/``precompile()``,
+        serving warmup, ``bench.py``, ``chip_smoke.py``), so no run is
+        without a cache and none sets a directory of its own. The
+        admission knobs still at their catalog default are left alone
+        (a direct ``jax.config.update`` by the user wins)."""
+        self._apply_side_effects("compilation_cache_dir")
+        for n in ("compilation_cache_min_entry_size",
                   "compilation_cache_min_compile_time"):
             if self._source(n) != "default":
                 self._apply_side_effects(n)

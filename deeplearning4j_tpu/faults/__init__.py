@@ -1,6 +1,6 @@
 """faults/ — the robustness layer: detect → decide → recover.
 
-- ``errors``    : structured fault taxonomy (step/epoch/batch provenance)
+- ``errors``    : structured fault hierarchy (step/epoch/batch provenance)
 - ``sentinels`` : device-side divergence sentinel semantics + host-side
   loss-spike / plateau watchers (the per-layer ``LayerHealthWatcher``
   lives in monitor/tensorstats.py — it rides the in-graph tensor

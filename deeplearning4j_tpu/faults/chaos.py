@@ -59,7 +59,7 @@ generator. Faults on offer (the ones the recovery rail must survive):
   (XLA does not raise on NaN; the resilient dispatcher must detect the
   non-finite output rows and quarantine exactly this request).
 - ``resource_exhausted(at_call)`` / ``oom_serving(server, at_call)`` —
-  synthetic device OOM (a real ``XlaRuntimeError`` with the
+  synthetic device OOM (a real ``JaxRuntimeError`` with the
   ``RESOURCE_EXHAUSTED:`` status) from the training dispatch / serving
   exec path: drives the OOM-forensics e2e — the exec paths must
   convert it to a structured ``memory.MemoryExhaustedError`` and the
@@ -98,18 +98,13 @@ from deeplearning4j_tpu.faults.errors import TransientDeviceError
 
 def _synthetic_resource_exhausted(nbytes: int) -> BaseException:
     """The backend's allocation-failure error, synthesized: a real
-    ``XlaRuntimeError`` with the ``RESOURCE_EXHAUSTED:`` status (so the
-    exec paths' detection — type AND message — exercises exactly the
-    production code path), falling back to a same-named RuntimeError
-    subclass where jaxlib's type is not constructible."""
-    msg = (f"RESOURCE_EXHAUSTED: chaos: out of memory while trying to "
-           f"allocate {int(nbytes)} bytes")
-    try:
-        from jaxlib.xla_extension import XlaRuntimeError
-        return XlaRuntimeError(msg)
-    except Exception:       # pragma: no cover - jaxlib layout drift
-        cls = type("XlaRuntimeError", (RuntimeError,), {})
-        return cls(msg)
+    ``jax.errors.JaxRuntimeError`` with the ``RESOURCE_EXHAUSTED:``
+    status, so the exec paths' detection — type AND message —
+    exercises exactly the production code path."""
+    from jax.errors import JaxRuntimeError
+    return JaxRuntimeError(
+        f"RESOURCE_EXHAUSTED: chaos: out of memory while trying to "
+        f"allocate {int(nbytes)} bytes")
 
 
 class ChaosSpec:

@@ -1,4 +1,4 @@
-"""Structured fault taxonomy for the training stack.
+"""Structured fault hierarchy for the training stack.
 
 Every error the detect → decide → recover loop routes on carries machine-
 readable provenance (absolute step, epoch, batch index, cause tag) so the
@@ -109,7 +109,7 @@ class TrainingStalledError(FaultError):
     """A blocking device boundary (window dispatch, flush device_get,
     serving exec, checkpoint capture) exceeded its adaptive stall
     deadline (integrity/watchdog.py) — the non-raising failure class:
-    a wedged collective, a hung host↔device transfer, a dead tunnel.
+    a wedged collective, a hung host↔device transfer, a lost device.
     RETRYABLE: a stall that eventually un-wedges (transient network
     partition, a straggling peer that recovers) heals through the
     normal rollback path; a permanent wedge never returns from the
@@ -174,7 +174,7 @@ class SilentCorruptionError(FaultError):
 
 def retryable_errors() -> tuple:
     """Exception classes the recovery driver treats as recoverable:
-    the structured fault taxonomy, numerics panics from the fit tiers,
+    the structured fault hierarchy, numerics panics from the fit tiers,
     checkpoint-write failures (``CheckpointError`` — which covers
     ``TopologyChangedError``/``ShardCountMismatchError``, the elastic
     topology-change signals routed through resharded restore), and the
@@ -186,9 +186,6 @@ def retryable_errors() -> tuple:
     types.append(NumericsException)
     from deeplearning4j_tpu.checkpoint.manager import CheckpointError
     types.append(CheckpointError)
-    try:
-        from jax.errors import JaxRuntimeError
-        types.append(JaxRuntimeError)
-    except ImportError:      # pragma: no cover - older jax
-        pass
+    from jax.errors import JaxRuntimeError
+    types.append(JaxRuntimeError)
     return tuple(types)

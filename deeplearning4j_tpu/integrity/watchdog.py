@@ -1,7 +1,7 @@
 """Stall watchdog: adaptive deadlines around blocking device boundaries.
 
 The fault rail (faults/) handles failures that RAISE; a wedged
-collective, a dead TPU tunnel or a hung host↔device transfer raises
+collective, a lost device or a hung host↔device transfer raises
 nothing — the process just stops making progress with healthy-looking
 /healthz. This module arms a daemon heartbeat thread over every
 blocking device boundary the tracer already names:

@@ -1,9 +1,9 @@
 """HBM memory observability: live telemetry, compiled-program memory
 plans, and OOM forensics.
 
-PROFILE.md rounds 5–6 did the 16 GB HBM budget math for gpt_medium **by
-hand** ("f32 masters + Adam m/v 6 GB + grads 2 GB + … logits 2.1 GB"),
-and an OOM surfaced as a raw ``RESOURCE_EXHAUSTED`` with no breakdown.
+The 16 GB HBM budget of gpt_medium used to be worked out **by hand**
+("f32 masters + Adam m/v 6 GB + grads 2 GB + … logits 2.1 GB"), and an
+OOM surfaced as a raw ``RESOURCE_EXHAUSTED`` with no breakdown.
 This module makes memory a first-class observable on the same "ride
 existing flush boundaries, bit-identical when on" discipline as the
 rest of monitor/:
@@ -346,19 +346,14 @@ def check_headroom(required_bytes: int, what: str,
 # OOM forensics
 def is_resource_exhausted(exc: BaseException) -> bool:
     """Is this the backend's allocation-failure error? XLA surfaces it
-    as ``XlaRuntimeError`` with a ``RESOURCE_EXHAUSTED:`` status (the
+    as ``JaxRuntimeError`` with a ``RESOURCE_EXHAUSTED:`` status (the
     chaos injector raises the same type+message)."""
     if isinstance(exc, MemoryExhaustedError):
         return False                 # already converted
     if "RESOURCE_EXHAUSTED" not in str(exc):
         return False
-    try:
-        from jax.errors import JaxRuntimeError
-        if isinstance(exc, JaxRuntimeError):
-            return True
-    except ImportError:              # pragma: no cover - older jax
-        pass
-    return type(exc).__name__ == "XlaRuntimeError"
+    from jax.errors import JaxRuntimeError
+    return isinstance(exc, JaxRuntimeError)
 
 
 def oom_error(cause: BaseException, program: Optional[str] = None,
@@ -426,32 +421,34 @@ def promote_dispatch(disp, args: Tuple, sig, label: str,
 
 # ---------------------------------------------------------------------
 # MFU estimate
-#: device-kind substring -> peak dense FLOPs/s per chip (bf16). The
-#: bench's V5E number; extend as kinds show up. Overridable via the
-#: DL4J_PEAK_FLOPS env var (any accelerator, CI on CPU).
+#: THE peak-rate table: ``device_kind`` substring (lower case, first
+#: match wins) -> peak dense bf16 FLOP/s of one chip, each with its
+#: source. bench.py and chip_smoke.py read it too; a kind that is not
+#: listed has no peak (an error where a utilization is printed, an
+#: absent gauge here) and nothing in the environment can invent one.
 _PEAK_FLOPS_BY_KIND = (
-    ("v5 lite", 394.0e12), ("v5e", 394.0e12),
+    # Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16 per chip
+    # (393 TOP/s is its int8 rate). PJRT reports this chip as
+    # "TPU v5 lite".
+    ("v5 lite", 197.0e12), ("v5e", 197.0e12),
+    # Google Cloud documentation, "TPU v5p": 459 TFLOP/s bf16
     ("v5p", 459.0e12), ("v5", 459.0e12),
-    ("v4", 275.0e12), ("v6", 918.0e12),
+    # Google Cloud documentation, "TPU v4": 275 TFLOP/s bf16
+    ("v4", 275.0e12),
+    # Google Cloud documentation, "TPU v6e": 918 TFLOP/s bf16
+    ("v6", 918.0e12),
 )
 
 
-def peak_flops() -> Optional[float]:
-    """Peak FLOPs/s for the MFU denominator: the ``DL4J_PEAK_FLOPS``
-    env var when set, else a device-kind table, else None (no MFU
-    gauge — better absent than wrong)."""
-    import os
-    env = os.environ.get("DL4J_PEAK_FLOPS")
-    if env:
-        try:
-            return float(env)
-        except ValueError:
-            pass
-    try:
+def peak_flops(device_kind: Optional[str] = None) -> Optional[float]:
+    """Peak bf16 FLOP/s for the MFU denominator from
+    :data:`_PEAK_FLOPS_BY_KIND`, for ``device_kind`` (default: the
+    first local device's). None for a kind the table does not list —
+    no MFU gauge is better than a wrong one."""
+    if device_kind is None:
         import jax
-        kind = jax.local_devices()[0].device_kind.lower()
-    except Exception:
-        return None
+        device_kind = jax.local_devices()[0].device_kind
+    kind = device_kind.lower()
     for sub, flops in _PEAK_FLOPS_BY_KIND:
         if sub in kind:
             return flops

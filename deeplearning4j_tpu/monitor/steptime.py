@@ -1,8 +1,7 @@
 """Per-window step-time attribution: data-wait vs dispatch vs flush.
 
-BENCH_r05 found the per-step fit tier dispatch-bound (~1.8 % MFU on
-lenet) by HAND-instrumenting the loop; this module makes that breakdown
-a standing observable. The window executor (autodiff/window.py) and the
+Where a step's wall time goes used to be found by HAND-instrumenting
+the loop; this module makes that breakdown a standing observable. The window executor (autodiff/window.py) and the
 per-step tier (samediff.fit) already emit ``window``/``step`` spans
 with ``data_wait`` / ``dispatch`` / ``flush`` children into
 ``monitor.trace.TRACER``; :class:`MonitorListener` drains those spans

@@ -26,20 +26,30 @@ from deeplearning4j_tpu.ndarray.ndarray import NDArray, _as_jax
 # counter-based equivalent)
 # ----------------------------------------------------------------------
 class Random:
-    """Stateful wrapper over jax's splittable PRNG."""
+    """Stateful wrapper over jax's splittable PRNG.
+
+    The key is built on the first ``next_key()``, not here:
+    ``jax.random.key`` initialises the backend, and the module-level
+    instance below must not take the chip from a process that only
+    imports the package (a chip belongs to one process).
+    """
 
     def __init__(self, seed: int = 0):
         self._lock = threading.Lock()
-        self._key = jax.random.key(seed)
+        self._seed = int(seed)
+        self._key: Optional[jax.Array] = None
 
     def set_seed(self, seed: int) -> None:
         with self._lock:
-            self._key = jax.random.key(seed)
+            self._seed = int(seed)
+            self._key = None
 
     setSeed = set_seed
 
     def next_key(self) -> jax.Array:
         with self._lock:
+            if self._key is None:
+                self._key = jax.random.key(self._seed)
             self._key, sub = jax.random.split(self._key)
             return sub
 

@@ -43,8 +43,8 @@ class MultiLayerConfiguration:
     dtype: str = "float32"
     grad_clip_value: Optional[float] = None
     mixed_precision: Optional[MixedPrecision] = None
-    # internal cnn tensor layout; "NHWC" is TPU-native (12x conv speedup vs
-    # logical NCHW, see PROFILE.md). External API stays NCHW either way.
+    # internal cnn tensor layout; "NHWC" is TPU-native (logical NCHW
+    # costs a physical transpose per conv). External API stays NCHW either way.
     # from_json defaults to "NCHW" so checkpoints saved before this field
     # existed keep their trained flatten-order weights valid.
     cnn_data_format: str = "NHWC"

@@ -180,8 +180,7 @@ class BuildContext:
     # network's EXTERNAL contract stays NCHW (reference convention; users
     # feed/receive NCHW) — but internally the compiled graph runs NHWC:
     # logical-NCHW convs on TPU force physical transposes of every
-    # activation, measured 12x slower than the same net in NHWC (see
-    # PROFILE.md). One permute at the network input; zero in the body.
+    # activation. One permute at the network input; zero in the body.
     cnn_format: str = "NHWC"
 
     def lname(self, kind: str) -> str:

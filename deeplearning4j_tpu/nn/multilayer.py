@@ -123,7 +123,7 @@ def _final_output_type(conf: MultiLayerConfiguration) -> InputType:
 def _to_internal_layout(sd, x, itype: InputType, fmt: str, name: str):
     """Users feed NCHW (reference convention); internally cnn tensors run
     NHWC on TPU (one permute here, none in the network body — logical-NCHW
-    convs cost a physical transpose per op on TPU, see PROFILE.md)."""
+    convs cost a physical transpose per op on TPU)."""
     if fmt != "NHWC" or itype.kind not in ("cnn", "cnn3d"):
         return x
     axes = (0, 2, 3, 1) if itype.kind == "cnn" else (0, 2, 3, 4, 1)

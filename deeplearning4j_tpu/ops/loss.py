@@ -35,9 +35,8 @@ def softmax_dtype_scope(dtype):
     losses are STILL reduced to the scalar loss in f32 (the accumulation
     is where bf16 actually loses training signal); what changes is the
     [batch..., vocab]-shaped exp/log/normalize tail — on a 32k vocab
-    that tail is the single largest f32 tensor in a bf16 LM step
-    (PROFILE.md round 5) and the MXU/VPU runs it at twice the rate in
-    bf16. Routed from ``MixedPrecision.softmax_dtype``
+    that tail is the single largest f32 tensor in a bf16 LM step and
+    the MXU/VPU runs it at twice the rate in bf16. Routed from ``MixedPrecision.softmax_dtype``
     (docs/training_performance.md)."""
     token = _SOFTMAX_DTYPE.set(None if dtype is None else jnp.dtype(dtype))
     try:

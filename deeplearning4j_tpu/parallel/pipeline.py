@@ -28,7 +28,7 @@ from typing import Any, Callable, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from deeplearning4j_tpu.parallel.mesh import DATA_AXIS, PIPE_AXIS, DeviceMesh
@@ -115,11 +115,6 @@ def pipeline_forward(stage_fn: Callable, mesh: DeviceMesh,
         outs = jnp.where(stage == S - 1, outs, jnp.zeros_like(outs))
         return lax.psum(outs, PIPE_AXIS)
 
-    try:
-        from jax import shard_map
-    except ImportError:                       # older jax
-        from jax.experimental.shard_map import shard_map
-
     def fn(stage_params, microbatches, *extra):
         pspecs = (param_specs if param_specs is not None else
                   jax.tree_util.tree_map(lambda _: pspec, stage_params))
@@ -127,11 +122,8 @@ def pipeline_forward(stage_fn: Callable, mesh: DeviceMesh,
                   in_specs=(pspecs, xspec) + tuple(
                       extra_specs or (xspec,) * len(extra)),
                   out_specs=xspec)
-        try:
-            sm = shard_map(_pp, check_vma=False, **kw)   # jax >= 0.8
-        except TypeError:
-            sm = shard_map(_pp, check_rep=False, **kw)
-        return sm(stage_params, microbatches, *extra)
+        return shard_map(_pp, check_vma=False, **kw)(
+            stage_params, microbatches, *extra)
 
     return fn
 

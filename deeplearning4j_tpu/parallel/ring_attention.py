@@ -25,27 +25,16 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
-
-try:
-    from jax import shard_map as _shard_map
-except ImportError:                           # older jax
-    from jax.experimental.shard_map import shard_map as _shard_map
 
 from deeplearning4j_tpu.parallel import collectives
 from deeplearning4j_tpu.parallel.mesh import SEQ_AXIS, DeviceMesh
 
 
 def _shard_map_norep(**kw):
-    """shard_map with the replication check off, across jax versions
-    (>= 0.8 spells it check_vma; older, check_rep)."""
-    def deco(f):
-        try:
-            return _shard_map(f, check_vma=False, **kw)
-        except TypeError:
-            return _shard_map(f, check_rep=False, **kw)
-    return deco
+    """shard_map as a decorator, with the replication check off."""
+    return lambda f: shard_map(f, check_vma=False, **kw)
 
 
 def _block_attn(q, k, v, m, l, o, scale, mask=None):

@@ -33,12 +33,26 @@ def shard_model(model_or_sd, strategy: ShardingStrategy) -> None:
     resharded-restore path in checkpoint/reshard.py)."""
     sd = getattr(model_or_sd, "samediff", model_or_sd)
     st = strategy
+    moved = False
+
+    def _place(v, sharding):
+        nonlocal moved
+        moved = moved or not v.sharding.is_equivalent_to(sharding, v.ndim)
+        return jax.device_put(v, sharding)
+
     for n, v in sd.trainable_params().items():
-        sd._arrays[n] = jax.device_put(v, st.param_sharding(n, v.ndim))
+        sd._arrays[n] = _place(v, st.param_sharding(n, v.ndim))
     for n, v in sd.state_vars_map().items():
-        sd._arrays[n] = jax.device_put(v, st.param_sharding(n, v.ndim))
+        sd._arrays[n] = _place(v, st.param_sharding(n, v.ndim))
     for n, v in sd.constants_map().items():
-        sd._arrays[n] = jax.device_put(v, st.replicated())
+        sd._arrays[n] = _place(v, st.replicated())
+    if moved:
+        # executables precompiled for the old placement would reject
+        # the re-placed arrays (compilecache/aot.py)
+        sd._drop_aot_executables()
+    # precompile() reads each array's placement off the array; what it
+    # cannot see there is how fit() will shard the BATCHES
+    sd._placement_strategy = st
     if sd._updater_state is not None:
         # updater state leaves mirror their parameter's sharding
         new_state = {}
@@ -260,10 +274,16 @@ class ParallelInference:
         a sharded-to-fit model is never forcibly replicated."""
         sd, st = self.sd, self.strategy
         mesh_devices = frozenset(self.strategy.mesh.mesh.devices.flat)
+        moved = False
         for n, v in {**sd.trainable_params(), **sd.state_vars_map(),
                      **sd.constants_map()}.items():
             if frozenset(v.sharding.device_set) != mesh_devices:
                 sd._arrays[n] = jax.device_put(v, st.replicated())
+                moved = True
+        if moved:
+            # as in shard_model: executables lowered for the old
+            # placement would reject the re-placed arrays
+            sd._drop_aot_executables()
 
     def output(self, x, output_names: Optional[Sequence[str]] = None):
         if hasattr(self.model, "_sync_infer"):

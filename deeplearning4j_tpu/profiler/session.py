@@ -99,8 +99,7 @@ class ProfilerSession:
         which fused training produces by construction, the proportional
         split is exact up to scheduling jitter). Each correlated span
         gains a ``device_ms_est`` arg (visible in the chrome trace) and
-        the summary reports device utilization over the window wall time
-        — the MFU-shaped number BENCH_r05 had to derive by hand.
+        the summary reports device utilization over the window wall time.
         """
         if spans is None:
             if tracer is None:

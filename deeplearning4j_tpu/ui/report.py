@@ -237,8 +237,7 @@ _PLAN_COLORS = (("argument_bytes", "#1f77b4", "arguments"),
 
 def _svg_budget(plans: List[dict], w=640, h=220, label="") -> str:
     """Stacked per-program memory-budget bars (one bar per captured
-    plan: argument/temp/output/generated-code bytes stacked) — the
-    chart version of PROFILE.md's hand-computed HBM breakdown."""
+    plan: argument/temp/output/generated-code bytes stacked)."""
     plans = [p for p in plans
              if any(p.get(k) for k, _, _ in _PLAN_COLORS)]
     if not plans:

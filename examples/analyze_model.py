@@ -92,6 +92,7 @@ print("\nwarm fit reused the cached report "
       f"(one-time cost {first.seconds:.3f}s, ~0 per-fit after)")
 
 # -- 5. the CLI runs the same rules on a saved artifact --------------------
+import os
 import subprocess
 import sys
 import tempfile
@@ -99,9 +100,13 @@ import tempfile
 with tempfile.TemporaryDirectory() as d:
     path = f"{d}/model.zip"
     build_mlp(w0_rows=13).save(path)
+    # this process has trained on the accelerator and holds it (a chip
+    # belongs to one process); the analyzer compiles nothing, so its
+    # child is pinned to the CPU instead of reaching for the same chip
     proc = subprocess.run(
         [sys.executable, "-m", "deeplearning4j_tpu.analyze", path],
-        capture_output=True, text=True)
+        capture_output=True, text=True,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"))
     print(f"\nCLI exit code {proc.returncode} (1 = error findings):")
     print(proc.stdout.splitlines()[0])
 print("done.")

@@ -1,7 +1,7 @@
 """Spike: Pallas fused BN(+ReLU) backward vs XLA's jax.grad fusions.
 
-PROFILE.md round-4 named "a Pallas fused conv-epilogue/BN kernel" as the
-next lever for ResNet-50. This measures whether a hand-written two-phase
+"A Pallas fused conv-epilogue/BN kernel" was once named as the next
+lever for ResNet-50. This measures whether a hand-written two-phase
 Pallas backward (the pass-count-optimal schedule: reduction pass over
 (x, dy) then dx pass over (x, dy)) beats the fusions XLA derives from
 jax.grad of the same chain, on the real chip at ResNet stage shapes.

@@ -99,14 +99,43 @@ def _params(sd):
 def test_cache_dir_set_applies_live_and_reset_undoes(tmp_path):
     env = environment()
     d = str(tmp_path / "cc")
-    before = jax.config.jax_compilation_cache_dir
     env.set("compilation_cache_dir", d)
     try:
         assert jax.config.jax_compilation_cache_dir == d
         assert env.compilation_cache_dir() == d
     finally:
         env.reset("compilation_cache_dir")
-    assert jax.config.jax_compilation_cache_dir in (before, None)
+    # reset() re-applies the resolved placement, never "no cache"
+    assert jax.config.jax_compilation_cache_dir == \
+        env.compilation_cache_dir() != d
+
+
+def test_cache_dir_placed_from_outside_or_fixed_in_checkout(
+        monkeypatch, tmp_path):
+    """$JAX_COMPILATION_CACHE_DIR set -> the cache lives there and no
+    code path (fit, precompile) moves it; unset -> the one fixed
+    directory in the checkout, next to pyproject.toml."""
+    env = environment()
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    fixed = os.path.join(repo, ".jax_cache")
+    assert os.path.exists(os.path.join(repo, "pyproject.toml"))
+    assert env.compilation_cache_dir() == fixed
+    try:
+        outside = str(tmp_path / "placed")
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", outside)
+        sd = _mlp()
+        sd.fit(_data(n=16), epochs=1)
+        assert jax.config.jax_compilation_cache_dir == outside
+        sd.precompile(batch_size=8)
+        assert jax.config.jax_compilation_cache_dir == outside
+        assert os.environ["JAX_COMPILATION_CACHE_DIR"] == outside
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        _mlp().fit(_data(n=16), epochs=1)
+        assert jax.config.jax_compilation_cache_dir == fixed
+    finally:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        env.apply_compilation_cache()
 
 
 def test_cache_admission_knobs_apply_live():
@@ -263,10 +292,11 @@ def test_precompile_unpredicted_shape_falls_back_to_lazy():
     assert np.isfinite(h.loss_curve.losses[0])
 
 
-def test_aot_dispatch_sharding_mismatch_falls_back_to_lazy():
-    # a jax Compiled raises ValueError (not TypeError) when called with
-    # mesh-committed inputs against an executable lowered from unsharded
-    # specs — the dispatch must degrade to lazy jit, not crash mid-fit
+def test_aot_dispatch_predicted_shape_rejection_is_not_absorbed():
+    # a jax Compiled raises ValueError when called with mesh-committed
+    # inputs against an executable lowered from unsharded specs. That
+    # is a defect at the lowering site; re-dispatching through lazy jit
+    # would hide it as a compile under the first request, so it raises
     import jax.numpy as jnp
     from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
@@ -275,12 +305,145 @@ def test_aot_dispatch_sharding_mismatch_falls_back_to_lazy():
     spec = {"x": jax.ShapeDtypeStruct((8, 4), jnp.float32)}
     disp.aot[ph_shape_sig(spec)] = disp.lower(spec).compile()
     mesh = Mesh(np.array(jax.devices()[:2]), ("data",))
+    x = np.arange(32, dtype=np.float32).reshape(8, 4)
     sharded = jax.device_put(
-        np.arange(32, dtype=np.float32).reshape(8, 4),
-        NamedSharding(mesh, PartitionSpec("data", None)))
-    out = disp({"x": sharded})          # must not raise
-    assert np.array_equal(np.asarray(out["x"]),
-                          np.arange(32, dtype=np.float32).reshape(8, 4) * 2)
+        x, NamedSharding(mesh, PartitionSpec("data", None)))
+    with pytest.raises(ValueError, match="shardings"):
+        disp({"x": sharded})
+    # a shape nobody predicted still takes the lazy path
+    out = disp({"x": x[:3]})
+    assert np.array_equal(np.asarray(out["x"]), x[:3] * 2)
+
+
+def test_resharding_drops_stale_aot_executables():
+    """Executables are lowered for one placement; moving the model to a
+    mesh must forget them (the lazy jit re-specialises), or the next
+    dispatch of a precompiled shape is rejected."""
+    from deeplearning4j_tpu.parallel import DeviceMesh, data_parallel
+    from deeplearning4j_tpu.parallel.trainer import shard_model
+    sd = _mlp(fused_steps=2)
+    sd.precompile(batch_size=8)
+    disp = sd.make_train_window(accum_steps=1)
+    assert disp.aot
+    strategy = data_parallel(DeviceMesh.create(devices=jax.devices()[:2]))
+    shard_model(sd, strategy)
+    assert not disp.aot
+    sd.precompile(batch_size=8)
+    shard_model(sd, strategy)           # same placement: nothing moved
+    assert disp.aot
+
+
+@pytest.mark.parametrize("fused_steps", [1, 4])
+def test_precompile_under_sharding_lowers_for_the_mesh(fused_steps):
+    """TrainingConfig.sharding: fit() places the model on the mesh and
+    feeds sharded batches, so precompile() must lower for exactly those
+    placements — then the sharded fit dispatches the prebuilt
+    executables and compiles nothing (bare-shape lowering used to be
+    rejected and silently recompiled under the first window)."""
+    from deeplearning4j_tpu.parallel import ShardingSpec
+
+    def sharded(seed=0):
+        sd = _mlp(seed=seed, fused_steps=fused_steps)
+        sd.training_config.sharding = ShardingSpec(
+            axes={"data": 2, "model": 2}, preset="tensor_parallel")
+        return sd
+
+    data = _data(n=96, batch=8)         # 12 batches: windows 4,4,4
+    listeners = [_quiet_listener()] if fused_steps > 1 else []
+    sharded().fit(data, epochs=2, listeners=listeners)   # eager helpers
+    sd = sharded()
+    info = sd.precompile(batch_size=8)
+    assert info["compiled"] >= 1
+    w0 = sd.trainable_params()["w0"]
+    assert len({s.device for s in w0.addressable_shards}) == 4
+    mark = COMPILE_STATS.mark()
+    sd.fit(data, epochs=2, listeners=listeners)
+    assert COMPILE_STATS.delta(mark)["backend_compiles"] == 0
+    disp = sd.make_train_window(accum_steps=1) if fused_steps > 1 \
+        else sd.make_train_step()
+    assert disp.aot, "the sharded fit dropped the precompiled programs"
+
+
+def test_precompile_after_parallel_trainer_placement():
+    """No TrainingConfig.sharding: the placement comes from a
+    ParallelTrainer. precompile() after it lowers for that placement,
+    and the trainer's fit dispatches the executables as they are."""
+    from deeplearning4j_tpu.parallel import (DeviceMesh, ParallelTrainer,
+                                             data_parallel)
+    data = _data(n=96, batch=8)
+    mesh = DeviceMesh.create(devices=jax.devices()[:4])
+
+    def trainer():
+        return ParallelTrainer(_mlp(fused_steps=4), data_parallel(mesh))
+
+    trainer().fit(data, epochs=1, listeners=[_quiet_listener()])
+    t = trainer()
+    t.shard_params()
+    assert t.sd.precompile(batch_size=8)["compiled"] == 3
+    mark = COMPILE_STATS.mark()
+    t.fit(data, epochs=1, listeners=[_quiet_listener()])
+    assert COMPILE_STATS.delta(mark)["backend_compiles"] == 0
+    assert t.sd.make_train_window(accum_steps=1).aot
+
+
+def test_serving_warmup_of_a_mesh_placed_model_zero_compiles():
+    """shard_params, THEN warm, then serve: precompile_output lowers
+    for the placement the arrays carry, so the first request runs the
+    warmed executable (a bare-shape lowering rejected the mesh-committed
+    parameters — and nothing absorbs that any more)."""
+    from deeplearning4j_tpu.parallel import (DeviceMesh, ParallelTrainer,
+                                             data_parallel)
+    from deeplearning4j_tpu.serving import InferenceMode, ParallelInference
+    net = _net()
+    x = np.random.default_rng(0).normal(size=(3, N_IN)).astype(np.float32)
+    want = np.asarray(net.output(x).to_numpy())
+    mesh = DeviceMesh.create(devices=jax.devices()[:4])
+    ParallelTrainer(net, data_parallel(mesh)).shard_params()
+    pi = ParallelInference(net, mode=InferenceMode.BATCHED,
+                           max_batch_size=8, max_delay_ms=1.0,
+                           warmup_buckets=True)
+    try:
+        mark = COMPILE_STATS.mark()
+        got = np.asarray(pi.output(x))
+        assert COMPILE_STATS.delta(mark)["backend_compiles"] == 0
+        assert pi.metrics.counters["compiles"] == 0
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+    finally:
+        pi.shutdown()
+
+
+def test_precompile_output_after_a_sharded_fit():
+    """A fit under TrainingConfig.sharding leaves mesh-committed
+    parameters behind; precompile_output + output on them compiles
+    once, at precompile."""
+    from deeplearning4j_tpu.parallel import ShardingSpec
+    sd = _mlp()
+    sd.training_config.sharding = ShardingSpec(
+        axes={"data": 2, "model": 2}, preset="tensor_parallel")
+    sd.fit(_data(n=32), epochs=1)
+    x = _data(n=4, batch=4)[0][0]
+    lazy = np.asarray(sd.output({"x": x}, ["logits"])["logits"].to_numpy())
+    sd.precompile_output({"x": (8, N_IN)}, outputs=["logits"])
+    mark = COMPILE_STATS.mark()
+    x8 = np.concatenate([x, x])
+    got = np.asarray(sd.output({"x": x8}, ["logits"])["logits"].to_numpy())
+    assert COMPILE_STATS.delta(mark)["backend_compiles"] == 0
+    np.testing.assert_allclose(got[:4], lazy, rtol=1e-5, atol=1e-6)
+
+
+def test_mesh_inference_drops_executables_of_the_old_placement():
+    """parallel.ParallelInference replicates an unplaced model onto its
+    mesh; an output executable warmed before that move is forgotten
+    (the lazy jit re-specialises), not dispatched and rejected."""
+    from deeplearning4j_tpu.parallel import DeviceMesh, ParallelInference
+    net = _net()
+    x = np.random.default_rng(0).normal(size=(8, N_IN)).astype(np.float32)
+    want = np.asarray(net.output(x).to_numpy())
+    net._sd_infer.precompile_output({"input": (8, N_IN)}, ["output"])
+    pi = ParallelInference(
+        net, mesh=DeviceMesh.create(devices=jax.devices()[:4]))
+    np.testing.assert_allclose(np.asarray(pi.output(x).to_numpy()), want,
+                               rtol=1e-5, atol=1e-6)
 
 
 def test_precompile_needs_resolvable_batch_dims():
@@ -453,23 +616,29 @@ def test_ph_shape_sig_matches_window_accounting():
 
 
 # ---------------------------------------------------------------------------
-# the real thing: a fresh-process warm restart (bench.py cold_start child)
+# the real thing: a fresh-process warm restart (bench.py's cold_start probe)
 
 @pytest.mark.slow
 def test_cold_vs_warm_restart_subprocess(tmp_path):
+    """bench.py's restart probe in two fresh processes sharing a cache
+    placed from outside through $JAX_COMPILATION_CACHE_DIR, as
+    bench_cold_start places it. (bench.py's own entry point refuses the
+    CPU, so the probe function is called directly.)"""
     import json
     import subprocess
     import sys
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    bench = os.path.join(repo, "bench.py")
-    cache_dir = str(tmp_path / "restart_cache")
+    code = ("import json, bench; "
+            "print(json.dumps(bench._cold_start_probe('samediff_mlp')))")
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=repo,
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "restart_cache"),
+               JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES="-1",
+               JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0")
     runs = {}
     for phase in ("cold", "warm"):
         proc = subprocess.run(
-            [sys.executable, bench, "_cold_start_child", "samediff_mlp",
-             cache_dir],
-            capture_output=True, text=True, timeout=600, cwd=repo,
-            env=dict(os.environ, JAX_PLATFORMS="cpu"))
+            [sys.executable, "-c", code],
+            capture_output=True, text=True, timeout=600, cwd=repo, env=env)
         assert proc.returncode == 0, proc.stderr[-800:]
         runs[phase] = json.loads(proc.stdout.strip().splitlines()[-1])
     assert runs["cold"]["cache_hits"] == 0

@@ -255,7 +255,7 @@ class TestCaptureAndRestoreStamp:
         assert mgr.latest_verified_step() == 8
         mgr.close()
 
-    def test_retryable_taxonomy(self):
+    def test_retryable_hierarchy(self):
         types = retryable_errors()
         assert SilentCorruptionError in types
         assert TrainingStalledError in types

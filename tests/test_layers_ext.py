@@ -320,7 +320,7 @@ def test_wave2_serde_roundtrip():
 
 
 def test_layer_config_count_target():
-    """VERDICT round-4 target: >= 55 layer/vertex config types."""
+    """Breadth target: >= 55 layer/vertex config types."""
     from deeplearning4j_tpu.nn.graph import VERTEX_TYPES
     from deeplearning4j_tpu.nn.layers import LAYER_TYPES
     assert len(LAYER_TYPES) + len(VERTEX_TYPES) >= 55, \

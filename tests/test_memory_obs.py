@@ -361,7 +361,8 @@ class TestMemoryPlans:
         """Acceptance: /metrics exports dl4j_hbm_* gauges and a live
         MFU-estimate gauge MID-FIT (scraped from inside a listener
         flush while the fit is running)."""
-        monkeypatch.setenv("DL4J_PEAK_FLOPS", "1e12")
+        # the CPU is in no peak-rate table; give the gauge a denominator
+        monkeypatch.setattr(memstats, "peak_flops", lambda: 1e12)
         sd = _mlp(fused_steps=4)
         sd.precompile(batch_size=8)          # plans → MFU numerator
         storage = StatsStorage()

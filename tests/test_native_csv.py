@@ -92,3 +92,23 @@ def test_tab_delimiter_native(tmp_path):
     np.testing.assert_array_equal(got, [[1, 2], [3, 4]])
     got2 = CSVRecordReader(p, delimiter="\t").as_matrix()
     np.testing.assert_array_equal(got2, [[1, 2], [3, 4]])
+
+
+def test_library_is_keyed_by_its_source_hash(tmp_path, monkeypatch):
+    """A checkout resets file times and _build/ is untracked, so a
+    library left by another revision (here: garbage under the old,
+    unkeyed name) must never be the one that loads."""
+    import hashlib
+    import os
+    os.makedirs(native_build._BUILD, exist_ok=True)
+    leftover = os.path.join(native_build._BUILD, "libfastcsv.so")
+    with open(leftover, "wb") as f:
+        f.write(b"not a shared library")
+    os.utime(leftover, (2 ** 31, 2 ** 31))      # "newer" than the source
+    monkeypatch.setattr(native_build, "_loaded", {})
+    lib = native_build.load("fastcsv")
+    assert lib is not None, native_build.build_error("fastcsv")
+    with open(os.path.join(native_build._DIR, "fastcsv.cpp"), "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    assert os.path.basename(lib._name) == f"libfastcsv-{digest}.so"
+    os.remove(leftover)

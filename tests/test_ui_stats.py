@@ -136,7 +136,7 @@ class TestStatsPipeline:
 
 class TestZooModelReport:
     def test_lenet_training_produces_browsable_report(self, tmp_path):
-        """VERDICT round-4 'done' criterion: training a zoo model
+        """The UI's 'done' criterion: training a zoo model
         produces a browsable report with PerformanceListener-style
         numbers in it."""
         from deeplearning4j_tpu.dataset import load_mnist
