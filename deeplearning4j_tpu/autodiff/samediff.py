@@ -2001,6 +2001,11 @@ class SameDiff:
 
     def _fit_scanned(self, dataset_iterator, epochs: int):
         """fit() fast path: epochs of lax.scan over device-stacked batches."""
+        with _tracer.span("fit", cat="train", tier="scanned_epoch",
+                          epochs=epochs) as fit_span:
+            return self._fit_scanned_body(dataset_iterator, epochs, fit_span)
+
+    def _fit_scanned_body(self, dataset_iterator, epochs: int, fit_span):
         from deeplearning4j_tpu.autodiff.training import History
         tc = self.training_config
         use_sentinel = bool(getattr(tc, "sentinel", False))
@@ -2009,26 +2014,30 @@ class SameDiff:
         epoch_step = self.make_train_epoch(
             unroll=getattr(tc, "scan_unroll", 1) or 1,
             sentinel=use_sentinel, fingerprint=fp_on)
-        params = jax.tree_util.tree_map(jnp.copy, self.trainable_params())
-        svars = jax.tree_util.tree_map(jnp.copy, self.state_vars_map())
-        if self._updater_state is not None and \
-                set(self._updater_state.keys()) == set(params.keys()):
-            state = jax.tree_util.tree_map(jnp.copy, self._updater_state)
-        else:
-            state = tc.updater.init(params)
-        constants = self.constants_map()
-        iteration = getattr(tc, "iteration_count", 0)
-        it_dev = jnp.asarray(iteration, jnp.int32)
-        self._fit_base_seed = self._seed    # resumable RNG state, see fit()
-        base_key = jax.random.key(self._seed)
-        self._seed += 1
-        feats, labels = dataset_iterator.stacked_batches()
-        stacked = {}
-        for name, arr in list(zip(tc.data_set_feature_mapping, feats)) + \
-                list(zip(tc.data_set_label_mapping, labels)):
-            dt = self._vars[name].dtype if name in self._vars else None
-            stacked[name] = _to_jnp(arr, dt)
+        with _tracer.span("fit.stage", cat="train"):
+            params = jax.tree_util.tree_map(jnp.copy,
+                                            self.trainable_params())
+            svars = jax.tree_util.tree_map(jnp.copy, self.state_vars_map())
+            if self._updater_state is not None and \
+                    set(self._updater_state.keys()) == set(params.keys()):
+                state = jax.tree_util.tree_map(jnp.copy,
+                                               self._updater_state)
+            else:
+                state = tc.updater.init(params)
+            constants = self.constants_map()
+            iteration = getattr(tc, "iteration_count", 0)
+            it_dev = jnp.asarray(iteration, jnp.int32)
+            self._fit_base_seed = self._seed  # resumable RNG state, see fit()
+            base_key = jax.random.key(self._seed)
+            self._seed += 1
+            feats, labels = dataset_iterator.stacked_batches()
+            stacked = {}
+            for name, arr in list(zip(tc.data_set_feature_mapping, feats)) + \
+                    list(zip(tc.data_set_label_mapping, labels)):
+                dt = self._vars[name].dtype if name in self._vars else None
+                stacked[name] = _to_jnp(arr, dt)
         n_steps = next(iter(stacked.values())).shape[0]
+        fit_span.set(steps=n_steps)
         # memory-plan capture + OOM forensics for the scanned tier: one
         # signature per fit, promoted to an AOT compile when capture is
         # armed so /report can show the whole-epoch program's footprint
@@ -2045,8 +2054,9 @@ class SameDiff:
         panic = self._nan_panic_active(tc)
         for epoch in range(epochs):
             try:
-                res = epoch_step(params, svars, state, it_dev,
-                                 constants, stacked, base_key)
+                with _tracer.span("fit.dispatch", cat="train", epoch=epoch):
+                    res = epoch_step(params, svars, state, it_dev,
+                                     constants, stacked, base_key)
             except Exception as e:
                 memstats.reraise_oom(e, program=scan_label,
                                      step=iteration, epoch=epoch)
@@ -2056,7 +2066,8 @@ class SameDiff:
             params, svars, state, it_dev, losses = res[:5]
             r = 5
             if use_sentinel:
-                bad = int(res[r])  # one scalar sync per scanned epoch
+                with _tracer.span("fit.sync", cat="train"):
+                    bad = int(res[r])  # one scalar sync per scanned epoch
                 r += 1
                 if bad >= 0:
                     from deeplearning4j_tpu.faults.sentinels import \
@@ -2068,10 +2079,13 @@ class SameDiff:
                 last_fp = res[r]
                 r += 1
             m = jnp.mean(losses)
-            if panic and not np.isfinite(float(m)):
-                raise NumericsException(
-                    f"non-finite mean loss {float(m)} in scanned epoch "
-                    f"(nan_panic); localize with sd.exec_debug()")
+            if panic:
+                with _tracer.span("fit.sync", cat="train"):
+                    mean = float(m)
+                if not np.isfinite(mean):
+                    raise NumericsException(
+                        f"non-finite mean loss {mean} in scanned epoch "
+                        f"(nan_panic); localize with sd.exec_debug()")
             epoch_means.append(m)
             iteration += n_steps
             self.last_fit_stats = {
@@ -2080,19 +2094,23 @@ class SameDiff:
                 "dispatches_per_epoch": 1, "window_sizes": {n_steps: 1},
                 "window_compiles": 0}
         # ONE device fetch for all epoch means at fit end
-        fetched = np.asarray(jnp.stack(epoch_means))
-        for e in range(epochs):
-            history.add_epoch(e, float(fetched[e]))
-        for n, p in {**params, **svars}.items():
-            self._arrays[n] = p
-        self._updater_state = state
-        tc.iteration_count = iteration
-        tc.epoch_count = getattr(tc, "epoch_count", 0) + epochs
-        if last_fp is not None:
-            # the boundary digest a checkpoint capture after this fit
-            # verifies against (integrity/fingerprint.py)
-            self._device_fingerprint = {"iteration": int(iteration),
-                                        "fp": int(last_fp)}
+        with _tracer.span("fit.sync", cat="train"):
+            fetched = np.asarray(jnp.stack(epoch_means))
+            if last_fp is not None:
+                last_fp = int(last_fp)
+        with _tracer.span("fit.commit", cat="train"):
+            for e in range(epochs):
+                history.add_epoch(e, float(fetched[e]))
+            for n, p in {**params, **svars}.items():
+                self._arrays[n] = p
+            self._updater_state = state
+            tc.iteration_count = iteration
+            tc.epoch_count = getattr(tc, "epoch_count", 0) + epochs
+            if last_fp is not None:
+                # the boundary digest a checkpoint capture after this fit
+                # verifies against (integrity/fingerprint.py)
+                self._device_fingerprint = {"iteration": int(iteration),
+                                            "fp": last_fp}
         return history
 
     # ------------------------------------------------------------------

@@ -276,8 +276,12 @@ def assemble(spans: Iterable[Span], trace_id: int,
     ``segments`` (retry/failover/replay attempts in wall-clock order),
     ``phases`` (queue_wait/admission/prefill/decode/verify/reply
     totals + per-round counts), and a compact ``spans`` list for lane
-    rendering. ``outcome`` (the router's measurement) is merged in as
-    the authoritative ttft/e2e."""
+    rendering. ``prefill_ms``, ``decode_ms``, ``draft_ms`` and
+    ``verify_ms`` are dispatch-to-host-sync times: those spans end
+    when the step's tokens are on the host, so the device's time is in
+    them (``queue_wait_ms`` ends where the prefill span begins).
+    ``outcome`` (the router's measurement) is merged in as the
+    authoritative ttft/e2e."""
     tid = int(trace_id)
     mine: List[Span] = []
     shared: List[Tuple[Span, int]] = []

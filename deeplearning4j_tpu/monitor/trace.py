@@ -13,9 +13,10 @@ Design constraints, in order:
 1. **Near-zero cost disabled.** Instrumentation is compiled into the
    hot paths permanently (window executor, serving lifecycle,
    checkpoint commits, fault recovery); the disabled path is one
-   attribute check returning a shared no-op span — no allocation, no
-   lock, no clock read. Always-on instrumentation with an off switch,
-   not an opt-in build.
+   attribute check and one question to the profiler ("is a session
+   open?") returning a shared no-op span — no allocation, no lock, no
+   clock read. Always-on instrumentation with an off switch, not an
+   opt-in build.
 2. **Thread-safe, per-thread lanes.** The window stager, serving
    workers and the checkpoint writer all trace concurrently; spans
    carry their thread id (a chrome-trace "tid" lane) and nest via a
@@ -25,10 +26,19 @@ Design constraints, in order:
    (monitor/steptime.py) incrementally drain "spans since mark"
    without copying the whole buffer, and eviction is explicit in the
    drain result (``dropped``).
-4. **No device syncs.** Spans time the HOST: a ``dispatch`` span is
-   enqueue cost, not device compute (jax dispatch is async). Device
-   time comes from profiler/ xplane captures, correlated onto window
-   spans by ``ProfilerSession.correlate_spans``.
+4. **No device syncs of its own.** A span times the HOST between its
+   two edges: a ``dispatch`` span is enqueue cost, not device compute
+   (jax dispatch is async); a span whose edges enclose a host sync
+   that the code makes anyway (``serving.decode``, ``fit.sync``)
+   includes the device's time.
+5. **One clock with the device.** While a ``jax.profiler`` session is
+   open (``start_trace``, ``start_server``, ``ProfilerSession``) every
+   span is also a ``jax.profiler.TraceAnnotation`` of the same name: it
+   lands in the profiler's own trace, on the profiler's clock, on the
+   calling thread's host line, nested as the spans nest, above the
+   device's lines. The session IS the switch: ``enabled`` governs only
+   the in-memory ring and its consumers (reqtrace, steptime,
+   ``/trace``). Scalar args ride along when the ring is enabled too.
 
 Usage::
 
@@ -39,8 +49,9 @@ Usage::
         sp.set(iteration=it)
     TRACER.write_chrome_trace("trace.json")
 
-Spans measure ``time.perf_counter`` and are recorded on ``__exit__``
-(a crashed span still records, with the exception type in its args).
+Ring spans measure ``time.perf_counter`` and are recorded on
+``__exit__`` (a crashed span still records, with the exception type in
+its args).
 """
 from __future__ import annotations
 
@@ -51,6 +62,28 @@ import json
 import threading
 import time
 from typing import Callable, Dict, List, Optional, Tuple
+
+
+#: ``jax.profiler.TraceAnnotation``, resolved by the first call of
+#: :func:`_profiling` (importing ``monitor`` must not import jax)
+_Annotation = None
+
+
+def _profiling() -> bool:
+    """Whether a profiler session is open. The first call resolves
+    jax's annotation class and rebinds this name to the class's own
+    check, so every later call is that check and nothing else."""
+    global _Annotation, _profiling
+    from jax.profiler import TraceAnnotation
+    _Annotation = TraceAnnotation
+    _profiling = TraceAnnotation.is_enabled
+    return _profiling()
+
+
+def _scalars(args: dict) -> dict:
+    """The args an annotation can carry (a bool is an int)."""
+    return {k: v for k, v in args.items()
+            if isinstance(v, (int, float, str))}
 
 
 class _NullSpan:
@@ -74,11 +107,36 @@ class _NullSpan:
 _NULL_SPAN = _NullSpan()
 
 
+class _ProfilerSpan:
+    """Ring disabled, profiler session open: the annotation alone, by
+    name (args are the ring's business)."""
+
+    __slots__ = ("_ann",)
+
+    def __init__(self, name: str):
+        self._ann = _Annotation(name)
+
+    def __enter__(self):
+        self._ann.__enter__()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        self._ann.__exit__(exc_type, exc, tb)
+        return False
+
+    def set(self, **args) -> "_ProfilerSpan":
+        return self
+
+    def discard(self) -> None:
+        """A mirrored event cannot be taken back."""
+
+
 class Span:
     """One live (then completed) span. Create via :meth:`Tracer.span`."""
 
     __slots__ = ("tracer", "name", "cat", "args", "t0", "dur", "tid",
-                 "thread_name", "seq", "sid", "parent", "_discarded")
+                 "thread_name", "seq", "sid", "parent", "_discarded",
+                 "_ann")
 
     #: process-wide id source — `next()` is atomic under the GIL
     _ids = itertools.count(1)
@@ -96,6 +154,7 @@ class Span:
         self.sid = 0           # assigned when entered
         self.parent = 0        # sid of the enclosing span on this thread
         self._discarded = False
+        self._ann = None       # the profiler's twin, while a session is open
 
     def __enter__(self) -> "Span":
         t = threading.current_thread()
@@ -106,11 +165,16 @@ class Span:
         if stack:
             self.parent = stack[-1].sid
         stack.append(self)
+        if _profiling():
+            self._ann = _Annotation(self.name, **_scalars(self.args))
+            self._ann.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         self.dur = time.perf_counter() - self.t0
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
         stack = self.tracer._stack()
         if stack and stack[-1] is self:
             stack.pop()
@@ -125,11 +189,14 @@ class Span:
     def set(self, **args) -> "Span":
         """Attach/overwrite span args (shows up in the chrome trace)."""
         self.args.update(args)
+        if self._ann is not None:
+            self._ann.set_metadata(**_scalars(args))
         return self
 
     def discard(self) -> None:
-        """Drop this span on exit (e.g. a data_wait that found
-        end-of-stream instead of data)."""
+        """Drop this span from the ring on exit (e.g. a data_wait that
+        found end-of-stream instead of data). Its twin in an open
+        profiler session stays: that event cannot be taken back."""
         self._discarded = True
 
     def to_dict(self, t0: float) -> dict:
@@ -163,11 +230,14 @@ class Tracer:
 
     # -- recording ------------------------------------------------------
     def span(self, name: str, cat: str = "", **args):
-        """Open a span context manager. THE hot call: when disabled it
-        returns a shared no-op singleton (no allocation, no clock)."""
-        if not self.enabled:
-            return _NULL_SPAN
-        return Span(self, name, cat, args)
+        """Open a span context manager. THE hot call: with the ring
+        disabled and no profiler session open it returns a shared no-op
+        singleton (no allocation, no clock)."""
+        if self.enabled:
+            return Span(self, name, cat, args)
+        if _profiling():
+            return _ProfilerSpan(name)
+        return _NULL_SPAN
 
     def traced(self, name: Optional[str] = None, cat: str = ""):
         """Decorator form: ``@TRACER.traced()`` spans every call."""
@@ -176,8 +246,6 @@ class Tracer:
 
             @functools.wraps(fn)
             def wrapper(*a, **kw):
-                if not self.enabled:
-                    return fn(*a, **kw)
                 with self.span(span_name, cat=cat):
                     return fn(*a, **kw)
             return wrapper
@@ -189,7 +257,12 @@ class Tracer:
         callback (e.g. a ``jax.monitoring`` compile event) that was
         never entered as a context manager. The span ends NOW and
         started ``dur`` seconds ago, lands in the current thread's lane,
-        and nests under whatever span is open on this thread."""
+        and nests under whatever span is open on this thread. An
+        annotation cannot be backdated, so an open profiler session gets
+        a marker at the span's END that carries its length."""
+        if _profiling():
+            with _Annotation(name, dur_ms=float(dur) * 1e3):
+                pass
         if not self.enabled:
             return
         sp = Span(self, name, cat, args)
@@ -358,6 +431,16 @@ SPAN_CATALOG: Dict[str, Tuple[str, Tuple[str, ...]]] = {
     "flush": ("train", ("steps",)),
     "h2d_stage": ("train", ("k",)),
     "integrity.replay_probe": ("integrity", ("k",)),
+    # the scanned-epoch tier's boundary (SameDiff._fit_scanned): fit >
+    # fit.stage (copies of parameters, state and updater state; the
+    # stacked batches), fit.dispatch (each scanned epoch), fit.sync
+    # (sentinel and panic reads, the one fetch of the epoch means),
+    # fit.commit (arrays, updater state and counters written back)
+    "fit": ("train", ("tier", "steps", "epochs")),
+    "fit.stage": ("train", ()),
+    "fit.dispatch": ("train", ("epoch",)),
+    "fit.sync": ("train", ()),
+    "fit.commit": ("train", ()),
     # compile pipeline (compilecache/, samediff precompile, memstats)
     "compile.precompile": ("compile", ("target",)),
     "compile.plan_capture": ("compile", ("target",)),
@@ -385,11 +468,23 @@ SPAN_CATALOG: Dict[str, Tuple[str, Tuple[str, ...]]] = {
                                   "segment")),
     "serving.warmup": ("serving", ("bucket",)),
     "serving.reload": ("serving", ("step", "arrays")),
-    "serving.prefill": ("serving", ("bucket", "slot", "trace_id",
+    # the generative scheduler's worker thread (serving/generative.py):
+    # serving.step > serving.admit > serving.prefill, and serving.step >
+    # serving.decode | serving.draft.. serving.verify, then serving.emit.
+    # A dispatch span runs from before the launch to after the host
+    # sync (the same edges as GenerativeMetrics' clocks) and holds
+    # serving.launch and serving.sync
+    "serving.step": ("serving", ()),
+    "serving.admit": ("serving", ("requests",)),
+    "serving.prefill": ("serving", ("bucket", "slot", "hist", "trace_id",
                                     "segment")),
     "serving.decode": ("serving", ("active", "slots")),
-    "serving.draft": ("serving", ("active", "step", "slots")),
+    "serving.draft": ("serving", ("active", "step", "slots", "phase",
+                                  "bucket", "slot")),
     "serving.verify": ("serving", ("active", "window", "slots")),
+    "serving.launch": ("serving", ()),
+    "serving.sync": ("serving", ()),
+    "serving.emit": ("serving", ("tokens", "window")),
     # fleet tier (serving/fleet/router.py) — one span per placement
     # attempt, the segment boundary request waterfalls link on
     "fleet.attempt": ("fleet", ("trace_id", "segment", "kind",

@@ -13,7 +13,6 @@ from __future__ import annotations
 import glob
 import os
 import tempfile
-import time
 from typing import Dict, List, Optional
 
 from deeplearning4j_tpu.profiler.xplane import (
@@ -51,26 +50,21 @@ class OpProfile:
 
 class ProfilerSession:
     """Context manager around jax.profiler.start_trace/stop_trace that
-    decodes the resulting xplane artifact."""
+    decodes the resulting xplane artifact. While it is open every
+    ``monitor.trace`` span is in the capture too, as a host event of the
+    same name on the capture's clock (monitor/trace.py)."""
 
     def __init__(self, log_dir: Optional[str] = None):
         self.log_dir = log_dir or tempfile.mkdtemp(prefix="dl4j_tpu_prof_")
         self._profile: Optional[OpProfile] = None
-        # capture window in time.perf_counter terms — the clock
-        # monitor/trace spans use, so correlate_spans can select the
-        # spans that overlap this capture
-        self.t_start: Optional[float] = None
-        self.t_stop: Optional[float] = None
 
     def __enter__(self):
         import jax
         jax.profiler.start_trace(self.log_dir)
-        self.t_start = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         import jax
-        self.t_stop = time.perf_counter()
         jax.profiler.stop_trace()
         return False
 
@@ -85,43 +79,3 @@ class ProfilerSession:
                 ops.extend(device_op_times(load_xspace(p)))
             self._profile = OpProfile(sorted(ops, key=lambda o: -o.total_ps))
         return self._profile
-
-    def correlate_spans(self, tracer=None, spans=None) -> dict:
-        """Correlate this capture's DEVICE op time with the monitor
-        tracer's host-side ``window``/``step`` spans.
-
-        The xplane capture knows what the device did but not which fit
-        window asked for it; the tracer knows the windows but times only
-        the host. This joins them at the capture boundary: window spans
-        overlapping [t_start, t_stop] share the capture's total device
-        op time proportionally to their wall duration (an ESTIMATE — the
-        two clocks are not event-correlated; with equal-length windows,
-        which fused training produces by construction, the proportional
-        split is exact up to scheduling jitter). Each correlated span
-        gains a ``device_ms_est`` arg (visible in the chrome trace) and
-        the summary reports device utilization over the window wall time.
-        """
-        if spans is None:
-            if tracer is None:
-                from deeplearning4j_tpu.monitor.trace import TRACER as tracer
-            spans = [
-                s for s in tracer.spans()
-                if s.name in ("window", "step")
-                and (self.t_start is None or s.t0 + s.dur >= self.t_start)
-                and (self.t_stop is None or s.t0 <= self.t_stop)]
-        device_ms = self.profile().total_ms()
-        wall_s = sum(s.dur for s in spans)
-        windows = []
-        for s in spans:
-            est = device_ms * (s.dur / wall_s) if wall_s > 0 else 0.0
-            s.set(device_ms_est=round(est, 4))
-            windows.append({
-                "name": s.name, "ts": s.t0, "dur_s": round(s.dur, 9),
-                "k": int(s.args.get("k", 1)),
-                "iteration": s.args.get("iteration"),
-                "device_ms_est": round(est, 4)})
-        return {"device_total_ms": round(device_ms, 4),
-                "window_wall_s": round(wall_s, 6),
-                "device_utilization": round(
-                    device_ms / (wall_s * 1e3), 6) if wall_s > 0 else 0.0,
-                "windows": windows}

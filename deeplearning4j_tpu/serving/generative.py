@@ -202,6 +202,9 @@ class GenerationRequest(InferenceRequest):
     trace_seg: int = 0
     generated: List[int] = field(default_factory=list)
     cancelled: bool = False
+    # when _admit first gave the request a slot (time.monotonic, like
+    # enqueue_t): queue wait is admit_t - enqueue_t
+    admit_t: Optional[float] = None
     first_token_t: Optional[float] = None
     last_token_t: Optional[float] = None
     _stream: SimpleQueue = field(default_factory=SimpleQueue)
@@ -310,8 +313,13 @@ class GenerativeMetrics(ServingMetrics):
         for c in ("tokens_generated", "prefills", "decode_steps",
                   "slots_active_sum", "requests_cancelled",
                   "spec_rounds", "draft_tokens", "draft_accepted",
-                  "draft_rejected"):
+                  "draft_rejected", "requests_admitted"):
             self.counters[c] = 0
+        # exact sums in milliseconds, taken on the worker thread where
+        # the work happens (PERF.md section 3 names the metric each is for)
+        for c in ("sched_host_ms_sum", "decode_launch_ms_sum",
+                  "queue_wait_ms_sum"):
+            self.counters[c] = 0.0
 
     def observe_ttft(self, ms: float) -> None:
         with self._lock:
@@ -320,6 +328,19 @@ class GenerativeMetrics(ServingMetrics):
     def observe_intertoken(self, ms: float) -> None:
         with self._lock:
             self.intertoken_ms.record(ms)
+
+    def observe_admit(self, queue_wait_ms: float) -> None:
+        """One request placed in a slot, ``queue_wait_ms`` after it was
+        enqueued."""
+        with self._lock:
+            self.counters["requests_admitted"] += 1
+            self.counters["queue_wait_ms_sum"] += queue_wait_ms
+
+    def observe_step(self, host_ms: float) -> None:
+        """One scheduler step that did work: its wall time outside any
+        dispatch-to-sync interval, which is the host's own bookkeeping."""
+        with self._lock:
+            self.counters["sched_host_ms_sum"] += host_ms
 
     def observe_prefill(self, ms: float) -> None:
         with self._lock:
@@ -339,9 +360,14 @@ class GenerativeMetrics(ServingMetrics):
             self.counters["draft_accepted"] += int(accepted)
             self.counters["draft_rejected"] += int(drafted) - int(accepted)
 
-    def observe_decode_step(self, active: int, ms: float) -> None:
+    def observe_decode_step(self, active: int, ms: float,
+                            launch_ms: float) -> None:
+        """One decode step (or speculative round): ``ms`` from before
+        the dispatch to after the host sync, of which ``launch_ms`` went
+        into the target's launch, when the device can do nothing."""
         with self._lock:
             self.counters["decode_steps"] += 1
+            self.counters["decode_launch_ms_sum"] += launch_ms
             self.counters["slots_active_sum"] += int(active)
             self.counters["batches_dispatched"] += 1
             self.counters["rows_served"] += int(active)
@@ -559,6 +585,8 @@ class GenerativeServer:
         self._id_lock = threading.Lock()
         self._closed = False
         self._killed = False         # abort(): fail in-flight, no drain
+        # dispatch-to-sync ms inside the current _step (worker thread)
+        self._step_busy_ms = 0.0
         self._dirty = False          # a respawned worker must reset state
         self._mem_every = (max(1, int(memory_sample_every))
                            if memory_sample_every else None)
@@ -1117,59 +1145,104 @@ class GenerativeServer:
         slot.requests = reqs or None
 
     def _step(self, slot: InflightSlot) -> bool:
-        progressed = self._admit(slot)
+        """One pass of the scheduler: admission, then one decode step or
+        speculative round. An idle server waits for work HERE, before
+        the ``serving.step`` span and the step's clock open, so it
+        traces nothing and its wait is nobody's host time."""
+        first = None
         if not self._active.any():
-            return progressed
-        if self._spec_ready():
-            self._speculate_once(slot)
-        else:
-            self._decode_once(slot)
-        return True
+            first = self._take(timeout=0.05)
+            if first is None:
+                return False
+        with _tracer.span("serving.step", cat="serving"):
+            t0 = time.perf_counter()
+            self._step_busy_ms = 0.0
+            progressed = self._admit(slot, first)
+            if self._active.any():
+                if self._spec_ready():
+                    self._speculate_once(slot)
+                else:
+                    self._decode_once(slot)
+                progressed = True
+            self.metrics.observe_step(
+                (time.perf_counter() - t0) * 1000.0 - self._step_busy_ms)
+        return progressed
 
-    def _admit(self, slot: InflightSlot) -> bool:
+    def _take(self, timeout: float = 0.0) -> Optional[GenerationRequest]:
+        """The queue's next live request, if a slot is free for it."""
+        if self._slots.free_count() == 0:
+            return None
+        return next(iter(self._queue.take(1, timeout=timeout)), None)
+
+    def _admit(self, slot: InflightSlot,
+               first: Optional[GenerationRequest] = None) -> bool:
         """Step-boundary admission: fill free slots from the queue
-        (continuous batching). In ``static`` mode a new wave is only
-        admitted when every slot is free — the wait-for-full-batch
-        baseline the benchmark compares against."""
+        (continuous batching), starting with ``first`` where an idle
+        :meth:`_step` already took one. Never waits: an active decode
+        batch must not stall at the boundary for new work. In ``static``
+        mode a new wave is only admitted when every slot is free — the
+        wait-for-full-batch baseline the benchmark compares against."""
         # static (wait-for-full-batch) baseline: a new WAVE is only
         # admitted once every slot is free — decided once per boundary,
         # then the whole wave fills (not one request per boundary)
         if self.admit_mode == "static" and self._n_active() > 0:
             return False
-        admitted = False
-        while self._slots.free_count() > 0:
-            # block briefly only when idle — an active decode batch
-            # must not stall at the boundary waiting for new work
-            block = not self._active.any() and not admitted
-            reqs = self._queue.take(1, timeout=0.05 if block else 0.0)
-            if not reqs:
-                break
-            req = reqs[0]
-            if req.cancelled:
-                # same accounting as a slot-occupying cancel (_retire):
-                # cancelled, not served
-                req.future.set_result(list(req.generated))
-                req.close_stream()
-                self.metrics.inc("requests_cancelled")
-                continue
-            if not self._can_place(req):
-                # memory-tier backpressure (paged: not enough free KV
-                # blocks): back to the FRONT — it keeps its place in
-                # line — and stop admitting until a retirement frees
-                # capacity. Does not consume the crash-requeue budget
-                self._queue.requeue(req)
-                break
-            s = self._slots.alloc()
-            self._slot_reqs[s] = req
-            self._sync_inflight(slot)
-            try:
-                self._prefill(s, req)
-                admitted = True
-            except Exception as e:      # noqa: BLE001 — per-request fail
-                # already OOM-wrapped by _dispatch; a failing prompt
-                # fails ITS request, not the decode worker
-                self._retire(s, error=e)
-        return admitted
+        req = first if first is not None else self._take()
+        if req is None:
+            return False
+        admitted = 0
+        with _tracer.span("serving.admit", cat="serving") as sp:
+            while req is not None:
+                if req.cancelled:
+                    # same accounting as a slot-occupying cancel
+                    # (_retire): cancelled, not served
+                    req.future.set_result(list(req.generated))
+                    req.close_stream()
+                    self.metrics.inc("requests_cancelled")
+                elif not self._can_place(req):
+                    # memory-tier backpressure (paged: not enough free
+                    # KV blocks): back to the FRONT — it keeps its place
+                    # in line — and stop admitting until a retirement
+                    # frees capacity. Does not consume the crash-requeue
+                    # budget
+                    self._queue.requeue(req)
+                    break
+                else:
+                    s = self._slots.alloc()
+                    self._slot_reqs[s] = req
+                    self._sync_inflight(slot)
+                    if req.admit_t is None:
+                        # a crash-requeued request re-enters here, but
+                        # it waited in the queue once
+                        req.admit_t = time.monotonic()
+                        self.metrics.observe_admit(
+                            (req.admit_t - req.enqueue_t) * 1000.0)
+                    try:
+                        self._prefill(s, req)
+                        admitted += 1
+                    except Exception as e:  # noqa: BLE001 — per-request
+                        # already OOM-wrapped by _dispatch; a failing
+                        # prompt fails ITS request, not the decode worker
+                        self._retire(s, error=e)
+                req = self._take()
+            sp.set(requests=admitted)
+        return admitted > 0
+
+    def _pad_to_bucket(self, tokens: np.ndarray):
+        """``(bucket, tokens zero-padded to it)``."""
+        bucket = self._buckets.bucket_for(int(tokens.size))
+        padded = np.zeros(bucket, np.int32)
+        padded[:tokens.size] = tokens
+        return bucket, padded
+
+    def _prefill_io(self, s: int, prefix: np.ndarray, L: int):
+        """What the prefill program of slot ``s`` is given:
+        ``(io, span args, filled)``; ``filled()`` runs once the device
+        has filled the slot's KV rows (the paged tier registers prefix
+        blocks there)."""
+        bucket, padded = self._pad_to_bucket(prefix)
+        return ({"tokens": padded, "length": np.int32(L),
+                 "slot": np.int32(s)}, {"bucket": bucket}, lambda: None)
 
     def _prefill(self, s: int, req: GenerationRequest) -> None:
         prefix = req.prefix()
@@ -1179,19 +1252,20 @@ class GenerativeServer:
             # sequence: nothing left to decode — finish with what it has
             self._retire(s)
             return
-        bucket = self._buckets.bucket_for(L)
-        padded = np.zeros(bucket, np.int32)
-        padded[:L] = prefix
-        io = {"tokens": padded, "length": np.int32(L), "slot": np.int32(s)}
-        t0 = time.perf_counter()
-        out = self._dispatch(self._prefill_disp, io, "serving.prefill",
-                             bucket=bucket, slot=s, **_trace_args(req))
-        tok = self._resolve_token(req, int(out[2]), out[3])
-        self.metrics.observe_prefill((time.perf_counter() - t0) * 1000.0)
+        io, attrs, filled = self._prefill_io(s, prefix, L)
+        tok, _, ms, _ = self._dispatch(
+            self._prefill_disp, io, "serving.prefill",
+            resolve=lambda nxt, logits: self._resolve_token(
+                req, int(nxt), logits),
+            slot=s, **attrs, **_trace_args(req))
+        self.metrics.observe_prefill(ms)
+        self._step_busy_ms += ms
+        filled()
         self._positions[s] = L
         self._tokens[s] = tok
         self._active[s] = True
-        self._emit(s, req, tok)
+        with _tracer.span("serving.emit", cat="serving", tokens=1):
+            self._emit(s, req, tok)
         self._draft_prefill(s, prefix, L)
 
     def _draft_prefill(self, s: int, prefix: np.ndarray, L: int) -> None:
@@ -1203,13 +1277,12 @@ class GenerativeServer:
         first speculative round."""
         if self.draft_spec is None or not self._active[s]:
             return
-        bucket = self._buckets.bucket_for(L)
-        padded = np.zeros(bucket, np.int32)
-        padded[:L] = prefix
+        bucket, padded = self._pad_to_bucket(prefix)
         io = {"tokens": padded, "length": np.int32(L),
               "slot": np.int32(s)}
         self._dispatch(self._draft_prefill_disp, io, "serving.draft",
-                       draft=True, phase="prefill", bucket=bucket, slot=s)
+                       draft=True, sync=False, phase="prefill",
+                       bucket=bucket, slot=s)
 
     def _resolve_token(self, req: GenerationRequest, device_tok: int,
                        logits_row) -> int:
@@ -1256,32 +1329,53 @@ class GenerativeServer:
                 attrs["slots"] = slots
         return attrs
 
-    def _decode_once(self, slot: InflightSlot) -> None:
-        n_active = self._n_active()
-        io = {"tokens": self._tokens.copy(),
-              "positions": self._positions.copy(),
-              "active": self._active.copy()}
-        t0 = time.perf_counter()
-        _, _, nxt_d, logits_d = self._dispatch(
-            self._decode_disp, io, "serving.decode",
-            **self._batch_span_args(n_active))
-        nxt = np.asarray(nxt_d)
-        ms = (time.perf_counter() - t0) * 1000.0
-        self.metrics.observe_decode_step(n_active, ms)
+    def _decode_io(self) -> Optional[dict]:
+        """What the decode program is given this step; ``None`` when no
+        lane is left to decode (the paged tier can retire lanes while it
+        grows their block tables)."""
+        return {"tokens": self._tokens.copy(),
+                "positions": self._positions.copy(),
+                "active": self._active.copy()}
+
+    def _sample_pool(self) -> None:
+        """Memory-tier occupancy sample, once per decode step or round
+        (paged: the block pool)."""
+
+    def _check_leaks(self) -> None:
+        """Memory-tier leak invariant at the end of a step or round
+        (paged, under ``debug_leaks``)."""
+
+    def _observe_decode(self, n_active: int, ms: float,
+                        launch_ms: float) -> None:
+        self.metrics.observe_decode_step(n_active, ms, launch_ms)
+        self._step_busy_ms += ms
         if self.admission is not None:
             self.admission.observe(ms)
         self._maybe_memory_record()
-        lg = np.asarray(logits_d) if self._sampled_active() else None
-        for s in np.flatnonzero(io["active"]):
-            req = self._slot_reqs[int(s)]
-            if req is None:
-                continue
-            s = int(s)
-            tok = self._resolve_token(req, int(nxt[s]),
-                                      lg[s] if lg is not None else None)
-            self._positions[s] += 1
-            self._tokens[s] = tok
-            self._emit(s, req, tok)
+
+    def _decode_once(self, slot: InflightSlot) -> None:
+        io = self._decode_io()
+        if io is None:
+            return
+        n_active = int(io["active"].sum())
+        nxt, logits_d, ms, launch_ms = self._dispatch(
+            self._decode_disp, io, "serving.decode",
+            **self._batch_span_args(n_active))
+        self._observe_decode(n_active, ms, launch_ms)
+        self._sample_pool()
+        with _tracer.span("serving.emit", cat="serving", tokens=n_active):
+            lg = np.asarray(logits_d) if self._sampled_active() else None
+            for s in np.flatnonzero(io["active"]):
+                req = self._slot_reqs[int(s)]
+                if req is None:
+                    continue
+                s = int(s)
+                tok = self._resolve_token(
+                    req, int(nxt[s]), lg[s] if lg is not None else None)
+                self._positions[s] += 1
+                self._tokens[s] = tok
+                self._emit(s, req, tok)
+        self._check_leaks()
 
     # -- speculative decoding (draft K, verify once) --------------------
     def _spec_ready(self) -> bool:
@@ -1302,10 +1396,6 @@ class GenerativeServer:
                    active: np.ndarray) -> dict:
         return {"tokens": window, "positions": positions.copy(),
                 "active": active.copy()}
-
-    def _observe_round(self) -> None:
-        """Post-round memory-tier bookkeeping hook (paged: pool
-        occupancy sample + leak invariant)."""
 
     def _speculate_once(self, slot: InflightSlot) -> None:
         """One draft-K / verify-once speculative round (Leviathan et
@@ -1345,12 +1435,12 @@ class GenerativeServer:
                    "positions": (positions + np.int32(m - 1)
                                  * active).astype(np.int32),
                    "active": active.copy()}
-            _, _, dnxt, dlg = self._dispatch(
+            dnxt, dlg, _, _ = self._dispatch(
                 self._draft_decode_disp, dio, "serving.draft",
-                draft=True, **self._batch_span_args(n_active, step=m))
+                draft=True, sync=m < W,
+                **self._batch_span_args(n_active, step=m))
             if m >= W:
                 break
-            dnxt = np.asarray(dnxt)
             dlg_h = np.asarray(dlg) if sampled else None
             for s in act_idx:
                 req = reqs[s]
@@ -1371,69 +1461,93 @@ class GenerativeServer:
                 window[s, m] = d
             d_tokens = window[:, m].copy()
         vio = self._verify_io(window, positions, active)
-        _, _, out_d, vlg_d = self._dispatch(
+        out, vlg_d, _, launch_ms = self._dispatch(
             self._verify_disp, vio, "serving.verify",
             **self._batch_span_args(n_active, window=W))
-        out = np.asarray(out_d)
-        ms = (time.perf_counter() - t0) * 1000.0
-        self.metrics.observe_decode_step(n_active, ms)
-        if self.admission is not None:
-            self.admission.observe(ms)
-        self._maybe_memory_record()
-        lg = np.asarray(vlg_d) if sampled else None
+        self._observe_decode(
+            n_active, (time.perf_counter() - t0) * 1000.0, launch_ms)
         drafted = accepted = 0
-        for s in act_idx:
-            req = reqs[s]
-            drafted += W - 1
-            pos0 = int(positions[s])
-            for j in range(W):
-                tok = self._resolve_token(
-                    req, int(out[s, j]),
-                    lg[s, j] if lg is not None else None)
-                self._positions[s] = pos0 + j + 1
-                self._tokens[s] = tok
-                self._emit(s, req, tok)
-                if not self._active[s]:
-                    break     # retired: EOS / budget / deadline / cancel
-                if j + 1 >= W:
-                    break
-                if int(window[s, j + 1]) != tok:
-                    break     # draft rejected: the window tail is invalid
-                accepted += 1
+        with _tracer.span("serving.emit", cat="serving", window=W):
+            lg = np.asarray(vlg_d) if sampled else None
+            for s in act_idx:
+                req = reqs[s]
+                drafted += W - 1
+                pos0 = int(positions[s])
+                for j in range(W):
+                    tok = self._resolve_token(
+                        req, int(out[s, j]),
+                        lg[s, j] if lg is not None else None)
+                    self._positions[s] = pos0 + j + 1
+                    self._tokens[s] = tok
+                    self._emit(s, req, tok)
+                    if not self._active[s]:
+                        break  # retired: EOS / budget / deadline / cancel
+                    if j + 1 >= W:
+                        break
+                    if int(window[s, j + 1]) != tok:
+                        break  # draft rejected: the window tail is invalid
+                    accepted += 1
         self.metrics.observe_spec_round(drafted, accepted)
-        self._observe_round()
+        self._sample_pool()
+        self._check_leaks()
 
     def _dispatch(self, disp: AOTDispatch, io: dict, span: str,
-                  draft: bool = False, **attrs):
-        """One device dispatch of prefill/decode/verify with the shared
-        plumbing: exec lock, span, stall-watchdog guard, compile
-        accounting, OOM forensics, and slab rebinding (the old slab
-        buffers are donated into the call). ``draft=True`` routes to
-        the draft model's params + slabs; the shapes-seen key carries
-        the role because draft and target share io signatures."""
-        sig = ("draft" if draft else "target", ph_shape_sig(io))
-        with self._exec_lock, _tracer.span(span, cat="serving", **attrs):
-            first = sig not in self._shapes_seen
-            if first:
-                self._shapes_seen.add(sig)
-                self.metrics.inc("compiles")
-            from deeplearning4j_tpu.integrity.watchdog import \
-                guard as _wd_guard
-            try:
-                with _wd_guard("generative_step", first=first):
-                    if draft:
-                        kc, vc, nxt, logits = disp(
-                            self._draft_params, self._dkc, self._dvc, io)
-                    else:
-                        kc, vc, nxt, logits = disp(
-                            self._params, self._kc, self._vc, io)
-            except Exception as e:
-                raise self._wrap_exec_error(e, span) from e
-            if draft:
-                self._dkc, self._dvc = kc, vc
-            else:
-                self._kc, self._vc = kc, vc
-        return kc, vc, nxt, logits
+                  draft: bool = False, sync: bool = True, resolve=None,
+                  **attrs):
+        """One device dispatch of prefill/decode/verify, from before
+        the launch until its result is on the host, with the shared
+        plumbing: the ``span`` and the clock that ``GenerativeMetrics``
+        is fed from (the same two edges), exec lock, stall-watchdog
+        guard, compile accounting, OOM forensics, and slab rebinding
+        (the old slab buffers are donated into the call).
+
+        Under ``span`` sit ``serving.launch`` (the call that enqueues
+        the program; the device can do nothing until it returns) and,
+        with ``sync``, ``serving.sync`` (the host's wait for the next
+        tokens). ``resolve(next_tokens, logits)``, where given, runs
+        after the sync and inside both span and clock, and its result
+        stands for the next tokens (a prefill's first token is only
+        known once it is resolved). ``draft=True`` routes to the draft
+        model's params + slabs; the shapes-seen key carries the role
+        because draft and target share io signatures.
+
+        Returns ``(next tokens, logits on the device, ms, launch ms)``;
+        the next tokens stay on the device without ``sync``."""
+        with _tracer.span(span, cat="serving", **attrs):
+            t0 = time.perf_counter()
+            sig = ("draft" if draft else "target", ph_shape_sig(io))
+            with self._exec_lock, \
+                    _tracer.span("serving.launch", cat="serving"):
+                t_launch = time.perf_counter()
+                first = sig not in self._shapes_seen
+                if first:
+                    self._shapes_seen.add(sig)
+                    self.metrics.inc("compiles")
+                from deeplearning4j_tpu.integrity.watchdog import \
+                    guard as _wd_guard
+                try:
+                    with _wd_guard("generative_step", first=first):
+                        if draft:
+                            kc, vc, nxt, logits = disp(
+                                self._draft_params, self._dkc, self._dvc,
+                                io)
+                        else:
+                            kc, vc, nxt, logits = disp(
+                                self._params, self._kc, self._vc, io)
+                except Exception as e:
+                    raise self._wrap_exec_error(e, span) from e
+                if draft:
+                    self._dkc, self._dvc = kc, vc
+                else:
+                    self._kc, self._vc = kc, vc
+                launch_ms = (time.perf_counter() - t_launch) * 1000.0
+            if sync:
+                with _tracer.span("serving.sync", cat="serving"):
+                    nxt = np.asarray(nxt)
+            if resolve is not None:
+                nxt = resolve(nxt, logits)
+            ms = (time.perf_counter() - t0) * 1000.0
+        return nxt, logits, ms, launch_ms
 
     def _wrap_exec_error(self, e: BaseException, what: str):
         from deeplearning4j_tpu.monitor import memstats
@@ -1525,7 +1639,7 @@ class GenerativeServer:
             else:
                 req.succeed()
                 self.metrics.observe_request(
-                    queue_wait_ms=((req.first_token_t or now)
+                    queue_wait_ms=((req.admit_t or now)
                                    - req.enqueue_t) * 1000.0,
                     e2e_ms=(now - req.enqueue_t) * 1000.0)
         # keep the supervisor's crash-requeue window exact

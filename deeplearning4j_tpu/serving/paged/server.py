@@ -51,7 +51,6 @@ not a numerics change. See docs/serving.md "Paged KV & prefix caching".
 from __future__ import annotations
 
 import threading
-import time
 from dataclasses import dataclass
 from typing import Callable, Dict, List, Optional, Sequence
 
@@ -62,7 +61,7 @@ from deeplearning4j_tpu.serving.generative import (GenerationHandle,
                                                    GenerationRequest,
                                                    GenerativeMetrics,
                                                    GenerativeServer,
-                                                   SlotAllocator, _trace_args)
+                                                   SlotAllocator)
 from deeplearning4j_tpu.serving.metrics import safe_ratio
 from deeplearning4j_tpu.serving.paged.pool import (NULL_BLOCK, BlockPool,
                                                    PoolExhaustedError,
@@ -475,14 +474,10 @@ class PagedGenerativeServer(GenerativeServer):
         self._consume_prefix_flush()
         return super()._step(slot)
 
-    def _prefill(self, s: int, req: GenerationRequest) -> None:
-        prefix = req.prefix()
-        L = int(prefix.size)
-        if L > self.max_seq_len - 1:
-            # crash-requeued request whose prefix already fills the
-            # sequence: nothing left to decode
-            self._retire(s)
-            return
+    def _prefill_io(self, s: int, prefix: np.ndarray, L: int):
+        """Blocks for the whole prefix (cached ones first, the rest
+        fresh), slot ``s``'s table, and the suffix that still has to run
+        through the program."""
         BS = self.block_size
         hashes: List[bytes] = []
         hit: List[int] = []
@@ -496,8 +491,6 @@ class PagedGenerativeServer(GenerativeServer):
             hit = self.pool.lookup(hashes, max_blocks=(L - 1) // BS)
             self.metrics.observe_prefix(True, len(hit))
         hist = len(hit) * BS
-        suffix = prefix[hist:]
-        Ls = L - hist
         fresh: List[int] = []
         try:
             for _ in range(blocks_for_tokens(L, BS) - len(hit)):
@@ -513,32 +506,20 @@ class PagedGenerativeServer(GenerativeServer):
         self._tables[s, :] = NULL_BLOCK
         self._tables[s, :len(blocks)] = blocks
         self._nblocks[s] = len(blocks)
-        bucket = self._buckets.bucket_for(Ls)
-        padded = np.zeros(bucket, np.int32)
-        padded[:Ls] = suffix
-        io = {"tokens": padded, "length": np.int32(Ls),
+        bucket, padded = self._pad_to_bucket(prefix[hist:])
+        io = {"tokens": padded, "length": np.int32(L - hist),
               "hist": np.int32(hist), "table": self._tables[s].copy()}
-        t0 = time.perf_counter()
-        out = self._dispatch(self._prefill_disp, io, "serving.prefill",
-                             bucket=bucket, slot=s, hist=hist,
-                             **_trace_args(req))
-        tok = self._resolve_token(req, int(out[2]), out[3])
-        self.metrics.observe_prefill((time.perf_counter() - t0) * 1000.0)
-        if self.prefix_cache_enabled:
+
+        def filled():
             # content-address the freshly FILLED full blocks (indices
             # [len(hit), L // BS) — the trailing partial block is still
             # being appended to and never registers)
             for u in range(len(hit), min(len(hashes), L // BS)):
                 self.pool.register(hashes[u], int(blocks[u]))
-        self._positions[s] = L
-        self._tokens[s] = tok
-        self._active[s] = True
-        self._emit(s, req, tok)
-        # the draft has no prefix cache: it prefills the FULL prefix
-        # into its own dense slabs (base-class helper)
-        self._draft_prefill(s, prefix, L)
 
-    def _decode_once(self, slot) -> None:
+        return io, {"bucket": bucket, "hist": hist}, filled
+
+    def _decode_io(self) -> Optional[dict]:
         BS = self.block_size
         # block-table growth at the step boundary: a lane whose next
         # write position crosses into an unallocated block gets one.
@@ -557,8 +538,7 @@ class PagedGenerativeServer(GenerativeServer):
                 self._nblocks[s] = u + 1
                 self.metrics.observe_blocks(allocated=1)
         if not self._active.any():
-            return
-        n_active = self._n_active()
+            return None
         act = self._active.copy()
         wb = np.full(self.max_slots, NULL_BLOCK, np.int32)
         wo = np.zeros(self.max_slots, np.int32)
@@ -567,34 +547,17 @@ class PagedGenerativeServer(GenerativeServer):
             pos = int(self._positions[s])
             wb[s] = self._tables[s, pos // BS]
             wo[s] = pos % BS
-        io = {"tokens": self._tokens.copy(),
-              "positions": self._positions.copy(),
-              "active": act,
-              "tables": self._tables.copy(),
-              "write_block": wb, "write_off": wo}
-        t0 = time.perf_counter()
-        _, _, nxt_d, logits_d = self._dispatch(self._decode_disp, io,
-                                               "serving.decode",
-                                               **self._batch_span_args(n_active))
-        nxt = np.asarray(nxt_d)
-        ms = (time.perf_counter() - t0) * 1000.0
-        self.metrics.observe_decode_step(n_active, ms)
+        return {"tokens": self._tokens.copy(),
+                "positions": self._positions.copy(),
+                "active": act,
+                "tables": self._tables.copy(),
+                "write_block": wb, "write_off": wo}
+
+    def _sample_pool(self) -> None:
         self.metrics.observe_pool(self.pool.held_count(),
                                   stats=self.pool.stats())
-        if self.admission is not None:
-            self.admission.observe(ms)
-        self._maybe_memory_record()
-        lg = np.asarray(logits_d) if self._sampled_active() else None
-        for s in np.flatnonzero(act):
-            req = self._slot_reqs[int(s)]
-            if req is None:
-                continue
-            s = int(s)
-            tok = self._resolve_token(req, int(nxt[s]),
-                                      lg[s] if lg is not None else None)
-            self._positions[s] += 1
-            self._tokens[s] = tok
-            self._emit(s, req, tok)
+
+    def _check_leaks(self) -> None:
         if self.debug_leaks:
             self.pool.check_invariant(tables=[
                 self._tables[s, :int(self._nblocks[s])]
@@ -662,15 +625,6 @@ class PagedGenerativeServer(GenerativeServer):
         return {"tokens": window, "positions": positions.copy(),
                 "active": active.copy(), "tables": self._tables.copy(),
                 "write_block": wb, "write_off": wo}
-
-    def _observe_round(self) -> None:
-        self.metrics.observe_pool(self.pool.held_count(),
-                                  stats=self.pool.stats())
-        if self.debug_leaks:
-            self.pool.check_invariant(tables=[
-                self._tables[s, :int(self._nblocks[s])]
-                for s in range(self.max_slots)
-                if self._slot_reqs[s] is not None])
 
     def _retire(self, s: int, error: Optional[BaseException] = None,
                 timed_out: bool = False, cancelled: bool = False) -> None:

@@ -483,6 +483,13 @@ class TestCrashRecovery:
         assert srv.metrics.counters["requests_requeued"] >= 1
         # streams saw each token exactly once: results == full greedy
         # sequences, nothing duplicated or dropped
+        # a requeued request is prefilled again but waited in the queue
+        # once: its admission instant is its first placement's
+        c = srv.metrics.counters
+        assert c["requests_admitted"] == 3 < c["prefills"]
+        for h in handles:
+            r = h._req
+            assert r.enqueue_t <= r.admit_t <= r.first_token_t
 
     @pytest.mark.chaos
     def test_twice_lost_request_fails_typed(self, spec):
@@ -544,6 +551,61 @@ class TestSpeculative:
         for p, n, g in zip(prompts, budgets, got):
             assert g == ref_tokens(spec, p, n)
         assert rec["spec_rounds"] >= 1          # speculation actually ran
+
+    def test_round_spans_end_at_their_sync(self, spec, draft_spec):
+        """A speculative round in the ring: K draft dispatches (the
+        last one unsynced: it only writes the draft's KV) and one
+        verify, each holding its launch and, where the host waits, its
+        sync; the verify's launch is what ``decode_launch_ms_sum``
+        counts, the round what ``sched_host_ms_sum`` leaves out."""
+        from deeplearning4j_tpu.monitor.trace import (TRACER,
+                                                      disable_tracing,
+                                                      enable_tracing)
+        with make_server(spec, draft_spec=draft_spec,
+                         speculate_k=4) as srv:
+            srv.generate(np.asarray([3, 1], np.int32), max_new_tokens=9)
+            time.sleep(0.1)
+            enable_tracing(reset=True)
+            try:
+                c0 = dict(srv.metrics.counters)
+                srv.generate(np.asarray([5, 2, 4], np.int32),
+                             max_new_tokens=9)
+                time.sleep(0.1)
+                c1 = dict(srv.metrics.counters)
+                spans = [s for s in TRACER.spans()
+                         if s.thread_name.startswith("GenerativeServer")]
+            finally:
+                disable_tracing()
+        rounds = c1["spec_rounds"] - c0["spec_rounds"]
+        assert rounds >= 1
+        name = {s.sid: s.name for s in spans}
+        kids = {}
+        for s in spans:
+            kids.setdefault(s.parent, []).append(s.name)
+        verifies = [s for s in spans if s.name == "serving.verify"]
+        drafts = [s for s in spans if s.name == "serving.draft"
+                  and "step" in s.args]
+        assert len(verifies) == rounds and len(drafts) == 4 * rounds
+        for v in verifies:
+            assert name[v.parent] == "serving.step"
+            assert kids[v.sid] == ["serving.launch", "serving.sync"]
+        for d in drafts:
+            want = ["serving.launch"] if d.args["step"] == 4 else \
+                ["serving.launch", "serving.sync"]
+            assert kids[d.sid] == want
+        # the draft's own prefill is a launch and nothing the host
+        # waits for
+        pre = [s for s in spans if s.name == "serving.draft"
+               and s.args.get("phase") == "prefill"]
+        assert len(pre) == 1 and kids[pre[0].sid] == ["serving.launch"]
+        launch = sum(s.dur for s in spans if s.name == "serving.launch"
+                     and name[s.parent] in ("serving.verify",
+                                            "serving.decode")) * 1e3
+        d = {k: c1[k] - c0[k] for k in c1}
+        assert d["decode_launch_ms_sum"] == pytest.approx(launch, rel=0.05)
+        steps = [s for s in spans if s.name == "serving.step"]
+        wall = sum(s.dur for s in steps) * 1e3
+        assert 0 < d["sched_host_ms_sum"] < wall
 
     def test_metrics_count_tokens_exactly_once(self, spec, draft_spec):
         """Accepted draft tokens and the verify-corrected token land in
@@ -858,8 +920,18 @@ class TestObservability:
         for needle in ("dl4j_serving_tokens_generated_total",
                        "dl4j_serving_slot_occupancy_ratio",
                        "dl4j_serving_tokens_per_sec",
-                       "dl4j_serving_latency_ms"):
+                       "dl4j_serving_latency_ms",
+                       "dl4j_serving_sched_host_ms_sum_total",
+                       "dl4j_serving_decode_launch_ms_sum_total",
+                       "dl4j_serving_queue_wait_ms_sum_total",
+                       "dl4j_serving_requests_admitted_total"):
             assert needle in text, needle
+        c = rec["counters"]
+        assert c["requests_admitted"] == 1
+        assert c["queue_wait_ms_sum"] >= 0
+        assert 0 < c["decode_launch_ms_sum"] \
+            <= srv.metrics.exec_ms.total_ms
+        assert c["sched_host_ms_sum"] > 0
 
     def test_report_renders_generative_panel(self, spec):
         from deeplearning4j_tpu.ui.report import render_report
@@ -915,9 +987,9 @@ class TestObservability:
                 names = {s.name for s in TRACER.drain()[0]}
         finally:
             TRACER.enabled = was
-        assert "serving.prefill" in names
-        assert "serving.decode" in names
-        assert "serving.enqueue" in names
+        assert {"serving.enqueue", "serving.step", "serving.admit",
+                "serving.prefill", "serving.decode", "serving.launch",
+                "serving.sync", "serving.emit", "serving.reply"} <= names
 
     def test_telemetry_endpoint_exports_generative_gauges(self, spec):
         from deeplearning4j_tpu.ui.stats import StatsStorage

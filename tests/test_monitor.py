@@ -597,36 +597,6 @@ class TestReportRendering:
         assert "unrendered record types" not in html
 
 
-class TestProfilerCorrelation:
-    def test_correlate_spans_distributes_device_time(self):
-        from deeplearning4j_tpu.profiler.session import (OpProfile,
-                                                         ProfilerSession)
-        from deeplearning4j_tpu.profiler.xplane import OpTime
-        tr = enable_tracing(reset=True)
-        sess = ProfilerSession.__new__(ProfilerSession)
-        sess.log_dir = "/nonexistent"
-        sess.t_start = time.perf_counter()
-        with tr.span("window", k=4, iteration=0):
-            time.sleep(0.004)
-        with tr.span("window", k=4, iteration=4):
-            time.sleep(0.004)
-        sess.t_stop = time.perf_counter()
-        with tr.span("window", k=4, iteration=8):   # outside the capture
-            pass
-        sess._profile = OpProfile([OpTime("fusion.1", 3, int(6e9),
-                                          "fusion")])  # 6 ms device
-        out = sess.correlate_spans(tracer=tr)
-        assert out["device_total_ms"] == pytest.approx(6.0)
-        assert len(out["windows"]) == 2          # capture-bounded
-        est = sum(w["device_ms_est"] for w in out["windows"])
-        assert est == pytest.approx(6.0, abs=1e-3)
-        assert 0 < out["device_utilization"] < 1.5
-        # the estimate is attached to the spans for the chrome trace
-        spans = [s for s in tr.spans() if s.name == "window"]
-        assert "device_ms_est" in spans[0].args
-        assert "device_ms_est" not in spans[2].args
-
-
 class TestProcessSelfTelemetry:
     def test_uptime_and_rss_in_exposition(self):
         reg = MetricsRegistry()

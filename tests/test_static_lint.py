@@ -301,6 +301,10 @@ class TestSpanNameLint:
         assert "serving.decode" in emitted       # _dispatch shape
         assert "compile.backend" in emitted      # record_completed shape
         assert "fleet.attempt" in emitted        # this PR's span
+        # the scheduler's and the scanned fit's boundary spans (ISSUE 26)
+        assert {"serving.step", "serving.admit", "serving.launch",
+                "serving.sync", "serving.emit", "fit", "fit.stage",
+                "fit.dispatch", "fit.sync", "fit.commit"} <= set(emitted)
         rogue = {n: sites for n, sites in emitted.items()
                  if n not in SPAN_CATALOG}
         assert not rogue, (

@@ -1,0 +1,520 @@
+"""One clock for host and device (ISSUE 26).
+
+The tracer's spans are also ``jax.profiler.TraceAnnotation``s while a
+profiler session is open, so a capture holds the program's phases on
+the profiler's own clock above the device's lines. Pinned here:
+
+- (a) with the RING DISABLED, a profiler session alone puts the
+  scheduler's ``serving.step/admit/prefill/decode/launch/sync/emit`` and
+  the scanned fit's ``fit/fit.stage/fit.dispatch/fit.sync/fit.commit``
+  on a host line of the xplane, nested as cataloged, inside an
+  enclosing annotation;
+- (b) with no session and the ring disabled a span is the shared no-op:
+  no ``Span`` allocated, nothing recorded, and well under a microsecond;
+- (c) every counter the benchmark's scheduler metrics read is held to
+  the spans that stand behind it, so neither can drift from the other;
+- (d) ``serving.sync`` covers the host's wait: it ends before the
+  step's tokens are handed to ``on_token``, and the dispatch span after
+  the sync;
+- (e) tokens are bit-identical with the ring on, off and under a
+  profiler session;
+- (f) the base and the paged server give the same span tree.
+"""
+import glob
+import os
+import time
+
+import numpy as np
+import pytest
+
+from deeplearning4j_tpu.monitor import trace as trace_mod
+from deeplearning4j_tpu.monitor.trace import (SPAN_CATALOG, TRACER, Span,
+                                              disable_tracing,
+                                              enable_tracing)
+from deeplearning4j_tpu.serving.generative import GenerativeServer
+from deeplearning4j_tpu.serving.paged import PagedGenerativeServer
+from deeplearning4j_tpu.zoo.gpt import (GPTConfig, build_gpt,
+                                        gpt_generative_spec,
+                                        gpt_paged_spec)
+
+CFG = GPTConfig(vocab_size=64, hidden_size=32, num_layers=2, num_heads=2,
+                intermediate_size=64, max_seq_len=32)
+MSL = 32
+
+#: the scheduler's span tree as SPAN_CATALOG describes it: child ->
+#: the parents it may sit under
+SERVING_TREE = {
+    "serving.step": {None},
+    "serving.admit": {"serving.step"},
+    "serving.prefill": {"serving.admit"},
+    "serving.decode": {"serving.step"},
+    "serving.launch": {"serving.prefill", "serving.decode"},
+    "serving.sync": {"serving.prefill", "serving.decode"},
+    "serving.emit": {"serving.admit", "serving.step"},
+    "serving.reply": {"serving.emit"},
+}
+FIT_TREE = {
+    "fit": {None},
+    "fit.stage": {"fit"},
+    "fit.dispatch": {"fit"},
+    "fit.sync": {"fit"},
+    "fit.commit": {"fit"},
+}
+
+
+@pytest.fixture(autouse=True)
+def _ring_off():
+    disable_tracing()
+    yield
+    disable_tracing()
+
+
+@pytest.fixture(scope="module")
+def gpt_sd():
+    return build_gpt(CFG, batch=2, seq_len=8, seed=0)
+
+
+@pytest.fixture(scope="module")
+def dense_spec(gpt_sd):
+    return gpt_generative_spec(gpt_sd, CFG)
+
+
+@pytest.fixture(scope="module")
+def paged_spec(gpt_sd):
+    return gpt_paged_spec(gpt_sd, CFG)
+
+
+def dense_server(spec, **kw):
+    return GenerativeServer(spec, max_slots=4, max_seq_len=MSL,
+                            warmup=False, **kw)
+
+
+def paged_server(spec, **kw):
+    return PagedGenerativeServer(spec, max_slots=4, max_seq_len=MSL,
+                                 block_size=8, warmup=False, **kw)
+
+
+def prompts(n=5, seed=3):
+    rng = np.random.default_rng(seed)
+    return [rng.integers(0, CFG.vocab_size,
+                         int(rng.integers(1, 12))).astype(np.int32)
+            for _ in range(n)]
+
+
+def finished(srv, n_tokens=6, **submit_kw):
+    """The handles of five requests on four slots, served to the end of
+    the worker's last step: the futures resolve inside that step's emit
+    loop, and a span that is still open when a capture stops is not in
+    it."""
+    hs = [srv.submit(p, max_new_tokens=n_tokens, **submit_kw)
+          for p in prompts()]
+    for h in hs:
+        h.result(timeout=120)
+    while srv._n_active() or srv._queue.pending():
+        time.sleep(0.005)
+    time.sleep(0.1)
+    return hs
+
+
+def serve(srv, n_tokens=6, **submit_kw):
+    return [h.result() for h in finished(srv, n_tokens, **submit_kw)]
+
+
+# ----------------------------------------------------------------------
+# reading a capture back
+
+def host_events(log_dir):
+    """``{line: [(name, start_ns, end_ns)]}`` of the capture's host
+    plane."""
+    from jax.profiler import ProfileData
+    paths = glob.glob(os.path.join(log_dir, "**", "*.xplane.pb"),
+                      recursive=True)
+    assert paths, "the profiler wrote no xplane"
+    out = {}
+    for plane in ProfileData.from_file(paths[0]).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for i, line in enumerate(plane.lines):
+            evs = [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+                   for e in line.events]
+            if evs:
+                out[(plane.name, i, line.name)] = evs
+    return out
+
+
+def parents_on_line(events, names):
+    """``[(name, parent name or None)]`` for the events called one of
+    ``names``, the parent being the innermost other such event on the
+    same line that encloses it."""
+    mine = sorted((e for e in events if e[0] in names),
+                  key=lambda e: (e[1], -e[2]))
+    out, stack = [], []
+    for name, a, b in mine:
+        while stack and stack[-1][2] < b:
+            stack.pop()
+        out.append((name, stack[-1][0] if stack else None))
+        stack.append((name, a, b))
+    return out
+
+
+def capture(tmp_path, work):
+    """Run ``work()`` under a profiler session with the Python-function
+    tracer off (as the benchmark's harness sets it), inside an
+    enclosing annotation on the calling thread."""
+    import jax
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        with jax.profiler.TraceAnnotation("test.window"):
+            out = work()
+    finally:
+        jax.profiler.stop_trace()
+    return out, host_events(str(tmp_path))
+
+
+# ----------------------------------------------------------------------
+# (a) the spans are in the profiler's own trace
+
+class TestSpansInTheProfilersTrace:
+    def test_scheduler_spans_on_a_host_line_nested_as_cataloged(
+            self, paged_spec, tmp_path):
+        assert not TRACER.enabled
+        with paged_server(paged_spec) as srv:
+            serve(srv, n_tokens=2)          # compile outside the capture
+            mark = TRACER.mark()
+            _, lines = capture(tmp_path, lambda: serve(srv))
+        assert TRACER.mark() == mark, "the disabled ring recorded spans"
+        worker = [evs for evs in lines.values()
+                  if any(n == "serving.step" for n, _, _ in evs)]
+        assert len(worker) == 1, "serving.step on one host line: the worker's"
+        tree = parents_on_line(worker[0], set(SERVING_TREE))
+        seen = {}
+        for name, parent in tree:
+            assert parent in SERVING_TREE[name], (name, parent)
+            seen[name] = seen.get(name, 0) + 1
+        assert set(seen) == set(SERVING_TREE), sorted(seen)
+        # one launch and one sync to each dispatch; 5 requests of 6
+        # tokens are 5 prefills and at least 5 decode steps
+        assert seen["serving.prefill"] == 5
+        assert seen["serving.decode"] >= 5
+        assert seen["serving.launch"] == seen["serving.sync"] \
+            == seen["serving.prefill"] + seen["serving.decode"]
+        # the capture's clock: every step lies inside the annotation the
+        # CALLING thread held open over the work
+        window = [e for evs in lines.values() for e in evs
+                  if e[0] == "test.window"]
+        assert len(window) == 1
+        _, lo, hi = window[0]
+        steps = [e for e in worker[0] if e[0] == "serving.step"]
+        assert all(lo <= a and b <= hi for _, a, b in steps)
+
+    def test_an_idle_server_puts_nothing_in_the_trace(self, paged_spec,
+                                                      tmp_path):
+        with paged_server(paged_spec) as srv:
+            serve(srv, n_tokens=2)
+            # twenty queue polls a second, none of them a step
+            _, lines = capture(tmp_path, lambda: time.sleep(0.3))
+        names = {n for evs in lines.values() for n, _, _ in evs}
+        assert not {n for n in names if n.startswith("serving.")}
+
+    def test_scanned_fit_boundary_spans(self, tmp_path):
+        sd, it = tiny_fit()
+        sd.fit(it, epochs=1)                # compile outside the capture
+        hist, lines = capture(tmp_path, lambda: sd.fit(it, epochs=2))
+        assert len(hist.loss_curve.losses) == 2
+        assert sd.last_fit_stats["tier"] == "scanned_epoch"
+        line = [evs for evs in lines.values()
+                if any(n == "fit" for n, _, _ in evs)]
+        assert len(line) == 1
+        tree = parents_on_line(line[0], set(FIT_TREE) | {"test.window"})
+        seen = {}
+        for name, parent in tree:
+            if name == "test.window":
+                continue
+            want = {"test.window"} if name == "fit" else FIT_TREE[name]
+            assert parent in want, (name, parent)
+            seen[name] = seen.get(name, 0) + 1
+        assert seen == {"fit": 1, "fit.stage": 1, "fit.dispatch": 2,
+                        "fit.sync": 1, "fit.commit": 1}
+
+    def test_ring_spans_carry_scalar_args_into_the_trace(self, tmp_path):
+        import jax
+        from jax.profiler import ProfileData
+        enable_tracing(reset=True)
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with TRACER.span("window", cat="train", k=8,
+                             slots={0: 1}) as sp:
+                sp.set(iteration=3)
+            TRACER.record_completed("compile.backend", cat="compile",
+                                    dur=0.25, cache_hit=False)
+        finally:
+            jax.profiler.stop_trace()
+        path = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                         recursive=True)[0]
+        stats = {}
+        for plane in ProfileData.from_file(path).planes:
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name in ("window", "compile.backend"):
+                        stats[e.name] = dict(e.stats)
+        assert stats["window"] == {"k": 8, "iteration": 3}
+        # a completed span cannot be backdated: a marker at its end
+        assert stats["compile.backend"]["dur_ms"] == 250.0
+        ring = {s.name: s for s in TRACER.spans()}
+        assert ring["window"].args == {"k": 8, "slots": {0: 1},
+                                       "iteration": 3}
+        assert ring["compile.backend"].dur == 0.25
+
+
+def tiny_fit():
+    from deeplearning4j_tpu.autodiff import SameDiff, TrainingConfig
+    from deeplearning4j_tpu.dataset import DeviceCachedIterator
+    from deeplearning4j_tpu.learning.updaters import Adam
+    rng = np.random.default_rng(0)
+    sd = SameDiff()
+    x = sd.placeholder("x", shape=(-1, 4))
+    w = sd.var("w", value=rng.normal(0, 0.1, (4, 3)).astype(np.float32))
+    labels = sd.placeholder("labels", shape=(-1, 3))
+    sd.loss.softmax_cross_entropy(x.mmul(w, name="logits"), labels,
+                                  name="loss")
+    sd.set_loss_variables(["loss"])
+    sd.training_config = (TrainingConfig.builder().updater(Adam(1e-2))
+                          .data_set_feature_mapping("x")
+                          .data_set_label_mapping("labels").build())
+    X = rng.normal(size=(32, 4)).astype(np.float32)
+    Y = np.eye(3, dtype=np.float32)[rng.integers(0, 3, 32)]
+    return sd, DeviceCachedIterator(X, Y, batch_size=8)
+
+
+# ----------------------------------------------------------------------
+# (b) off is off
+
+class TestTheDisabledPath:
+    def test_no_session_no_ring_is_the_shared_no_op(self, monkeypatch):
+        made = []
+        real_init = Span.__init__
+
+        def counting_init(self, *a, **k):
+            made.append(1)
+            real_init(self, *a, **k)
+
+        monkeypatch.setattr(Span, "__init__", counting_init)
+        mark = TRACER.mark()
+        a = TRACER.span("serving.step", cat="serving")
+        b = TRACER.span("fit", cat="train", steps=8)
+        assert a is b is trace_mod._NULL_SPAN
+        with a as sp:
+            assert sp.set(k=1) is sp
+            sp.discard()
+        TRACER.record_completed("compile.backend", dur=0.1)
+        assert not made and TRACER.mark() == mark
+
+    def test_disabled_span_costs_under_a_microsecond(self):
+        """The figure PERF.md quotes for the host (the chip's machine
+        is measured there). Best of five batches, so that a neighbour's
+        burst does not fail it; the bound is the ISSUE's."""
+        span, n, best = TRACER.span, 20000, float("inf")
+        for _ in range(5):
+            t0 = time.perf_counter()
+            for _ in range(n):
+                with span("step", cat="train", k=1):
+                    pass
+            best = min(best, (time.perf_counter() - t0) / n)
+        assert best < 1e-6, f"{best * 1e6:.2f} us a disabled span"
+
+    def test_importing_the_tracer_does_not_import_jax(self):
+        import subprocess
+        import sys
+        code = ("import sys, importlib.util as u\n"
+                "spec = u.spec_from_file_location('t', sys.argv[1])\n"
+                "m = u.module_from_spec(spec); spec.loader.exec_module(m)\n"
+                "assert 'jax' not in sys.modules\n"
+                "with m.TRACER.span('x'):\n    pass\n"
+                "assert 'jax' in sys.modules\n")
+        r = subprocess.run([sys.executable, "-c", code, trace_mod.__file__],
+                           capture_output=True, text=True, timeout=120,
+                           env=dict(os.environ, JAX_PLATFORMS="cpu"))
+        assert r.returncode == 0, r.stderr
+
+
+# ----------------------------------------------------------------------
+# (c) (d) (f): the ring's spans against the counters and each other
+
+def ring_run(make, spec, n_tokens=6):
+    """Serve the prompts with the ring on; returns ``(spans of the
+    worker thread, counter deltas, requests, on_token times)``."""
+    stamps = []
+
+    def on_token(tok):
+        stamps.append(time.perf_counter())
+
+    with make(spec) as srv:
+        serve(srv, n_tokens=2)              # compile first
+        enable_tracing(reset=True)
+        m = srv.metrics
+        c0 = dict(m.counters, prefill=m.prefill_ms.total_ms,
+                  decode=m.exec_ms.total_ms)
+        hs = finished(srv, n_tokens, on_token=on_token)
+        c1 = dict(m.counters, prefill=m.prefill_ms.total_ms,
+                  decode=m.exec_ms.total_ms)
+        disable_tracing()
+        spans = [s for s in TRACER.spans()
+                 if s.thread_name.startswith("GenerativeServer")]
+        reqs = [h._req for h in hs]
+    return spans, {k: c1[k] - c0[k] for k in c1}, reqs, stamps
+
+
+def by_name(spans, name):
+    return [s for s in spans if s.name == name]
+
+
+def tree_of(spans):
+    """The set of (name, parent name) edges of a span list."""
+    names = {s.sid: s.name for s in spans}
+    return {(s.name, names.get(s.parent)) for s in spans}
+
+
+@pytest.fixture(scope="module")
+def paged_run(paged_spec):
+    try:
+        return ring_run(paged_server, paged_spec)
+    finally:
+        disable_tracing()
+
+
+@pytest.fixture(scope="module")
+def dense_run(dense_spec):
+    try:
+        return ring_run(dense_server, dense_spec)
+    finally:
+        disable_tracing()
+
+
+class TestCountersHeldToTheirSpans:
+    def test_dispatch_spans_are_the_clocks_of_the_exact_sums(self,
+                                                             paged_run):
+        spans, d, _, _ = paged_run
+        decode = sum(s.dur for s in by_name(spans, "serving.decode")) * 1e3
+        prefill = sum(s.dur for s in by_name(spans, "serving.prefill")) * 1e3
+        assert d["decode_steps"] == len(by_name(spans, "serving.decode"))
+        assert d["prefills"] == len(by_name(spans, "serving.prefill")) == 5
+        assert d["decode"] == pytest.approx(decode, rel=0.01)
+        assert d["prefill"] == pytest.approx(prefill, rel=0.01)
+
+    def test_sched_host_is_the_steps_self_time(self, paged_run):
+        spans, d, _, _ = paged_run
+        steps = by_name(spans, "serving.step")
+        inside = {s.sid for s in steps}
+        admits = {s.sid for s in by_name(spans, "serving.admit")}
+        busy = sum(s.dur for s in spans
+                   if (s.name == "serving.decode" and s.parent in inside)
+                   or (s.name == "serving.prefill" and s.parent in admits))
+        self_ms = (sum(s.dur for s in steps) - busy) * 1e3
+        assert self_ms > 0
+        assert d["sched_host_ms_sum"] == pytest.approx(self_ms, rel=0.05)
+
+    def test_decode_launch_is_the_launch_under_decode(self, paged_run):
+        spans, d, _, _ = paged_run
+        decodes = {s.sid for s in by_name(spans, "serving.decode")}
+        launch = sum(s.dur for s in by_name(spans, "serving.launch")
+                     if s.parent in decodes) * 1e3
+        assert 0 < d["decode_launch_ms_sum"] <= d["decode"]
+        assert d["decode_launch_ms_sum"] == pytest.approx(launch, rel=0.05)
+
+    def test_queue_wait_is_stamped_at_placement(self, paged_run):
+        spans, d, reqs, _ = paged_run
+        assert d["requests_admitted"] == d["prefills"] == len(reqs)
+        waits = []
+        for r in reqs:
+            assert r.admit_t is not None
+            wait = r.admit_t - r.enqueue_t
+            assert 0 <= wait <= r.first_token_t - r.enqueue_t
+            waits.append(wait * 1e3)
+        assert d["queue_wait_ms_sum"] == pytest.approx(sum(waits), rel=1e-6)
+        # five requests on four slots: the fifth waits for a retirement,
+        # and what _retire reports as queue wait is that, not TTFT
+        assert max(waits) > 2 * sorted(waits)[2]
+
+    def test_retire_reports_the_queue_wait_not_the_ttft(self, paged_spec):
+        with paged_server(paged_spec) as srv:
+            serve(srv, n_tokens=2)
+            seen = []
+            real = srv.metrics.observe_request
+            srv.metrics.observe_request = \
+                lambda queue_wait_ms, e2e_ms: (
+                    seen.append(queue_wait_ms),
+                    real(queue_wait_ms=queue_wait_ms, e2e_ms=e2e_ms))
+            h = srv.submit(prompts()[0], max_new_tokens=3)
+            h.result(timeout=120)
+            deadline = time.monotonic() + 5
+            while not seen and time.monotonic() < deadline:
+                time.sleep(0.01)
+            r = h._req
+        assert seen == [pytest.approx(
+            (r.admit_t - r.enqueue_t) * 1e3, rel=1e-9)]
+        assert seen[0] < (r.first_token_t - r.enqueue_t) * 1e3
+
+
+class TestSpanEdges:
+    def test_sync_ends_before_the_tokens_are_handed_over(self, paged_run):
+        """Every ``on_token`` time lies after the end of the sync of the
+        dispatch that produced the token, and inside the ``serving.emit``
+        that follows it: the span covers the wait."""
+        spans, _, _, stamps = paged_run
+        syncs = sorted(by_name(spans, "serving.sync"), key=lambda s: s.t0)
+        emits = sorted(by_name(spans, "serving.emit"), key=lambda s: s.t0)
+        assert len(stamps) == 5 * 6 and len(emits) == len(syncs)
+        for t in stamps:
+            emit = [e for e in emits if e.t0 <= t <= e.t0 + e.dur]
+            assert len(emit) == 1
+            before = [s for s in syncs if s.t0 + s.dur <= emit[0].t0]
+            assert before and before[-1].t0 + before[-1].dur <= t
+
+    def test_a_dispatch_span_ends_after_its_sync(self, paged_run):
+        spans, _, _, _ = paged_run
+        sids = {s.sid: s for s in spans}
+        for sync in by_name(spans, "serving.sync"):
+            outer = sids[sync.parent]
+            assert outer.name in ("serving.prefill", "serving.decode")
+            launch = [s for s in by_name(spans, "serving.launch")
+                      if s.parent == outer.sid]
+            assert len(launch) == 1
+            assert outer.t0 <= launch[0].t0
+            assert launch[0].t0 + launch[0].dur <= sync.t0
+            assert sync.t0 + sync.dur <= outer.t0 + outer.dur
+
+    def test_base_and_paged_give_the_same_span_tree(self, paged_run,
+                                                    dense_run):
+        paged, dense = tree_of(paged_run[0]), tree_of(dense_run[0])
+        assert paged == dense
+        assert paged == {(n, p) for n, ps in SERVING_TREE.items()
+                         for p in ps}
+        for name, _ in paged:
+            assert name in SPAN_CATALOG
+
+
+# ----------------------------------------------------------------------
+# (e) observation changes nothing
+
+class TestTokensUnmoved:
+    @pytest.mark.parametrize("make,spec_name", [
+        (dense_server, "dense_spec"), (paged_server, "paged_spec")])
+    def test_ring_on_off_and_profiler_session(self, make, spec_name,
+                                              request, tmp_path):
+        spec = request.getfixturevalue(spec_name)
+
+        def run():
+            with make(spec) as srv:
+                return serve(srv, temperature=0.7, seed=11), serve(srv)
+
+        off = run()
+        enable_tracing(reset=True)
+        on = run()
+        assert by_name(TRACER.spans(), "serving.sync")
+        disable_tracing()
+        profiled, lines = capture(tmp_path, run)
+        assert any(n == "serving.step" for evs in lines.values()
+                   for n, _, _ in evs)
+        assert off == on == profiled
