@@ -289,7 +289,8 @@ def serve_leg(sd, cfg, max_slots: int, block_size: int, max_seq_len: int,
             f"{srv.metrics.counters['warmup_compiles']} programs "
             f"(cache hits {warm['cache_hits']}, misses "
             f"{warm['cache_misses']})")
-        kv_devices = len(shard_devices(srv._kc))
+        kv_devices = len(set().union(
+            *(shard_devices(leaf) for leaf in srv._kc + srv._vc)))
 
         mark = COMPILE_STATS.mark()
         t0 = time.perf_counter()

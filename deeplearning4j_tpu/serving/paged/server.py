@@ -4,12 +4,13 @@ tensor-parallel dispatch over the continuous-batching scheduler.
 The memory tier vLLM proved out (PagedAttention, Kwon et al. SOSP '23)
 under the Orca-style step scheduler PR 15 built: instead of one dense
 ``[layers, max_slots, heads, max_seq, head_dim]`` row per slot, K/V
-live in fixed-size token BLOCKS carved from one preallocated slab
-``[layers, num_blocks, heads, block_size, head_dim]``, and each request
-holds a BLOCK TABLE grown one block at a time at decode-step
-boundaries. Capacity is proportional to tokens actually held — a
-12-token chat costs one block, not a ``max_seq`` row — so the same HBM
-serves several times the concurrent requests (bench.py serving_paged).
+live in fixed-size token BLOCKS carved from a preallocated pool, one
+array a layer ``[num_blocks, block_size, heads * head_dim]`` for K and
+one for V, and each request holds a BLOCK TABLE grown one block at a
+time at decode-step boundaries. Capacity is proportional to tokens
+actually held — a 12-token chat costs one block, not a ``max_seq`` row
+— so the same HBM serves several times the concurrent requests
+(bench.py serving_paged).
 
 Three layers, all riding :class:`GenerativeServer`'s scheduler/queue/
 resilience plumbing unchanged:
@@ -28,7 +29,7 @@ resilience plumbing unchanged:
   its SUFFIX (``hist`` cached tokens skip straight to reused blocks),
   so repeated-prefix TTFT approaches one decode step. Refcounts release
   exactly once on completion, shed, cancel AND crash-recovery requeue
-  (``pool.reset()`` on worker respawn — the slab is mid-dispatch
+  (``pool.reset()`` on worker respawn — the pool is mid-dispatch
   garbage, so the cache addressing its contents drops wholesale); a
   hot reload (``update_model``) fences the cache too — cached K/V
   belong to the superseded weights, so the worker flushes every
@@ -36,8 +37,8 @@ resilience plumbing unchanged:
 - **tensor parallel** — ``tp > 1`` builds a ``{model: tp}`` mesh from
   the PR-7 :class:`~deeplearning4j_tpu.parallel.sharding.ShardingSpec`
   ("transformer" preset: qkv/fc column, proj row, wte vocab-sharded),
-  shards both KV slabs on the HEADS axis, replicates the tiny host io
-  (tables, tokens, positions), and lets GSPMD propagate through the
+  shards every KV leaf by HEADS (its last axis), replicates the tiny
+  host io (tables, tokens, positions), and lets GSPMD propagate through the
   jitted step — a model larger than one chip's HBM serves, and greedy
   tokens still match the single-chip server (tests/test_paged.py).
 
@@ -83,9 +84,13 @@ class PagedGenerativeSpec:
       dispatchers per geometry, so every server over the same model +
       geometry shares one compile set). Io contracts are documented on
       ``zoo.gpt.gpt_paged_decode_fns``.
-    - ``kv_shape(num_blocks, block_size)`` is the shape of ONE slab —
-      required layout ``[layers, num_blocks, heads, block_size,
-      head_dim]`` (the tensor-parallel path shards axis 2, the heads).
+    - ``kv_shape(num_blocks, block_size)`` gives the pool's five
+      numbers ``(layers, num_blocks, heads, block_size, head_dim)``.
+      The server holds K and V each as a tuple of ``layers`` arrays
+      ``[num_blocks, block_size, heads * head_dim]``, one a layer (the
+      tensor-parallel path shards the last axis, whose outermost part is
+      the heads), hands both tuples to every program and takes them
+      back: the programs donate each leaf and write it in place.
     """
 
     params: Callable[[], Dict[str, object]]
@@ -101,7 +106,7 @@ class PagedGenerativeSpec:
 def _paged_dispatchers(spec: PagedGenerativeSpec, kv_shape: tuple,
                        block_size: int, max_blocks: int,
                        mesh_key) -> Dict[str, AOTDispatch]:
-    """One (decode, prefill) dispatcher pair per (spec, slab geometry,
+    """One (decode, prefill) dispatcher pair per (spec, pool geometry,
     mesh), memoized on the spec object — the paged analogue of
     ``generative._spec_dispatchers``. ``make_fns`` builds fresh closure
     objects each call, so without this memo a second server (a restart,
@@ -233,8 +238,8 @@ class PagedGenerativeServer(GenerativeServer):
       ``max_seq``) — same capacity floor as the dense server, but
       short requests release what they don't use.
     - ``tp``: tensor-parallel ways over the ``model`` mesh axis
-      (params sharded per the "transformer" preset, KV slabs sharded
-      on heads; requires ``num_heads % tp == 0``).
+      (params sharded per the "transformer" preset, every KV leaf
+      sharded by heads; requires ``num_heads % tp == 0``).
     - ``prefix_cache=False`` disables content-addressed block reuse
       (every prefill allocates fresh blocks).
     - ``debug_leaks=True`` runs the pool's full accounting invariant
@@ -294,11 +299,12 @@ class PagedGenerativeServer(GenerativeServer):
         return PagedMetrics(self.max_slots, 0, self.block_size)
 
     def _init_kv(self) -> None:
-        """Allocate the paged memory tier: one K + one V slab shaped
-        ``[layers, num_blocks, heads, block_size, head_dim]`` (block 0
-        reserved as the null block), the block pool, per-slot block
-        tables, and the geometry-memoized dispatchers. With ``tp > 1``
-        also builds the mesh and shards params + slabs."""
+        """Allocate the paged memory tier: ``layers`` K leaves and as
+        many V leaves, each ``[num_blocks, block_size, heads *
+        head_dim]`` (block 0 reserved as the null block), the block
+        pool, per-slot block tables, and the geometry-memoized
+        dispatchers. With ``tp > 1`` also builds the mesh and shards
+        params + leaves."""
         import jax
         import jax.numpy as jnp
 
@@ -329,7 +335,7 @@ class PagedGenerativeServer(GenerativeServer):
         self.kv_slab_bytes = 2 * int(np.prod(shape)) * itemsize
         memstats.check_headroom(
             self.kv_slab_bytes,
-            f"paged KV slabs ({num_blocks} blocks x {BS} tokens)")
+            f"paged KV pool ({num_blocks} blocks x {BS} tokens)")
         mesh_key = None
         if self.tp > 1:
             from jax.sharding import NamedSharding, PartitionSpec
@@ -339,8 +345,7 @@ class PagedGenerativeServer(GenerativeServer):
             if spec.num_heads % self.tp:
                 raise ValueError(
                     f"tp={self.tp} must divide num_heads "
-                    f"{spec.num_heads} (the KV slab shards on the "
-                    f"heads axis)")
+                    f"{spec.num_heads} (a KV leaf is split by heads)")
             devices = list(self._devices_arg
                            if self._devices_arg is not None
                            else jax.devices())
@@ -354,16 +359,19 @@ class PagedGenerativeServer(GenerativeServer):
             self._params = {
                 n: jax.device_put(a, strat.param_sharding(n, np.ndim(a)))
                 for n, a in self._params.items()}
-            # slab layout contract: axis 2 is heads
+            # leaf layout contract: the last axis is heads x head_dim,
+            # heads outermost, so an even split of it is a split by head
             self._kv_sharding = NamedSharding(
-                strat.mesh.mesh,
-                PartitionSpec(None, None, MODEL_AXIS, None, None))
+                strat.mesh.mesh, PartitionSpec(None, None, MODEL_AXIS))
             self._io_sharding = NamedSharding(strat.mesh.mesh,
                                               PartitionSpec())
             mesh_key = (self.tp,
                         tuple(str(d) for d in strat.mesh.mesh.devices.flat))
-        self._kc = self._fresh_slab(shape)
-        self._vc = self._fresh_slab(shape)
+        layers, _, heads, _, head_dim = (int(d) for d in shape)
+        self._kv_leaves = layers
+        self._kv_leaf_shape = (num_blocks, BS, heads * head_dim)
+        self._kc = self._fresh_leaves()
+        self._vc = self._fresh_leaves()
         AllocationsTracker.get_instance().allocate("kv_slab",
                                                    self.kv_slab_bytes)
         # host scheduler state (worker thread owns mutation)
@@ -383,15 +391,14 @@ class PagedGenerativeServer(GenerativeServer):
         self._prefill_disp = disp["prefill"]
         self._verify_disp = disp.get("verify")
 
-    def _fresh_slab(self, shape=None):
-        import jax
+    def _fresh_leaves(self) -> tuple:
+        """One side of the pool (K or V), zeroed: a tuple of one array a
+        layer, each made where it will live."""
         import jax.numpy as jnp
-        if shape is None:
-            shape = tuple(self._kc.shape)
-        slab = jnp.zeros(shape, self._kv_dtype)
-        if self._kv_sharding is not None:
-            slab = jax.device_put(slab, self._kv_sharding)
-        return slab
+        return tuple(
+            jnp.zeros(self._kv_leaf_shape, self._kv_dtype,
+                      device=self._kv_sharding)
+            for _ in range(self._kv_leaves))
 
     # -- block-commitment admission (submit thread) ---------------------
     def _worst_case_blocks(self, prompt_len: int,
@@ -670,14 +677,14 @@ class PagedGenerativeServer(GenerativeServer):
             self.pool.register(h, int(self._tables[s, u]))
 
     def _reset_state(self) -> None:
-        """Crash-recovery respawn: fresh slabs, a hard pool reset
+        """Crash-recovery respawn: fresh leaves, a hard pool reset
         (every held block released ONCE, the prefix cache dropped — it
-        content-addresses slab rows that are now garbage), clean
+        content-addresses pool rows that are now garbage), clean
         tables. The requeued requests keep their submit-side block
         commitment (their futures are unresolved) and re-enter at
         prefill."""
-        self._kc = self._fresh_slab()
-        self._vc = self._fresh_slab()
+        self._kc = self._fresh_leaves()
+        self._vc = self._fresh_leaves()
         self._reset_draft_slabs()
         self.pool.reset()
         # the wholesale reset already dropped the prefix cache — a
@@ -725,7 +732,8 @@ class PagedGenerativeServer(GenerativeServer):
                     self._strategy.param_sharding(n, np.ndim(a))
                     if self._strategy is not None else None)
             for n, a in self._params.items()}
-        kv_abs = _abs(self._kc.shape, self._kc.dtype, self._kv_sharding)
+        kv_abs = (_abs(self._kv_leaf_shape, self._kv_dtype,
+                       self._kv_sharding),) * self._kv_leaves
         S, MAXB = self.max_slots, self._maxb
         mark = COMPILE_STATS.mark()
         t0 = _time.perf_counter()
@@ -848,7 +856,7 @@ class PagedGenerativeServer(GenerativeServer):
         granularity instead of the dense per-slot rows."""
         st = self.pool.stats()
         return {"kv_slab_bytes": self.kv_slab_bytes,
-                "kv_slab_shape": list(self._kc.shape),
+                "kv_slab_shape": [self._kv_leaves, *self._kv_leaf_shape],
                 "kv_bytes_per_block": self.bytes_per_block,
                 "block_size": self.block_size,
                 "num_blocks": self.pool.capacity,

@@ -472,15 +472,31 @@ def gpt_decode_fns(cfg: GPTConfig, quantize_weights: bool = False,
 def gpt_paged_decode_fns(cfg: GPTConfig, block_size: int,
                          max_blocks_per_req: int,
                          quantize_weights: bool = False, kv_scales=None):
-    """Pure-jax ``(prefill_fn, decode_fn, verify_fn)`` over PAGED KV
-    slabs — the same math as :func:`gpt_decode_fns` op-for-op, but
+    """Pure-jax ``(prefill_fn, decode_fn, verify_fn)`` over a PAGED KV
+    pool — the same math as :func:`gpt_decode_fns` op-for-op, but
     attention reads/writes fixed-size token BLOCKS addressed through
     per-request block tables (vLLM's PagedAttention layout, Kwon et al.
     SOSP '23) instead of one contiguous ``max_seq`` row per slot.
 
-    KV slab layout (one array each for K and V)::
+    The pool is ONE ARRAY A LAYER: ``kc`` and ``vc`` are tuples of
+    ``num_layers`` leaves, each::
 
-        [num_layers, num_blocks, heads, block_size, head_dim]
+        [num_blocks, block_size, heads * head_dim]
+
+    a block's tokens together, a token's row over all heads (heads
+    outermost) as the qkv product makes it. Layer ``i`` touches leaf
+    ``i`` only: one scatter of whole rows into it, in place, and one
+    gather ``leaf[tables]`` straight from it, so no value of the whole
+    pool's size exists in any program and nothing of that size can be
+    copied or converted (PERF.md section 5 has what the TPU compiler did
+    to a single ``[layers, blocks, heads, block, head_dim]`` array).
+    With a last axis of a model's whole width (1600 floats for GPT-2
+    XL) in place of one head's 64, a float32 leaf's default TPU layout
+    is row-major with next to no padding, the one the scatter and the
+    gather both use: a leaf goes from one program to the next
+    untouched. All three programs take the leaves and return them (the
+    server donates them). Each leaf is viewed as that shape on the way
+    in, whatever shape it arrives in.
 
     Block 0 is the NULL block: never handed out by the pool, the target
     of every unused table entry and every inactive decode lane's write —
@@ -505,7 +521,7 @@ def gpt_paged_decode_fns(cfg: GPTConfig, block_size: int,
       the null block), each lane attends over its own gathered table
       masked to ``index <= position``.
     - ``verify_fn(params, kc, vc, io)`` — the speculative-decoding
-      verifier over paged slabs: ``io`` carries a [S, W] token window
+      verifier over the paged pool: ``io`` carries a [S, W] token window
       plus [S, W] ``write_block``/``write_off`` (host-computed per
       window position; inactive lanes point every column at the null
       block) and returns ``(kc, vc, out [S, W], logits [S, W, vocab])``
@@ -583,12 +599,22 @@ def gpt_paged_decode_fns(cfg: GPTConfig, block_size: int,
             return x
         return x.astype(jnp.float32) * s
 
+    def _leaves(side):
+        # one array a layer, each taken as [num_blocks, block_size, H]:
+        # rows of one token over all heads, a block's rows together
+        return [leaf.reshape(-1, BS, H) for leaf in side]
+
+    def _heads_first(ctx):
+        # gathered rows [..., T, A, D] -> [..., A, T, D], the dense
+        # slab's order of the same elements
+        return jnp.swapaxes(ctx, -3, -2)
+
     def prefill_fn(params, kc, vc, io):
         p = params
+        kc, vc = _leaves(kc), _leaves(vc)
         tokens, length = io["tokens"], io["length"]
         hist, table = io["hist"], io["table"]
         Lb = tokens.shape[0]
-        ai = jnp.arange(A)
         # global positions of the suffix rows; clip keeps the padded
         # tail's wpe lookups in range (those rows never reach logits)
         g = hist + jnp.arange(Lb, dtype=jnp.int32)
@@ -610,19 +636,21 @@ def gpt_paged_decode_fns(cfg: GPTConfig, block_size: int,
             y = _ln(x, p[f"{sc}/ln_1/gamma"], p[f"{sc}/ln_1/beta"])
             qkv = _matmul(p, f"{sc}/attn/qkv/kernel", y) \
                 + p[f"{sc}/attn/qkv/bias"]
-            qkv = jnp.transpose(qkv.reshape(Lb, A, 3 * D), (1, 0, 2))
-            q, k, v = jnp.split(qkv, 3, axis=-1)             # [A, Lb, D]
+            q, k, v = jnp.split(qkv.reshape(Lb, A, 3 * D), 3, axis=-1)
+            q = jnp.transpose(q, (1, 0, 2))                  # [A, Lb, D]
             # write the suffix K/V FIRST, then gather the whole table —
-            # suffix self-attention reads its own fresh rows
-            kc = kc.at[i, blk[None, :], ai[:, None], off[None, :]].set(
-                _q_store(k, kc.dtype, ksc[i][:, None, :] if KQ else None))
-            vc = vc.at[i, blk[None, :], ai[:, None], off[None, :]].set(
-                _q_store(v, vc.dtype, vsc[i][:, None, :] if KQ else None))
-            ctx_k = _q_load(jnp.transpose(kc[i][table], (1, 0, 2, 3))
-                            .reshape(A, T, D),
+            # suffix self-attention reads its own fresh rows. A token's
+            # row over all heads goes into leaf i as it comes out of the
+            # qkv product
+            kc[i] = kc[i].at[blk, off].set(
+                _q_store(k, kc[i].dtype, ksc[i][None] if KQ else None)
+                .reshape(Lb, H))
+            vc[i] = vc[i].at[blk, off].set(
+                _q_store(v, vc[i].dtype, vsc[i][None] if KQ else None)
+                .reshape(Lb, H))
+            ctx_k = _q_load(_heads_first(kc[i][table].reshape(T, A, D)),
                             ksc[i][:, None, :] if KQ else None)
-            ctx_v = _q_load(jnp.transpose(vc[i][table], (1, 0, 2, 3))
-                            .reshape(A, T, D),
+            ctx_v = _q_load(_heads_first(vc[i][table].reshape(T, A, D)),
                             vsc[i][:, None, :] if KQ else None)
             # zero unwritten rows BEFORE the matmuls: null-block trash
             # (even NaN-poisoned) must not reach any reduction
@@ -644,10 +672,12 @@ def gpt_paged_decode_fns(cfg: GPTConfig, block_size: int,
         h_last = jax.lax.dynamic_slice_in_dim(
             x, jnp.maximum(length - 1, 0), 1, axis=0)        # [1, H]
         logits = _logits(p, h_last)[0]
-        return kc, vc, jnp.argmax(logits).astype(jnp.int32), logits
+        return tuple(kc), tuple(vc), \
+            jnp.argmax(logits).astype(jnp.int32), logits
 
     def decode_fn(params, kc, vc, io):
         p = params
+        kc, vc = _leaves(kc), _leaves(vc)
         tokens, active = io["tokens"], io["active"]
         tables = io["tables"]                                # [S, MAXB]
         wb, wo = io["write_block"], io["write_off"]
@@ -655,9 +685,8 @@ def gpt_paged_decode_fns(cfg: GPTConfig, block_size: int,
         pos = jnp.clip(io["positions"], 0, cfg.max_seq_len - 1)
         x = _tok_emb(p, tokens) \
             + jnp.take(p["wpe"], pos, axis=0)                # [S, H]
-        ai = jnp.arange(A)
         # attend to global index <= position; later table rows are
-        # unwritten blocks or another layer of the null block
+        # unwritten blocks or the null block
         mask = jnp.arange(T)[None, None, :] <= pos[:, None, None]
         for i in range(L):
             sc = f"h{i}"
@@ -668,16 +697,18 @@ def gpt_paged_decode_fns(cfg: GPTConfig, block_size: int,
             # unconditional scatter: the host points inactive lanes at
             # the null block, so no active request's rows are touched
             # (active lanes own disjoint blocks — no write collisions)
-            kc = kc.at[i, wb[:, None], ai[None, :], wo[:, None]].set(
-                _q_store(k, kc.dtype, ksc[i][None] if KQ else None))
-            vc = vc.at[i, wb[:, None], ai[None, :], wo[:, None]].set(
-                _q_store(v, vc.dtype, vsc[i][None] if KQ else None))
-            ctx_k = _q_load(jnp.transpose(kc[i][tables], (0, 2, 1, 3, 4))
-                            .reshape(S, A, T, D),
-                            ksc[i][None, :, None, :] if KQ else None)
-            ctx_v = _q_load(jnp.transpose(vc[i][tables], (0, 2, 1, 3, 4))
-                            .reshape(S, A, T, D),
-                            vsc[i][None, :, None, :] if KQ else None)
+            kc[i] = kc[i].at[wb, wo].set(
+                _q_store(k, kc[i].dtype, ksc[i][None] if KQ else None)
+                .reshape(S, H))
+            vc[i] = vc[i].at[wb, wo].set(
+                _q_store(v, vc[i].dtype, vsc[i][None] if KQ else None)
+                .reshape(S, H))
+            ctx_k = _q_load(
+                _heads_first(kc[i][tables].reshape(S, T, A, D)),
+                ksc[i][None, :, None, :] if KQ else None)
+            ctx_v = _q_load(
+                _heads_first(vc[i][tables].reshape(S, T, A, D)),
+                vsc[i][None, :, None, :] if KQ else None)
             scores = jnp.einsum(
                 "sad,satd->sat", q, ctx_k,
                 preferred_element_type=jnp.float32) * scale
@@ -695,11 +726,12 @@ def gpt_paged_decode_fns(cfg: GPTConfig, block_size: int,
             x = x + _mlp(p, sc, y)
         x = _ln(x, p["ln_f/gamma"], p["ln_f/beta"])
         logits = _logits(p, x)                               # [S, vocab]
-        return kc, vc, jnp.argmax(logits, axis=-1).astype(jnp.int32), \
-            logits
+        return tuple(kc), tuple(vc), \
+            jnp.argmax(logits, axis=-1).astype(jnp.int32), logits
 
     def verify_fn(params, kc, vc, io):
         p = params
+        kc, vc = _leaves(kc), _leaves(vc)
         tokens, active = io["tokens"], io["active"]          # [S, W]
         tables = io["tables"]                                # [S, MAXB]
         wb, wo = io["write_block"], io["write_off"]          # [S, W]
@@ -709,7 +741,6 @@ def gpt_paged_decode_fns(cfg: GPTConfig, block_size: int,
                        0, cfg.max_seq_len - 1)               # [S, W]
         x = _tok_emb(p, tokens) \
             + jnp.take(p["wpe"], pos, axis=0)                # [S, W, H]
-        ai = jnp.arange(A)
         mask = jnp.arange(T)[None, None, :] <= pos[:, :, None]
         # per-slot stale-row bound — see the dense verify_fn: in-window
         # rows masked for earlier w are fresh finite writes, rows past
@@ -725,20 +756,20 @@ def gpt_paged_decode_fns(cfg: GPTConfig, block_size: int,
             # in-order (block, off) pairs, inactive lanes' W columns all
             # target the null block (colliding writes there are trash
             # over trash by construction)
-            kc = kc.at[i, wb[:, :, None], ai[None, None, :],
-                       wo[:, :, None]].set(
-                _q_store(k, kc.dtype,
-                         ksc[i][None, None] if KQ else None))
-            vc = vc.at[i, wb[:, :, None], ai[None, None, :],
-                       wo[:, :, None]].set(
-                _q_store(v, vc.dtype,
-                         vsc[i][None, None] if KQ else None))
-            ctx_k = _q_load(jnp.transpose(kc[i][tables], (0, 2, 1, 3, 4))
-                            .reshape(S, A, T, D),
-                            ksc[i][None, :, None, :] if KQ else None)
-            ctx_v = _q_load(jnp.transpose(vc[i][tables], (0, 2, 1, 3, 4))
-                            .reshape(S, A, T, D),
-                            vsc[i][None, :, None, :] if KQ else None)
+            kc[i] = kc[i].at[wb, wo].set(
+                _q_store(k, kc[i].dtype,
+                         ksc[i][None, None] if KQ else None)
+                .reshape(S, W, H))
+            vc[i] = vc[i].at[wb, wo].set(
+                _q_store(v, vc[i].dtype,
+                         vsc[i][None, None] if KQ else None)
+                .reshape(S, W, H))
+            ctx_k = _q_load(
+                _heads_first(kc[i][tables].reshape(S, T, A, D)),
+                ksc[i][None, :, None, :] if KQ else None)
+            ctx_v = _q_load(
+                _heads_first(vc[i][tables].reshape(S, T, A, D)),
+                vsc[i][None, :, None, :] if KQ else None)
             scores = jnp.einsum(
                 "swad,satd->swat", q, ctx_k,
                 preferred_element_type=jnp.float32) * scale
@@ -755,8 +786,8 @@ def gpt_paged_decode_fns(cfg: GPTConfig, block_size: int,
             x = x + _mlp(p, sc, y)
         x = _ln(x, p["ln_f/gamma"], p["ln_f/beta"])
         logits = _logits(p, x)                           # [S, W, vocab]
-        return kc, vc, jnp.argmax(logits, axis=-1).astype(jnp.int32), \
-            logits
+        return tuple(kc), tuple(vc), \
+            jnp.argmax(logits, axis=-1).astype(jnp.int32), logits
 
     return prefill_fn, decode_fn, verify_fn
 

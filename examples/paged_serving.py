@@ -5,7 +5,8 @@ What this shows (docs/serving.md "Paged KV & prefix caching"):
 
 1. train a tiny GPT, then serve it through the PAGED memory tier
    (``zoo.gpt.gpt_paged_spec`` + ``PagedGenerativeServer``): K/V live
-   in fixed-size token blocks from one preallocated slab, each request
+   in fixed-size token blocks from a preallocated pool (one array a
+   layer, written in place and read where it lies), each request
    holds a block table grown at decode-step boundaries — capacity is
    proportional to tokens actually held, not ``max_slots x max_seq``;
 2. the HBM sizing math: the same budget a small dense deployment
@@ -18,7 +19,7 @@ What this shows (docs/serving.md "Paged KV & prefix caching"):
    (``greedy_decode``) — paged vs dense is a memory-layout change,
    not a numerics change;
 5. tensor-parallel serving (``tp=2`` when 2+ devices are visible):
-   params + KV slabs sharded over the model mesh axis, same tokens.
+   params + every KV leaf sharded over the model mesh axis, same tokens.
 """
 import numpy as np
 

@@ -68,6 +68,14 @@ def dense_spec(gpt_sd):
     return gpt_generative_spec(gpt_sd, CFG)
 
 
+@pytest.fixture(scope="module")
+def draft_spec():
+    dcfg = GPTConfig(vocab_size=64, hidden_size=16, num_layers=1,
+                     num_heads=2, intermediate_size=32, max_seq_len=32)
+    return gpt_generative_spec(
+        build_gpt(dcfg, batch=2, seq_len=8, seed=3), dcfg)
+
+
 def make_server(spec, **kw):
     kw.setdefault("max_slots", 4)
     kw.setdefault("max_seq_len", MSL)
@@ -86,6 +94,26 @@ def mixed_prompts(n=6, seed=0, max_len=12):
     return [rng.integers(0, CFG.vocab_size,
                          int(rng.integers(1, max_len + 1)))
             .astype(np.int32) for _ in range(n)]
+
+
+def crash_decode_once(srv, after=2):
+    """Make ``srv``'s decode dispatcher raise once, on its call number
+    ``after + 1``. Returns the state: ``fired``, and ``leaves``, the
+    pool's arrays as the dying worker held them."""
+    real = srv._decode_disp
+    state = {"calls": 0, "fired": False, "leaves": None}
+
+    class CrashOnce:
+        def __call__(self, *args):
+            state["calls"] += 1
+            if not state["fired"] and state["calls"] > after:
+                state["fired"] = True
+                state["leaves"] = srv._kc + srv._vc
+                raise RuntimeError("chaos: decode worker dies")
+            return real(*args)
+
+    srv._decode_disp = CrashOnce()
+    return state
 
 
 def wait_uncommitted(srv, timeout=10.0):
@@ -486,18 +514,7 @@ class TestLifecycleRelease:
                           resilience=ResilienceConfig(
                               worker_backoff_base_s=0.01,
                               worker_backoff_max_s=0.05))
-        real = srv._decode_disp
-        state = {"calls": 0, "fired": False}
-
-        class CrashOnce:
-            def __call__(self, *args):
-                state["calls"] += 1
-                if not state["fired"] and state["calls"] > 2:
-                    state["fired"] = True
-                    raise RuntimeError("chaos: decode worker dies")
-                return real(*args)
-
-        srv._decode_disp = CrashOnce()
+        state = crash_decode_once(srv)
         try:
             srv.start()
             handles = [srv.submit(p, max_new_tokens=8) for p in prompts]
@@ -534,6 +551,149 @@ class TestTensorParallel:
     def test_tp_must_divide_heads(self, spec):
         with pytest.raises(ValueError, match="num_heads"):
             make_server(spec, tp=3)            # 2 heads % 3 != 0
+
+
+# ----------------------------------------------------------------------
+CFG6 = GPTConfig(vocab_size=64, hidden_size=32, num_layers=6, num_heads=2,
+                 intermediate_size=64, max_seq_len=32)
+POOL_BUCKETS = (8, 16, 32)
+POOL_PROGRAMS = ("decode", "verify") + tuple(
+    f"prefill{b}" for b in POOL_BUCKETS)
+
+
+@pytest.fixture(scope="module")
+def warmed_programs(draft_spec):
+    """Optimised HLO of every program a 6-layer server warms, by K/V
+    dtype: built once a dtype, through the server's own warmup."""
+    sd6 = build_gpt(CFG6, batch=2, seq_len=8, seed=1)
+    cache = {}
+
+    def get(kv):
+        if kv not in cache:
+            spec6 = gpt_paged_spec(sd6, CFG6, quantize_kv=(kv == "int8"))
+            srv = make_server(spec6, num_blocks=33, start=False,
+                              buckets=list(POOL_BUCKETS), warmup=True,
+                              draft_spec=draft_spec, speculate_k=4)
+            try:
+                leaf = srv._kc[0]
+                assert str(leaf.dtype) == kv
+                hlo = {"decode": next(iter(
+                           srv._decode_disp.aot.values())).as_text(),
+                       "verify": next(iter(
+                           srv._verify_disp.aot.values())).as_text()}
+                for sig, comp in srv._prefill_disp.aot.items():
+                    hlo[f"prefill{dict(sig)['tokens'][0]}"] = comp.as_text()
+                cache[kv] = (hlo, int(leaf.size), len(srv._kc))
+            finally:
+                srv.shutdown()
+        return cache[kv]
+
+    return get
+
+
+class TestPoolLeaves:
+    """ISSUE 27: K and V are one array a layer. No program holds a value
+    of the pool's size, a dispatch donates every leaf, crash recovery
+    rebuilds every leaf, and ``tp`` splits each leaf by heads."""
+
+    @pytest.mark.parametrize("program", POOL_PROGRAMS)
+    @pytest.mark.parametrize("kv", ["float32", "int8"])
+    def test_no_instruction_as_large_as_two_layers(self, warmed_programs,
+                                                   kv, program):
+        import re
+        hlo, leaf_elems, n_leaves = warmed_programs(kv)
+        assert n_leaves == CFG6.num_layers
+        assert set(hlo) == set(POOL_PROGRAMS)
+        largest, where = 0, None
+        for m in re.finditer(r"\b[a-z]+\d*\[([\d,]+)\]", hlo[program]):
+            n = int(np.prod([int(d) for d in m.group(1).split(",")]))
+            if n > largest:
+                largest, where = n, m.group(0)
+        # a leaf itself is there (parameter, scatter, result) ...
+        assert largest >= leaf_elems, (largest, where)
+        # ... and nothing, parameters included, holds two of them
+        assert largest < 2 * leaf_elems, (
+            f"{program}/{kv}: {where} has {largest} elements, two "
+            f"layers of the pool have {2 * leaf_elems}")
+
+    def test_dispatch_donates_every_leaf(self, spec):
+        import jax
+        import jax.numpy as jnp
+        probe = jnp.zeros(8)
+        jax.jit(lambda x: x + 1, donate_argnums=0)(probe)
+        if not probe.is_deleted():
+            pytest.skip("this backend does not donate")
+        with make_server(spec) as srv:
+            old = srv._kc + srv._vc
+            assert len(old) == 2 * CFG.num_layers
+            srv.submit(np.arange(5, dtype=np.int32),
+                       max_new_tokens=3).result(timeout=60)
+            assert all(leaf.is_deleted() for leaf in old)
+            new = srv._kc + srv._vc
+            assert len(srv._kc) == len(srv._vc) == CFG.num_layers
+            assert not any(leaf.is_deleted() for leaf in new)
+            assert {leaf.shape for leaf in new} == {
+                (srv.pool.capacity + 1, BS, CFG.hidden_size)}
+
+    @pytest.mark.chaos
+    def test_reset_state_rebuilds_every_leaf(self, spec, dense_spec):
+        """A worker crash mid-generation: ``_reset_state`` makes every
+        leaf anew (none of the crashed worker's arrays is kept) and the
+        requeued requests still produce the dense reference's tokens."""
+        prompts = mixed_prompts(3, seed=11)
+        srv = make_server(spec, start=False,
+                          resilience=ResilienceConfig(
+                              worker_backoff_base_s=0.01,
+                              worker_backoff_max_s=0.05))
+        state = crash_decode_once(srv)
+        try:
+            srv.start()
+            handles = [srv.submit(p, max_new_tokens=8) for p in prompts]
+            got = [h.result(timeout=120) for h in handles]
+            after = srv._kc + srv._vc
+        finally:
+            srv.shutdown()
+        assert state["fired"]
+        assert srv.metrics.counters["worker_restarts"] >= 1
+        assert len(after) == 2 * CFG.num_layers
+        crashed = {id(leaf) for leaf in state["leaves"]}
+        assert not crashed & {id(leaf) for leaf in after}
+        assert not any(leaf.is_deleted() for leaf in after)
+        assert got == [ref_tokens(dense_spec, p, 8) for p in prompts]
+
+    def test_tp2_shards_every_leaf_by_heads(self, spec, dense_spec):
+        import jax
+        from jax.sharding import PartitionSpec
+
+        from deeplearning4j_tpu.parallel.mesh import MODEL_AXIS
+        if len(jax.devices()) < 2:
+            pytest.skip("needs >= 2 devices")
+        prompts = mixed_prompts(2, seed=13, max_len=6)
+        H = CFG.hidden_size
+
+        def held(srv):
+            leaves = srv._kc + srv._vc
+            assert len(leaves) == 2 * CFG.num_layers
+            for leaf in leaves:
+                assert leaf.sharding.spec == PartitionSpec(
+                    None, None, MODEL_AXIS)
+                shards = leaf.addressable_shards
+                assert len({s.device for s in shards}) == 2
+                # heads outermost in the last axis: half of it is one
+                # of the two heads
+                assert {s.data.shape for s in shards} == {
+                    (leaf.shape[0], BS, H // 2)}
+                assert sorted(s.index[2].start or 0 for s in shards) \
+                    == [0, H // 2]
+
+        with make_server(spec, tp=2, num_blocks=16) as srv:
+            held(srv)
+            got = [srv.submit(p, max_new_tokens=4).result(timeout=120)
+                   for p in prompts]
+            held(srv)           # a program's results stay split the same
+            assert srv.memory_report()["kv_slab_shape"] == [
+                CFG.num_layers, 16, BS, H]
+        assert got == [ref_tokens(dense_spec, p, 4) for p in prompts]
 
 
 # ----------------------------------------------------------------------
@@ -597,15 +757,12 @@ class TestSpeculativeAndQuant:
     invariant after every scheduler step), and int8 KV multiplies the
     block pool's token capacity at equal slab bytes."""
 
-    def test_paged_speculation_bit_identical(self, spec, dense_spec):
-        dcfg = GPTConfig(vocab_size=64, hidden_size=16, num_layers=1,
-                         num_heads=2, intermediate_size=32,
-                         max_seq_len=32)
-        draft = gpt_generative_spec(
-            build_gpt(dcfg, batch=2, seq_len=8, seed=3), dcfg)
+    def test_paged_speculation_bit_identical(self, spec, dense_spec,
+                                             draft_spec):
         prompts = mixed_prompts(6, seed=31)
         budgets = [4 + i % 5 for i in range(6)]
-        with make_server(spec, draft_spec=draft, speculate_k=4) as srv:
+        with make_server(spec, draft_spec=draft_spec,
+                         speculate_k=4) as srv:
             hs = [srv.submit(p, n) for p, n in zip(prompts, budgets)]
             got = [h.result(timeout=120) for h in hs]
             rec = srv.metrics.to_record()["generative"]
