@@ -478,6 +478,10 @@ SPAN_CATALOG: Dict[str, Tuple[str, Tuple[str, ...]]] = {
     "serving.admit": ("serving", ("requests",)),
     "serving.prefill": ("serving", ("bucket", "slot", "hist", "trace_id",
                                     "segment")),
+    # a prompt longer than the largest bucket: serving.prefill then holds
+    # one serving.prefill_chunk a run of the program, each with its
+    # serving.launch and only the last with a serving.sync
+    "serving.prefill_chunk": ("serving", ("index", "of", "bucket", "hist")),
     "serving.decode": ("serving", ("active", "slots")),
     "serving.draft": ("serving", ("active", "step", "slots", "phase",
                                   "bucket", "slot")),

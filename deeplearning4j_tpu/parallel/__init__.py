@@ -20,8 +20,8 @@ from deeplearning4j_tpu.parallel.pipeline import (
     pipeline_forward, pipeline_model_train_step, pipeline_train_step,
     place_stage_params, sequential_forward, split_microbatches)
 from deeplearning4j_tpu.parallel.moe import (
-    EXPERT_AXIS, expert_parallel_specs, init_moe_params, moe_ffn,
-    moe_train_step, switch_gating)
+    EXPERT_AXIS, dropless_topk_ffn, expert_parallel_specs, init_moe_params,
+    moe_ffn, moe_train_step, switch_gating, topk_route)
 from deeplearning4j_tpu.parallel import collectives, multihost
 
 __all__ = [
@@ -37,5 +37,6 @@ __all__ = [
     "place_stage_params", "sequential_forward", "split_microbatches",
     "transformer_tensor_parallel_rules",
     "EXPERT_AXIS", "moe_ffn", "switch_gating", "init_moe_params",
-    "expert_parallel_specs", "moe_train_step",
+    "expert_parallel_specs", "moe_train_step", "topk_route",
+    "dropless_topk_ffn",
 ]

@@ -15,6 +15,17 @@ TPU framework expresses it:
 - The load-balancing auxiliary loss is the standard fraction·probability
   dot product (Switch eq. 4), returned for the caller to add to the
   task loss.
+
+Beside the Switch layer, which drops what overflows an expert's
+capacity, stands a DROPLESS top-k layer for models whose reference drops
+nothing (:func:`topk_route`, :func:`dropless_topk_ffn`): the (token,
+choice) pairs are sorted by expert and go through one grouped product
+(``jax.lax.ragged_dot``) per weight, so its shapes are static too
+(``tokens x k`` rows whatever the load of an expert) and only the
+experts that have a token are read. It is told which experts it holds,
+routes over all of them, and returns the part of the result its own
+experts give: a chip that holds a share of the experts runs the same
+function, and the shares add up to the whole layer.
 """
 from __future__ import annotations
 
@@ -118,6 +129,63 @@ def moe_ffn(x, gate_w, w_in, w_out, b_in=None, b_out=None,
                                               None))
     y = jnp.einsum("gsec,gecd->gsd", combine.astype(x.dtype), out)
     return y.reshape(n, d), jnp.asarray(aux, jnp.float32)
+
+
+def topk_route(x, router_w, k: int):
+    """Dropless top-k routing over ALL experts. x: (N, d); router_w:
+    (d, E). The router's product is float32 at the highest precision
+    (a choice between near-equal experts should not turn on operand
+    rounding); the weights of a token's k experts are a softmax over
+    those k logits, so they sum to one. Returns (idx (N, k) int32,
+    weights (N, k) float32)."""
+    logits = jnp.matmul(x.astype(jnp.float32), router_w.astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+    top, idx = jax.lax.top_k(logits, int(k))
+    return idx.astype(jnp.int32), jax.nn.softmax(top, axis=-1)
+
+
+def dropless_topk_ffn(x, idx, weights, w_gate, w_up, w_down,
+                      first_expert: int = 0, valid=None):
+    """The held experts' part of a ReGLU top-k expert layer (``(relu(x
+    @ gate) * (x @ up)) @ down``), no token dropped whatever the load of
+    an expert.
+
+    x: (N, d); ``idx``/``weights``: (N, k) from :func:`topk_route`, over
+    all experts; ``w_gate``/``w_up``: (held, d, f) and ``w_down``:
+    (held, f, d) are experts ``first_expert .. first_expert + held - 1``.
+    A pair routed to an expert held elsewhere adds nothing here, and
+    neither does a token where ``valid`` (N,) is false (padding, an idle
+    decode lane): such pairs sort behind every group and no expert is
+    read for them. Returns ``(y (N, d) float32, tokens (held,) int32)``,
+    the second the number of tokens each held expert served.
+
+    Operands go into the products in the weights' dtype and accumulate
+    in float32."""
+    n, k = idx.shape
+    held = w_gate.shape[0]
+    local = idx - jnp.int32(first_expert)
+    mine = (local >= 0) & (local < held)
+    if valid is not None:
+        mine = mine & valid[:, None]
+    flat = jnp.where(mine, local, held).reshape(-1)        # (N k,)
+    tokens = jnp.zeros(held + 1, jnp.int32).at[flat].add(1)[:held]
+    order = jnp.argsort(flat, stable=True)
+    rows = x[order // k].astype(w_gate.dtype)              # (N k, d)
+
+    def grouped(lhs, rhs):
+        return jax.lax.ragged_dot(lhs, rhs, tokens,
+                                  preferred_element_type=jnp.float32)
+
+    h = jax.nn.relu(grouped(rows, w_gate)) * grouped(rows, w_up)
+    out = grouped(h.astype(w_down.dtype), w_down)          # (N k, d)
+    # rows past the last group belong to nobody here: whatever the
+    # grouped product left there is not a result
+    out = jnp.where((jnp.arange(n * k) < jnp.sum(tokens))[:, None], out, 0.0)
+    back = jnp.zeros(n * k, jnp.int32).at[order].set(
+        jnp.arange(n * k, dtype=jnp.int32))
+    w = jnp.where(mine, weights, 0.0).astype(jnp.float32)
+    y = jnp.sum(out[back].reshape(n, k, -1) * w[:, :, None], axis=1)
+    return y, tokens
 
 
 def init_moe_params(rng, d_model: int, d_ff: int, n_experts: int,

@@ -56,7 +56,7 @@ import threading
 import time
 from dataclasses import dataclass, field
 from queue import SimpleQueue
-from typing import Callable, Dict, List, Optional, Sequence
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -313,7 +313,10 @@ class GenerativeMetrics(ServingMetrics):
         for c in ("tokens_generated", "prefills", "decode_steps",
                   "slots_active_sum", "requests_cancelled",
                   "spec_rounds", "draft_tokens", "draft_accepted",
-                  "draft_rejected", "requests_admitted"):
+                  "draft_rejected", "requests_admitted",
+                  # runs of the prefill program: a prompt longer than
+                  # the largest bucket takes several
+                  "prefill_runs"):
             self.counters[c] = 0
         # exact sums in milliseconds, taken on the worker thread where
         # the work happens (PERF.md section 3 names the metric each is for)
@@ -342,10 +345,21 @@ class GenerativeMetrics(ServingMetrics):
         with self._lock:
             self.counters["sched_host_ms_sum"] += host_ms
 
-    def observe_prefill(self, ms: float) -> None:
+    def observe_prefill(self, ms: float, runs: int) -> None:
+        """One prompt prefilled in ``runs`` runs of the program, ``ms``
+        from before the first launch to after the first token."""
         with self._lock:
             self.counters["prefills"] += 1
+            self.counters["prefill_runs"] += int(runs)
             self.prefill_ms.record(ms)
+
+    def observe_program(self, names: Sequence[str], counted) -> None:
+        """What a decode program counted on the device in one step,
+        added to the counters ``names`` (a spec's
+        ``program_counters``, registered by the server)."""
+        with self._lock:
+            for name, n in zip(names, counted):
+                self.counters[name] += int(n)
 
     def observe_spec_round(self, drafted: int, accepted: int) -> None:
         """One speculative round: ``drafted`` proposals across the
@@ -624,6 +638,10 @@ class GenerativeServer:
             self.start()
 
     # -- subclass hooks (serving/paged/server.py overrides) -------------
+    #: counters a spec's decode program feeds (the paged tier reads its
+    #: spec's ``program_counters``)
+    _program_counters: Tuple[str, ...] = ()
+
     def _coerce_spec(self, spec):
         if not isinstance(spec, GenerativeSpec):
             if hasattr(spec, "generative_spec"):
@@ -1235,11 +1253,20 @@ class GenerativeServer:
         padded[:tokens.size] = tokens
         return bucket, padded
 
-    def _prefill_io(self, s: int, prefix: np.ndarray, L: int):
-        """What the prefill program of slot ``s`` is given:
-        ``(io, span args, filled)``; ``filled()`` runs once the device
-        has filled the slot's KV rows (the paged tier registers prefix
-        blocks there)."""
+    def _prefill_runs(self, s: int, prefix: np.ndarray, L: int):
+        """The runs of the prefill program that fill slot ``s``, as
+        ``(start, stop)`` positions of the prefix: one, here (a prompt
+        past the largest bucket is refused when it is padded). The paged
+        tier starts after what its prefix cache holds and cuts the rest
+        into chunks."""
+        return [(0, L)]
+
+    def _prefill_io(self, s: int, prefix: np.ndarray, L: int,
+                    start: int, stop: int):
+        """What the prefill program of slot ``s`` is given for the run
+        over ``[start, stop)`` of the prefix (all of it, here): ``(io,
+        span args, filled)``; ``filled()`` runs once the run is launched
+        (the paged tier registers prefix blocks there)."""
         bucket, padded = self._pad_to_bucket(prefix)
         return ({"tokens": padded, "length": np.int32(L),
                  "slot": np.int32(s)}, {"bucket": bucket}, lambda: None)
@@ -1252,15 +1279,36 @@ class GenerativeServer:
             # sequence: nothing left to decode — finish with what it has
             self._retire(s)
             return
-        io, attrs, filled = self._prefill_io(s, prefix, L)
-        tok, _, ms, _ = self._dispatch(
-            self._prefill_disp, io, "serving.prefill",
-            resolve=lambda nxt, logits: self._resolve_token(
-                req, int(nxt), logits),
-            slot=s, **attrs, **_trace_args(req))
-        self.metrics.observe_prefill(ms)
+        runs = self._prefill_runs(s, prefix, L)
+
+        def resolve(nxt, logits):
+            return self._resolve_token(req, int(nxt), logits)
+
+        if len(runs) == 1:
+            io, attrs, filled = self._prefill_io(s, prefix, L, *runs[0])
+            tok, _, ms, _ = self._dispatch(
+                self._prefill_disp, io, "serving.prefill", resolve=resolve,
+                slot=s, **attrs, **_trace_args(req))
+            filled()
+        else:
+            # a prompt in chunks: the runs are launched one behind the
+            # other, each on the pool the last one leaves, and the host
+            # waits for the last alone, which gives the first token
+            with _tracer.span("serving.prefill", cat="serving", slot=s,
+                              hist=runs[0][0], **_trace_args(req)):
+                t0 = time.perf_counter()
+                for k, (start, stop) in enumerate(runs):
+                    last = k == len(runs) - 1
+                    io, attrs, filled = self._prefill_io(s, prefix, L,
+                                                         start, stop)
+                    tok = self._dispatch(
+                        self._prefill_disp, io, "serving.prefill_chunk",
+                        sync=last, resolve=resolve if last else None,
+                        index=k, of=len(runs), **attrs)[0]
+                    filled()
+                ms = (time.perf_counter() - t0) * 1000.0
+        self.metrics.observe_prefill(ms, runs=len(runs))
         self._step_busy_ms += ms
-        filled()
         self._positions[s] = L
         self._tokens[s] = tok
         self._active[s] = True
@@ -1362,6 +1410,11 @@ class GenerativeServer:
             self._decode_disp, io, "serving.decode",
             **self._batch_span_args(n_active))
         self._observe_decode(n_active, ms, launch_ms)
+        if self._program_counters:
+            # what the program counted rides behind its next tokens, in
+            # the one array the sync brings over
+            self.metrics.observe_program(self._program_counters,
+                                         nxt[self.max_slots:])
         self._sample_pool()
         with _tracer.span("serving.emit", cat="serving", tokens=n_active):
             lg = np.asarray(logits_d) if self._sampled_active() else None

@@ -6,13 +6,15 @@ docstrings of :mod:`.pool` (the allocator/prefix-cache bookkeeping) and
 :mod:`.server` (the server itself).
 """
 from deeplearning4j_tpu.serving.paged.pool import (NULL_BLOCK, BlockPool,
+                                                   KVTier,
                                                    PoolExhaustedError,
                                                    blocks_for_tokens,
                                                    prefix_block_hashes)
-from deeplearning4j_tpu.serving.paged.server import (PagedGenerativeServer,
-                                                     PagedGenerativeSpec,
-                                                     PagedMetrics)
+from deeplearning4j_tpu.serving.paged.server import (
+    PagedGenerativeServer, PagedGenerativeSpec, PagedMetrics,
+    PrefixCacheUnsupportedError)
 
-__all__ = ["BlockPool", "PoolExhaustedError", "NULL_BLOCK",
+__all__ = ["BlockPool", "PoolExhaustedError", "NULL_BLOCK", "KVTier",
            "prefix_block_hashes", "blocks_for_tokens",
-           "PagedGenerativeSpec", "PagedGenerativeServer", "PagedMetrics"]
+           "PagedGenerativeSpec", "PagedGenerativeServer", "PagedMetrics",
+           "PrefixCacheUnsupportedError"]

@@ -34,6 +34,7 @@ from __future__ import annotations
 
 import hashlib
 from collections import OrderedDict
+from dataclasses import dataclass
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -297,5 +298,57 @@ def blocks_for_tokens(n_tokens: int, block_size: int) -> int:
     return -(-int(n_tokens) // int(block_size))
 
 
-__all__ = ["BlockPool", "PoolExhaustedError", "NULL_BLOCK",
+@dataclass(frozen=True)
+class KVTier:
+    """The layers of a model whose KV leaves share one block pool and
+    one table a request.
+
+    ``window`` None: every block of a request is kept until it retires,
+    and block ``u`` of a request sits in entry ``u`` of its table. With a
+    ``window`` a query at position ``p`` reads positions ``j > p -
+    window`` only, so a block that lies wholly behind that is given back
+    at the next step boundary, and the table is a RING of
+    :meth:`table_blocks` entries with block ``u`` in entry ``u %
+    table_blocks``: a request's table, and what a program gathers
+    through it, stop growing with the request.
+
+    ``name`` suffixes the tier's keys in a program's io
+    (``tables.<name>``); the one unnamed tier of a model whose layers
+    are all alike keeps the bare keys."""
+
+    name: str
+    layers: Tuple[int, ...]
+    window: Optional[int] = None
+
+    def key(self, base: str) -> str:
+        return f"{base}.{self.name}" if self.name else base
+
+    def table_blocks(self, block_size: int, max_blocks: int) -> int:
+        """Entries of a request's table: every block of the longest
+        request, or the most blocks one query's window can touch."""
+        if self.window is None:
+            return int(max_blocks)
+        return min(int(max_blocks),
+                   blocks_for_tokens(self.window, block_size) + 1)
+
+    def first_live_block(self, position: int, block_size: int) -> int:
+        """The lowest block a query at ``position`` or later reads."""
+        if self.window is None:
+            return 0
+        return max(0, int(position) - self.window + 1) // int(block_size)
+
+    def peak_blocks(self, n_tokens: int, block_size: int,
+                    run_tokens: int) -> int:
+        """The most blocks a request of ``n_tokens`` holds at once when
+        its rows arrive in runs of at most ``run_tokens`` (a prompt's
+        chunks; one token a decode step): a run's own blocks and those
+        its first query still reads."""
+        whole = blocks_for_tokens(n_tokens, block_size)
+        if self.window is None:
+            return whole
+        return min(whole, blocks_for_tokens(
+            self.window + int(run_tokens), block_size) + 1)
+
+
+__all__ = ["BlockPool", "PoolExhaustedError", "NULL_BLOCK", "KVTier",
            "prefix_block_hashes", "blocks_for_tokens"]

@@ -42,6 +42,17 @@ resilience plumbing unchanged:
   jitted step — a model larger than one chip's HBM serves, and greedy
   tokens still match the single-chip server (tests/test_paged.py).
 
+Two more things a model may ask of the tier (``zoo/smallthinker.py``
+does): its layers may fall into several TIERS (``pool.KVTier``), each
+with a block pool, a table a request and a block count of its own,
+reserved and admitted against one by one, a WINDOW tier giving a block
+back at the first step boundary at which it lies wholly behind the
+window; and a prompt longer than the largest bucket runs through the
+one prefill program in CHUNKS of that bucket, ``hist`` advancing, the
+first token from the last run. A spec whose layers are all alike has
+one unnamed tier and takes the path it always took, with the same
+programs and arguments.
+
 Correctness contract: with ``max_blocks_per_req * block_size ==
 max_seq`` the gathered paged context is elementwise identical to the
 dense slab context (zoo/gpt.py ``gpt_paged_decode_fns``), so greedy
@@ -53,7 +64,7 @@ from __future__ import annotations
 
 import threading
 from dataclasses import dataclass
-from typing import Callable, Dict, List, Optional, Sequence
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -65,6 +76,7 @@ from deeplearning4j_tpu.serving.generative import (GenerationHandle,
                                                    SlotAllocator)
 from deeplearning4j_tpu.serving.metrics import safe_ratio
 from deeplearning4j_tpu.serving.paged.pool import (NULL_BLOCK, BlockPool,
+                                                   KVTier,
                                                    PoolExhaustedError,
                                                    blocks_for_tokens,
                                                    prefix_block_hashes)
@@ -91,6 +103,15 @@ class PagedGenerativeSpec:
       tensor-parallel path shards the last axis, whose outermost part is
       the heads), hands both tuples to every program and takes them
       back: the programs donate each leaf and write it in place.
+    - ``kv_tiers`` says, layer by layer, which tier a leaf belongs to
+      and the tier's window (``pool.KVTier``); ``None`` is one unnamed
+      tier of every layer. A leaf has its TIER's ``num_blocks``, and the
+      programs take one table per tier under the tier's keys.
+    - ``program_counters`` names what the decode program counts on the
+      device (an expert layer's routing): its next tokens are then
+      ``[max_slots + len(program_counters)]``, the counts of the step
+      behind the tokens in this order, and the server adds them to its
+      counters of these names at the sync the tokens pay for.
     """
 
     params: Callable[[], Dict[str, object]]
@@ -101,6 +122,108 @@ class PagedGenerativeSpec:
     num_heads: int
     kv_dtype: str = "float32"
     eos_id: Optional[int] = None
+    kv_tiers: Optional[Sequence[KVTier]] = None
+    program_counters: Tuple[str, ...] = ()
+
+
+class PrefixCacheUnsupportedError(ValueError):
+    """Asked for the prefix cache over a spec with a window tier or more
+    than one tier: a cached prefix would have to bring back the window
+    tier's blocks too, which were given back while the request ran."""
+
+
+class _TierState:
+    """The host's books of one KV tier: its pool and, per slot, the
+    block indices ``[first, stop)`` the slot holds and the table the
+    programs read through. Block ``u`` sits in entry ``u % entries`` of
+    the table. In a tier that keeps every block that is entry ``u``, a
+    block is in the table from the moment it is allocated, and ``first``
+    stays 0. A window tier's table is a ring of the blocks whose rows
+    are WRITTEN: while a run of a program fills fresh blocks, the
+    entries they will take still hold blocks the run reads, so fresh
+    blocks wait in ``pending`` (the program is told where its rows go)
+    until :meth:`advance`."""
+
+    def __init__(self, tier: KVTier, block_size: int, entries: int,
+                 num_blocks: int, max_slots: int):
+        self.tier, self.BS, self.entries = tier, int(block_size), int(entries)
+        self.pool = BlockPool(num_blocks, block_size)
+        self.tables = np.zeros((max_slots, self.entries), np.int32)
+        self.first = np.zeros(max_slots, np.int32)
+        self.stop = np.zeros(max_slots, np.int32)
+        self.pending: List[Dict[int, int]] = [{} for _ in range(max_slots)]
+
+    def grow(self, s: int, stop: int) -> int:
+        """Blocks for slot ``s`` up to index ``stop``; how many were
+        allocated. Raises :class:`PoolExhaustedError` with what it did
+        allocate on the books, for :meth:`clear` to give back."""
+        n = 0
+        for u in range(int(self.stop[s]), int(stop)):
+            b = self.pool.alloc()
+            if self.tier.window is None:
+                self.tables[s, u] = b
+            else:
+                self.pending[s][u] = b
+            self.stop[s] = u + 1
+            n += 1
+        return n
+
+    def advance(self, s: int, position: int) -> int:
+        """The rows of slot ``s`` before ``position`` are written and the
+        next query is at ``position`` or later: fresh blocks enter the
+        table, and every block that lies wholly behind that query's
+        window is given back. Returns how many were. (Window tiers
+        only: a tier that keeps every block has nothing to advance.)"""
+        live = min(self.tier.first_live_block(position, self.BS),
+                   int(self.stop[s]))
+        if live <= self.first[s] and not self.pending[s]:
+            return 0
+        n = 0
+        for u in range(int(self.first[s]), live):
+            b = self.pending[s].pop(u, None)
+            if b is None:
+                e = u % self.entries
+                b, self.tables[s, e] = int(self.tables[s, e]), NULL_BLOCK
+            self.pool.release(b)
+            n += 1
+        self.first[s] = max(int(self.first[s]), live)
+        for u, b in self.pending[s].items():
+            e = u % self.entries
+            if self.tables[s, e] != NULL_BLOCK:
+                raise RuntimeError(
+                    f"tier {self.tier.name!r}, slot {s}: block {u} has no "
+                    f"free table entry at position {position}")
+            self.tables[s, e] = b
+        self.pending[s].clear()
+        return n
+
+    def blocks(self, s: int) -> List[int]:
+        """The blocks slot ``s`` holds, in order."""
+        return [self.pending[s][u] if u in self.pending[s]
+                else int(self.tables[s, u % self.entries])
+                for u in range(int(self.first[s]), int(self.stop[s]))]
+
+    def block_at(self, s: int, u: int) -> int:
+        """Block ``u`` of slot ``s``, in the table or still pending."""
+        b = self.pending[s].get(u) if self.pending[s] else None
+        return self.tables[s, u % self.entries] if b is None else b
+
+    def clear(self, s: int) -> int:
+        held = self.blocks(s)
+        for b in held:
+            self.pool.release(b)
+        self.tables[s, :] = NULL_BLOCK
+        self.first[s] = self.stop[s] = 0
+        self.pending[s].clear()
+        return len(held)
+
+    def reset(self) -> None:
+        self.pool.reset()
+        self.tables[:] = NULL_BLOCK
+        self.first[:] = 0
+        self.stop[:] = 0
+        for p in self.pending:
+            p.clear()
 
 
 def _paged_dispatchers(spec: PagedGenerativeSpec, kv_shape: tuple,
@@ -153,15 +276,26 @@ class PagedMetrics(GenerativeMetrics):
                   "prefix_cache_flushes",
                   "blocks_allocated", "blocks_released",
                   "blocks_held_sum", "pool_samples",
-                  "request_blocks_sum", "requests_retired"):
+                  "request_blocks_sum", "requests_retired",
+                  # a window tier, sampled where blocks_held_sum is: the
+                  # blocks it holds, the blocks it has (a sum, so that a
+                  # difference of two readings divides by the samples
+                  # between them), and what it gave back behind a window
+                  "window_blocks_held_sum", "window_blocks_capacity_sum",
+                  "window_blocks_released"):
             self.counters[c] = 0
         self._pool_stats: Dict[str, int] = {}
 
-    def observe_pool(self, held: int, stats: Optional[dict] = None) -> None:
-        """One per-decode-step occupancy sample (held blocks)."""
+    def observe_pool(self, held: int, stats: Optional[dict] = None,
+                     window_held: int = 0, window_capacity: int = 0) -> None:
+        """One per-decode-step occupancy sample: blocks held over all
+        tiers, and the window tiers' own share of that."""
         with self._lock:
             self.counters["blocks_held_sum"] += int(held)
             self.counters["pool_samples"] += 1
+            self.counters["window_blocks_held_sum"] += int(window_held)
+            self.counters["window_blocks_capacity_sum"] += \
+                int(window_capacity)
             if stats is not None:
                 self._pool_stats = dict(stats)
 
@@ -173,10 +307,14 @@ class PagedMetrics(GenerativeMetrics):
                 self.counters["prefix_hits"] += 1
                 self.counters["prefix_blocks_hit"] += int(blocks_hit)
 
-    def observe_blocks(self, allocated: int = 0, released: int = 0) -> None:
+    def observe_blocks(self, allocated: int = 0, released: int = 0,
+                       behind_window: int = 0) -> None:
+        """``behind_window`` of the ``released`` were a window tier's,
+        given back while their request ran."""
         with self._lock:
             self.counters["blocks_allocated"] += int(allocated)
             self.counters["blocks_released"] += int(released)
+            self.counters["window_blocks_released"] += int(behind_window)
 
     def observe_request_blocks(self, n: int) -> None:
         with self._lock:
@@ -241,7 +379,13 @@ class PagedGenerativeServer(GenerativeServer):
       (params sharded per the "transformer" preset, every KV leaf
       sharded by heads; requires ``num_heads % tp == 0``).
     - ``prefix_cache=False`` disables content-addressed block reuse
-      (every prefill allocates fresh blocks).
+      (every prefill allocates fresh blocks). The default is on wherever
+      the spec allows it: a spec with a window tier or several tiers
+      serves without, and asking for it there raises
+      :class:`PrefixCacheUnsupportedError`.
+    - ``num_blocks``, ``kv_hbm_bytes``, ``tp > 1`` and a draft are for
+      a spec with one tier that keeps every block; every tier of any
+      other spec has the default size.
     - ``debug_leaks=True`` runs the pool's full accounting invariant
       against the live block tables after EVERY decode step (test/CI
       flag; O(blocks) per step).
@@ -256,8 +400,8 @@ class PagedGenerativeServer(GenerativeServer):
                  kv_hbm_bytes: Optional[int] = None,
                  max_blocks_per_req: Optional[int] = None,
                  tp: int = 1, devices: Optional[Sequence] = None,
-                 prefix_cache: bool = True, debug_leaks: bool = False,
-                 **kw):
+                 prefix_cache: Optional[bool] = None,
+                 debug_leaks: bool = False, **kw):
         if int(block_size) < 1:
             raise ValueError(f"block_size must be >= 1, got {block_size}")
         if int(tp) < 1:
@@ -270,12 +414,12 @@ class PagedGenerativeServer(GenerativeServer):
         self._maxb_arg = max_blocks_per_req
         self.tp = int(tp)
         self._devices_arg = devices
-        self.prefix_cache_enabled = bool(prefix_cache)
+        self._prefix_cache_arg = prefix_cache
         self.debug_leaks = bool(debug_leaks)
         self._strategy = None
         self._kv_sharding = None
         self._commit_lock = threading.Lock()
-        self._committed = 0          # reserved worst-case blocks
+        self._reserved: List[int] = []   # worst-case blocks, a tier
         # hot-reload fence: set by update_model(), consumed by the
         # worker at its next step boundary (the pool is worker-owned)
         self._prefix_flush_pending = threading.Event()
@@ -296,7 +440,11 @@ class PagedGenerativeServer(GenerativeServer):
     def _make_metrics(self) -> PagedMetrics:
         # pool geometry is resolved later in _init_kv, which backfills
         # num_blocks/block_size on this instance
-        return PagedMetrics(self.max_slots, 0, self.block_size)
+        metrics = PagedMetrics(self.max_slots, 0, self.block_size)
+        self._program_counters = tuple(self.spec.program_counters)
+        for c in self._program_counters:
+            metrics.counters[c] = 0
+        return metrics
 
     def _init_kv(self) -> None:
         """Allocate the paged memory tier: ``layers`` K leaves and as
@@ -321,21 +469,56 @@ class PagedGenerativeServer(GenerativeServer):
                 f"cannot hold max_seq_len {self.max_seq_len}")
         self._kv_dtype = DataType.from_any(spec.kv_dtype).jnp
         itemsize = jnp.zeros((), self._kv_dtype).dtype.itemsize
-        per_block_shape = tuple(spec.kv_shape(1, BS))
-        self.bytes_per_block = 2 * int(np.prod(per_block_shape)) * itemsize
-        if self._num_blocks_arg is not None:
-            num_blocks = int(self._num_blocks_arg)
-        elif self._kv_hbm_bytes_arg is not None:
-            num_blocks = max(2, int(self._kv_hbm_bytes_arg)
-                             // self.bytes_per_block)
-        else:
-            # dense-equivalent floor: every slot at full max_seq fits
-            num_blocks = 1 + self.max_slots * self._maxb
-        shape = tuple(spec.kv_shape(num_blocks, BS))
-        self.kv_slab_bytes = 2 * int(np.prod(shape)) * itemsize
+        layers, _, heads, _, head_dim = (int(d) for d in
+                                         spec.kv_shape(1, BS))
+        tiers = tuple(spec.kv_tiers) if spec.kv_tiers is not None \
+            else (KVTier("", tuple(range(layers))),)
+        if sorted(i for t in tiers for i in t.layers) != list(range(layers)):
+            raise ValueError("kv_tiers must name every layer once")
+        plain = len(tiers) == 1 and tiers[0].window is None
+        if self._prefix_cache_arg and not plain:
+            raise PrefixCacheUnsupportedError(
+                "the prefix cache serves one tier that keeps every block; "
+                f"this spec has {[(t.name, t.window) for t in tiers]}")
+        self.prefix_cache_enabled = plain and self._prefix_cache_arg \
+            is not False
+        if not plain and (self.tp > 1 or self.draft_spec is not None
+                          or self._kv_hbm_bytes_arg is not None
+                          or self._num_blocks_arg is not None):
+            raise ValueError("tp > 1, a draft, num_blocks and kv_hbm_bytes "
+                             "are for a spec with one tier that keeps "
+                             "every block")
+
+        def per_block(t):
+            return 2 * len(t.layers) * BS * heads * head_dim * itemsize
+
+        self.bytes_per_block = sum(per_block(t) for t in tiers)
+        self._tiers: List[_TierState] = []
+        self.kv_slab_bytes = 0
+        for t in tiers:
+            if self._num_blocks_arg is not None:
+                num_blocks = int(self._num_blocks_arg)
+            elif self._kv_hbm_bytes_arg is not None:
+                num_blocks = max(2, int(self._kv_hbm_bytes_arg)
+                                 // self.bytes_per_block)
+            else:
+                # dense-equivalent floor: every slot at its worst fits
+                num_blocks = 1 + self.max_slots * self._peak_blocks(
+                    t, self.max_seq_len)
+            self._tiers.append(_TierState(
+                t, BS, t.table_blocks(BS, self._maxb), num_blocks,
+                self.max_slots))
+            self.kv_slab_bytes += num_blocks * per_block(t)
+        self._tier_of = {i: ts for ts in self._tiers
+                         for i in ts.tier.layers}
+        self._window_tiers = [ts for ts in self._tiers
+                              if ts.tier.window is not None]
         memstats.check_headroom(
             self.kv_slab_bytes,
-            f"paged KV pool ({num_blocks} blocks x {BS} tokens)")
+            "paged KV pool (" + ", ".join(
+                f"{ts.tier.name or 'all'}: {ts.pool.num_blocks} blocks"
+                for ts in self._tiers) + f" x {BS} tokens)")
+        shape = tuple(spec.kv_shape(self._tiers[0].pool.num_blocks, BS))
         mesh_key = None
         if self.tp > 1:
             from jax.sharding import NamedSharding, PartitionSpec
@@ -367,16 +550,21 @@ class PagedGenerativeServer(GenerativeServer):
                                               PartitionSpec())
             mesh_key = (self.tp,
                         tuple(str(d) for d in strat.mesh.mesh.devices.flat))
-        layers, _, heads, _, head_dim = (int(d) for d in shape)
-        self._kv_leaves = layers
-        self._kv_leaf_shape = (num_blocks, BS, heads * head_dim)
+        self._kv_leaf_shapes = tuple(
+            (self._tier_of[i].pool.num_blocks, BS, heads * head_dim)
+            for i in range(layers))
         self._kc = self._fresh_leaves()
         self._vc = self._fresh_leaves()
         AllocationsTracker.get_instance().allocate("kv_slab",
                                                    self.kv_slab_bytes)
-        # host scheduler state (worker thread owns mutation)
-        self.pool = BlockPool(num_blocks, BS)
-        self.metrics.num_blocks = self.pool.capacity
+        # host scheduler state (worker thread owns mutation). The first
+        # tier's pool, tables and block counts under the names they had
+        # when there was one tier (the prefix cache and speculation,
+        # which need that one tier to keep every block, use them)
+        self.pool = self._tiers[0].pool
+        self._reserved = [0] * len(self._tiers)
+        self.metrics.num_blocks = sum(ts.pool.capacity
+                                      for ts in self._tiers)
         self.metrics.block_size = BS
         self._slots = SlotAllocator(self.max_slots)
         self._slot_reqs: List[Optional[GenerationRequest]] = \
@@ -384,9 +572,15 @@ class PagedGenerativeServer(GenerativeServer):
         self._tokens = np.zeros(self.max_slots, np.int32)
         self._positions = np.zeros(self.max_slots, np.int32)
         self._active = np.zeros(self.max_slots, bool)
-        self._tables = np.zeros((self.max_slots, self._maxb), np.int32)
-        self._nblocks = np.zeros(self.max_slots, np.int32)
-        disp = _paged_dispatchers(spec, shape, BS, self._maxb, mesh_key)
+        self._lane_ids = np.arange(self.max_slots)
+        self._tables = self._tiers[0].tables
+        self._nblocks = self._tiers[0].stop
+        # chain hashes of the prefix being prefilled (worker thread)
+        self._hashes: List[bytes] = []
+        disp = _paged_dispatchers(
+            spec, shape + tuple(ts.pool.num_blocks
+                                for ts in self._tiers[1:]),
+            BS, self._maxb, mesh_key)
         self._decode_disp = disp["decode"]
         self._prefill_disp = disp["prefill"]
         self._verify_disp = disp.get("verify")
@@ -396,33 +590,45 @@ class PagedGenerativeServer(GenerativeServer):
         layer, each made where it will live."""
         import jax.numpy as jnp
         return tuple(
-            jnp.zeros(self._kv_leaf_shape, self._kv_dtype,
-                      device=self._kv_sharding)
-            for _ in range(self._kv_leaves))
+            jnp.zeros(shape, self._kv_dtype, device=self._kv_sharding)
+            for shape in self._kv_leaf_shapes)
 
     # -- block-commitment admission (submit thread) ---------------------
-    def _worst_case_blocks(self, prompt_len: int,
-                           max_new_tokens: int) -> int:
-        return blocks_for_tokens(
-            min(int(prompt_len) + int(max_new_tokens), self.max_seq_len),
-            self.block_size)
+    def _peak_blocks(self, tier: KVTier, n_tokens: int) -> int:
+        """The most blocks of ``tier`` a request of ``n_tokens`` holds at
+        once; the most rows one run adds to it is a chunk."""
+        return tier.peak_blocks(n_tokens, self.block_size,
+                                self._buckets.max_rows)
 
-    def _uncommit(self, n: int) -> None:
+    def _worst_case_blocks(self, prompt_len: int,
+                           max_new_tokens: int) -> List[int]:
+        """The most blocks the request can hold at once, tier by tier."""
+        n = min(int(prompt_len) + int(max_new_tokens), self.max_seq_len)
+        return [self._peak_blocks(ts.tier, n) for ts in self._tiers]
+
+    @property
+    def _committed(self) -> int:
+        """Blocks reserved over all tiers."""
+        return sum(self._reserved)
+
+    def _uncommit(self, need: Sequence[int]) -> None:
         with self._commit_lock:
-            self._committed -= int(n)
+            for k, n in enumerate(need):
+                self._reserved[k] -= int(n)
 
     def submit(self, prompt, max_new_tokens: int = 16,
                **kw) -> GenerationHandle:
         """:meth:`GenerativeServer.submit` plus block-pool admission:
         the request's WORST-CASE block footprint (prompt + full token
-        budget) is reserved against pool capacity up front, so a placed
-        request can never fail a block allocation mid-decode. A request
-        the pool cannot ever hold alongside the committed load sheds
-        typed — :class:`PoolExhaustedError` with a ``retry_after_s``
-        backoff hint — instead of crashing a worker later. The
-        reservation is released exactly once, whenever the request's
-        future resolves (success, failure, timeout, shed, cancel, or a
-        second-crash fail — every resolution path sets the future).
+        budget) is reserved against pool capacity up front, in every
+        tier, so a placed request can never fail a block allocation
+        mid-decode. A request the pool cannot ever hold alongside the
+        committed load sheds typed — :class:`PoolExhaustedError` with a
+        ``retry_after_s`` backoff hint — instead of crashing a worker
+        later. The reservation is released exactly once, whenever the
+        request's future resolves (success, failure, timeout, shed,
+        cancel, or a second-crash fail — every resolution path sets the
+        future).
 
         Validation runs BEFORE the commitment: a request that could
         never run (empty/over-long/out-of-vocab prompt, zero token
@@ -432,7 +638,9 @@ class PagedGenerativeServer(GenerativeServer):
         p = self._validate_submit(prompt, max_new_tokens)
         need = self._worst_case_blocks(p.size, max_new_tokens)
         with self._commit_lock:
-            if self._committed + need > self.pool.capacity:
+            for ts, have, n in zip(self._tiers, self._reserved, need):
+                if have + n <= ts.pool.capacity:
+                    continue
                 self.metrics.inc("requests_submitted")
                 self.metrics.inc("requests_shed")
                 hint = (self.admission.retry_hint_s(
@@ -440,10 +648,11 @@ class PagedGenerativeServer(GenerativeServer):
                         if self.admission is not None else 0.25)
                 raise PoolExhaustedError(
                     f"KV block pool cannot hold the request: needs "
-                    f"{need} blocks worst-case, {self._committed} of "
-                    f"{self.pool.capacity} already committed — shed at "
+                    f"{n} blocks worst-case, {have} of "
+                    f"{ts.pool.capacity} already committed — shed at "
                     f"admission", retry_after_s=hint)
-            self._committed += need
+            for k, n in enumerate(need):
+                self._reserved[k] += n
         try:
             handle = super().submit(p, max_new_tokens, **kw)
         except BaseException:
@@ -455,11 +664,12 @@ class PagedGenerativeServer(GenerativeServer):
 
     def _can_place(self, req: GenerationRequest) -> bool:
         """Step-boundary gate: hold a queued request at the FRONT until
-        its prefill's blocks are actually free (free list + evictable
-        cached blocks). The submit-side commitment makes this
+        its prefill's blocks are actually free in every tier (free list +
+        evictable cached blocks). The submit-side commitment makes this
         eventually true without failing anything."""
-        need = blocks_for_tokens(int(req.prefix().size), self.block_size)
-        return self.pool.usable_free_count() >= need
+        n = int(req.prefix().size)
+        return all(ts.pool.usable_free_count()
+                   >= self._peak_blocks(ts.tier, n) for ts in self._tiers)
 
     # -- worker: prefill / decode / retire ------------------------------
     def _consume_prefix_flush(self) -> None:
@@ -481,95 +691,129 @@ class PagedGenerativeServer(GenerativeServer):
         self._consume_prefix_flush()
         return super()._step(slot)
 
-    def _prefill_io(self, s: int, prefix: np.ndarray, L: int):
-        """Blocks for the whole prefix (cached ones first, the rest
-        fresh), slot ``s``'s table, and the suffix that still has to run
-        through the program."""
+    def _prefill_runs(self, s: int, prefix: np.ndarray, L: int):
+        """Slot ``s``'s table starts with what the prefix cache holds of
+        the prefix; the rest runs through the program in chunks of the
+        largest bucket."""
         BS = self.block_size
-        hashes: List[bytes] = []
+        self._hashes = []
         hit: List[int] = []
         if self.prefix_cache_enabled:
             self._consume_prefix_flush()
-            hashes = prefix_block_hashes(prefix, BS)
+            self._hashes = prefix_block_hashes(prefix, BS)
             # reuse is capped one block short of the full prefix: at
             # least one suffix token must run through prefill (the
             # logits at the LAST prompt position produce the first
             # generated token)
-            hit = self.pool.lookup(hashes, max_blocks=(L - 1) // BS)
+            hit = self.pool.lookup(self._hashes, max_blocks=(L - 1) // BS)
             self.metrics.observe_prefix(True, len(hit))
-        hist = len(hit) * BS
-        fresh: List[int] = []
-        try:
-            for _ in range(blocks_for_tokens(L, BS) - len(hit)):
-                fresh.append(self.pool.alloc())
-        except PoolExhaustedError:
-            # roll back BOTH the fresh allocations and the cache-hit
-            # retains — the request fails typed without leaking a block
-            for b in fresh + hit:
-                self.pool.release(b)
-            raise
-        blocks = hit + fresh
-        self.metrics.observe_blocks(allocated=len(fresh))
-        self._tables[s, :] = NULL_BLOCK
-        self._tables[s, :len(blocks)] = blocks
-        self._nblocks[s] = len(blocks)
-        bucket, padded = self._pad_to_bucket(prefix[hist:])
-        io = {"tokens": padded, "length": np.int32(L - hist),
-              "hist": np.int32(hist), "table": self._tables[s].copy()}
+            self._tables[s, :len(hit)] = hit
+            self._nblocks[s] = len(hit)
+        hist, run = len(hit) * BS, self._buckets.max_rows
+        return [(a, min(a + run, L)) for a in range(hist, L, run)]
+
+    def _prefill_io(self, s: int, prefix: np.ndarray, L: int,
+                    start: int, stop: int):
+        """Fresh blocks for the rows ``[start, stop)`` of the prefix in
+        every tier, and what the program is given to run them. A
+        failed allocation leaves what it took on the slot's books, which
+        the retirement that follows gives back."""
+        BS = self.block_size
+        fresh = sum(ts.grow(s, blocks_for_tokens(stop, BS))
+                    for ts in self._tiers)
+        self.metrics.observe_blocks(allocated=fresh)
+        bucket, padded = self._pad_to_bucket(prefix[start:stop])
+        io = {"tokens": padded, "length": np.int32(stop - start),
+              "hist": np.int32(start)}
+        for ts in self._tiers:
+            t = ts.tier
+            io[t.key("table")] = ts.tables[s].copy()
+            if t.name:
+                # a window tier's ring still holds what the run reads,
+                # so where the run's own rows go comes beside the table
+                # (the unnamed tier's program finds it in the table)
+                u0 = start // BS
+                per_block = np.asarray(
+                    [ts.block_at(s, u)
+                     for u in range(u0, blocks_for_tokens(stop, BS))],
+                    np.int32)
+                wb = np.full(bucket, NULL_BLOCK, np.int32)
+                wb[:stop - start] = per_block[
+                    np.arange(start, stop) // BS - u0]
+                io[t.key("write_block")] = wb
 
         def filled():
-            # content-address the freshly FILLED full blocks (indices
-            # [len(hit), L // BS) — the trailing partial block is still
-            # being appended to and never registers)
-            for u in range(len(hit), min(len(hashes), L // BS)):
-                self.pool.register(hashes[u], int(blocks[u]))
+            # content-address the freshly FILLED full blocks (the
+            # trailing partial block is still being appended to and
+            # never registers); give back what the next run's window
+            # no longer reaches
+            for u in range(start // BS, min(len(self._hashes), stop // BS)):
+                self.pool.register(self._hashes[u], int(self._tables[s, u]))
+            self._advance(s, stop)
 
-        return io, {"bucket": bucket, "hist": hist}, filled
+        return io, {"bucket": bucket, "hist": start}, filled
+
+    def _advance(self, s: int, position: int) -> None:
+        gone = sum(ts.advance(s, position) for ts in self._window_tiers)
+        if gone:
+            self.metrics.observe_blocks(released=gone, behind_window=gone)
 
     def _decode_io(self) -> Optional[dict]:
         BS = self.block_size
-        # block-table growth at the step boundary: a lane whose next
-        # write position crosses into an unallocated block gets one.
-        # The submit-side commitment guarantees this cannot fail for a
-        # placed request; the typed retire is the defensive belt
+        # at the step boundary a window tier takes the last step's block
+        # into its table and gives back what the lane's next query no
+        # longer reads, and a lane whose next write position crosses
+        # into an unallocated block gets one in every tier (the tiers'
+        # block counts run together). The submit-side commitment
+        # guarantees this cannot fail for a placed request; the typed
+        # retire is the defensive belt
         for s in np.flatnonzero(self._active):
             s = int(s)
-            u = int(self._positions[s]) // BS
-            if u >= int(self._nblocks[s]):
+            pos = int(self._positions[s])
+            if self._window_tiers:
+                self._advance(s, pos)
+            if pos // BS >= int(self._nblocks[s]):
                 try:
-                    b = self.pool.alloc()
+                    grown = sum(ts.grow(s, pos // BS + 1)
+                                for ts in self._tiers)
                 except PoolExhaustedError as e:   # pragma: no cover
                     self._retire(s, error=e)
                     continue
-                self._tables[s, u] = b
-                self._nblocks[s] = u + 1
-                self.metrics.observe_blocks(allocated=1)
+                self.metrics.observe_blocks(allocated=grown)
         if not self._active.any():
             return None
         act = self._active.copy()
-        wb = np.full(self.max_slots, NULL_BLOCK, np.int32)
-        wo = np.zeros(self.max_slots, np.int32)
-        for s in np.flatnonzero(act):
-            s = int(s)
-            pos = int(self._positions[s])
-            wb[s] = self._tables[s, pos // BS]
-            wo[s] = pos % BS
-        return {"tokens": self._tokens.copy(),
-                "positions": self._positions.copy(),
-                "active": act,
-                "tables": self._tables.copy(),
-                "write_block": wb, "write_off": wo}
+        wo = self._positions % BS
+        wo[~act] = 0
+        io = {"tokens": self._tokens.copy(),
+              "positions": self._positions.copy(),
+              "active": act, "write_off": wo}
+        u = self._positions // BS
+        for ts in self._tiers:
+            # an idle lane's table is all null blocks, wherever it points
+            wb = ts.tables[self._lane_ids, u % ts.entries]
+            if ts.tier.window is not None:
+                for s in np.flatnonzero(act):
+                    if ts.pending[s]:
+                        wb[s] = ts.pending[s].get(int(u[s]), wb[s])
+            io[ts.tier.key("tables")] = ts.tables.copy()
+            io[ts.tier.key("write_block")] = wb
+        return io
 
     def _sample_pool(self) -> None:
-        self.metrics.observe_pool(self.pool.held_count(),
-                                  stats=self.pool.stats())
+        windows = [ts.pool for ts in self._window_tiers]
+        self.metrics.observe_pool(
+            sum(ts.pool.held_count() for ts in self._tiers),
+            stats=self.pool.stats(),
+            window_held=sum(p.held_count() for p in windows),
+            window_capacity=sum(p.capacity for p in windows))
 
     def _check_leaks(self) -> None:
         if self.debug_leaks:
-            self.pool.check_invariant(tables=[
-                self._tables[s, :int(self._nblocks[s])]
-                for s in range(self.max_slots)
-                if self._slot_reqs[s] is not None])
+            for ts in self._tiers:
+                ts.pool.check_invariant(tables=[
+                    ts.blocks(s) for s in range(self.max_slots)
+                    if self._slot_reqs[s] is not None])
 
     # -- speculative decoding over the paged tier -----------------------
     def _spec_ready(self) -> bool:
@@ -644,13 +888,9 @@ class PagedGenerativeServer(GenerativeServer):
             if (error is None and not cancelled
                     and self.prefix_cache_enabled and req.generated):
                 self._register_generated(s, req)
-            n = int(self._nblocks[s])
-            for u in range(n):
-                self.pool.release(int(self._tables[s, u]))
+            n = sum(ts.clear(s) for ts in self._tiers)
             self.metrics.observe_blocks(released=n)
             self.metrics.observe_request_blocks(n)
-            self._tables[s, :] = NULL_BLOCK
-            self._nblocks[s] = 0
         super()._retire(s, error=error, timed_out=timed_out,
                         cancelled=cancelled)
 
@@ -686,7 +926,8 @@ class PagedGenerativeServer(GenerativeServer):
         self._kc = self._fresh_leaves()
         self._vc = self._fresh_leaves()
         self._reset_draft_slabs()
-        self.pool.reset()
+        for ts in self._tiers:
+            ts.reset()
         # the wholesale reset already dropped the prefix cache — a
         # pending hot-reload flush is thereby satisfied
         self._prefix_flush_pending.clear()
@@ -695,8 +936,6 @@ class PagedGenerativeServer(GenerativeServer):
         self._tokens[:] = 0
         self._positions[:] = 0
         self._active[:] = False
-        self._tables[:] = NULL_BLOCK
-        self._nblocks[:] = 0
 
     # -- AOT warmup -----------------------------------------------------
     def warmup(self, buckets: Optional[Sequence[int]] = None) -> dict:
@@ -732,9 +971,25 @@ class PagedGenerativeServer(GenerativeServer):
                     self._strategy.param_sharding(n, np.ndim(a))
                     if self._strategy is not None else None)
             for n, a in self._params.items()}
-        kv_abs = (_abs(self._kv_leaf_shape, self._kv_dtype,
-                       self._kv_sharding),) * self._kv_leaves
+        kv_abs = tuple(_abs(shape, self._kv_dtype, self._kv_sharding)
+                       for shape in self._kv_leaf_shapes)
         S, MAXB = self.max_slots, self._maxb
+
+        def _tier_io(table_key, lead, rows):
+            """The tiers' part of a program's io: a table a request
+            (``lead`` requests) and, where the program is told (decode;
+            a named tier's prefill), the block of each of its ``rows``
+            fresh rows."""
+            out = {}
+            for ts in self._tiers:
+                t = ts.tier
+                out[t.key(table_key)] = _abs(lead + (ts.entries,),
+                                             jnp.int32, io_sh)
+                if lead or t.name:
+                    out[t.key("write_block")] = _abs((rows,), jnp.int32,
+                                                     io_sh)
+            return out
+
         mark = COMPILE_STATS.mark()
         t0 = _time.perf_counter()
 
@@ -757,16 +1012,15 @@ class PagedGenerativeServer(GenerativeServer):
                {"tokens": _abs((S,), jnp.int32, io_sh),
                 "positions": _abs((S,), jnp.int32, io_sh),
                 "active": _abs((S,), jnp.bool_, io_sh),
-                "tables": _abs((S, MAXB), jnp.int32, io_sh),
-                "write_block": _abs((S,), jnp.int32, io_sh),
-                "write_off": _abs((S,), jnp.int32, io_sh)},
+                "write_off": _abs((S,), jnp.int32, io_sh),
+                **_tier_io("tables", (S,), S)},
                f"paged_decode_s{S}")
         for b in bucket_list:
             _build(self._prefill_disp,
                    {"tokens": _abs((int(b),), jnp.int32, io_sh),
                     "length": _abs((), jnp.int32, io_sh),
                     "hist": _abs((), jnp.int32, io_sh),
-                    "table": _abs((MAXB,), jnp.int32, io_sh)},
+                    **_tier_io("table", (), int(b))},
                    f"paged_prefill_b{int(b)}")
         if self.draft_spec is not None:
             W = self.speculate_k
@@ -845,9 +1099,9 @@ class PagedGenerativeServer(GenerativeServer):
         load = super()._telemetry_load(depth, active)
         # capacity on the paged path is blocks held, not slots filled —
         # a router balancing on occupancy must see pool pressure
-        load["pool_occupancy"] = round(
-            self.pool.held_count() / self.pool.capacity, 4) \
-            if self.pool.capacity else 0.0
+        load["pool_occupancy"] = round(safe_ratio(
+            sum(ts.pool.held_count() for ts in self._tiers),
+            sum(ts.pool.capacity for ts in self._tiers)), 4)
         load["blocks_committed"] = self._committed
         return load
 
@@ -855,11 +1109,22 @@ class PagedGenerativeServer(GenerativeServer):
         """Pool accounting for /memory + capacity planning — block
         granularity instead of the dense per-slot rows."""
         st = self.pool.stats()
+        for ts in self._tiers[1:]:
+            for k, v in ts.pool.stats().items():
+                st[k] += v
         return {"kv_slab_bytes": self.kv_slab_bytes,
-                "kv_slab_shape": [self._kv_leaves, *self._kv_leaf_shape],
+                "kv_slab_shape": [len(self._kv_leaf_shapes),
+                                  *self._kv_leaf_shapes[0]],
+                "kv_tiers": {ts.tier.name or "all": {
+                    "layers": len(ts.tier.layers),
+                    "window": ts.tier.window,
+                    "table_entries": ts.entries,
+                    "num_blocks": ts.pool.capacity,
+                    "blocks_held": ts.pool.held_count()}
+                    for ts in self._tiers},
                 "kv_bytes_per_block": self.bytes_per_block,
                 "block_size": self.block_size,
-                "num_blocks": self.pool.capacity,
+                "num_blocks": st["capacity"],
                 "blocks_free": st["free"],
                 "blocks_held": st["held"],
                 "blocks_evictable": st["evictable"],
@@ -872,4 +1137,5 @@ class PagedGenerativeServer(GenerativeServer):
                 "active_slots": self._n_active()}
 
 
-__all__ = ["PagedGenerativeSpec", "PagedGenerativeServer", "PagedMetrics"]
+__all__ = ["PagedGenerativeSpec", "PagedGenerativeServer", "PagedMetrics",
+           "PrefixCacheUnsupportedError"]

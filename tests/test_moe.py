@@ -153,3 +153,102 @@ def test_grouped_dispatch_matches_ungrouped_at_ample_capacity():
     assert y8.shape == (32, d) and np.isfinite(float(aux))
     with pytest.raises(ValueError, match="divisible"):
         moe_ffn(x, p["gate_w"], p["w_in"], p["w_out"], n_groups=5)
+
+
+# ----------------------------------------------------------------------
+# the dropless top-k layer (parallel/moe.py topk_route, dropless_topk_ffn)
+from deeplearning4j_tpu.parallel import dropless_topk_ffn, topk_route
+
+
+def _reglu_experts(rng, d=8, f=12, e=8):
+    return (jnp.asarray(rng.normal(size=(e, d, f)) * 0.3, jnp.float32),
+            jnp.asarray(rng.normal(size=(e, d, f)) * 0.3, jnp.float32),
+            jnp.asarray(rng.normal(size=(e, f, d)) * 0.3, jnp.float32))
+
+
+def _token_loop(x, idx, w, gate, up, down):
+    """The layer as its equations read: token by token, choice by
+    choice."""
+    out = np.zeros(x.shape, np.float64)
+    x, gate, up, down = (np.asarray(a, np.float64)
+                         for a in (x, gate, up, down))
+    for n in range(x.shape[0]):
+        for e, we in zip(np.asarray(idx[n]), np.asarray(w[n])):
+            h = np.maximum(x[n] @ gate[e], 0.0) * (x[n] @ up[e])
+            out[n] += float(we) * (h @ down[e])
+    return out
+
+
+def test_topk_route_takes_the_k_largest_with_a_softmax_over_them():
+    rng = np.random.default_rng(3)
+    x = jnp.asarray(rng.normal(size=(10, 8)), jnp.float32)
+    router = jnp.asarray(rng.normal(size=(8, 6)), jnp.float32)
+    idx, w = topk_route(x, router, 3)
+    logits = np.asarray(x, np.float64) @ np.asarray(router, np.float64)
+    for n in range(10):
+        order = np.argsort(-logits[n])[:3]
+        assert sorted(np.asarray(idx[n])) == sorted(order)
+        e = np.exp(logits[n][np.asarray(idx[n])] - logits[n].max())
+        np.testing.assert_allclose(np.asarray(w[n]), e / e.sum(), rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(w).sum(axis=1), 1.0, rtol=1e-6)
+
+
+def test_dropless_layer_drops_nothing_when_one_expert_takes_half():
+    """Expert 0 is the first choice of half the tokens, 16 of 32, four
+    times an even share; the Switch layer at its default capacity would
+    keep 5 of them. Every pair is served, as a loop over tokens gives
+    it."""
+    rng = np.random.default_rng(4)
+    n, d, e, k = 32, 8, 8, 2
+    gate, up, down = _reglu_experts(rng, d, 12, e)
+    x = jnp.asarray(rng.normal(size=(n, d)), jnp.float32)
+    router = np.asarray(rng.normal(size=(d, e)), np.float32)
+    idx, w = topk_route(x, jnp.asarray(router), k)
+    idx = np.asarray(idx).copy()
+    idx[:n // 2, 0] = 0
+    idx[:n // 2, 1] = np.where(idx[:n // 2, 1] == 0, 1, idx[:n // 2, 1])
+    idx = jnp.asarray(idx)
+    y, served = jax.jit(dropless_topk_ffn)(x, idx, w, gate, up, down)
+    assert int(served[0]) >= n // 2 and int(served.sum()) == n * k
+    np.testing.assert_allclose(np.asarray(y),
+                               _token_loop(x, idx, w, gate, up, down),
+                               rtol=1e-4, atol=1e-5)
+
+
+def test_the_parts_of_four_shares_add_up_to_the_uncut_layer():
+    """Four chips holding two experts each route over all eight and give
+    their own experts' part; the parts add up to what one chip holding
+    all eight gives, and each serves only its own pairs."""
+    rng = np.random.default_rng(5)
+    n, d, e, k = 24, 8, 8, 3
+    gate, up, down = _reglu_experts(rng, d, 12, e)
+    x = jnp.asarray(rng.normal(size=(n, d)), jnp.float32)
+    idx, w = topk_route(x, jnp.asarray(rng.normal(size=(d, e)),
+                                       jnp.float32), k)
+    whole, served = dropless_topk_ffn(x, idx, w, gate, up, down)
+    parts, counts = zip(*(
+        dropless_topk_ffn(x, idx, w, gate[a:a + 2], up[a:a + 2],
+                          down[a:a + 2], first_expert=a)
+        for a in range(0, e, 2)))
+    np.testing.assert_allclose(np.asarray(sum(parts)), np.asarray(whole),
+                               rtol=1e-5, atol=1e-6)
+    np.testing.assert_array_equal(np.concatenate(counts), np.asarray(served))
+    np.testing.assert_allclose(np.asarray(whole),
+                               _token_loop(x, idx, w, gate, up, down),
+                               rtol=1e-4, atol=1e-5)
+
+
+def test_a_token_that_is_not_valid_is_served_by_no_expert():
+    rng = np.random.default_rng(6)
+    gate, up, down = _reglu_experts(rng)
+    x = jnp.asarray(rng.normal(size=(6, 8)), jnp.float32)
+    idx, w = topk_route(x, jnp.asarray(rng.normal(size=(8, 8)),
+                                       jnp.float32), 2)
+    valid = jnp.asarray([True, False, True, True, False, False])
+    y, served = dropless_topk_ffn(x, idx, w, gate, up, down, valid=valid)
+    assert int(served.sum()) == 3 * 2
+    assert not np.asarray(y)[~np.asarray(valid)].any()
+    want = _token_loop(x, idx, w, gate, up, down)
+    np.testing.assert_allclose(np.asarray(y)[np.asarray(valid)],
+                               want[np.asarray(valid)], rtol=1e-4,
+                               atol=1e-5)

@@ -403,6 +403,47 @@ class TestCountersHeldToTheirSpans:
         assert d["decode"] == pytest.approx(decode, rel=0.01)
         assert d["prefill"] == pytest.approx(prefill, rel=0.01)
 
+    def test_a_prompt_in_chunks_is_one_prefill_of_several_runs(self,
+                                                               paged_spec):
+        """Buckets of 4 alone: a prompt of more tokens runs through the
+        prefill program in chunks. ``serving.prefill`` then holds one
+        ``serving.prefill_chunk`` a run, each with its launch and only
+        the last with a sync; ``prefill_runs`` counts the runs,
+        ``prefills`` the prompts, and the exact sum is still the
+        ``serving.prefill`` spans'. The tokens are those of one run."""
+        def chunked(spec):
+            return paged_server(spec, buckets=[4], prefix_cache=False)
+
+        try:
+            spans, d, reqs, _ = ring_run(chunked, paged_spec)
+        finally:
+            disable_tracing()
+        prefills = by_name(spans, "serving.prefill")
+        chunks = by_name(spans, "serving.prefill_chunk")
+        runs = [-(-int(r.prompt.size) // 4) for r in reqs]
+        assert max(runs) > 1 and d["prefills"] == len(prefills) == 5
+        assert d["prefill_runs"] == sum(runs)
+        assert len(chunks) == sum(n for n in runs if n > 1)
+        whole = {s.sid for s in prefills}
+        assert all(c.parent in whole for c in chunks)
+        for p in prefills:
+            mine = sorted((c for c in chunks if c.parent == p.sid),
+                          key=lambda c: c.args["index"])
+            assert [c.args["index"] for c in mine] == list(range(len(mine)))
+            assert all(c.args["of"] == len(mine) for c in mine)
+            assert [c.args["hist"] for c in mine] == \
+                [4 * k for k in range(len(mine))]
+        inside = whole | {c.sid for c in chunks}
+        launches = [s for s in by_name(spans, "serving.launch")
+                    if s.parent in inside]
+        syncs = [s for s in by_name(spans, "serving.sync")
+                 if s.parent in inside]
+        assert len(launches) == sum(runs) and len(syncs) == 5
+        assert d["prefill"] == pytest.approx(
+            sum(s.dur for s in prefills) * 1e3, rel=0.02)
+        with paged_server(paged_spec) as srv:
+            assert serve(srv) == [list(r.generated) for r in reqs]
+
     def test_sched_host_is_the_steps_self_time(self, paged_run):
         spans, d, _, _ = paged_run
         steps = by_name(spans, "serving.step")
