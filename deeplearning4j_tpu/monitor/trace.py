@@ -482,7 +482,7 @@ SPAN_CATALOG: Dict[str, Tuple[str, Tuple[str, ...]]] = {
     # one serving.prefill_chunk a run of the program, each with its
     # serving.launch and only the last with a serving.sync
     "serving.prefill_chunk": ("serving", ("index", "of", "bucket", "hist")),
-    "serving.decode": ("serving", ("active", "slots")),
+    "serving.decode": ("serving", ("active", "slots", "table_entries")),
     "serving.draft": ("serving", ("active", "step", "slots", "phase",
                                   "bucket", "slot")),
     "serving.verify": ("serving", ("active", "window", "slots")),

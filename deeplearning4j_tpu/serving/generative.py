@@ -1385,6 +1385,11 @@ class GenerativeServer:
                 "positions": self._positions.copy(),
                 "active": self._active.copy()}
 
+    def _decode_span_args(self, io: dict) -> dict:
+        """What the ``serving.decode`` span says of this step's io beside
+        the lanes (paged: the width of the tables sent)."""
+        return {}
+
     def _sample_pool(self) -> None:
         """Memory-tier occupancy sample, once per decode step or round
         (paged: the block pool)."""
@@ -1408,7 +1413,7 @@ class GenerativeServer:
         n_active = int(io["active"].sum())
         nxt, logits_d, ms, launch_ms = self._dispatch(
             self._decode_disp, io, "serving.decode",
-            **self._batch_span_args(n_active))
+            **self._batch_span_args(n_active, **self._decode_span_args(io)))
         self._observe_decode(n_active, ms, launch_ms)
         if self._program_counters:
             # what the program counted rides behind its next tokens, in

@@ -9,12 +9,13 @@ from deeplearning4j_tpu.serving.paged.pool import (NULL_BLOCK, BlockPool,
                                                    KVTier,
                                                    PoolExhaustedError,
                                                    blocks_for_tokens,
-                                                   prefix_block_hashes)
+                                                   prefix_block_hashes,
+                                                   table_widths)
 from deeplearning4j_tpu.serving.paged.server import (
     PagedGenerativeServer, PagedGenerativeSpec, PagedMetrics,
     PrefixCacheUnsupportedError)
 
 __all__ = ["BlockPool", "PoolExhaustedError", "NULL_BLOCK", "KVTier",
-           "prefix_block_hashes", "blocks_for_tokens",
+           "prefix_block_hashes", "blocks_for_tokens", "table_widths",
            "PagedGenerativeSpec", "PagedGenerativeServer", "PagedMetrics",
            "PrefixCacheUnsupportedError"]

@@ -298,6 +298,32 @@ def blocks_for_tokens(n_tokens: int, block_size: int) -> int:
     return -(-int(n_tokens) // int(block_size))
 
 
+#: how many widths a decode program's table comes in, and the multiple
+#: of entries each is rounded up to (:func:`table_widths`)
+TABLE_RUNGS = 3
+TABLE_WIDTH_MULTIPLE = 8
+
+
+def table_widths(entries: int) -> Tuple[int, ...]:
+    """The ladder of widths in which the decode program is handed a
+    table of ``entries`` blocks a request: ``TABLE_RUNGS`` of them,
+    rising, each an equal share of the table rounded up to a multiple of
+    ``TABLE_WIDTH_MULTIPLE`` entries, the last the whole table (24: 8,
+    16, 24; 512: 176, 344, 512). At every step boundary the server sends
+    the narrowest that covers the longest active lane, so a program
+    gathers, masks and multiplies that many blocks a lane and not
+    ``max_seq_len``'s. The rule reads the table alone. A table too short
+    to split gives the same width on every rung, which is one program."""
+    entries = int(entries)
+    widths = []
+    for k in range(1, TABLE_RUNGS + 1):
+        share = blocks_for_tokens(entries * k, TABLE_RUNGS)
+        rounded = TABLE_WIDTH_MULTIPLE * blocks_for_tokens(
+            share, TABLE_WIDTH_MULTIPLE)
+        widths.append(min(entries, rounded))
+    return tuple(widths)
+
+
 @dataclass(frozen=True)
 class KVTier:
     """The layers of a model whose KV leaves share one block pool and
@@ -351,4 +377,5 @@ class KVTier:
 
 
 __all__ = ["BlockPool", "PoolExhaustedError", "NULL_BLOCK", "KVTier",
+           "TABLE_RUNGS", "TABLE_WIDTH_MULTIPLE", "table_widths",
            "prefix_block_hashes", "blocks_for_tokens"]

@@ -53,6 +53,13 @@ first token from the last run. A spec whose layers are all alike has
 one unnamed tier and takes the path it always took, with the same
 programs and arguments.
 
+The DECODE program reads as many table entries as its longest lane
+holds: at every step boundary the server sends each tier's tables cut to
+the narrowest of ``pool.table_widths`` (three widths, a rule on the
+table's entries alone) that covers the longest active lane, and warm-up
+builds the program once a width. A window tier's ring is addressed
+``u % entries`` and is never cut; prefill and verify take whole tables.
+
 Correctness contract: with ``max_blocks_per_req * block_size ==
 max_seq`` the gathered paged context is elementwise identical to the
 dense slab context (zoo/gpt.py ``gpt_paged_decode_fns``), so greedy
@@ -63,6 +70,7 @@ not a numerics change. See docs/serving.md "Paged KV & prefix caching".
 from __future__ import annotations
 
 import threading
+from bisect import bisect_left
 from dataclasses import dataclass
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
@@ -75,11 +83,12 @@ from deeplearning4j_tpu.serving.generative import (GenerationHandle,
                                                    GenerativeServer,
                                                    SlotAllocator)
 from deeplearning4j_tpu.serving.metrics import safe_ratio
-from deeplearning4j_tpu.serving.paged.pool import (NULL_BLOCK, BlockPool,
-                                                   KVTier,
+from deeplearning4j_tpu.serving.paged.pool import (NULL_BLOCK, TABLE_RUNGS,
+                                                   BlockPool, KVTier,
                                                    PoolExhaustedError,
                                                    blocks_for_tokens,
-                                                   prefix_block_hashes)
+                                                   prefix_block_hashes,
+                                                   table_widths)
 
 
 @dataclass
@@ -95,7 +104,13 @@ class PagedGenerativeSpec:
       — for one block geometry (the server memoizes the jitted
       dispatchers per geometry, so every server over the same model +
       geometry shares one compile set). Io contracts are documented on
-      ``zoo.gpt.gpt_paged_decode_fns``.
+      ``zoo.gpt.gpt_paged_decode_fns``. ``decode_fn`` takes the width of
+      a tier's table from its INPUT: for a tier without a window the
+      server hands it ``tables[:, :W]`` with ``W <=
+      max_blocks_per_req`` covering every active lane's blocks
+      (``pool.table_widths``), and the program must read no further; a
+      window tier's ring, and every table of ``prefill_fn`` and
+      ``verify_fn``, come whole.
     - ``kv_shape(num_blocks, block_size)`` gives the pool's five
       numbers ``(layers, num_blocks, heads, block_size, head_dim)``.
       The server holds K and V each as a tuple of ``layers`` arrays
@@ -142,11 +157,15 @@ class _TierState:
     are WRITTEN: while a run of a program fills fresh blocks, the
     entries they will take still hold blocks the run reads, so fresh
     blocks wait in ``pending`` (the program is told where its rows go)
-    until :meth:`advance`."""
+    until :meth:`advance`. ``widths`` are the widths the decode program
+    reads the table in, rung by rung (``pool.table_widths``); a ring is
+    read whole on every rung."""
 
     def __init__(self, tier: KVTier, block_size: int, entries: int,
                  num_blocks: int, max_slots: int):
         self.tier, self.BS, self.entries = tier, int(block_size), int(entries)
+        self.widths = table_widths(self.entries) if tier.window is None \
+            else (self.entries,) * TABLE_RUNGS
         self.pool = BlockPool(num_blocks, block_size)
         self.tables = np.zeros((max_slots, self.entries), np.int32)
         self.first = np.zeros(max_slots, np.int32)
@@ -282,7 +301,11 @@ class PagedMetrics(GenerativeMetrics):
                   # difference of two readings divides by the samples
                   # between them), and what it gave back behind a window
                   "window_blocks_held_sum", "window_blocks_capacity_sum",
-                  "window_blocks_released"):
+                  "window_blocks_released",
+                  # table entries a lane the decode program was handed,
+                  # and the whole table's, summed over plain decode steps
+                  # and the tiers without a window
+                  "decode_table_entries_sum", "decode_table_capacity_sum"):
             self.counters[c] = 0
         self._pool_stats: Dict[str, int] = {}
 
@@ -298,6 +321,13 @@ class PagedMetrics(GenerativeMetrics):
                 int(window_capacity)
             if stats is not None:
                 self._pool_stats = dict(stats)
+
+    def observe_tables(self, entries: int, capacity: int) -> None:
+        """One decode step's tables: ``entries`` a lane sent of
+        ``capacity``, over the tiers without a window."""
+        with self._lock:
+            self.counters["decode_table_entries_sum"] += int(entries)
+            self.counters["decode_table_capacity_sum"] += int(capacity)
 
     def observe_prefix(self, looked_up: bool, blocks_hit: int) -> None:
         with self._lock:
@@ -513,6 +543,9 @@ class PagedGenerativeServer(GenerativeServer):
                          for i in ts.tier.layers}
         self._window_tiers = [ts for ts in self._tiers
                               if ts.tier.window is not None]
+        self._ladder_tiers = [ts for ts in self._tiers
+                              if ts.tier.window is None]
+        self._ladder_capacity = sum(ts.entries for ts in self._ladder_tiers)
         memstats.check_headroom(
             self.kv_slab_bytes,
             "paged KV pool (" + ", ".join(
@@ -789,6 +822,12 @@ class PagedGenerativeServer(GenerativeServer):
               "positions": self._positions.copy(),
               "active": act, "write_off": wo}
         u = self._positions // BS
+        # the narrowest rung whose tables hold every active lane's
+        # blocks, this boundary's growth included (an active lane's
+        # position lies in a block it holds, so the program's mask never
+        # reaches past the width)
+        rung = max((bisect_left(ts.widths, int(ts.stop[act].max()))
+                    for ts in self._ladder_tiers), default=0)
         for ts in self._tiers:
             # an idle lane's table is all null blocks, wherever it points
             wb = ts.tables[self._lane_ids, u % ts.entries]
@@ -796,9 +835,16 @@ class PagedGenerativeServer(GenerativeServer):
                 for s in np.flatnonzero(act):
                     if ts.pending[s]:
                         wb[s] = ts.pending[s].get(int(u[s]), wb[s])
-            io[ts.tier.key("tables")] = ts.tables.copy()
+            io[ts.tier.key("tables")] = ts.tables[:, :ts.widths[rung]].copy()
             io[ts.tier.key("write_block")] = wb
+        self.metrics.observe_tables(
+            sum(ts.widths[rung] for ts in self._ladder_tiers),
+            self._ladder_capacity)
         return io
+
+    def _decode_span_args(self, io: dict) -> dict:
+        return {"table_entries": sum(
+            io[ts.tier.key("tables")].shape[1] for ts in self._ladder_tiers)}
 
     def _sample_pool(self) -> None:
         windows = [ts.pool for ts in self._window_tiers]
@@ -975,15 +1021,16 @@ class PagedGenerativeServer(GenerativeServer):
                        for shape in self._kv_leaf_shapes)
         S, MAXB = self.max_slots, self._maxb
 
-        def _tier_io(table_key, lead, rows):
+        def _tier_io(table_key, lead, rows, rung=-1):
             """The tiers' part of a program's io: a table a request
-            (``lead`` requests) and, where the program is told (decode;
-            a named tier's prefill), the block of each of its ``rows``
+            (``lead`` requests; whole, or for decode at ``rung`` of the
+            tiers' widths) and, where the program is told (decode; a
+            named tier's prefill), the block of each of its ``rows``
             fresh rows."""
             out = {}
             for ts in self._tiers:
                 t = ts.tier
-                out[t.key(table_key)] = _abs(lead + (ts.entries,),
+                out[t.key(table_key)] = _abs(lead + (ts.widths[rung],),
                                              jnp.int32, io_sh)
                 if lead or t.name:
                     out[t.key("write_block")] = _abs((rows,), jnp.int32,
@@ -1008,13 +1055,16 @@ class PagedGenerativeServer(GenerativeServer):
                     self._shapes_seen.add((role, sig))
                     self.metrics.inc("warmup_compiles")
 
-        _build(self._decode_disp,
-               {"tokens": _abs((S,), jnp.int32, io_sh),
-                "positions": _abs((S,), jnp.int32, io_sh),
-                "active": _abs((S,), jnp.bool_, io_sh),
-                "write_off": _abs((S,), jnp.int32, io_sh),
-                **_tier_io("tables", (S,), S)},
-               f"paged_decode_s{S}")
+        # one decode program a rung of the tiers' widths (rungs of one
+        # width are one signature, built once)
+        for rung in range(TABLE_RUNGS):
+            _build(self._decode_disp,
+                   {"tokens": _abs((S,), jnp.int32, io_sh),
+                    "positions": _abs((S,), jnp.int32, io_sh),
+                    "active": _abs((S,), jnp.bool_, io_sh),
+                    "write_off": _abs((S,), jnp.int32, io_sh),
+                    **_tier_io("tables", (S,), S, rung)},
+                   f"paged_decode_s{S}r{rung}")
         for b in bucket_list:
             _build(self._prefill_disp,
                    {"tokens": _abs((int(b),), jnp.int32, io_sh),
@@ -1053,6 +1103,9 @@ class PagedGenerativeServer(GenerativeServer):
                        kv_abs=dkv_abs, role="draft")
         self.warmup_report = {
             "decode_slots": S,
+            "decode_table_widths": {
+                ts.tier.name or "all": sorted(set(ts.widths))
+                for ts in self._tiers},
             "prefill_buckets": bucket_list,
             "speculative": self.draft_spec is not None,
             "seconds": round(_time.perf_counter() - t0, 4),

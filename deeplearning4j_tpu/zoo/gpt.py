@@ -514,12 +514,19 @@ def gpt_paged_decode_fns(cfg: GPTConfig, block_size: int,
       from global position ``hist + length - 1``. ONE program shape
       serves both the cold path (``hist = 0``) and every prefix hit.
     - ``decode_fn(params, kc, vc, io)`` with ``io = {"tokens": [S],
-      "positions": [S], "active": [S] bool, "tables": [S, MAXB] int32,
+      "positions": [S], "active": [S] bool, "tables": [S, W] int32,
       "write_block": [S] int32, "write_off": [S] int32}`` advances
       every active lane one token in ONE dispatch: the new K/V lands at
       host-computed ``(write_block, write_off)`` (inactive lanes write
       the null block), each lane attends over its own gathered table
-      masked to ``index <= position``.
+      masked to ``index <= position``. The table's width ``W`` is the
+      INPUT's: any ``W <= MAXB`` whose ``W * block_size`` positions
+      hold every active lane's position (the server sends the
+      narrowest of ``serving.paged.table_widths`` that does), and the
+      gather, the mask and both products run over that many blocks a
+      lane. The columns a narrower table leaves out are those whose
+      weights were 0 and whose V rows were zeroed; at ``W = MAXB`` it
+      is the program it always was.
     - ``verify_fn(params, kc, vc, io)`` — the speculative-decoding
       verifier over the paged pool: ``io`` carries a [S, W] token window
       plus [S, W] ``write_block``/``write_off`` (host-computed per
@@ -609,6 +616,59 @@ def gpt_paged_decode_fns(cfg: GPTConfig, block_size: int,
         # slab's order of the same elements
         return jnp.swapaxes(ctx, -3, -2)
 
+    # Each program's layer is jitted on its own, so that the program's
+    # trace and lowering hold the layer once and call it ``num_layers``
+    # times (the compiler inlines the calls): a decode program is built
+    # once a table width, and set-up pays for each. A layer takes ``lp``,
+    # its parameters under their names within the layer, ``kl``/``vl``,
+    # its leaves, and last ``ks``/``vs``, its K/V scales or None.
+    def _layers(layer, p, x, kc, vc, *args):
+        for i in range(L):
+            sc = f"h{i}"
+            lp = {n[len(sc):]: a for n, a in p.items()
+                  if n.startswith(sc + "/")}
+            x, kc[i], vc[i] = layer(
+                lp, x, kc[i], vc[i], *args,
+                ksc[i] if KQ else None, vsc[i] if KQ else None)
+        return x
+
+    @jax.jit
+    def _prefill_layer(lp, x, kl, vl, table, blk, off, cm, valid, ks, vs):
+        Lb = x.shape[0]
+        y = _ln(x, lp["/ln_1/gamma"], lp["/ln_1/beta"])
+        qkv = _matmul(lp, "/attn/qkv/kernel", y) + lp["/attn/qkv/bias"]
+        q, k, v = jnp.split(qkv.reshape(Lb, A, 3 * D), 3, axis=-1)
+        q = jnp.transpose(q, (1, 0, 2))                      # [A, Lb, D]
+        # write the suffix K/V FIRST, then gather the whole table —
+        # suffix self-attention reads its own fresh rows. A token's
+        # row over all heads goes into the leaf as it comes out of the
+        # qkv product
+        kl = kl.at[blk, off].set(
+            _q_store(k, kl.dtype, None if ks is None else ks[None])
+            .reshape(Lb, H))
+        vl = vl.at[blk, off].set(
+            _q_store(v, vl.dtype, None if vs is None else vs[None])
+            .reshape(Lb, H))
+        ctx_k = _q_load(_heads_first(kl[table].reshape(T, A, D)),
+                        None if ks is None else ks[:, None, :])
+        ctx_v = _q_load(_heads_first(vl[table].reshape(T, A, D)),
+                        None if vs is None else vs[:, None, :])
+        # zero unwritten rows BEFORE the matmuls: null-block trash
+        # (even NaN-poisoned) must not reach any reduction
+        ctx_k = jnp.where(valid[0][:, None], ctx_k, 0)
+        ctx_v = jnp.where(valid[0][:, None], ctx_v, 0)
+        scores = jnp.einsum(
+            "aqd,akd->aqk", q, ctx_k,
+            preferred_element_type=jnp.float32) * scale
+        scores = jnp.where(cm[None], scores, jnp.float32(-1e30))
+        probs = jax.nn.softmax(scores, axis=-1).astype(ctx_v.dtype)
+        att = jnp.einsum("aqk,akd->aqd", probs, ctx_v)
+        att = jnp.transpose(att, (1, 0, 2)).reshape(Lb, H)
+        att = _matmul(lp, "/attn/proj/kernel", att) + lp["/attn/proj/bias"]
+        x = x + att
+        y = _ln(x, lp["/ln_2/gamma"], lp["/ln_2/beta"])
+        return x + _mlp(lp, "", y), kl, vl
+
     def prefill_fn(params, kc, vc, io):
         p = params
         kc, vc = _leaves(kc), _leaves(vc)
@@ -631,43 +691,8 @@ def gpt_paged_decode_fns(cfg: GPTConfig, block_size: int,
         cm = jnp.arange(T)[None, :] <= g[:, None]            # [Lb, T]
         # rows past hist+length are unwritten blocks / null-block trash
         valid = jnp.arange(T)[None, :] < hist + length       # [1, T]
-        for i in range(L):
-            sc = f"h{i}"
-            y = _ln(x, p[f"{sc}/ln_1/gamma"], p[f"{sc}/ln_1/beta"])
-            qkv = _matmul(p, f"{sc}/attn/qkv/kernel", y) \
-                + p[f"{sc}/attn/qkv/bias"]
-            q, k, v = jnp.split(qkv.reshape(Lb, A, 3 * D), 3, axis=-1)
-            q = jnp.transpose(q, (1, 0, 2))                  # [A, Lb, D]
-            # write the suffix K/V FIRST, then gather the whole table —
-            # suffix self-attention reads its own fresh rows. A token's
-            # row over all heads goes into leaf i as it comes out of the
-            # qkv product
-            kc[i] = kc[i].at[blk, off].set(
-                _q_store(k, kc[i].dtype, ksc[i][None] if KQ else None)
-                .reshape(Lb, H))
-            vc[i] = vc[i].at[blk, off].set(
-                _q_store(v, vc[i].dtype, vsc[i][None] if KQ else None)
-                .reshape(Lb, H))
-            ctx_k = _q_load(_heads_first(kc[i][table].reshape(T, A, D)),
-                            ksc[i][:, None, :] if KQ else None)
-            ctx_v = _q_load(_heads_first(vc[i][table].reshape(T, A, D)),
-                            vsc[i][:, None, :] if KQ else None)
-            # zero unwritten rows BEFORE the matmuls: null-block trash
-            # (even NaN-poisoned) must not reach any reduction
-            ctx_k = jnp.where(valid[0][:, None], ctx_k, 0)
-            ctx_v = jnp.where(valid[0][:, None], ctx_v, 0)
-            scores = jnp.einsum(
-                "aqd,akd->aqk", q, ctx_k,
-                preferred_element_type=jnp.float32) * scale
-            scores = jnp.where(cm[None], scores, jnp.float32(-1e30))
-            probs = jax.nn.softmax(scores, axis=-1).astype(ctx_v.dtype)
-            att = jnp.einsum("aqk,akd->aqd", probs, ctx_v)
-            att = jnp.transpose(att, (1, 0, 2)).reshape(Lb, H)
-            att = _matmul(p, f"{sc}/attn/proj/kernel", att) \
-                + p[f"{sc}/attn/proj/bias"]
-            x = x + att
-            y = _ln(x, p[f"{sc}/ln_2/gamma"], p[f"{sc}/ln_2/beta"])
-            x = x + _mlp(p, sc, y)
+        x = _layers(_prefill_layer, p, x, kc, vc, table, blk, off, cm,
+                    valid)
         x = _ln(x, p["ln_f/gamma"], p["ln_f/beta"])
         h_last = jax.lax.dynamic_slice_in_dim(
             x, jnp.maximum(length - 1, 0), 1, axis=0)        # [1, H]
@@ -675,59 +700,99 @@ def gpt_paged_decode_fns(cfg: GPTConfig, block_size: int,
         return tuple(kc), tuple(vc), \
             jnp.argmax(logits).astype(jnp.int32), logits
 
+    @jax.jit
+    def _decode_layer(lp, x, kl, vl, tables, wb, wo, mask, ks, vs):
+        S, T = x.shape[0], mask.shape[-1]
+        y = _ln(x, lp["/ln_1/gamma"], lp["/ln_1/beta"])
+        qkv = _matmul(lp, "/attn/qkv/kernel", y) + lp["/attn/qkv/bias"]
+        q, k, v = jnp.split(qkv.reshape(S, A, 3 * D), 3, axis=-1)
+        # unconditional scatter: the host points inactive lanes at
+        # the null block, so no active request's rows are touched
+        # (active lanes own disjoint blocks — no write collisions)
+        kl = kl.at[wb, wo].set(
+            _q_store(k, kl.dtype, None if ks is None else ks[None])
+            .reshape(S, H))
+        vl = vl.at[wb, wo].set(
+            _q_store(v, vl.dtype, None if vs is None else vs[None])
+            .reshape(S, H))
+        ctx_k = _q_load(
+            _heads_first(kl[tables].reshape(S, T, A, D)),
+            None if ks is None else ks[None, :, None, :])
+        ctx_v = _q_load(
+            _heads_first(vl[tables].reshape(S, T, A, D)),
+            None if vs is None else vs[None, :, None, :])
+        scores = jnp.einsum(
+            "sad,satd->sat", q, ctx_k,
+            preferred_element_type=jnp.float32) * scale
+        scores = jnp.where(mask, scores, jnp.float32(-1e30))
+        probs = jax.nn.softmax(scores, axis=-1).astype(ctx_v.dtype)
+        # zero masked V rows — same poisoned-slab-reuse discipline
+        # as the slotted decode: weight 0 x NaN trash is still NaN
+        v_safe = jnp.where(mask[..., None], ctx_v, 0)
+        att = jnp.einsum("sat,satd->sad", probs, v_safe)
+        att = att.reshape(S, H)
+        att = _matmul(lp, "/attn/proj/kernel", att) + lp["/attn/proj/bias"]
+        x = x + att
+        y = _ln(x, lp["/ln_2/gamma"], lp["/ln_2/beta"])
+        return x + _mlp(lp, "", y), kl, vl
+
     def decode_fn(params, kc, vc, io):
         p = params
         kc, vc = _leaves(kc), _leaves(vc)
         tokens, active = io["tokens"], io["active"]
-        tables = io["tables"]                                # [S, MAXB]
+        tables = io["tables"]                        # [S, W], W <= MAXB
         wb, wo = io["write_block"], io["write_off"]
-        S = tokens.shape[0]
+        if tables.shape[1] > MAXB:
+            raise ValueError(f"tables has {tables.shape[1]} entries, a "
+                             f"request at most {MAXB} blocks")
+        T = tables.shape[1] * BS        # the context gathered, a lane
         pos = jnp.clip(io["positions"], 0, cfg.max_seq_len - 1)
         x = _tok_emb(p, tokens) \
             + jnp.take(p["wpe"], pos, axis=0)                # [S, H]
-        # attend to global index <= position; later table rows are
-        # unwritten blocks or the null block
+        # causal over global positions (index t IS position t since the
+        # host builds tables in order); rows past pos are masked
         mask = jnp.arange(T)[None, None, :] <= pos[:, None, None]
-        for i in range(L):
-            sc = f"h{i}"
-            y = _ln(x, p[f"{sc}/ln_1/gamma"], p[f"{sc}/ln_1/beta"])
-            qkv = _matmul(p, f"{sc}/attn/qkv/kernel", y) \
-                + p[f"{sc}/attn/qkv/bias"]
-            q, k, v = jnp.split(qkv.reshape(S, A, 3 * D), 3, axis=-1)
-            # unconditional scatter: the host points inactive lanes at
-            # the null block, so no active request's rows are touched
-            # (active lanes own disjoint blocks — no write collisions)
-            kc[i] = kc[i].at[wb, wo].set(
-                _q_store(k, kc[i].dtype, ksc[i][None] if KQ else None)
-                .reshape(S, H))
-            vc[i] = vc[i].at[wb, wo].set(
-                _q_store(v, vc[i].dtype, vsc[i][None] if KQ else None)
-                .reshape(S, H))
-            ctx_k = _q_load(
-                _heads_first(kc[i][tables].reshape(S, T, A, D)),
-                ksc[i][None, :, None, :] if KQ else None)
-            ctx_v = _q_load(
-                _heads_first(vc[i][tables].reshape(S, T, A, D)),
-                vsc[i][None, :, None, :] if KQ else None)
-            scores = jnp.einsum(
-                "sad,satd->sat", q, ctx_k,
-                preferred_element_type=jnp.float32) * scale
-            scores = jnp.where(mask, scores, jnp.float32(-1e30))
-            probs = jax.nn.softmax(scores, axis=-1).astype(ctx_v.dtype)
-            # zero masked V rows — same poisoned-slab-reuse discipline
-            # as the slotted decode: weight 0 x NaN trash is still NaN
-            v_safe = jnp.where(mask[..., None], ctx_v, 0)
-            att = jnp.einsum("sat,satd->sad", probs, v_safe)
-            att = att.reshape(S, H)
-            att = _matmul(p, f"{sc}/attn/proj/kernel", att) \
-                + p[f"{sc}/attn/proj/bias"]
-            x = x + att
-            y = _ln(x, p[f"{sc}/ln_2/gamma"], p[f"{sc}/ln_2/beta"])
-            x = x + _mlp(p, sc, y)
+        x = _layers(_decode_layer, p, x, kc, vc, tables, wb, wo, mask)
         x = _ln(x, p["ln_f/gamma"], p["ln_f/beta"])
         logits = _logits(p, x)                               # [S, vocab]
         return tuple(kc), tuple(vc), \
             jnp.argmax(logits, axis=-1).astype(jnp.int32), logits
+
+    @jax.jit
+    def _verify_layer(lp, x, kl, vl, tables, wb, wo, mask, vmask, ks, vs):
+        S, W, _ = x.shape
+        y = _ln(x, lp["/ln_1/gamma"], lp["/ln_1/beta"])
+        qkv = _matmul(lp, "/attn/qkv/kernel", y) + lp["/attn/qkv/bias"]
+        q, k, v = jnp.split(qkv.reshape(S, W, A, 3 * D), 3, axis=-1)
+        # unconditional [S, W] scatter: active lanes own disjoint
+        # in-order (block, off) pairs, inactive lanes' W columns all
+        # target the null block (colliding writes there are trash
+        # over trash by construction)
+        kl = kl.at[wb, wo].set(
+            _q_store(k, kl.dtype, None if ks is None else ks[None, None])
+            .reshape(S, W, H))
+        vl = vl.at[wb, wo].set(
+            _q_store(v, vl.dtype, None if vs is None else vs[None, None])
+            .reshape(S, W, H))
+        ctx_k = _q_load(
+            _heads_first(kl[tables].reshape(S, T, A, D)),
+            None if ks is None else ks[None, :, None, :])
+        ctx_v = _q_load(
+            _heads_first(vl[tables].reshape(S, T, A, D)),
+            None if vs is None else vs[None, :, None, :])
+        scores = jnp.einsum(
+            "swad,satd->swat", q, ctx_k,
+            preferred_element_type=jnp.float32) * scale
+        scores = jnp.where(mask[:, :, None, :], scores,
+                           jnp.float32(-1e30))
+        probs = jax.nn.softmax(scores, axis=-1).astype(ctx_v.dtype)
+        v_safe = jnp.where(vmask[:, None, :, None], ctx_v, 0)
+        att = jnp.einsum("swat,satd->swad", probs, v_safe)
+        att = att.reshape(S, W, H)
+        att = _matmul(lp, "/attn/proj/kernel", att) + lp["/attn/proj/bias"]
+        x = x + att
+        y = _ln(x, lp["/ln_2/gamma"], lp["/ln_2/beta"])
+        return x + _mlp(lp, "", y), kl, vl
 
     def verify_fn(params, kc, vc, io):
         p = params
@@ -746,44 +811,8 @@ def gpt_paged_decode_fns(cfg: GPTConfig, block_size: int,
         # rows masked for earlier w are fresh finite writes, rows past
         # the window's last position may be poisoned trash
         vmask = jnp.arange(T)[None, :] <= pos[:, -1][:, None]
-        for i in range(L):
-            sc = f"h{i}"
-            y = _ln(x, p[f"{sc}/ln_1/gamma"], p[f"{sc}/ln_1/beta"])
-            qkv = _matmul(p, f"{sc}/attn/qkv/kernel", y) \
-                + p[f"{sc}/attn/qkv/bias"]
-            q, k, v = jnp.split(qkv.reshape(S, W, A, 3 * D), 3, axis=-1)
-            # unconditional [S, W] scatter: active lanes own disjoint
-            # in-order (block, off) pairs, inactive lanes' W columns all
-            # target the null block (colliding writes there are trash
-            # over trash by construction)
-            kc[i] = kc[i].at[wb, wo].set(
-                _q_store(k, kc[i].dtype,
-                         ksc[i][None, None] if KQ else None)
-                .reshape(S, W, H))
-            vc[i] = vc[i].at[wb, wo].set(
-                _q_store(v, vc[i].dtype,
-                         vsc[i][None, None] if KQ else None)
-                .reshape(S, W, H))
-            ctx_k = _q_load(
-                _heads_first(kc[i][tables].reshape(S, T, A, D)),
-                ksc[i][None, :, None, :] if KQ else None)
-            ctx_v = _q_load(
-                _heads_first(vc[i][tables].reshape(S, T, A, D)),
-                vsc[i][None, :, None, :] if KQ else None)
-            scores = jnp.einsum(
-                "swad,satd->swat", q, ctx_k,
-                preferred_element_type=jnp.float32) * scale
-            scores = jnp.where(mask[:, :, None, :], scores,
-                               jnp.float32(-1e30))
-            probs = jax.nn.softmax(scores, axis=-1).astype(ctx_v.dtype)
-            v_safe = jnp.where(vmask[:, None, :, None], ctx_v, 0)
-            att = jnp.einsum("swat,satd->swad", probs, v_safe)
-            att = att.reshape(S, W, H)
-            att = _matmul(p, f"{sc}/attn/proj/kernel", att) \
-                + p[f"{sc}/attn/proj/bias"]
-            x = x + att
-            y = _ln(x, p[f"{sc}/ln_2/gamma"], p[f"{sc}/ln_2/beta"])
-            x = x + _mlp(p, sc, y)
+        x = _layers(_verify_layer, p, x, kc, vc, tables, wb, wo, mask,
+                    vmask)
         x = _ln(x, p["ln_f/gamma"], p["ln_f/beta"])
         logits = _logits(p, x)                           # [S, W, vocab]
         return tuple(kc), tuple(vc), \

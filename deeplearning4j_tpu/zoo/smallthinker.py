@@ -28,7 +28,7 @@ are cached in that dtype.
 The K/V a layer caches live in one of TWO TIERS of the paged pool
 (``serving.paged.KVTier``): global layers keep every block of a request,
 window layers a ring of the blocks one window can touch. The block sees
-neither: it is given a cache that knows, for layer ``i``, which rows its
+neither: it is given a cache that knows, for its layer, which rows its
 requests have cached and at which positions, and where the fresh rows
 go. Fresh rows are attended to as they come out of the projections and
 written afterwards, so a chunk of a prompt reads the ring as the chunk
@@ -148,47 +148,43 @@ def smallthinker_param_names(cfg: SmallThinkerConfig):
 
 
 class _PagedCache:
-    """What the block sees of the paged pool. ``hist`` [R] is how many
-    positions each of the R requests in the program has cached;
-    ``tiers`` maps a layer to ``(table [R, entries], write_block [N])``
-    of its tier; the N fresh rows (request-major) go to ``(write_block,
-    write_off)``. Leaf ``i`` is ``[num_blocks, block_size, kv_heads *
-    head_dim]``."""
+    """What a block sees of the paged pool: its layer's leaves ``kl``,
+    ``vl`` ``[num_blocks, block_size, kv_heads * head_dim]``, its tier's
+    ``table [R, entries]`` (R requests in the program), ``hist`` [R], how
+    many positions each request has cached, and where the N fresh rows
+    (request-major) go: ``(write_block [N], write_off [N])``."""
 
-    def __init__(self, kc, vc, tiers, hist, write_off, block_size):
-        self.kc, self.vc = list(kc), list(vc)
-        self.tiers, self.hist = tiers, hist
-        self.write_off, self.BS = write_off, int(block_size)
+    def __init__(self, kl, vl, table, write_block, hist, write_off,
+                 block_size):
+        self.kl, self.vl, self.table = kl, vl, table
+        self.write_block, self.write_off = write_block, write_off
+        self.hist, self.BS = hist, int(block_size)
 
-    def read(self, i):
-        """Layer ``i``'s cached rows of every request, ``K, V [R, T,
-        kv_heads * head_dim]``, and ``pos [R, T]``, the position of each
-        row in its sequence (negative: the entry holds nothing yet).
-        Entry ``e`` of a table of E entries holds block ``u = e (mod E)``,
-        the one such ``u`` among the last E blocks up to the block of
-        position ``hist - 1``: for a table as long as the longest
-        request that is ``u = e``, for a window tier's ring the block
-        that was written there last."""
+    def read(self):
+        """The cached rows of every request, ``K, V [R, T, kv_heads *
+        head_dim]``, and ``pos [R, T]``, the position of each row in its
+        sequence (negative: the entry holds nothing yet). Entry ``e`` of
+        a table of E entries holds block ``u = e (mod E)``, the one such
+        ``u`` among the last E blocks up to the block of position
+        ``hist - 1``: for a table that holds every block up to that one
+        (however much wider) that is ``u = e``, for a window tier's ring
+        the block that was written there last."""
         import jax.numpy as jnp
-        table, _ = self.tiers[i]
-        R, E = table.shape
+        R, E = self.table.shape
         last = jnp.floor_divide(self.hist - 1, self.BS)[:, None]    # [R, 1]
         u = last - jnp.mod(last - jnp.arange(E, dtype=jnp.int32)[None], E)
         pos = (u[:, :, None] * self.BS
                + jnp.arange(self.BS, dtype=jnp.int32)[None, None])
         pos = jnp.where(u[:, :, None] >= 0, pos, -1).reshape(R, E * self.BS)
-        K = self.kc[i][table].reshape(R, E * self.BS, -1)
-        V = self.vc[i][table].reshape(R, E * self.BS, -1)
+        K = self.kl[self.table].reshape(R, E * self.BS, -1)
+        V = self.vl[self.table].reshape(R, E * self.BS, -1)
         return K, V, pos
 
-    def write(self, i, k, v):
-        """The fresh rows ``k, v [N, kv_heads * head_dim]`` of layer
-        ``i``, in place."""
-        _, wb = self.tiers[i]
-        self.kc[i] = self.kc[i].at[wb, self.write_off].set(
-            k.astype(self.kc[i].dtype))
-        self.vc[i] = self.vc[i].at[wb, self.write_off].set(
-            v.astype(self.vc[i].dtype))
+    def write(self, k, v):
+        """The fresh rows ``k, v [N, kv_heads * head_dim]``, in place."""
+        at = (self.write_block, self.write_off)
+        self.kl = self.kl.at[at].set(k.astype(self.kl.dtype))
+        self.vl = self.vl.at[at].set(v.astype(self.vl.dtype))
 
 
 def smallthinker_paged_decode_fns(cfg: SmallThinkerConfig, block_size: int,
@@ -207,9 +203,12 @@ def smallthinker_paged_decode_fns(cfg: SmallThinkerConfig, block_size: int,
       ``hist + length - 1``. A prompt longer than the largest bucket is
       this program run several times with ``hist`` advancing.
     - ``decode_fn``: ``io = {"tokens", "positions", "active": [S],
-      "tables.<t>": [S, entries_t], "write_block.<t>": [S], "write_off":
-      [S]}``; returns ``(kc, vc, next [S + 4], logits [S, vocab])``:
-      behind the S next tokens come the step's :data:`PROGRAM_COUNTERS`,
+      "tables.<t>": [S, E_t] (a window tier's ring: its ``entries_t``;
+      a tier that keeps every block: any ``E_t <= entries_t`` that holds
+      every active lane's blocks, read as far as it goes),
+      "write_block.<t>": [S], "write_off": [S]}``; returns ``(kc, vc,
+      next [S + 4], logits [S, vocab])``: behind the S next tokens come
+      the step's :data:`PROGRAM_COUNTERS`,
       what the routers chose (idle lanes route nothing).
     """
     import jax
@@ -243,19 +242,20 @@ def smallthinker_paged_decode_fns(cfg: SmallThinkerConfig, block_size: int,
         x1, x2 = jnp.split(x, 2, axis=-1)
         return x * cos + jnp.concatenate([-x2, x1], axis=-1) * sin
 
-    def _attend(i, q, k, v, qpos, valid, cache):
+    def _attend(window, q, k, v, qpos, valid, cache):
         """q [R, Q, A, D], k/v [R, Q, KV, D] (the fresh rows), qpos/valid
         [R, Q]: each query over its request's cached rows and the fresh
-        rows up to itself, one softmax over both."""
+        rows up to itself, one softmax over both; ``window``: a window
+        layer."""
         R, Q = qpos.shape
-        Kc, Vc, cpos = cache.read(i)
+        Kc, Vc, cpos = cache.read()
         T = cpos.shape[1]
         Kc, Vc = Kc.reshape(R, T, KV, D), Vc.reshape(R, T, KV, D)
         dt = Kc.dtype
         see_c = (cpos >= 0) & (cpos < cache.hist[:, None])      # [R, T]
         see_c = jnp.broadcast_to(see_c[:, None, :], (R, Q, T))
         see_f = (qpos[:, None, :] <= qpos[:, :, None]) & valid[:, None, :]
-        if cfg.window_layout[i]:
+        if window:
             see_c = see_c & (cpos[:, None, :] > qpos[:, :, None] - W)
             see_f = see_f & (qpos[:, None, :] > qpos[:, :, None] - W)
         qg = q.reshape(R, Q, KV, G, D).astype(dt)
@@ -277,47 +277,71 @@ def smallthinker_paged_decode_fns(cfg: SmallThinkerConfig, block_size: int,
         out = out / jnp.transpose(den, (0, 3, 1, 2))[..., None]
         return out.reshape(R, Q, A * D)
 
-    def _block(p, i, x, qpos, valid, cache):
-        """Layer ``i`` on the stream ``x [R, Q, H]`` (R requests, Q fresh
-        rows each). Returns the stream and the tokens each expert
-        served."""
+    def _block(lp, x, qpos, valid, kl, vl, table, wb, hist, write_off,
+               rope, window):
+        """One layer on the stream ``x [R, Q, H]`` (R requests, Q fresh
+        rows each): ``lp`` its parameters under their names within the
+        layer, ``kl``/``vl`` its leaves, ``(table, wb)`` its tier's.
+        Returns the stream, the tokens each expert served, and the
+        leaves. Jitted on its own (``rope``/``window`` static: two kinds
+        of layer), so that a program's trace and lowering hold each kind
+        once and call it (the compiler inlines the calls): the decode
+        program is built once a table width, and set-up pays for
+        each."""
+        cache = _PagedCache(kl, vl, table, wb, hist, write_off, BS)
         R, Q, _ = x.shape
-        sc = f"h{i}"
-        a = _rmsnorm(x, p[f"{sc}/norm_1"])
+        a = _rmsnorm(x, lp["/norm_1"])
         # the router is placed before attention
-        idx, wts = topk_route(a.reshape(R * Q, H), p[f"{sc}/router"],
+        idx, wts = topk_route(a.reshape(R * Q, H), lp["/router"],
                               cfg.experts_per_token)
-        q = _mm(a, p[f"{sc}/attn/q"]).reshape(R, Q, A, D)
-        k = _mm(a, p[f"{sc}/attn/k"]).reshape(R, Q, KV, D)
-        v = _mm(a, p[f"{sc}/attn/v"]).reshape(R, Q, KV, D)
-        if cfg.rope_layout[i]:
+        q = _mm(a, lp["/attn/q"]).reshape(R, Q, A, D)
+        k = _mm(a, lp["/attn/k"]).reshape(R, Q, KV, D)
+        v = _mm(a, lp["/attn/v"]).reshape(R, Q, KV, D)
+        if rope:
             q, k = _rope(q, qpos), _rope(k, qpos)
-        att = _attend(i, q, k, v, qpos, valid, cache)
-        cache.write(i, k.reshape(R * Q, KV * D), v.reshape(R * Q, KV * D))
-        x = x + _mm(att, p[f"{sc}/attn/o"])
-        m = _rmsnorm(x, p[f"{sc}/norm_2"])
+        att = _attend(window, q, k, v, qpos, valid, cache)
+        cache.write(k.reshape(R * Q, KV * D), v.reshape(R * Q, KV * D))
+        x = x + _mm(att, lp["/attn/o"])
+        m = _rmsnorm(x, lp["/norm_2"])
         y, served = dropless_topk_ffn(
-            m.reshape(R * Q, H), idx, wts, p[f"{sc}/experts/gate"],
-            p[f"{sc}/experts/up"], p[f"{sc}/experts/down"],
+            m.reshape(R * Q, H), idx, wts, lp["/experts/gate"],
+            lp["/experts/up"], lp["/experts/down"],
             valid=valid.reshape(R * Q))
-        return x + y.reshape(R, Q, H), served
+        return x + y.reshape(R, Q, H), served, cache.kl, cache.vl
 
-    def _stack(p, tokens, qpos, valid, cache):
+    block = jax.jit(_block, static_argnames=("rope", "window"))
+
+    def _stack(p, tokens, qpos, valid, kc, vc, tiers, hist, write_off):
+        """Every layer over the stream; ``tiers`` maps a layer to its
+        tier's ``(table, write_block)``. Returns the stream, the tokens
+        each expert served a layer ``[L, E]``, and the leaves."""
+        kc, vc = list(kc), list(vc)
         x = jnp.take(p["embed"], tokens, axis=0).astype(jnp.float32)
         served = []
         for i in range(L):
-            x, n = _block(p, i, x, qpos, valid, cache)
+            sc = f"h{i}"
+            lp = {n[len(sc):]: a for n, a in p.items()
+                  if n.startswith(sc + "/")}
+            x, n, kc[i], vc[i] = block(
+                lp, x, qpos, valid, kc[i], vc[i], *tiers[i], hist,
+                write_off, rope=bool(cfg.rope_layout[i]),
+                window=bool(cfg.window_layout[i]))
             served.append(n)
-        return _rmsnorm(x, p["norm_f"]), jnp.stack(served)       # [L, E]
+        return _rmsnorm(x, p["norm_f"]), jnp.stack(served), \
+            tuple(kc), tuple(vc)
 
     def _tiers(io, table_key, lift):
         per_tier = {}
         for t in cfg.kv_tiers():
             table = lift(io[t.key(table_key)])
-            if table.shape[1] != t.table_blocks(BS, max_blocks_per_req):
+            entries = t.table_blocks(BS, max_blocks_per_req)
+            # a ring is addressed u % entries: exact. A table that keeps
+            # every block may come cut to the blocks its lanes hold
+            if table.shape[1] > entries or (
+                    t.window is not None and table.shape[1] != entries):
                 raise ValueError(
                     f"{t.key(table_key)} has {table.shape[1]} entries, the "
-                    f"tier's table {t.table_blocks(BS, max_blocks_per_req)}")
+                    f"tier's table {entries}")
             per_tier[t.name] = (table, io[t.key("write_block")])
         return {i: per_tier[t.name] for i, t in tier_of.items()}
 
@@ -326,29 +350,26 @@ def smallthinker_paged_decode_fns(cfg: SmallThinkerConfig, block_size: int,
         Lb = tokens.shape[0]
         g = hist + jnp.arange(Lb, dtype=jnp.int32)
         valid = jnp.arange(Lb) < length
-        cache = _PagedCache(kc, vc, _tiers(io, "table", lambda t: t[None]),
-                            hist[None], g % BS, BS)
-        x, _ = _stack(params, tokens[None], g[None], valid[None], cache)
+        x, _, kc, vc = _stack(
+            params, tokens[None], g[None], valid[None], kc, vc,
+            _tiers(io, "table", lambda t: t[None]), hist[None], g % BS)
         h_last = jax.lax.dynamic_slice_in_dim(
             x[0], jnp.maximum(length - 1, 0), 1, axis=0)
         logits = _mm(h_last, params["lm_head"])[0]
-        return tuple(cache.kc), tuple(cache.vc), \
-            jnp.argmax(logits).astype(jnp.int32), logits
+        return kc, vc, jnp.argmax(logits).astype(jnp.int32), logits
 
     def decode_fn(params, kc, vc, io):
         tokens, pos, active = io["tokens"], io["positions"], io["active"]
-        cache = _PagedCache(kc, vc, _tiers(io, "tables", lambda t: t),
-                            pos, io["write_off"], BS)
-        x, served = _stack(params, tokens[:, None], pos[:, None],
-                           active[:, None], cache)
+        x, served, kc, vc = _stack(
+            params, tokens[:, None], pos[:, None], active[:, None], kc, vc,
+            _tiers(io, "tables", lambda t: t), pos, io["write_off"])
         logits = _mm(x[:, 0], params["lm_head"])
         counted = jnp.stack([                  # PROGRAM_COUNTERS' order
             jnp.int32(L), jnp.sum(served > 0, dtype=jnp.int32),
             jnp.sum(served, dtype=jnp.int32),
             jnp.sum(jnp.max(served, axis=1), dtype=jnp.int32)])
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        return tuple(cache.kc), tuple(cache.vc), \
-            jnp.concatenate([nxt, counted]), logits
+        return kc, vc, jnp.concatenate([nxt, counted]), logits
 
     return prefill_fn, decode_fn
 

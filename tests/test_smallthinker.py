@@ -192,6 +192,116 @@ def test_a_request_over_the_window_tiers_pool_is_shed_typed(spec):
         srv.shutdown(drain=False)
 
 
+def test_the_global_tiers_table_is_cut_to_the_lanes_and_the_ring_is_not(
+        spec, monkeypatch):
+    """The global tier's 16 entries come 8 or 16 wide (``table_widths``),
+    the window tier's ring of 3 whole at every step; the logits are
+    those of a server that reads every table whole, in another order of
+    summation only."""
+    from deeplearning4j_tpu.serving.paged import server as paged_server
+    from deeplearning4j_tpu.serving.paged.pool import TABLE_RUNGS
+    prompts, late = [prompt(5, 1), prompt(21, 2), prompt(9, 3)], [prompt(6, 4)]
+    shapes = []
+    with server(spec) as srv:
+        glob, win = srv._tiers
+        assert glob.widths == (8, 16, 16) and win.widths == (3, 3, 3)
+        real = srv._decode_io
+
+        def spy():
+            io = real()
+            if io is not None:
+                shapes.append((io["tables.global"].shape[1],
+                               io["tables.window"].shape[1],
+                               int(glob.stop[io["active"]].max())))
+            return io
+
+        srv._decode_io = spy
+        t1, l1 = logits_served(srv, prompts, 3 * WINDOW + 4)
+        t1 += logits_served(srv, late, 4)[0]
+        c = dict(srv.metrics.counters)
+    assert {w for _, w, _ in shapes} == {3}
+    assert {g for g, _, _ in shapes} == {8, 16}
+    assert all(g == (8 if held <= 8 else 16) for g, _, held in shapes)
+    # the width falls back once the long lanes have retired
+    sent = [g for g, _, _ in shapes]
+    assert any(b < a for a, b in zip(sent, sent[1:]))
+    assert c["decode_table_entries_sum"] == sum(sent)
+    assert c["decode_table_capacity_sum"] == 16 * len(sent)
+    monkeypatch.setattr(paged_server, "table_widths",
+                        lambda entries: (int(entries),) * TABLE_RUNGS)
+    with server(spec) as srv:
+        assert srv._tiers[0].widths == (16, 16, 16)
+        t2, l2 = logits_served(srv, prompts, 3 * WINDOW + 4)
+        t2 += logits_served(srv, late, 4)[0]
+        c = srv.metrics.counters
+        assert c["decode_table_entries_sum"] == \
+            c["decode_table_capacity_sum"] == 16 * c["decode_steps"]
+    assert t1 == t2
+    for a, b in zip(l1, l2):
+        np.testing.assert_allclose(a, b, rtol=0, atol=1e-5)
+
+
+@pytest.mark.parametrize("glob, win, ok", [
+    (16, 3, True), (8, 3, True), (17, 3, False), (16, 2, False),
+    (8, 4, False)])
+def test_the_program_takes_a_cut_global_table_and_only_a_whole_ring(
+        spec, glob, win, ok):
+    import jax
+    import jax.numpy as jnp
+    _, decode_fn = spec.make_fns(BS, 16)
+    S = 3
+    lane = jax.ShapeDtypeStruct((S,), jnp.int32)
+    io = {"tokens": lane, "positions": lane, "write_off": lane,
+          "active": jax.ShapeDtypeStruct((S,), jnp.bool_),
+          "tables.global": jax.ShapeDtypeStruct((S, glob), jnp.int32),
+          "tables.window": jax.ShapeDtypeStruct((S, win), jnp.int32),
+          "write_block.global": lane, "write_block.window": lane}
+    params = {n: jax.ShapeDtypeStruct(np.shape(a), a.dtype)
+              for n, a in spec.params().items()}
+    side = tuple(jax.ShapeDtypeStruct((9, BS, 2 * 8), jnp.bfloat16)
+                 for _ in range(4))
+    if ok:
+        out = jax.eval_shape(decode_fn, params, side, side, io)
+        assert out[3].shape == (S, CFG["vocab_size"])
+    else:
+        with pytest.raises(ValueError, match="entries"):
+            jax.eval_shape(decode_fn, params, side, side, io)
+
+
+@pytest.mark.parametrize("program", ["prefill", "decode"])
+def test_both_programs_call_the_one_block_a_kind_of_layer(spec, program):
+    """One layer function for both programs, jitted on its own: a
+    program's trace holds it once a kind of layer (global, window) and
+    calls it ``num_layers`` times."""
+    import jax
+    import jax.numpy as jnp
+    prefill_fn, decode_fn = spec.make_fns(BS, 16)
+    S = 3
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+    if program == "decode":
+        fn, io = decode_fn, {
+            "tokens": i32(S), "positions": i32(S), "write_off": i32(S),
+            "active": jax.ShapeDtypeStruct((S,), jnp.bool_),
+            "tables.global": i32(S, 8), "tables.window": i32(S, 3),
+            "write_block.global": i32(S), "write_block.window": i32(S)}
+    else:
+        fn, io = prefill_fn, {
+            "tokens": i32(8), "length": i32(), "hist": i32(),
+            "table.global": i32(16), "table.window": i32(3),
+            "write_block.global": i32(8), "write_block.window": i32(8)}
+    params = {n: jax.ShapeDtypeStruct(np.shape(a), a.dtype)
+              for n, a in spec.params().items()}
+    side = tuple(jax.ShapeDtypeStruct((9, BS, 2 * 8), jnp.bfloat16)
+                 for _ in range(4))
+    jaxpr = jax.make_jaxpr(fn)(params, side, side, io)
+    blocks = [e for e in jaxpr.eqns if e.params.get("name") == "_block"]
+    assert len(blocks) == 4
+    pc = SmallThinkerConfig.from_dict(CFG)
+    kinds = {(bool(r), bool(w))
+             for r, w in zip(pc.rope_layout, pc.window_layout)}
+    assert len({id(e.params["jaxpr"]) for e in blocks}) == len(kinds)
+
+
 def test_tier_arithmetic():
     t = KVTier("window", (1,), 4096)
     assert t.table_blocks(16, 512) == 257 and t.key("tables") == \
