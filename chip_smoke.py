@@ -155,8 +155,8 @@ def divergence_gap(sd, cfg, prompt, a, b, max_seq_len: int):
 def train_leg(cfg, batch: int, seq_len: int, steps: int, epochs: int,
               sharding=None, seed: int = 0):
     """``SameDiff.fit`` twice for ``epochs`` passes over ``steps``
-    seeded batches, wired as bench.py wires gpt_medium: the first call
-    compiles, the second is timed and must compile nothing. Returns
+    seeded batches: the first call compiles, the second is timed and
+    must compile nothing. Returns
     ``(sd, report)``; raises :class:`SmokeFailure` when a loss is not
     finite, the first is not near ln(vocab), or the last is not below
     the first."""

@@ -121,7 +121,7 @@ class SameDiff:
         self._analysis_key = None
         # dispatch/compile accounting of the most recent fit() epoch
         # (tier, dispatches_per_epoch, window sizes/compiles) — consumed
-        # by ui/stats StatsListener and bench.py
+        # by ui/stats StatsListener
         self.last_fit_stats = None
         # op namespaces (reference: SDMath/SDNN/... generated classes)
         from deeplearning4j_tpu.autodiff.ops_namespaces import make_namespaces
@@ -1018,8 +1018,7 @@ class SameDiff:
         it. The check is a boolean ``isfinite``-AND reduce (not a float
         norm accumulation): XLA fuses the elementwise ``isfinite`` into
         each gradient's producer and the AND-reduce has no serial float
-        dependency chain — measured noise-level next to the step's
-        matmuls (bench.py sentinel_overhead tracks it)."""
+        dependency chain."""
         ok = jnp.isfinite(data_loss)
         for g in jax.tree_util.tree_leaves(grads):
             ok = ok & jnp.all(jnp.isfinite(g))
@@ -1263,8 +1262,8 @@ class SameDiff:
         (True = warn on error findings and proceed; "strict" = raise
         GraphAnalysisError BEFORE any compile; False = off). Cached on
         the graph version + fit context, so only the first fit of a
-        given graph pays the walk — warm dispatches see a dict lookup
-        (bench.py analyze_overhead)."""
+        given graph pays the walk — warm dispatches see a dict
+        lookup."""
         tc = self.training_config
         mode = getattr(tc, "analyze", True) if tc is not None else False
         if not mode:
@@ -1961,7 +1960,7 @@ class SameDiff:
                     else jnp.asarray(float("nan")))
             history.add_epoch(epoch, mean_loss)
             tc.epoch_count = getattr(tc, "epoch_count", 0) + 1
-            # dispatch accounting (ui/stats 'dispatch' records, bench.py)
+            # dispatch accounting (ui/stats 'dispatch' records)
             self.last_fit_stats = {
                 "tier": "per_step", "fused_steps": 1, "accum_steps": 1,
                 "steps_per_epoch": iteration - epoch_start_iter,

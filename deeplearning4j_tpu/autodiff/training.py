@@ -167,8 +167,7 @@ class TrainingConfig:
     # compare it against the host bytes and stamp the snapshot;
     # restores re-verify the stamp; mismatch raises a typed
     # faults.SilentCorruptionError. Parameter math is untouched —
-    # fingerprints-on training is bit-identical (bench.py
-    # integrity_overhead, ≤2% bar with the stall watchdog armed too).
+    # fingerprints-on training is bit-identical.
     fingerprints: bool = False
     # replay probe cadence (windows): every Nth window is re-dispatched
     # from a stashed carry and the two digests compared — genuine
@@ -186,8 +185,7 @@ class TrainingConfig:
     # error-severity findings warn (GraphAnalysisWarning) and the fit
     # proceeds; "strict" = raise GraphAnalysisError BEFORE any XLA
     # compile; False = off. Analysis runs once per graph version, so
-    # its cost never touches the warm dispatch path (bench.py
-    # analyze_overhead).
+    # its cost never touches the warm dispatch path.
     analyze: Any = True
 
     def __post_init__(self):

@@ -18,9 +18,6 @@ FIRST step fast. Three rails, all composing with the existing stack:
   batch/request, and :class:`AOTDispatch` routes matching dispatches to
   the prebuilt executables (falling back to lazy ``jax.jit`` for shapes
   nobody predicted).
-- ``bench.py cold_start`` — fresh-process first-compile vs warm-restart
-  (populated cache) time per model, so cold-start is a tracked BENCH
-  metric next to throughput.
 
 See docs/cold_start.md for the operational story (what is and is not
 cacheable across JAX/libtpu versions, cache invalidation, sizing).

@@ -18,8 +18,9 @@ invisible; this module makes it a wired, observable part of the runtime:
 - :class:`CompileStats` (singleton :data:`COMPILE_STATS`) counts every
   compile in the process via ``jax.monitoring`` events and splits them
   into persistent-cache HITS (cheap deserialization) vs MISSES (real
-  backend compiles), with cumulative wall time per phase. Tests and the
-  ``cold_start`` bench assert against deltas of these counters;
+  backend compiles), with cumulative wall time per phase. Tests assert
+  against deltas of these counters, and the benchmark's
+  ``setup_compile_s`` reads one;
   ``MetricsRegistry.fold_compile`` exports them as ``dl4j_compile_*``.
 - Each compile phase also lands in the monitor/ tracer ring as a
   synthetic span — ``compile.trace`` (jaxpr tracing), ``compile.lower``

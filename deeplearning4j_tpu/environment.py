@@ -106,7 +106,7 @@ _SIDE_EFFECT_PROPS = ("log_compiles", "compilation_cache_dir",
                       "compilation_cache_min_compile_time")
 
 # cache properties additionally export their env var on set() so child
-# processes (bench probes, multihost workers) inherit the cache
+# processes (restart probes, multihost workers) inherit the cache
 _CACHE_PROPS = ("compilation_cache_dir",
                 "compilation_cache_min_entry_size",
                 "compilation_cache_min_compile_time")
@@ -248,7 +248,7 @@ class Environment:
         ``$JAX_COMPILATION_CACHE_DIR`` where set, else
         :data:`DEFAULT_CACHE_DIR`. Every path that compiles a model
         program calls this first (``SameDiff.fit()``/``precompile()``,
-        serving warmup, ``bench.py``, ``chip_smoke.py``), so no run is
+        serving warmup, ``chip_smoke.py``), so no run is
         without a cache and none sets a directory of its own. The
         admission knobs still at their catalog default are left alone
         (a direct ``jax.config.update`` by the user wins)."""

@@ -38,8 +38,8 @@ Arm it: ``TrainingConfig.fingerprints = True`` (+
 ``StallWatchdog(...).install()`` (or context manager) around the run,
 and a ``checkpoint.Scrubber(manager)`` next to long-retention trees.
 Clean-path training with the whole rail armed is bit-identical to
-rail-off (tested; bench.py ``integrity_overhead``, ≤2% bar). See
-docs/fault_tolerance.md "Non-raising failures".
+rail-off (tested); its cost on the chip: not measured (ROADMAP D5).
+See docs/fault_tolerance.md "Non-raising failures".
 """
 from deeplearning4j_tpu.checkpoint.scrub import Scrubber
 from deeplearning4j_tpu.faults.errors import (SilentCorruptionError,

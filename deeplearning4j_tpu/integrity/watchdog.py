@@ -40,10 +40,9 @@ On expiry the monitor thread (NOT the wedged one):
    supervisor that will kill the process.
 
 When no watchdog is installed, :func:`guard` returns a shared no-op
-context — the boundaries pay one global read (bench.py
-``integrity_overhead``, ≤2% bar). Clean-path training with the
-watchdog armed is bit-identical to unguarded (the guard never touches
-the math).
+context — the boundaries pay one global read. Clean-path training
+with the watchdog armed is bit-identical to unguarded (the guard never
+touches the math).
 """
 from __future__ import annotations
 

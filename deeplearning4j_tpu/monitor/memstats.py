@@ -423,7 +423,7 @@ def promote_dispatch(disp, args: Tuple, sig, label: str,
 # MFU estimate
 #: THE peak-rate table: ``device_kind`` substring (lower case, first
 #: match wins) -> peak dense bf16 FLOP/s of one chip, each with its
-#: source. bench.py and chip_smoke.py read it too; a kind that is not
+#: source. chip_smoke.py reads it too; a kind that is not
 #: listed has no peak (an error where a utilization is printed, an
 #: absent gauge here) and nothing in the environment can invent one.
 _PEAK_FLOPS_BY_KIND = (

@@ -38,8 +38,8 @@ Everything here is host-side accounting over spans that never touch
 device state: the standing contract holds — clean serving runs are
 bit-identical with request tracing on or off, and the whole rail is
 inert (no span buffering, no assembly) while the shared tracer is
-disabled. ``bench.py reqtrace_overhead`` guards <=3% on the fleet
-loadgen loop. See docs/observability.md ("Request tracing & SLOs").
+disabled. Cost on the chip with the tracer on: not measured (ROADMAP
+D5). See docs/observability.md ("Request tracing & SLOs").
 """
 from __future__ import annotations
 
@@ -115,7 +115,7 @@ def head_sampled(trace_id: int, fraction: float) -> bool:
 
 
 # ----------------------------------------------------------------------
-# the ONE attainment definition (satellite: bench rows and the
+# the ONE attainment definition (a load generator's rows and the
 # SLOTracker must not disagree about what "met the SLO" means)
 
 def slo_attainment(records: Iterable[Tuple[str, Optional[float]]],

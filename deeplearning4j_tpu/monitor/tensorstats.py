@@ -74,8 +74,8 @@ class TensorStatsConfig:
     ``every_n``: sample every Nth step (absolute iterations; with
     ``accum_steps > 1`` every Nth *update*, aligned to apply
     boundaries). The overhead tier: stats cost is paid only on sampled
-    steps (``lax.cond``), so the amortized cost scales as 1/every_n —
-    ``bench.py tensorstats_overhead`` guards ≤3% at the default.
+    steps (``lax.cond``), so the amortized cost scales as 1/every_n;
+    cost on the chip: not measured (ROADMAP D5).
     ``families``: which of grads/updates/params to summarize.
     ``hist_bins``/``hist_min_exp``: the fixed log2-magnitude histogram
     covers exponents ``[hist_min_exp, hist_min_exp + hist_bins)``;

@@ -485,10 +485,8 @@ class GenerativeServer:
         tokens = handle.result()         # or the full list
         srv.shutdown()
 
-    ``admit="continuous"`` (default) fills free slots from the queue at
-    every step boundary; ``admit="static"`` is the wait-for-full-batch
-    baseline (a new wave is admitted only when every slot is free) —
-    kept for the benchmark comparison, not for production.
+    Admission is continuous: free slots are filled from the queue at
+    every step boundary.
 
     ``warmup=True`` AOT-precompiles the decode program and every
     prefill bucket before the worker starts (compiles stay 0 under
@@ -508,15 +506,11 @@ class GenerativeServer:
                  telemetry_port: Optional[int] = None,
                  resilience=True,
                  warmup: bool = True,
-                 admit: str = "continuous",
                  memory_sample_every: Optional[int] = 64,
                  draft_spec=None,
                  speculate_k: int = 4,
                  start: bool = True):
         spec = self._coerce_spec(spec)
-        if admit not in ("continuous", "static"):
-            raise ValueError(f"admit= must be 'continuous' or 'static', "
-                             f"got {admit!r}")
         self.spec = spec
         self.max_slots = int(max_slots)
         self.max_seq_len = int(max_seq_len or spec.max_seq_len)
@@ -559,7 +553,6 @@ class GenerativeServer:
                     f"(a window of 1 holds only the already-emitted "
                     f"token and drafts nothing)")
             self.draft_spec = draft_spec
-        self.admit_mode = admit
         self.eos_id = eos_id if eos_id is not None else spec.eos_id
         self.default_timeout_ms = default_timeout_ms
         self.max_queue_len = int(max_queue_len)
@@ -1197,14 +1190,7 @@ class GenerativeServer:
         """Step-boundary admission: fill free slots from the queue
         (continuous batching), starting with ``first`` where an idle
         :meth:`_step` already took one. Never waits: an active decode
-        batch must not stall at the boundary for new work. In ``static``
-        mode a new wave is only admitted when every slot is free — the
-        wait-for-full-batch baseline the benchmark compares against."""
-        # static (wait-for-full-batch) baseline: a new WAVE is only
-        # admitted once every slot is free — decided once per boundary,
-        # then the whole wave fills (not one request per boundary)
-        if self.admit_mode == "static" and self._n_active() > 0:
-            return False
+        batch must not stall at the boundary for new work."""
         req = first if first is not None else self._take()
         if req is None:
             return False

@@ -13,11 +13,10 @@ standard serving-benchmark pair:
 :class:`GenerativeLoadGenerator` is the autoregressive twin over a
 ``serving.generative.GenerativeServer``: mixed prompt/output lengths
 sampled from a **seeded per-request distribution** (request ``i`` is
-identical across runs and concurrency settings, so continuous- and
-static-batching servers can be compared on the SAME trace), optional
-per-request deadlines, and TTFT + inter-token percentiles on
-:class:`LoadResult` — one driver shared by the acceptance tests
-(tests/test_generative.py) and ``bench.py generative``.
+identical across runs and concurrency settings, so two servers can
+be compared on the SAME trace), optional per-request deadlines, and
+TTFT + inter-token percentiles on :class:`LoadResult` — the driver of
+the acceptance tests (tests/test_generative.py).
 
 :class:`FleetLoadGenerator` is the multi-target replay: it drives a
 **callable front door** (``serving.fleet.FleetRouter.generate``, or
@@ -123,7 +122,7 @@ class LoadResult:
     def slo_attainment(self, slo_ms: float, lane: str = "ttft_ms") -> float:
         """Fraction of issued requests that met ``slo_ms`` on ``lane``
         (``"ttft_ms"`` or ``"e2e_ms"``) — the SAME definition the
-        router-side ``monitor.reqtrace.SLOTracker`` applies, so bench
+        router-side ``monitor.reqtrace.SLOTracker`` applies, so these
         rows and the fleet record's ``slo`` sub-dict can't disagree:
         non-ok outcomes are misses, ok rows without a measurement are
         excluded."""

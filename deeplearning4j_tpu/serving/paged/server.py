@@ -9,8 +9,7 @@ array a layer ``[num_blocks, block_size, heads * head_dim]`` for K and
 one for V, and each request holds a BLOCK TABLE grown one block at a
 time at decode-step boundaries. Capacity is proportional to tokens
 actually held — a 12-token chat costs one block, not a ``max_seq`` row
-— so the same HBM serves several times the concurrent requests
-(bench.py serving_paged).
+— so the same HBM holds more concurrent requests.
 
 Three layers, all riding :class:`GenerativeServer`'s scheduler/queue/
 resilience plumbing unchanged:

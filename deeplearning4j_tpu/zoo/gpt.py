@@ -43,7 +43,7 @@ class GPTConfig:
         return self.hidden_size // self.num_heads
 
 
-# ~510M params: the compute-dense flagship (BENCH config gpt_medium) —
+# ~510M params: the compute-dense flagship (what chip_smoke.py trains) —
 # sized so f32 masters + Adam slots + grads + bf16 compute copies +
 # remat-bounded activations fill (but fit) one v5e chip's 16 GB HBM
 GPT_MEDIUM = GPTConfig(hidden_size=1536, num_layers=16,

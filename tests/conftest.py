@@ -34,6 +34,54 @@ def rng():
     return np.random.default_rng(12345)
 
 
+class ServedBatches:
+    """Every padded batch a ``ParallelInference`` really ran, so that a
+    test can put a served row beside the model's output FOR THE SAME
+    PADDED BATCH: on the CPU the last bit of a row follows the batch's
+    shape and the row's place in it, so a row served from a batch and
+    ``net.output`` of that row alone differ by an ulp or two. Install it
+    before any other wrapper of ``_execute``."""
+
+    def __init__(self, pi):
+        self.batches = []
+        inner = pi._execute
+
+        def recording(features, real_rows=None):
+            outs = inner(features, real_rows=real_rows)
+            self.batches.append(np.array(features[0], copy=True))
+            return outs
+
+        pi._execute = recording
+
+    def directs(self, net, x):
+        """``net.output`` of each recorded batch that held ``x``, cut
+        to ``x``'s rows; the batch that ran last comes first (a request
+        is answered from the last batch it was in)."""
+        x = np.asarray(x)
+        rows, want = x.shape[0], x.tobytes()
+        outs, given = {}, set()     # one net.output a distinct batch
+        for feats in reversed(self.batches):
+            batch = feats.tobytes()
+            for off in range(feats.shape[0] - rows + 1):
+                if feats[off:off + rows].tobytes() != want \
+                        or (batch, off) in given:
+                    continue
+                given.add((batch, off))
+                if batch not in outs:
+                    outs[batch] = net.output(feats).to_numpy()
+                yield outs[batch][off:off + rows]
+
+    def direct(self, net, x):
+        for out in self.directs(net, x):
+            return out
+        raise AssertionError("no executed batch held these rows")
+
+
+@pytest.fixture
+def served_batches():
+    return ServedBatches
+
+
 CHAOS_DEFAULT_TIMEOUT = 120
 
 

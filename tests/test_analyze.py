@@ -589,8 +589,7 @@ class TestFitIntegration:
         assert sd.last_analysis is None
 
     def test_analysis_cached_per_graph_version(self):
-        """Warm fits pay a dict lookup, not a re-analysis — the
-        bench.py analyze_overhead contract."""
+        """Warm fits pay a dict lookup, not a re-analysis."""
         sd = _mlp()
         it = _iterator(sd)
         sd.fit(it, epochs=1)

@@ -1,8 +1,8 @@
 """The bring-up rails (ISSUE 21): chip_smoke.py's legs at GPT_TINY on the
 CPU mesh, and the places where a run could hide its device — importing
-the package must not take the chip, chip_smoke.py and bench.py must
-refuse the CPU, a raising bench config must end the run, and there is
-one peak-rate table that nothing in the environment can override.
+the package must not take the chip, chip_smoke.py must refuse the CPU,
+and there is one peak-rate table that nothing in the environment can
+override. (The benchmark's own refusals are in tests/benchmarking/.)
 """
 import json
 import os
@@ -12,9 +12,8 @@ import sys
 import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, REPO)        # chip_smoke.py and bench.py live at the root
+sys.path.insert(0, REPO)        # chip_smoke.py lives at the root
 
-import bench            # noqa: E402
 import chip_smoke       # noqa: E402
 
 from deeplearning4j_tpu.monitor import memstats      # noqa: E402
@@ -129,8 +128,7 @@ def test_chip_smoke_refuses_the_cpu():
 
 def test_import_and_analyze_cli_initialise_no_backend():
     """A chip belongs to one process: a parent that only imports the
-    package (bench.py before its cold-start probes) or runs the
-    analyzer CLI must not have taken it."""
+    package or runs the analyzer CLI must not have taken it."""
     code = (
         "import sys, runpy\n"
         "import deeplearning4j_tpu\n"
@@ -157,36 +155,3 @@ def test_peak_rate_table_is_the_only_source(monkeypatch):
     monkeypatch.setenv("DL4J_PEAK_FLOPS", "1e15")   # the removed override
     assert memstats.peak_flops() is None
     assert memstats.peak_flops("TPU v5 lite") == 197e12
-
-
-def test_bench_refuses_the_cpu():
-    with pytest.raises(SystemExit) as ei:
-        bench.main(["samediff_mlp"])
-    assert ei.value.code not in (0, None)
-    assert "backend 'cpu'" in str(ei.value.code)
-
-
-def test_bench_config_that_raises_ends_the_run(monkeypatch, capsys):
-    """No {"error": "failed"} with exit 0, no "whatever ran" headline:
-    the exception leaves main(), which is a non-zero exit."""
-    stamp = {"platform": "tpu", "device_kind": "TPU v5 lite",
-             "device_count": 1}
-    monkeypatch.setattr(bench, "_require_tpu", lambda: stamp)
-    monkeypatch.setattr(bench, "REGISTRY",
-                        (("fine", lambda: {"samples_per_sec": 1.0}),
-                         ("boom", lambda: 1 / 0)))
-    with pytest.raises(ZeroDivisionError):
-        bench.main(["fine", "boom"])
-    assert capsys.readouterr().out == ""
-    # and a clean run stamps the device it measured
-    bench.main(["fine"])
-    out = json.loads(capsys.readouterr().out)
-    assert out["platform"] == "tpu" and out["device_count"] == 1
-    assert out["value"] is None      # resnet50 did not run: no stand-in
-
-
-def test_cold_start_refuses_a_parent_that_holds_a_device():
-    import jax
-    jax.devices()                    # this process has a backend now
-    with pytest.raises(RuntimeError, match="already initialised"):
-        bench.bench_cold_start(models=("samediff_mlp",))

@@ -616,20 +616,48 @@ def test_ph_shape_sig_matches_window_accounting():
 
 
 # ---------------------------------------------------------------------------
-# the real thing: a fresh-process warm restart (bench.py's cold_start probe)
+# the real thing: a fresh-process warm restart
+
+#: what each fresh process runs: the 784-512-256-10 SameDiff MLP fitted
+#: one step, then the process's compile accounting and its seconds
+_RESTART_PROBE = """
+import json, time
+t0 = time.perf_counter()
+import numpy as np
+from deeplearning4j_tpu.autodiff import SameDiff, TrainingConfig
+from deeplearning4j_tpu.compilecache import (COMPILE_STATS,
+                                             install_compile_watcher)
+from deeplearning4j_tpu.learning.updaters import Adam
+install_compile_watcher()
+rng = np.random.default_rng(0)
+sd = SameDiff()
+cur, n_in = sd.placeholder("x", shape=(-1, 784)), 784
+for i, h in enumerate((512, 256, 10)):
+    w = sd.var(f"w{i}", value=rng.normal(0, .05, (n_in, h)).astype("f4"))
+    cur = cur.mmul(w).add(sd.var(f"b{i}", value=np.zeros(h, "f4")))
+    cur, n_in = (sd.nn.relu(cur) if h != 10 else cur), h
+labels = sd.placeholder("labels", shape=(-1, 10))
+sd.loss.softmax_cross_entropy(cur, labels, name="loss")
+sd.set_loss_variables(["loss"])
+sd.training_config = (TrainingConfig.builder().updater(Adam(1e-3))
+                      .data_set_feature_mapping("x")
+                      .data_set_label_mapping("labels").build())
+X = rng.normal(size=(128, 784)).astype("f4")
+sd.fit([(X, np.eye(10, dtype="f4")[rng.integers(0, 10, 128)])], epochs=1)
+print(json.dumps({**COMPILE_STATS.snapshot(),
+                  "restart_to_first_step_s": time.perf_counter() - t0}))
+"""
+
 
 @pytest.mark.slow
 def test_cold_vs_warm_restart_subprocess(tmp_path):
-    """bench.py's restart probe in two fresh processes sharing a cache
-    placed from outside through $JAX_COMPILATION_CACHE_DIR, as
-    bench_cold_start places it. (bench.py's own entry point refuses the
-    CPU, so the probe function is called directly.)"""
+    """A restart in two fresh processes sharing a cache placed from
+    outside through $JAX_COMPILATION_CACHE_DIR: the second compiles
+    nothing anew."""
     import json
     import subprocess
     import sys
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    code = ("import json, bench; "
-            "print(json.dumps(bench._cold_start_probe('samediff_mlp')))")
     env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=repo,
                JAX_COMPILATION_CACHE_DIR=str(tmp_path / "restart_cache"),
                JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES="-1",
@@ -637,7 +665,7 @@ def test_cold_vs_warm_restart_subprocess(tmp_path):
     runs = {}
     for phase in ("cold", "warm"):
         proc = subprocess.run(
-            [sys.executable, "-c", code],
+            [sys.executable, "-c", _RESTART_PROBE],
             capture_output=True, text=True, timeout=600, cwd=repo, env=env)
         assert proc.returncode == 0, proc.stderr[-800:]
         runs[phase] = json.loads(proc.stdout.strip().splitlines()[-1])

