@@ -15,7 +15,8 @@ from a seed, no network):
 It refuses any backend but ``tpu`` (it never selects a platform itself),
 checks what comes out (finite falling losses from ln(vocab), exact token
 budgets, tokens in range, populated latency histograms, a prefix-cache
-hit, ZERO programs compiled after warmup) and ends its stdout with two
+hit, ZERO programs compiled after warmup, the one-chip train step's
+attention on the tiled kernel) and ends its stdout with two
 JSON lines: the readings (per-leg wall and compile seconds, losses,
 tokens, ``"claim": null``), then, LAST, the verdict the driver parses,
 which holds exactly ``{"ok": true, "device": {"platform", "kind",
@@ -215,6 +216,22 @@ def train_leg(cfg, batch: int, seq_len: int, steps: int, epochs: int,
     check(timed_compiles == 0,
           f"train: the second fit of the same shapes compiled "
           f"{timed_compiles} program(s)")
+    # which path each block's attention took when the step was traced
+    # (monitor/attention.py): a step on one chip has to take the tiled
+    # kernel, a step under a mesh cannot
+    sites = sd.attention_sites
+    check(sites is not None and sites.kernel + sites.plain == cfg.num_layers,
+          f"train: the traced step counted attention sites "
+          f"{sites and sites.to_json()}, the model has {cfg.num_layers}")
+    say(f"train: attention sites on the tiled kernel {sites.kernel}, plain "
+        f"{sites.plain} (first reason: {sites.first_reason})")
+    check(sites.kernel > 0 or sharding is not None
+          or jax.default_backend() != "tpu",
+          f"train: the unsharded step took no attention site on the tiled "
+          f"kernel: {sites.to_json()}")
+    check(sites.kernel == 0 or sharding is None,
+          f"train: a step under a mesh took the tiled kernel, which "
+          f"cannot be partitioned: {sites.to_json()}")
     report = {
         "wall_s": round(time.perf_counter() - t_leg, 2),
         "tier": tier,
@@ -229,6 +246,7 @@ def train_leg(cfg, batch: int, seq_len: int, steps: int, epochs: int,
         "cache_hits": compiles["cache_hits"],
         "cache_misses": compiles["cache_misses"],
         "hbm_peak_bytes": hbm_peak_bytes(),
+        "attention_sites": sites.to_json(),
     }
     say(f"train: step {report['step_time_ms']} ms, HBM peak "
         f"{report['hbm_peak_bytes'] / 2**30:.2f} GiB")

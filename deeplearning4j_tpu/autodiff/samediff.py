@@ -123,6 +123,9 @@ class SameDiff:
         # (tier, dispatches_per_epoch, window sizes/compiles) — consumed
         # by ui/stats StatsListener
         self.last_fit_stats = None
+        # which path each attention site of the train step traced last
+        # took (monitor/attention.py AttentionSites; None before any)
+        self.attention_sites = None
         # op namespaces (reference: SDMath/SDNN/... generated classes)
         from deeplearning4j_tpu.autodiff.ops_namespaces import make_namespaces
         for ns_name, ns in make_namespaces(self).items():
@@ -790,6 +793,14 @@ class SameDiff:
         grads = compiled(wrt_params, other, self.constants_map(), ph, key)
         return {k: NDArray(v) for k, v in grads.items()}
 
+    def _device_span(self) -> int:
+        """How many devices the model's arrays lie on: 1 unless
+        ``parallel.trainer.shard_model`` (or anything else) has placed
+        them on a mesh."""
+        return max((len(a.sharding.device_set)
+                    for a in self._arrays.values()
+                    if isinstance(a, jax.Array)), default=1)
+
     def _resolve_loss(self, loss=None) -> Tuple[str, ...]:
         if loss is not None:
             return (loss.name if isinstance(loss, SDVariable) else loss,)
@@ -858,13 +869,25 @@ class SameDiff:
             from deeplearning4j_tpu.ops.loss import softmax_dtype_scope
             return softmax_dtype_scope(_ce_dt)
 
+        def _attention_scope():
+            # attention's choice of path (ops/nn_ops.py
+            # scaled_dot_product_attention) needs what only the tracer
+            # of the step knows: how many devices the model's arrays
+            # span. Read when the step is TRACED (a jit retraces when
+            # its arguments move onto a mesh), with a fresh tally of the
+            # sites each trace (monitor/attention.py)
+            from deeplearning4j_tpu.monitor.attention import open_train_step
+            from deeplearning4j_tpu.ops.nn_ops import attention_trace_scope
+            self.attention_sites = open_train_step(self._device_span())
+            return attention_trace_scope(self.attention_sites)
+
         def grad_fn(params, svars, iteration, constants, phv, base_key):
             # per-step key derived ON DEVICE (a host-side jax.random.key per
             # step costs a host dispatch; fold_in is free inside the jit)
             key = jax.random.fold_in(base_key, iteration)
 
             def loss_fn(p):
-                with _ce_scope():
+                with _ce_scope(), _attention_scope():
                     if _cast is not None:
                         # bf16 compute: params/inputs/constants cast at
                         # the top of the trace (XLA fuses the casts);

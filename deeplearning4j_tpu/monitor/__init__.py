@@ -21,6 +21,9 @@ per-step tier), serving, checkpointing and the fault rail:
   param summaries (norms, nonfinite counts, log2-magnitude histograms)
   sampled inside the compiled step, folded into the scan carry like
   the divergence sentinel; plus the dead/exploding-layer watcher.
+- :mod:`monitor.attention` — which path (tiled kernel or plain) each
+  attention site of the train step traced last took, and the first
+  reason for a plain one: a trace-time fact no span can show.
 - :mod:`monitor.server` — the live telemetry HTTP endpoint
   (``monitor.serve(port=0)``): /metrics, /healthz, /readyz, /report,
   /trace, /stats over a stdlib ThreadingHTTPServer, loopback-bound.

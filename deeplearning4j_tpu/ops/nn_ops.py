@@ -14,6 +14,8 @@ Weight layout convention here is HWIO for 2d convs (TPU/XLA-preferred).
 """
 from __future__ import annotations
 
+import contextlib
+import contextvars
 from typing import Optional, Sequence, Tuple
 
 import jax
@@ -459,23 +461,142 @@ def dot_product_attention(queries, keys, values, mask=None, scaled: bool = True,
     return (out, weights) if with_weights else out
 
 
+#: What the tracer of the running program knows and the op below cannot
+#: read off its arrays: a ``monitor.attention.AttentionSites`` (how many
+#: devices the program is traced for, and the tally of sites). Set by
+#: :func:`attention_trace_scope`; consulted at TRACE time, so the scope
+#: wraps the graph fn's execution inside the jitted step — the train
+#: step builder (SameDiff._build_step_parts) does this, as it does for
+#: ``ops.loss.softmax_dtype_scope``.
+_ATTENTION_TRACE: contextvars.ContextVar = contextvars.ContextVar(
+    "dl4j_attention_trace", default=None)
+
+
+@contextlib.contextmanager
+def attention_trace_scope(sites):
+    """While active, ``scaled_dot_product_attention`` may take its tiled
+    kernel where ``sites.devices`` is 1, and tells ``sites`` which path
+    each call took (``sites.note(reason)``, None for the kernel)."""
+    token = _ATTENTION_TRACE.set(sites)
+    try:
+        yield sites
+    finally:
+        _ATTENTION_TRACE.reset(token)
+
+
+#: the tiled kernel's smallest block, the largest this op hands it, and
+#: the head sizes at which blocks of that size fit the v5e's VMEM in
+#: bf16 and f32 at every seq_len to 4096 (compiled for a described chip:
+#: tests/test_attention_tiled.py; 256 does not fit)
+_TILE, _TILE_MAX = 128, 1024
+_TILED_HEAD_SIZES = (64, 128)
+
+
+def _tile_of(seq_len: int) -> int:
+    """The block along the sequence: the largest multiple of 128 up to
+    1024 that divides ``seq_len``. A tile that the diagonal crosses is
+    computed whole, so a smaller block skips more of the causal half,
+    and a larger one pays fewer grid steps: on the v5e at
+    ``[16, 16, 1024, 64]`` the second outweighs the first up to 1024
+    (PERF.md §6, PR 31)."""
+    return max(b for b in range(_TILE, min(seq_len, _TILE_MAX) + 1, _TILE)
+               if seq_len % b == 0)
+
+
+def _plain_reason(q, k, v, mask, causal, sites) -> Optional[str]:
+    """Why this call takes the plain path (None: the tiled kernel can
+    run it). Everything here is known while the program is traced."""
+    if sites is None:
+        return "not traced as a train step"
+    if jax.default_backend() != "tpu":
+        return f"backend {jax.default_backend()}"
+    if jax.config.jax_enable_x64:
+        # the kernel's lowering for the chip recurses without end on a
+        # 64-bit scalar (jax 0.9.0)
+        return "jax_enable_x64"
+    if sites.devices > 1:
+        # pallas_call has no partitioning rule
+        return f"traced for a mesh of {sites.devices} devices"
+    if mask is not None:
+        return "mask"
+    if not causal:
+        return "not causal"
+    if q.ndim != 4 or not q.shape == k.shape == v.shape:
+        return f"shapes {q.shape} {k.shape} {v.shape}"
+    if q.shape[-2] % _TILE:
+        return f"seq_len {q.shape[-2]} is not a multiple of {_TILE}"
+    if q.shape[-1] not in _TILED_HEAD_SIZES:
+        return f"head size {q.shape[-1]}"
+    if not (q.dtype == k.dtype == v.dtype
+            and q.dtype in (jnp.bfloat16, jnp.float32)):
+        return f"dtypes {q.dtype} {k.dtype} {v.dtype}"
+    return None
+
+
+def _tiled_causal_attention(q, k, v, scale: float):
+    """Causal attention tile by tile with a running maximum and sum
+    (JAX's ``splash_attention`` Pallas kernel): no ``[B, H, S, S]`` array
+    reaches HBM. Its ``custom_vjp`` keeps q, k, v, the output and each
+    row's log-sum-exp and recomputes the tiles in ONE backward kernel.
+    Tiles wholly above the diagonal are not in the grid (there are none
+    until ``seq`` passes the largest block). Scores, statistics
+    and accumulation are float32; the operands of the backward's
+    products are the inputs' dtype, the forward's second product runs in
+    float32. The kernel has no scale of its own, so it goes onto q
+    first: exact where it is a power of two (head size 64), one more
+    rounding of q to its dtype elsewhere (head size 128)."""
+    from jax.experimental.pallas.ops.tpu import splash_attention as sa
+    heads, seq_len = q.shape[1], q.shape[2]
+    t = _tile_of(seq_len)
+    # the forward works through a tile of 1024 keys in two halves (1.22
+    # against 1.33 ms); a tile of 512 in halves is slower (1.71 against
+    # 1.33), and the backward is fastest whole
+    blocks = sa.BlockSizes(
+        block_q=t, block_kv=t, block_kv_compute=512 if t == _TILE_MAX else t,
+        block_q_dkv=t, block_kv_dkv=t, block_kv_dkv_compute=t,
+        use_fused_bwd_kernel=True,
+        q_layout=sa.QKVLayout.SEQ_MINOR, k_layout=sa.QKVLayout.SEQ_MINOR,
+        v_layout=sa.QKVLayout.SEQ_MINOR)
+    kernel = sa.make_splash_mha_single_device(
+        sa.MultiHeadMask([sa.CausalMask((seq_len, seq_len))] * heads),
+        block_sizes=blocks)
+    return jax.vmap(kernel)(q * jnp.asarray(scale, q.dtype), k, v)
+
+
 @op("scaled_dot_product_attention", _N, aliases=("sdpa",))
 def scaled_dot_product_attention(q, k, v, mask=None, causal: bool = False,
                                  scale: float = None):
-    """Fused multi-head attention core, TPU-shaped: q/k/v are
-    (batch, heads, seq, head_dim); score accumulation and softmax run in
-    f32 regardless of input dtype (bf16-safe — the MXU accumulates f32
-    natively so the upcast is free), probabilities are cast back to the
-    value dtype for the PV matmul.
+    """Multi-head attention core, TPU-shaped: q/k/v are
+    (batch, heads, seq, head_dim); scores and softmax are f32 regardless
+    of input dtype (bf16-safe — the MXU accumulates f32 natively so the
+    upcast is free).
 
     ``causal=True`` applies the autoregressive mask; ``mask`` (broadcast
     to [batch, heads, sq, sk], nonzero = attend) composes with it.
     Reference: multi_head_dot_product_attention.cpp:34 computes the same
-    math head-by-head via mmul/softmax graph ops; here it is one op so
-    XLA sees the whole pattern and its backward as a unit.
-    """
+    math head-by-head via mmul/softmax graph ops; here it is one op.
+
+    TWO PATHS, one algorithm, chosen from what the op can observe while
+    it is traced; no keyword, config field or environment variable
+    decides. Inside a train step on ONE TPU device
+    (:func:`attention_trace_scope`), causal self-attention without a
+    mask, with ``seq`` a multiple of 128, a head size in
+    ``_TILED_HEAD_SIZES`` and bf16 or f32 inputs runs
+    :func:`_tiled_causal_attention`: forward, recomputed forward and
+    backward write no score matrix. Every other call (a padding mask,
+    cross attention, another backend, a short or ragged ``seq``, a step
+    traced for a mesh, 64-bit mode: :func:`_plain_reason`) builds the
+    whole f32 ``[batch, heads, sq, sk]`` score array, masks it, runs a
+    softmax over it and casts the probabilities to the value dtype for
+    the PV product, which XLA differentiates as a unit."""
     d = q.shape[-1]
     s = (1.0 / np.sqrt(d)) if scale is None else scale
+    sites = _ATTENTION_TRACE.get()
+    reason = _plain_reason(q, k, v, mask, causal, sites)
+    if sites is not None:
+        sites.note(reason)
+    if reason is None:
+        return _tiled_causal_attention(q, k, v, s)
     scores = jnp.einsum("bhqd,bhkd->bhqk", q, k,
                         preferred_element_type=jnp.float32) * s
     if causal:

@@ -8,8 +8,14 @@ no native decoder-LM; this model is the TPU-first flagship config — the
 compute-dense benchmark where MXU utilization is actually reachable:
 
 - pre-LN residual blocks, erf-gelu MLP, learned positions (GPT-2 layout);
-- the attention core is ONE fused ``scaled_dot_product_attention`` op
-  (f32 scores/softmax, bf16 matmuls under mixed precision);
+- the attention core is ONE ``scaled_dot_product_attention`` op (f32
+  scores/softmax, bf16 matmuls under mixed precision). In a train step
+  on one TPU device it runs a tiled kernel, forward and backward, and
+  writes no ``[B, heads, S, S]`` score array (``seq_len`` a multiple of
+  128, head size 64 or 128); on the CPU, under a mesh and at any other
+  shape it builds the scores whole. ``sd.attention_sites`` says which
+  path each block took when the step was traced (ops/nn_ops.py,
+  monitor/attention.py);
 - every block records inside ``sd.remat_scope`` — the whole layer is one
   ``jax.checkpoint`` region, so live activation memory is per-layer
   boundaries only and batch*seq can grow to MXU-saturating sizes;

@@ -46,6 +46,10 @@ def test_smoke_legs_at_gpt_tiny(tiny_run):
     _, train, serve = tiny_run
     assert train["tier"] == "scanned_epoch"
     assert train["last_loss"] < train["first_loss"]
+    # the tally of the traced step's attention sites: all plain here
+    assert train["attention_sites"] == {
+        "devices": 1, "kernel": 0, "plain": GPT_TINY.num_layers,
+        "first_reason": "backend cpu"}
     assert serve["tokens_delivered"] == 6 * 8
     assert serve["compiles_after_warmup"] == 0
     assert serve["prefix_blocks_hit"] >= 4
@@ -91,6 +95,8 @@ def test_four_chip_placement_on_the_virtual_mesh():
     four = out["four_chip"]
     assert four["serve_tp2"]["kv_slab_devices"] == 2
     assert four["serve_tp2_untrained"]["kv_slab_devices"] == 2
+    assert four["train_2x2"]["attention_sites"]["devices"] == 4
+    assert four["train_2x2"]["attention_sites"]["kernel"] == 0
     for key in ("tp2_vs_tp1", "tp2_vs_tp1_untrained"):
         assert [four[key][i]["equal"] for i in (0, 2, 3, 4, 5)] == [8] * 5
     json.dumps(out)
