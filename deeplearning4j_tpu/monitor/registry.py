@@ -350,6 +350,10 @@ class MetricsRegistry:
             self.set_gauge("serving_pool_block_size",
                            paged.get("block_size", 0),
                            help="tokens per KV block")
+            self.set_gauge("serving_pool_bytes_per_token",
+                           paged.get("kv_bytes_per_token", 0),
+                           help="bytes one cached token costs over all "
+                                "layers (K and V rows, or a latent row)")
             self.set_gauge("serving_pool_occupancy_ratio",
                            paged.get("pool_occupancy", 0.0),
                            help="mean held blocks / pool capacity per "

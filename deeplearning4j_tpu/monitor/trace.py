@@ -482,6 +482,12 @@ SPAN_CATALOG: Dict[str, Tuple[str, Tuple[str, ...]]] = {
     # one serving.prefill_chunk a run of the program, each with its
     # serving.launch and only the last with a serving.sync
     "serving.prefill_chunk": ("serving", ("index", "of", "bucket", "hist")),
+    # serving.decode's one sync also brings what the program counted on
+    # the device where a spec names counters (PagedGenerativeSpec.
+    # program_counters: the expert layers' moe_layer_steps,
+    # moe_experts_touched_sum, moe_tokens_routed_sum,
+    # moe_peak_expert_tokens_sum and, for a sigmoid router with a
+    # correction bias, moe_bias_moved_sum), packed behind the next tokens
     "serving.decode": ("serving", ("active", "slots", "table_entries")),
     "serving.draft": ("serving", ("active", "step", "slots", "phase",
                                   "bucket", "slot")),

@@ -21,7 +21,8 @@ from deeplearning4j_tpu.parallel.pipeline import (
     place_stage_params, sequential_forward, split_microbatches)
 from deeplearning4j_tpu.parallel.moe import (
     EXPERT_AXIS, dropless_topk_ffn, expert_parallel_specs, init_moe_params,
-    moe_ffn, moe_train_step, switch_gating, topk_route)
+    moe_ffn, moe_train_step, sigmoid_bias_route, switch_gating,
+    tiled_grouped_dot, topk_route)
 from deeplearning4j_tpu.parallel import collectives, multihost
 
 __all__ = [
@@ -38,5 +39,5 @@ __all__ = [
     "transformer_tensor_parallel_rules",
     "EXPERT_AXIS", "moe_ffn", "switch_gating", "init_moe_params",
     "expert_parallel_specs", "moe_train_step", "topk_route",
-    "dropless_topk_ffn",
+    "dropless_topk_ffn", "sigmoid_bias_route", "tiled_grouped_dot",
 ]

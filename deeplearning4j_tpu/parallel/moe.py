@@ -18,14 +18,18 @@ TPU framework expresses it:
 
 Beside the Switch layer, which drops what overflows an expert's
 capacity, stands a DROPLESS top-k layer for models whose reference drops
-nothing (:func:`topk_route`, :func:`dropless_topk_ffn`): the (token,
-choice) pairs are sorted by expert and go through one grouped product
-(``jax.lax.ragged_dot``) per weight, so its shapes are static too
-(``tokens x k`` rows whatever the load of an expert) and only the
-experts that have a token are read. It is told which experts it holds,
-routes over all of them, and returns the part of the result its own
-experts give: a chip that holds a share of the experts runs the same
-function, and the shares add up to the whole layer.
+nothing (:func:`topk_route` or :func:`sigmoid_bias_route`, then
+:func:`dropless_topk_ffn`): the (token, choice) pairs are sorted by
+expert and go through one grouped product (``jax.lax.ragged_dot``, or
+:func:`tiled_grouped_dot` where the caller names it) per weight, so its
+shapes are static too (``tokens x k`` rows whatever the load of an
+expert) and only the experts that have a token are read. It is told
+which experts it holds, routes over all of them, and returns the part of
+the result its own experts give: a chip that holds a share of the
+experts runs the same function, and the shares add up to the whole
+layer. A SHARED expert, which every token passes, is no part of it: it
+is the caller's dense product, which every share computes alike and
+which is added once.
 """
 from __future__ import annotations
 
@@ -144,14 +148,78 @@ def topk_route(x, router_w, k: int):
     return idx.astype(jnp.int32), jax.nn.softmax(top, axis=-1)
 
 
-def dropless_topk_ffn(x, idx, weights, w_gate, w_up, w_down,
-                      first_expert: int = 0, valid=None):
-    """The held experts' part of a ReGLU top-k expert layer (``(relu(x
-    @ gate) * (x @ up)) @ down``), no token dropped whatever the load of
-    an expert.
+def sigmoid_bias_route(x, router_w, bias, k: int, scale: float = 1.0):
+    """Dropless top-k routing by sigmoid scores with a correction bias
+    (the load balancing without an auxiliary loss of DeepSeek-V3,
+    ``noaux_tc`` in the published configurations). x: (N, d); router_w:
+    (d, E); bias: (E,). With ``s = sigmoid(x @ router_w)`` (float32 at
+    the highest precision, as :func:`topk_route` and for its reason) the
+    k experts of a token are those with the largest ``s + bias``; their
+    weights are ``scale * s / (sum of the k s + 1e-20)``: the bias
+    steers the CHOICE and never enters a weight. Returns ``(idx (N, k)
+    int32, weights (N, k) float32, moved (N,) int32)``, the first two as
+    :func:`topk_route` gives them; ``moved`` counts the token's choices
+    that are not among the k largest of ``s`` alone, which is how hard
+    the correction steers."""
+    k = int(k)
+    logits = jnp.matmul(x.astype(jnp.float32), router_w.astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+    s = jax.nn.sigmoid(logits)
+    _, idx = jax.lax.top_k(s + bias.astype(jnp.float32), k)
+    chosen = jnp.take_along_axis(s, idx, axis=-1)
+    weights = scale * chosen / (jnp.sum(chosen, axis=-1, keepdims=True)
+                                + 1e-20)
+    # a choice the bias moved scores under the k-th largest bare score
+    moved = jnp.sum(chosen < jax.lax.top_k(s, k)[0][:, -1:], axis=-1)
+    return idx.astype(jnp.int32), weights, moved.astype(jnp.int32)
 
-    x: (N, d); ``idx``/``weights``: (N, k) from :func:`topk_route`, over
-    all experts; ``w_gate``/``w_up``: (held, d, f) and ``w_down``:
+
+#: rows and output columns a tile of :func:`tiled_grouped_dot` holds
+#: (the best of eight tilings on a v5e at 2,048 rows over 64 groups of
+#: 2,048 x 1,536 bfloat16: ``experiments/glm_grouped_product.py``)
+GROUPED_TILE_ROWS, GROUPED_TILE_COLS = 128, 512
+
+
+def tiled_grouped_dot(lhs, rhs, group_sizes):
+    """``jax.lax.ragged_dot`` for MANY rows: ``lhs (M, K)`` sorted by
+    group, ``rhs (G, K, N)``, ``group_sizes (G,)`` int32 with a sum of at
+    most M; float32 out. JAX's TPU grouped-matmul kernel (Pallas,
+    ``megablox.gmm``) in tiles of ``GROUPED_TILE_ROWS`` rows, the whole of
+    K and ``GROUPED_TILE_COLS`` columns: a group's weights are read once a
+    tile of rows that holds one of its rows, where ``ragged_dot`` at 2,048
+    rows reads every group's (0.66 ms against 1.35 a product of the shape
+    above; at a decode step's 32 rows ``ragged_dot`` reads only the groups
+    that have a row, and is what :func:`dropless_topk_ffn` takes where a
+    caller names nothing). M fills whole tiles of rows and N whole tiles
+    of columns (ValueError). Rows past the last group belong to nobody and
+    their result is not one (the kernel wants every row in a group: they ride
+    with the last). The kernel is a TPU's: on another backend it is
+    interpreted (the tests' path)."""
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+    m, k = lhs.shape
+    if m % GROUPED_TILE_ROWS or rhs.shape[2] % GROUPED_TILE_COLS:
+        raise ValueError(
+            f"{m} rows -> {rhs.shape[2]} columns fill no whole tiles of "
+            f"{GROUPED_TILE_ROWS} rows and {GROUPED_TILE_COLS} columns")
+    sizes = group_sizes.astype(jnp.int32)
+    sizes = sizes.at[-1].add(jnp.int32(m) - jnp.sum(sizes, dtype=jnp.int32))
+    return gmm(lhs, rhs, sizes, jnp.float32,
+               (GROUPED_TILE_ROWS, k, GROUPED_TILE_COLS),
+               interpret=jax.default_backend() != "tpu")
+
+
+def dropless_topk_ffn(x, idx, weights, w_gate, w_up, w_down,
+                      first_expert: int = 0, valid=None,
+                      activation: Callable = jax.nn.relu,
+                      grouped: Optional[Callable] = None):
+    """The held experts' part of a gated top-k expert layer
+    (``(activation(x @ gate) * (x @ up)) @ down``: ReGLU where none is
+    named, SwiGLU with ``jax.nn.silu``), no token dropped whatever the
+    load of an expert.
+
+    x: (N, d); ``idx``/``weights``: (N, k) from :func:`topk_route` or
+    :func:`sigmoid_bias_route`, over all experts; ``w_gate``/``w_up``:
+    (held, d, f) and ``w_down``:
     (held, f, d) are experts ``first_expert .. first_expert + held - 1``.
     A pair routed to an expert held elsewhere adds nothing here, and
     neither does a token where ``valid`` (N,) is false (padding, an idle
@@ -160,7 +228,9 @@ def dropless_topk_ffn(x, idx, weights, w_gate, w_up, w_down,
     the second the number of tokens each held expert served.
 
     Operands go into the products in the weights' dtype and accumulate
-    in float32."""
+    in float32. ``grouped(lhs, rhs, group_sizes)`` is the grouped product
+    (``jax.lax.ragged_dot`` where none is named; a caller that knows its
+    run is long names :func:`tiled_grouped_dot`)."""
     n, k = idx.shape
     held = w_gate.shape[0]
     local = idx - jnp.int32(first_expert)
@@ -172,12 +242,14 @@ def dropless_topk_ffn(x, idx, weights, w_gate, w_up, w_down,
     order = jnp.argsort(flat, stable=True)
     rows = x[order // k].astype(w_gate.dtype)              # (N k, d)
 
-    def grouped(lhs, rhs):
+    def product(lhs, rhs):
+        if grouped is not None:
+            return grouped(lhs, rhs, tokens)
         return jax.lax.ragged_dot(lhs, rhs, tokens,
                                   preferred_element_type=jnp.float32)
 
-    h = jax.nn.relu(grouped(rows, w_gate)) * grouped(rows, w_up)
-    out = grouped(h.astype(w_down.dtype), w_down)          # (N k, d)
+    h = activation(product(rows, w_gate)) * product(rows, w_up)
+    out = product(h.astype(w_down.dtype), w_down)          # (N k, d)
     # rows past the last group belong to nobody here: whatever the
     # grouped product left there is not a result
     out = jnp.where((jnp.arange(n * k) < jnp.sum(tokens))[:, None], out, 0.0)

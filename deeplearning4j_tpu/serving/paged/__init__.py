@@ -6,6 +6,8 @@ docstrings of :mod:`.pool` (the allocator/prefix-cache bookkeeping) and
 :mod:`.server` (the server itself).
 """
 from deeplearning4j_tpu.serving.paged.pool import (NULL_BLOCK, BlockPool,
+                                                   KVLeaf,
+                                                   KVLeafUnsupportedError,
                                                    KVTier,
                                                    PoolExhaustedError,
                                                    blocks_for_tokens,
@@ -16,6 +18,7 @@ from deeplearning4j_tpu.serving.paged.server import (
     PrefixCacheUnsupportedError)
 
 __all__ = ["BlockPool", "PoolExhaustedError", "NULL_BLOCK", "KVTier",
+           "KVLeaf", "KVLeafUnsupportedError",
            "prefix_block_hashes", "blocks_for_tokens", "table_widths",
            "PagedGenerativeSpec", "PagedGenerativeServer", "PagedMetrics",
            "PrefixCacheUnsupportedError"]

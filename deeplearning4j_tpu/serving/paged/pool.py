@@ -325,6 +325,31 @@ def table_widths(entries: int) -> Tuple[int, ...]:
 
 
 @dataclass(frozen=True)
+class KVLeaf:
+    """What a layer caches a token, as one array of the pool: ``width``
+    numbers a row, the leaf ``[num_blocks, block_size, width]``. A layer
+    of K and V heads has two (``k`` and ``v``, each ``heads x head_dim``
+    wide with the heads outermost, which is what the tensor-parallel path
+    splits); a latent-attention layer has ONE, the compressed row every
+    head reads, and no heads to split (``heads`` 0). ``filled`` is how
+    many of the row's numbers the model fills where that is fewer than
+    ``width`` (the rest completes a lane tile and holds zeros; 0: all of
+    them): the pool is sized and admits by ``width``, and
+    ``memory_report`` gives both."""
+
+    name: str
+    width: int
+    heads: int = 0
+    filled: int = 0
+
+
+class KVLeafUnsupportedError(ValueError):
+    """Asked of a leaf what only a K-and-V pair of heads can give: a
+    split over ``tp`` chips (the leaf has no heads), int8 rows (the
+    scales are per head and channel), or a dense draft beside it."""
+
+
+@dataclass(frozen=True)
 class KVTier:
     """The layers of a model whose KV leaves share one block pool and
     one table a request.
@@ -377,5 +402,6 @@ class KVTier:
 
 
 __all__ = ["BlockPool", "PoolExhaustedError", "NULL_BLOCK", "KVTier",
+           "KVLeaf", "KVLeafUnsupportedError",
            "TABLE_RUNGS", "TABLE_WIDTH_MULTIPLE", "table_widths",
            "prefix_block_hashes", "blocks_for_tokens"]

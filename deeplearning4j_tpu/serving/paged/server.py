@@ -52,6 +52,12 @@ first token from the last run. A spec whose layers are all alike has
 one unnamed tier and takes the path it always took, with the same
 programs and arguments.
 
+What a layer caches a token is the spec's to say (``pool.KVLeaf``): K
+and V rows of ``heads x head_dim`` where it says nothing, ONE compressed
+row for a latent-attention model (``zoo/glm_moe_lite.py``), whose pool has
+one array a layer and no V at all; allocation, the bytes accounted and
+admission follow the row.
+
 The DECODE program reads as many table entries as its longest lane
 holds: at every step boundary the server sends each tier's tables cut to
 the narrowest of ``pool.table_widths`` (three widths, a rule on the
@@ -83,7 +89,9 @@ from deeplearning4j_tpu.serving.generative import (GenerationHandle,
                                                    SlotAllocator)
 from deeplearning4j_tpu.serving.metrics import safe_ratio
 from deeplearning4j_tpu.serving.paged.pool import (NULL_BLOCK, TABLE_RUNGS,
-                                                   BlockPool, KVTier,
+                                                   BlockPool, KVLeaf,
+                                                   KVLeafUnsupportedError,
+                                                   KVTier,
                                                    PoolExhaustedError,
                                                    blocks_for_tokens,
                                                    prefix_block_hashes,
@@ -117,6 +125,17 @@ class PagedGenerativeSpec:
       tensor-parallel path shards the last axis, whose outermost part is
       the heads), hands both tuples to every program and takes them
       back: the programs donate each leaf and write it in place.
+    - ``kv_leaves`` says what a layer caches a token where that is not
+      the pair above (``pool.KVLeaf``: a name and the row's width; one
+      or two a layer). A latent-attention model names ONE leaf, its
+      compressed row: the pool then holds one array a layer ``[num_blocks,
+      block_size, width]``, the programs are handed ``(that tuple, ())``
+      where a pair's get ``(K, V)``, and the bytes the server sizes,
+      reports and admits by are that row's. Of ``kv_shape`` the layers,
+      blocks and block size are then read, the heads and head size not.
+      A leaf without heads has nothing for ``tp`` to split, no int8
+      scales and no dense draft beside it: each is refused typed
+      (``pool.KVLeafUnsupportedError``).
     - ``kv_tiers`` says, layer by layer, which tier a leaf belongs to
       and the tier's window (``pool.KVTier``); ``None`` is one unnamed
       tier of every layer. A leaf has its TIER's ``num_blocks``, and the
@@ -138,6 +157,7 @@ class PagedGenerativeSpec:
     eos_id: Optional[int] = None
     kv_tiers: Optional[Sequence[KVTier]] = None
     program_counters: Tuple[str, ...] = ()
+    kv_leaves: Optional[Sequence[KVLeaf]] = None
 
 
 class PrefixCacheUnsupportedError(ValueError):
@@ -290,6 +310,7 @@ class PagedMetrics(GenerativeMetrics):
         super().__init__(max_slots)
         self.num_blocks = int(num_blocks)     # usable (non-null) blocks
         self.block_size = int(block_size)
+        self.kv_bytes_per_token = 0           # one row of every leaf
         for c in ("prefix_lookups", "prefix_hits", "prefix_blocks_hit",
                   "prefix_cache_flushes",
                   "blocks_allocated", "blocks_released",
@@ -357,6 +378,7 @@ class PagedMetrics(GenerativeMetrics):
             rec["paged"] = {
                 "num_blocks": self.num_blocks,
                 "block_size": self.block_size,
+                "kv_bytes_per_token": self.kv_bytes_per_token,
                 "pool_occupancy": round(safe_ratio(
                     c["blocks_held_sum"],
                     c["pool_samples"] * self.num_blocks), 4),
@@ -476,10 +498,10 @@ class PagedGenerativeServer(GenerativeServer):
         return metrics
 
     def _init_kv(self) -> None:
-        """Allocate the paged memory tier: ``layers`` K leaves and as
-        many V leaves, each ``[num_blocks, block_size, heads *
-        head_dim]`` (block 0 reserved as the null block), the block
-        pool, per-slot block tables, and the geometry-memoized
+        """Allocate the paged memory tier: for each leaf the spec names
+        (K and V where it names none) ``layers`` arrays ``[num_blocks,
+        block_size, width]`` (block 0 reserved as the null block), the
+        block pool, per-slot block tables, and the geometry-memoized
         dispatchers. With ``tp > 1`` also builds the mesh and shards
         params + leaves."""
         import jax
@@ -500,6 +522,25 @@ class PagedGenerativeServer(GenerativeServer):
         itemsize = jnp.zeros((), self._kv_dtype).dtype.itemsize
         layers, _, heads, _, head_dim = (int(d) for d in
                                          spec.kv_shape(1, BS))
+        leaves = tuple(spec.kv_leaves) if spec.kv_leaves is not None \
+            else (KVLeaf("k", heads * head_dim, heads),
+                  KVLeaf("v", heads * head_dim, heads))
+        if not 1 <= len(leaves) <= 2:
+            raise ValueError(f"a layer caches one or two leaves, the spec "
+                             f"names {[lf.name for lf in leaves]}")
+        for lf in leaves:
+            asked = None if lf.heads else (
+                f"tp={self.tp}" if self.tp > 1 else
+                "int8 rows" if jnp.dtype(self._kv_dtype) == jnp.int8 else
+                "a dense draft" if self.draft_spec is not None else None)
+            if asked:
+                raise KVLeafUnsupportedError(
+                    f"KV leaf {lf.name!r} ({lf.width} numbers a token, no "
+                    f"heads) cannot serve {asked}: that is for a K-and-V "
+                    f"pair of heads")
+        self._kv_leaves = leaves
+        row = sum(lf.width for lf in leaves)
+        self.kv_bytes_per_token = layers * row * itemsize
         tiers = tuple(spec.kv_tiers) if spec.kv_tiers is not None \
             else (KVTier("", tuple(range(layers))),)
         if sorted(i for t in tiers for i in t.layers) != list(range(layers)):
@@ -519,7 +560,7 @@ class PagedGenerativeServer(GenerativeServer):
                              "every block")
 
         def per_block(t):
-            return 2 * len(t.layers) * BS * heads * head_dim * itemsize
+            return len(t.layers) * BS * row * itemsize
 
         self.bytes_per_block = sum(per_block(t) for t in tiers)
         self._tiers: List[_TierState] = []
@@ -583,10 +624,9 @@ class PagedGenerativeServer(GenerativeServer):
             mesh_key = (self.tp,
                         tuple(str(d) for d in strat.mesh.mesh.devices.flat))
         self._kv_leaf_shapes = tuple(
-            (self._tier_of[i].pool.num_blocks, BS, heads * head_dim)
-            for i in range(layers))
-        self._kc = self._fresh_leaves()
-        self._vc = self._fresh_leaves()
+            tuple((self._tier_of[i].pool.num_blocks, BS, lf.width)
+                  for i in range(layers)) for lf in leaves)
+        self._kc, self._vc = self._fresh_leaves()
         AllocationsTracker.get_instance().allocate("kv_slab",
                                                    self.kv_slab_bytes)
         # host scheduler state (worker thread owns mutation). The first
@@ -598,6 +638,7 @@ class PagedGenerativeServer(GenerativeServer):
         self.metrics.num_blocks = sum(ts.pool.capacity
                                       for ts in self._tiers)
         self.metrics.block_size = BS
+        self.metrics.kv_bytes_per_token = self.kv_bytes_per_token
         self._slots = SlotAllocator(self.max_slots)
         self._slot_reqs: List[Optional[GenerationRequest]] = \
             [None] * self.max_slots
@@ -618,12 +659,15 @@ class PagedGenerativeServer(GenerativeServer):
         self._verify_disp = disp.get("verify")
 
     def _fresh_leaves(self) -> tuple:
-        """One side of the pool (K or V), zeroed: a tuple of one array a
-        layer, each made where it will live."""
+        """The pool, zeroed, as the two arguments every program takes
+        and gives back: a tuple of one array a layer for each leaf (K,
+        then V), each array made where it will live; a spec of ONE leaf
+        has nothing on the second side, ``()``."""
         import jax.numpy as jnp
-        return tuple(
-            jnp.zeros(shape, self._kv_dtype, device=self._kv_sharding)
-            for shape in self._kv_leaf_shapes)
+        sides = tuple(
+            tuple(jnp.zeros(shape, self._kv_dtype, device=self._kv_sharding)
+                  for shape in side) for side in self._kv_leaf_shapes)
+        return sides + ((),) * (2 - len(sides))
 
     # -- block-commitment admission (submit thread) ---------------------
     def _peak_blocks(self, tier: KVTier, n_tokens: int) -> int:
@@ -968,8 +1012,7 @@ class PagedGenerativeServer(GenerativeServer):
         tables. The requeued requests keep their submit-side block
         commitment (their futures are unresolved) and re-enter at
         prefill."""
-        self._kc = self._fresh_leaves()
-        self._vc = self._fresh_leaves()
+        self._kc, self._vc = self._fresh_leaves()
         self._reset_draft_slabs()
         for ts in self._tiers:
             ts.reset()
@@ -1016,8 +1059,10 @@ class PagedGenerativeServer(GenerativeServer):
                     self._strategy.param_sharding(n, np.ndim(a))
                     if self._strategy is not None else None)
             for n, a in self._params.items()}
-        kv_abs = tuple(_abs(shape, self._kv_dtype, self._kv_sharding)
-                       for shape in self._kv_leaf_shapes)
+        kv_abs = tuple(
+            tuple(_abs(shape, self._kv_dtype, self._kv_sharding)
+                  for shape in side) for side in self._kv_leaf_shapes)
+        kv_abs += ((),) * (2 - len(kv_abs))
         S, MAXB = self.max_slots, self._maxb
 
         def _tier_io(table_key, lead, rows, rung=-1):
@@ -1047,7 +1092,7 @@ class PagedGenerativeServer(GenerativeServer):
                     with _tracer.span("compile.precompile", cat="compile",
                                       target=label):
                         disp.aot[sig] = disp.lower(
-                            params_abs, kv_abs, kv_abs, io_abs).compile()
+                            params_abs, *kv_abs, io_abs).compile()
                     memstats.capture_plan(label, sig,
                                           compiled=disp.aot[sig])
                 if (role, sig) not in self._shapes_seen:
@@ -1086,7 +1131,7 @@ class PagedGenerativeServer(GenerativeServer):
             dparams_abs = {
                 n: _abs(np.shape(a), np.asarray(a).dtype)
                 for n, a in self._draft_params.items()}
-            dkv_abs = _abs(self._dkc.shape, self._dkc.dtype)
+            dkv_abs = (_abs(self._dkc.shape, self._dkc.dtype),) * 2
             _build(self._draft_decode_disp,
                    {"tokens": _abs((S,), jnp.int32),
                     "positions": _abs((S,), jnp.int32),
@@ -1164,9 +1209,19 @@ class PagedGenerativeServer(GenerativeServer):
         for ts in self._tiers[1:]:
             for k, v in ts.pool.stats().items():
                 st[k] += v
+        row = sum(lf.width for lf in self._kv_leaves)
         return {"kv_slab_bytes": self.kv_slab_bytes,
-                "kv_slab_shape": [len(self._kv_leaf_shapes),
-                                  *self._kv_leaf_shapes[0]],
+                "kv_slab_shape": [len(self._kv_leaf_shapes[0]),
+                                  *self._kv_leaf_shapes[0][0]],
+                "kv_leaves": {lf.name: lf.width for lf in self._kv_leaves},
+                "kv_bytes_per_token": self.kv_bytes_per_token,
+                # what the model fills of that (a leaf may lay its row
+                # out wider than it is: KVLeaf.filled)
+                "kv_leaves_filled": {lf.name: lf.filled or lf.width
+                                     for lf in self._kv_leaves},
+                "kv_bytes_per_token_filled":
+                    self.kv_bytes_per_token // row * sum(
+                        lf.filled or lf.width for lf in self._kv_leaves),
                 "kv_tiers": {ts.tier.name or "all": {
                     "layers": len(ts.tier.layers),
                     "window": ts.tier.window,

@@ -15,10 +15,12 @@ from deeplearning4j_tpu.zoo.models_wave3 import (
 from deeplearning4j_tpu.zoo.bert import BERT_BASE, BERT_TINY, BertConfig, bert_base
 from deeplearning4j_tpu.zoo.gpt import GPT_MEDIUM, GPT_TINY, GPTConfig, build_gpt
 from deeplearning4j_tpu.zoo.smallthinker import SmallThinkerConfig
+from deeplearning4j_tpu.zoo.glm_moe_lite import GlmMoeLiteConfig
 
 __all__ = ["LeNet", "SimpleCNN", "AlexNet", "VGG16", "ResNet50",
            "TextGenLSTM", "TransformerEncoder", "SqueezeNet", "UNet",
            "Xception", "Darknet19", "TinyYOLO", "VGG19", "InceptionResNetV1",
            "FaceNet", "NASNet", "YOLO2", "BertConfig", "BERT_BASE",
            "BERT_TINY", "bert_base", "GPTConfig", "GPT_MEDIUM", "GPT_TINY",
-           "build_gpt", "SmallThinkerConfig"]
+           "build_gpt", "SmallThinkerConfig",
+           "GlmMoeLiteConfig"]

@@ -1,12 +1,14 @@
 """One window of a serving cell and then, on the rows it sampled, the
 reference's verdict on the program beside the controls' (float8
-operands; the window off; the window a block short), each through
-``harness.judge`` and the cell's limits: the readings a cell's
-``widest_gap`` limit is set between (PERF.md section 2). With ``trace`` as the last word the window is traced
+operands; for SmallThinker the window off and the window a block short;
+any others named as ``controls=a,b,c``, such as GLM-4.7-Flash's
+``bias_off`` and ``scale_off``), each through ``harness.judge`` and the
+cell's limits: the readings a cell's ``widest_gap`` limit is set between
+(PERF.md section 2). With ``trace`` as the last word the window is traced
 instead, and the device seconds and runs of each XLA program are printed
 with the breakdown (PERF.md section 5). Run on the chip from the root
 of a checkout: ``PYTHONPATH=. python experiments/st_control.py <cell>
-<seed> <seconds> [trace]``; prints one JSON line."""
+<seed> <seconds> [trace | controls=<names>]``; prints one JSON line."""
 import json
 import os
 import sys
@@ -15,7 +17,8 @@ from benchmark import harness
 from benchmark.drivers import serve
 
 
-def main(cell_name: str, seed: int, seconds: float, trace: bool) -> None:
+def main(cell_name: str, seed: int, seconds: float, trace: bool,
+         controls=None) -> None:
     cell = harness.Cell(os.getcwd(), cell_name)
     harness.place_compile_cache(cell.root)
     stamp = harness.device_stamp(cell.chips, require_chip=True)
@@ -33,8 +36,8 @@ def main(cell_name: str, seed: int, seconds: float, trace: bool) -> None:
         # benchmark.run would compare, through the cell's own limits
         pad = int(cell.traffic["server"]["max_seq_len"])
         block = int(cell.traffic["server"]["block_size"])
-        for control in (None, "float8", "window_off",
-                        f"window_less_{block}"):
+        for control in [None] + list(controls or (
+                "float8", "window_off", f"window_less_{block}")):
             got = cell.adapter.check_served(cell.config, seed, rows, pad,
                                             control=control)
             got["correct"] = harness.judge(
@@ -45,5 +48,7 @@ def main(cell_name: str, seed: int, seconds: float, trace: bool) -> None:
 
 
 if __name__ == "__main__":
+    named = [a[9:].split(",") for a in sys.argv[4:]
+             if a.startswith("controls=")]
     main(sys.argv[1], int(sys.argv[2]), float(sys.argv[3]),
-         sys.argv[4:] == ["trace"])
+         sys.argv[4:] == ["trace"], named[0] if named else None)

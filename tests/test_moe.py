@@ -252,3 +252,205 @@ def test_a_token_that_is_not_valid_is_served_by_no_expert():
     np.testing.assert_allclose(np.asarray(y)[np.asarray(valid)],
                                want[np.asarray(valid)], rtol=1e-4,
                                atol=1e-5)
+
+
+# ----------------------------------------------------------------------
+# sigmoid routing with a correction bias, SiLU experts, a shared expert
+from deeplearning4j_tpu.parallel import sigmoid_bias_route
+
+
+def _silu(v):
+    return v / (1.0 + np.exp(-v))
+
+
+def _route_loop(x, router, bias, k, scale):
+    """The rule as it reads, token by token, in float64: the k largest
+    of ``sigmoid + bias``; weights the chosen sigmoids WITHOUT the bias
+    over their sum, times the scale."""
+    s = 1.0 / (1.0 + np.exp(-(np.asarray(x, np.float64)
+                              @ np.asarray(router, np.float64))))
+    idx, w, moved = [], [], []
+    for n in range(s.shape[0]):
+        pick = np.argsort(-(s[n] + np.asarray(bias, np.float64)))[:k]
+        bare = set(np.argsort(-s[n])[:k])
+        idx.append(pick)
+        w.append(scale * s[n][pick] / (s[n][pick].sum() + 1e-20))
+        moved.append(sum(int(e) not in bare for e in pick))
+    return np.stack(idx), np.stack(w), np.asarray(moved)
+
+
+def _biased_router(seed=7, n=40, d=8, e=8):
+    rng = np.random.default_rng(seed)
+    x = jnp.asarray(rng.normal(size=(n, d)), jnp.float32)
+    router = jnp.asarray(rng.normal(size=(d, e)) * 0.4, jnp.float32)
+    # a bias large enough to change choices: one expert pushed up, one down
+    bias = jnp.asarray(rng.normal(size=e) * 0.1, jnp.float32
+                       ).at[2].add(0.4).at[5].add(-0.4)
+    return x, router, bias
+
+
+def test_the_sigmoid_router_chooses_with_the_bias_and_weighs_without_it():
+    x, router, bias = _biased_router()
+    idx, w, moved = sigmoid_bias_route(x, router, bias, 3, scale=1.8)
+    want_idx, want_w, want_moved = _route_loop(x, router, bias, 3, 1.8)
+    got_order = np.argsort(np.asarray(idx), axis=1)
+    want_order = np.argsort(want_idx, axis=1)
+    np.testing.assert_array_equal(
+        np.take_along_axis(np.asarray(idx), got_order, 1),
+        np.take_along_axis(want_idx, want_order, 1))
+    np.testing.assert_allclose(
+        np.take_along_axis(np.asarray(w), got_order, 1),
+        np.take_along_axis(want_w, want_order, 1), rtol=1e-5)
+    np.testing.assert_array_equal(np.asarray(moved), want_moved)
+    # the weights sum to the scale, whatever the bias
+    np.testing.assert_allclose(np.asarray(w).sum(axis=1), 1.8, rtol=1e-6)
+    # the bias CHANGES the choice ...
+    bare_idx, bare_w, none = sigmoid_bias_route(
+        x, router, jnp.zeros_like(bias), 3, scale=1.8)
+    assert int(moved.sum()) > 0 and not int(none.sum())
+    changed = [n for n in range(x.shape[0]) if set(
+        np.asarray(idx[n])) != set(np.asarray(bare_idx[n]))]
+    assert changed and len(changed) == int((np.asarray(moved) > 0).sum())
+    # ... and does NOT enter a weight: a token whose choice it left alone
+    # has the bare router's weights to the digit
+    same = [n for n in range(x.shape[0]) if n not in changed]
+    assert same
+    for n in same:
+        by_expert = lambda i, v: np.asarray(v[n])[  # noqa: E731
+            np.argsort(np.asarray(i[n]))]
+        np.testing.assert_array_equal(by_expert(idx, w),
+                                      by_expert(bare_idx, bare_w))
+
+
+@pytest.mark.parametrize("fault", ["bias_in_the_weights",
+                                   "bias_out_of_the_choice"])
+def test_the_router_test_tells_a_wrong_rule(fault):
+    """The two ways to get the rule wrong, each against the loop: the
+    comparison above would fail on either."""
+    x, router, bias = _biased_router()
+    want_idx, want_w, _ = _route_loop(x, router, bias, 3, 1.8)
+    s = jax.nn.sigmoid(x @ router)
+    if fault == "bias_in_the_weights":
+        top, idx = jax.lax.top_k(s + bias, 3)
+        w = 1.8 * top / top.sum(axis=1, keepdims=True)
+        order = np.argsort(np.asarray(idx), axis=1)
+        got = np.take_along_axis(np.asarray(w), order, 1)
+        want = np.take_along_axis(want_w, np.argsort(want_idx, axis=1), 1)
+        assert np.abs(got - want).max() > 1e-2
+    else:
+        _, idx = jax.lax.top_k(s, 3)
+        assert any(set(np.asarray(idx[n])) != set(want_idx[n])
+                   for n in range(x.shape[0]))
+
+
+def test_four_shares_and_the_shared_expert_once_add_up_to_the_layer():
+    """Four chips holding two SiLU experts each route over all eight by
+    the sigmoid rule and give their own experts' part; the shared expert
+    is what every chip computes alike, counted ONCE: the sum is the
+    uncut layer as a loop over tokens gives it."""
+    rng = np.random.default_rng(8)
+    n, d, f, e, k = 24, 8, 12, 8, 3
+    gate, up, down = _reglu_experts(rng, d, f, e)
+    sg, su, sd = (a[0] for a in _reglu_experts(rng, d, f, 1))
+    x, router, bias = _biased_router(9, n, d, e)
+    idx, w, _ = sigmoid_bias_route(x, router, bias, k, scale=1.8)
+
+    def shared(v):
+        return (jax.nn.silu(v @ sg) * (v @ su)) @ sd
+
+    whole, served = dropless_topk_ffn(x, idx, w, gate, up, down,
+                                      activation=jax.nn.silu)
+    parts, counts = zip(*(
+        dropless_topk_ffn(x, idx, w, gate[a:a + 2], up[a:a + 2],
+                          down[a:a + 2], first_expert=a,
+                          activation=jax.nn.silu)
+        for a in range(0, e, 2)))
+    np.testing.assert_array_equal(np.concatenate(counts), np.asarray(served))
+    assert int(served.sum()) == n * k
+    x64, g64, u64, d64 = (np.asarray(a, np.float64)
+                          for a in (x, gate, up, down))
+    want = np.asarray(shared(x), np.float64)
+    for t in range(n):
+        for ex, we in zip(np.asarray(idx[t]), np.asarray(w[t])):
+            h = _silu(x64[t] @ g64[ex]) * (x64[t] @ u64[ex])
+            want[t] += float(we) * (h @ d64[ex])
+    np.testing.assert_allclose(np.asarray(sum(parts) + shared(x)), want,
+                               rtol=1e-4, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(whole + shared(x)), want,
+                               rtol=1e-4, atol=1e-5)
+    # counted four times it is not the layer
+    assert np.abs(np.asarray(sum(p + shared(x) for p in parts))
+                  - want).max() > 1e-2
+
+
+def test_a_caller_who_names_no_activation_gets_relu_as_before():
+    """SmallThinker's call lowers to the text it lowered to: the default
+    is the same function object's trace as naming ``jax.nn.relu``."""
+    rng = np.random.default_rng(10)
+    gate, up, down = _reglu_experts(rng)
+    x = jnp.asarray(rng.normal(size=(6, 8)), jnp.float32)
+    idx, w = topk_route(x, jnp.asarray(rng.normal(size=(8, 8)),
+                                       jnp.float32), 2)
+    plain = jax.jit(dropless_topk_ffn).lower(x, idx, w, gate, up, down)
+    named = jax.jit(lambda *a: dropless_topk_ffn(
+        *a, activation=jax.nn.relu)).lower(x, idx, w, gate, up, down)
+    strip = lambda t: "\n".join(l for l in t.as_text().splitlines()  # noqa
+                                if "module @" not in l)
+    assert strip(plain) == strip(named)
+    y, _ = dropless_topk_ffn(x, idx, w, gate, up, down)
+    np.testing.assert_allclose(np.asarray(y),
+                               _token_loop(x, idx, w, gate, up, down),
+                               rtol=1e-4, atol=1e-5)
+    swi, _ = dropless_topk_ffn(x, idx, w, gate, up, down,
+                               activation=jax.nn.silu)
+    assert np.abs(np.asarray(swi) - np.asarray(y)).max() > 1e-3
+
+
+# ----------------------------------------------------------------------
+# the grouped product for many rows (JAX's TPU kernel, interpreted here)
+from deeplearning4j_tpu.parallel import tiled_grouped_dot
+
+
+def test_the_tiled_grouped_product_is_ragged_dot_for_many_rows():
+    """256 rows over 4 groups, one of them empty, 29 rows behind the last
+    group: the kernel (interpreted on the CPU) gives ``ragged_dot``'s rows
+    for every row that is in a group; what it leaves behind the last
+    group is finite, and ``dropless_topk_ffn`` puts zeros there."""
+    rng = np.random.default_rng(11)
+    m, k, n = 256, 256, 512
+    x = jnp.asarray(rng.normal(size=(m, k)), jnp.bfloat16)
+    w = jnp.asarray(rng.normal(size=(4, k, n)) * 0.05, jnp.bfloat16)
+    sizes = jnp.asarray([100, 0, 37, 90], jnp.int32)
+    got = np.asarray(tiled_grouped_dot(x, w, sizes))
+    want = np.asarray(jax.lax.ragged_dot(
+        x, w, sizes, preferred_element_type=jnp.float32))
+    assert got.dtype == np.float32 and np.isfinite(got).all()
+    np.testing.assert_allclose(got[:227], want[:227], rtol=1e-5, atol=1e-5)
+
+
+def test_the_layer_takes_a_named_grouped_product():
+    """The layer with the kernel named (interpreted where there is no
+    TPU, as here) gives the layer with ``ragged_dot``, invalid tokens and
+    all; shapes that fill no whole tile are refused."""
+    rng = np.random.default_rng(12)
+    n, d, f, e, k = 128, 512, 512, 4, 2
+    gate, up, down = (jnp.asarray(a, jnp.bfloat16)
+                      for a in _reglu_experts(rng, d, f, e))
+    x = jnp.asarray(rng.normal(size=(n, d)), jnp.bfloat16)
+    idx, w = topk_route(x, jnp.asarray(rng.normal(size=(d, e)),
+                                       jnp.float32), k)
+    valid = jnp.asarray(rng.random(n) > 0.1)
+    plain, served = dropless_topk_ffn(x, idx, w, gate, up, down,
+                                      valid=valid, activation=jax.nn.silu)
+    tiled, served_t = dropless_topk_ffn(
+        x, idx, w, gate, up, down, valid=valid, activation=jax.nn.silu,
+        grouped=tiled_grouped_dot)
+    np.testing.assert_array_equal(np.asarray(served), np.asarray(served_t))
+    np.testing.assert_allclose(np.asarray(tiled), np.asarray(plain),
+                               rtol=2e-2, atol=2e-2 * float(
+                                   np.abs(np.asarray(plain)).max()))
+    assert not np.asarray(tiled)[~np.asarray(valid)].any()
+    for rows, cols in ((100, 512), (128, 500)):
+        with pytest.raises(ValueError, match="whole tiles"):
+            tiled_grouped_dot(x[:rows], up[:, :, :cols],
+                              jnp.zeros(e, jnp.int32))
