@@ -473,7 +473,10 @@ SPAN_CATALOG: Dict[str, Tuple[str, Tuple[str, ...]]] = {
     # serving.decode | serving.draft.. serving.verify, then serving.emit.
     # A dispatch span runs from before the launch to after the host
     # sync (the same edges as GenerativeMetrics' clocks) and holds
-    # serving.launch and serving.sync
+    # serving.launch and serving.sync. With the decode loop one step
+    # ahead, a serving.decode also holds the serving.launch of the step
+    # after it, and that step's own span (ahead=1) opens where this one
+    # closes and holds this step's serving.emit first
     "serving.step": ("serving", ()),
     "serving.admit": ("serving", ("requests",)),
     "serving.prefill": ("serving", ("bucket", "slot", "hist", "trace_id",
@@ -488,7 +491,8 @@ SPAN_CATALOG: Dict[str, Tuple[str, Tuple[str, ...]]] = {
     # moe_experts_touched_sum, moe_tokens_routed_sum,
     # moe_peak_expert_tokens_sum and, for a sigmoid router with a
     # correction bias, moe_bias_moved_sum), packed behind the next tokens
-    "serving.decode": ("serving", ("active", "slots", "table_entries")),
+    "serving.decode": ("serving", ("active", "slots", "table_entries",
+                                   "ahead")),
     "serving.draft": ("serving", ("active", "step", "slots", "phase",
                                   "bucket", "slot")),
     "serving.verify": ("serving", ("active", "window", "slots")),

@@ -30,6 +30,11 @@ requests **at step boundaries** into preallocated KV slots:
   slots (EOS / ``max_new_tokens`` / deadline / cancel / sequence
   capacity) immediately, so the next queued request starts on the very
   next step.
+- **one step ahead** — at a boundary where every lane is greedy, no
+  slot is free and no lane ends by something the host knows, step n+1
+  is launched, fed step n's next tokens where they lie on the device,
+  before the host reads them: token n is handed out while the device
+  runs step n+1 (``_decode_once``; docs/serving.md "One step ahead").
 - **SLO admission** — a rolling p99 of decode-step time
   (``serving/resilience.AdmissionController``) turns queue depth into a
   TTFT estimate; a deadline-carrying request that cannot make it is
@@ -56,7 +61,8 @@ import threading
 import time
 from dataclasses import dataclass, field
 from queue import SimpleQueue
-from typing import Callable, Dict, List, Optional, Sequence, Tuple
+from typing import (Callable, Dict, List, NamedTuple, Optional, Sequence,
+                    Tuple)
 
 import numpy as np
 
@@ -163,6 +169,20 @@ class SlotAllocator:
 
 
 _STREAM_DONE = object()
+
+
+class _Flight(NamedTuple):
+    """One decode step that is launched and whose tokens the host has
+    not read yet: what the program gave back (still on the device), the
+    lanes it ran and who held them at the launch, and what its
+    ``serving.decode`` span and counters will say of it."""
+
+    nxt: object
+    logits: object
+    active: np.ndarray
+    reqs: list
+    launch_ms: float
+    attrs: dict
 
 
 def _trace_args(req: "GenerationRequest") -> dict:
@@ -316,7 +336,10 @@ class GenerativeMetrics(ServingMetrics):
                   "draft_rejected", "requests_admitted",
                   # runs of the prefill program: a prompt longer than
                   # the largest bucket takes several
-                  "prefill_runs"):
+                  "prefill_runs",
+                  # decode steps launched before the tokens of the step
+                  # before them were on the host (of decode_steps)
+                  "decode_ahead_steps"):
             self.counters[c] = 0
         # exact sums in milliseconds, taken on the worker thread where
         # the work happens (PERF.md section 3 names the metric each is for)
@@ -375,12 +398,16 @@ class GenerativeMetrics(ServingMetrics):
             self.counters["draft_rejected"] += int(drafted) - int(accepted)
 
     def observe_decode_step(self, active: int, ms: float,
-                            launch_ms: float) -> None:
+                            launch_ms: float, ahead: bool = False) -> None:
         """One decode step (or speculative round): ``ms`` from before
         the dispatch to after the host sync, of which ``launch_ms`` went
-        into the target's launch, when the device can do nothing."""
+        into the target's launch, when the device can do nothing. A
+        step launched ``ahead``, while the step before it was still
+        unread, is counted from that step's sync to its own (its launch
+        lies in the interval before)."""
         with self._lock:
             self.counters["decode_steps"] += 1
+            self.counters["decode_ahead_steps"] += bool(ahead)
             self.counters["decode_launch_ms_sum"] += launch_ms
             self.counters["slots_active_sum"] += int(active)
             self.counters["batches_dispatched"] += 1
@@ -594,6 +621,11 @@ class GenerativeServer:
         self._killed = False         # abort(): fail in-flight, no drain
         # dispatch-to-sync ms inside the current _step (worker thread)
         self._step_busy_ms = 0.0
+        # the decode loop one step ahead (worker thread): the step that
+        # is launched and unread when a pass ends, and the step before
+        # it, read and not yet handed out (_decode_once)
+        self._ahead: Optional[_Flight] = None
+        self._unemitted: Optional[Tuple[_Flight, np.ndarray]] = None
         self._dirty = False          # a respawned worker must reset state
         self._mem_every = (max(1, int(memory_sample_every))
                            if memory_sample_every else None)
@@ -634,6 +666,10 @@ class GenerativeServer:
     #: counters a spec's decode program feeds (the paged tier reads its
     #: spec's ``program_counters``)
     _program_counters: Tuple[str, ...] = ()
+    #: whether the decode program takes its own next tokens as its next
+    #: ``tokens`` where they lie on the device (the paged tier reads it
+    #: off the compiled programs of a mesh)
+    _feed_on_device = True
 
     def _coerce_spec(self, spec):
         if not isinstance(spec, GenerativeSpec):
@@ -1117,11 +1153,16 @@ class GenerativeServer:
         self._kc = jnp.zeros(shape, self._kv_dtype)
         self._vc = jnp.zeros(shape, self._kv_dtype)
         self._reset_draft_slabs()
+        self._reset_slots()
+
+    def _reset_slots(self) -> None:
+        """A clean slot table, and nothing launched or unread."""
         self._slots.reset()
         self._slot_reqs = [None] * self.max_slots
         self._tokens[:] = 0
         self._positions[:] = 0
         self._active[:] = False
+        self._ahead = self._unemitted = None
 
     def _worker_loop(self, slot: InflightSlot) -> None:
         while True:
@@ -1139,6 +1180,9 @@ class GenerativeServer:
                 return
 
     def _abort_inflight(self) -> None:
+        # a step launched ahead, and tokens read and not yet handed
+        # out, go with their lanes
+        self._ahead = self._unemitted = None
         for s in range(self.max_slots):
             req = self._slot_reqs[s]
             if req is not None:
@@ -1157,24 +1201,30 @@ class GenerativeServer:
 
     def _step(self, slot: InflightSlot) -> bool:
         """One pass of the scheduler: admission, then one decode step or
-        speculative round. An idle server waits for work HERE, before
-        the ``serving.step`` span and the step's clock open, so it
-        traces nothing and its wait is nobody's host time."""
+        speculative round; with a step launched ahead by the pass before
+        (no slot was free then and none has freed since), that step
+        alone. An idle server waits for work HERE, before the
+        ``serving.step`` span and the step's clock open, so it traces
+        nothing and its wait is nobody's host time."""
         first = None
-        if not self._active.any():
+        if self._ahead is None and not self._active.any():
             first = self._take(timeout=0.05)
             if first is None:
                 return False
         with _tracer.span("serving.step", cat="serving"):
             t0 = time.perf_counter()
             self._step_busy_ms = 0.0
-            progressed = self._admit(slot, first)
-            if self._active.any():
-                if self._spec_ready():
-                    self._speculate_once(slot)
-                else:
-                    self._decode_once(slot)
+            if self._ahead is not None:
+                self._decode_once(slot)
                 progressed = True
+            else:
+                progressed = self._admit(slot, first)
+                if self._active.any():
+                    if self._spec_ready():
+                        self._speculate_once(slot)
+                    else:
+                        self._decode_once(slot)
+                    progressed = True
             self.metrics.observe_step(
                 (time.perf_counter() - t0) * 1000.0 - self._step_busy_ms)
         return progressed
@@ -1363,12 +1413,16 @@ class GenerativeServer:
                 attrs["slots"] = slots
         return attrs
 
-    def _decode_io(self) -> Optional[dict]:
+    def _decode_io(self, lead: int = 0) -> Optional[dict]:
         """What the decode program is given this step; ``None`` when no
         lane is left to decode (the paged tier can retire lanes while it
-        grows their block tables)."""
-        return {"tokens": self._tokens.copy(),
-                "positions": self._positions.copy(),
+        grows their block tables). ``lead`` is 1 while the step before
+        is launched and unread: the books hold the positions of the
+        tokens handed out, and that step has each active lane's next
+        row."""
+        positions = self._positions.copy()
+        positions[self._active] += lead
+        return {"tokens": self._tokens.copy(), "positions": positions,
                 "active": self._active.copy()}
 
     def _decode_span_args(self, io: dict) -> dict:
@@ -1385,41 +1439,127 @@ class GenerativeServer:
         (paged, under ``debug_leaks``)."""
 
     def _observe_decode(self, n_active: int, ms: float,
-                        launch_ms: float) -> None:
-        self.metrics.observe_decode_step(n_active, ms, launch_ms)
+                        launch_ms: float, ahead: bool = False) -> None:
+        self.metrics.observe_decode_step(n_active, ms, launch_ms, ahead)
         self._step_busy_ms += ms
         if self.admission is not None:
             self.admission.observe(ms)
         self._maybe_memory_record()
 
     def _decode_once(self, slot: InflightSlot) -> None:
-        io = self._decode_io()
-        if io is None:
-            return
-        n_active = int(io["active"].sum())
-        nxt, logits_d, ms, launch_ms = self._dispatch(
-            self._decode_disp, io, "serving.decode",
-            **self._batch_span_args(n_active, **self._decode_span_args(io)))
-        self._observe_decode(n_active, ms, launch_ms)
+        """One decode step, from its launch to its tokens on the host
+        and out to their requests, with the loop ONE STEP AHEAD where
+        the books allow (:meth:`_may_run_ahead`): the step after this
+        one is launched, fed this step's next tokens as they lie on the
+        device, before the host waits for them. This step's tokens then
+        wait until the next pass, which hands them out first thing,
+        inside the ``serving.decode`` span and clock of the step in the
+        air: while a decode program is launched and unread, the time is
+        decode's. Where the books say no, the boundary is synchronous:
+        sync, emit, and the next pass admits and launches."""
+        fl, self._ahead = self._ahead, None
+        if fl is None:
+            io = self._decode_io()
+            if io is None:
+                return
+            attrs = self._decode_attrs(io)
+        else:
+            io, attrs = None, fl.attrs
+        with _tracer.span("serving.decode", cat="serving", **attrs):
+            t0 = time.perf_counter()
+            if fl is None:
+                fl = self._launch_decode(io, attrs)
+            else:
+                self._emit_step(*self._unemitted)
+                self._unemitted = None
+            # the memory tier as this step runs on it, before the step
+            # after it takes its blocks
+            self._sample_pool()
+            nfl = self._launch_ahead(fl)
+            with _tracer.span("serving.sync", cat="serving"):
+                nxt = np.asarray(fl.nxt)
+            ms = (time.perf_counter() - t0) * 1000.0
+        self._observe_decode(attrs["active"], ms, fl.launch_ms,
+                             ahead="ahead" in attrs)
         if self._program_counters:
             # what the program counted rides behind its next tokens, in
             # the one array the sync brings over
             self.metrics.observe_program(self._program_counters,
                                          nxt[self.max_slots:])
-        self._sample_pool()
-        with _tracer.span("serving.emit", cat="serving", tokens=n_active):
-            lg = np.asarray(logits_d) if self._sampled_active() else None
-            for s in np.flatnonzero(io["active"]):
-                req = self._slot_reqs[int(s)]
-                if req is None:
-                    continue
+        if nfl is not None:
+            self._ahead, self._unemitted = nfl, (fl, nxt)
+        else:
+            self._emit_step(fl, nxt)
+        self._check_leaks()
+
+    def _decode_attrs(self, io: dict) -> dict:
+        return self._batch_span_args(int(io["active"].sum()),
+                                     **self._decode_span_args(io))
+
+    def _launch_decode(self, io: dict, attrs: dict) -> _Flight:
+        nxt, logits, launch_ms = self._launch(self._decode_disp, io,
+                                              "serving.decode")
+        return _Flight(nxt, logits, io["active"], list(self._slot_reqs),
+                       launch_ms, attrs)
+
+    def _may_run_ahead(self) -> bool:
+        """Whether the step after the one in the air may be launched
+        before that one's tokens are read, by the server's own books:
+        every lane greedy (its token is the device's argmax and needs
+        nothing from the host) and no draft armed; no slot free, and no
+        lane that ends at the step in the air by something known
+        beforehand (its budget, the sequence's end, a cancel), so that
+        no admission can follow it: a request is only ever placed at a
+        boundary with nothing in the air, on an idle device."""
+        if (self._draft_decode_disp is not None or not self._feed_on_device
+                or self._slots.free_count()):
+            return False
+        for s, req in enumerate(self._slot_reqs):
+            if (req is None or not self._active[s] or req.temperature > 0
+                    or req.cancelled
+                    or len(req.generated) + 1 >= req.max_new_tokens
+                    or int(self._positions[s]) + 2 >= self.max_seq_len):
+                return False
+        return True
+
+    def _next_tokens(self, nxt):
+        """The decode program's next tokens as its next run's ``tokens``
+        input, on the device (the paged tier cuts them out of a packed
+        array)."""
+        return nxt
+
+    def _launch_ahead(self, fl: _Flight) -> Optional[_Flight]:
+        """Launch the step after ``fl`` while ``fl`` is unread, if the
+        books allow: its io follows from positions alone (a lane is one
+        row further whatever its token is) and its tokens are ``fl``'s
+        next tokens where they lie."""
+        if not self._may_run_ahead():
+            return None
+        io = self._decode_io(1)
+        if io is None:
+            return None
+        io["tokens"] = self._next_tokens(fl.nxt)
+        return self._launch_decode(io, dict(self._decode_attrs(io), ahead=1))
+
+    def _emit_step(self, fl: _Flight, nxt: np.ndarray) -> None:
+        """Hand a decode step's tokens to the lanes that ran it. A lane
+        that has ended since the launch (an EOS, a cancel, a deadline or
+        a failing ``on_token`` at the step before, with this one already
+        in the air) drops its token: its books are those of the tokens
+        it was handed."""
+        with _tracer.span("serving.emit", cat="serving",
+                          tokens=fl.attrs["active"]):
+            lg = np.asarray(fl.logits) if self._sampled_active() else None
+            for s in np.flatnonzero(fl.active):
                 s = int(s)
+                req = self._slot_reqs[s]
+                if req is None or req is not fl.reqs[s]:
+                    continue
                 tok = self._resolve_token(
                     req, int(nxt[s]), lg[s] if lg is not None else None)
                 self._positions[s] += 1
                 self._tokens[s] = tok
                 self._emit(s, req, tok)
-        self._check_leaks()
 
     # -- speculative decoding (draft K, verify once) --------------------
     def _spec_ready(self) -> bool:
@@ -1559,32 +1699,7 @@ class GenerativeServer:
         the next tokens stay on the device without ``sync``."""
         with _tracer.span(span, cat="serving", **attrs):
             t0 = time.perf_counter()
-            sig = ("draft" if draft else "target", ph_shape_sig(io))
-            with self._exec_lock, \
-                    _tracer.span("serving.launch", cat="serving"):
-                t_launch = time.perf_counter()
-                first = sig not in self._shapes_seen
-                if first:
-                    self._shapes_seen.add(sig)
-                    self.metrics.inc("compiles")
-                from deeplearning4j_tpu.integrity.watchdog import \
-                    guard as _wd_guard
-                try:
-                    with _wd_guard("generative_step", first=first):
-                        if draft:
-                            kc, vc, nxt, logits = disp(
-                                self._draft_params, self._dkc, self._dvc,
-                                io)
-                        else:
-                            kc, vc, nxt, logits = disp(
-                                self._params, self._kc, self._vc, io)
-                except Exception as e:
-                    raise self._wrap_exec_error(e, span) from e
-                if draft:
-                    self._dkc, self._dvc = kc, vc
-                else:
-                    self._kc, self._vc = kc, vc
-                launch_ms = (time.perf_counter() - t_launch) * 1000.0
+            nxt, logits, launch_ms = self._launch(disp, io, span, draft)
             if sync:
                 with _tracer.span("serving.sync", cat="serving"):
                     nxt = np.asarray(nxt)
@@ -1592,6 +1707,39 @@ class GenerativeServer:
                 nxt = resolve(nxt, logits)
             ms = (time.perf_counter() - t0) * 1000.0
         return nxt, logits, ms, launch_ms
+
+    def _launch(self, disp: AOTDispatch, io: dict, what: str,
+                draft: bool = False):
+        """Enqueue one program (``serving.launch``): exec lock,
+        stall-watchdog guard, compile accounting, OOM forensics, and
+        slab rebinding. Returns ``(next tokens, logits, launch ms)``,
+        both arrays on the device."""
+        sig = ("draft" if draft else "target", ph_shape_sig(io))
+        with self._exec_lock, \
+                _tracer.span("serving.launch", cat="serving"):
+            t_launch = time.perf_counter()
+            first = sig not in self._shapes_seen
+            if first:
+                self._shapes_seen.add(sig)
+                self.metrics.inc("compiles")
+            from deeplearning4j_tpu.integrity.watchdog import \
+                guard as _wd_guard
+            try:
+                with _wd_guard("generative_step", first=first):
+                    if draft:
+                        kc, vc, nxt, logits = disp(
+                            self._draft_params, self._dkc, self._dvc, io)
+                    else:
+                        kc, vc, nxt, logits = disp(
+                            self._params, self._kc, self._vc, io)
+            except Exception as e:
+                raise self._wrap_exec_error(e, what) from e
+            if draft:
+                self._dkc, self._dvc = kc, vc
+            else:
+                self._kc, self._vc = kc, vc
+            launch_ms = (time.perf_counter() - t_launch) * 1000.0
+        return nxt, logits, launch_ms
 
     def _wrap_exec_error(self, e: BaseException, what: str):
         from deeplearning4j_tpu.monitor import memstats

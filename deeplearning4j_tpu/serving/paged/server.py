@@ -657,6 +657,19 @@ class PagedGenerativeServer(GenerativeServer):
         self._decode_disp = disp["decode"]
         self._prefill_disp = disp["prefill"]
         self._verify_disp = disp.get("verify")
+        # a program that packs its counts behind its next tokens: the
+        # tokens cut out on the device, for the step launched ahead
+        # (warm-up compiles it; without warm-up it compiles like the rest)
+        self._cut_tokens = self._cut_program() \
+            if self._program_counters else None
+
+    def _cut_program(self):
+        import jax
+        S = self.max_slots
+        return jax.jit(lambda packed: packed[:S])
+
+    def _next_tokens(self, nxt):
+        return nxt if self._cut_tokens is None else self._cut_tokens(nxt)
 
     def _fresh_leaves(self) -> tuple:
         """The pool, zeroed, as the two arguments every program takes
@@ -834,7 +847,7 @@ class PagedGenerativeServer(GenerativeServer):
         if gone:
             self.metrics.observe_blocks(released=gone, behind_window=gone)
 
-    def _decode_io(self) -> Optional[dict]:
+    def _decode_io(self, lead: int = 0) -> Optional[dict]:
         BS = self.block_size
         # at the step boundary a window tier takes the last step's block
         # into its table and gives back what the lane's next query no
@@ -842,10 +855,14 @@ class PagedGenerativeServer(GenerativeServer):
         # into an unallocated block gets one in every tier (the tiers'
         # block counts run together). The submit-side commitment
         # guarantees this cannot fail for a placed request; the typed
-        # retire is the defensive belt
+        # retire is the defensive belt. With the step before still in
+        # the air (``lead`` 1) the books move as they would after it: the
+        # device runs programs in the order they were launched, so a
+        # block given back here is read by that step before any later
+        # program writes it
         for s in np.flatnonzero(self._active):
             s = int(s)
-            pos = int(self._positions[s])
+            pos = int(self._positions[s]) + lead
             if self._window_tiers:
                 self._advance(s, pos)
             if pos // BS >= int(self._nblocks[s]):
@@ -859,12 +876,13 @@ class PagedGenerativeServer(GenerativeServer):
         if not self._active.any():
             return None
         act = self._active.copy()
-        wo = self._positions % BS
+        positions = self._positions.copy()
+        positions[act] += lead
+        wo = positions % BS
         wo[~act] = 0
-        io = {"tokens": self._tokens.copy(),
-              "positions": self._positions.copy(),
+        io = {"tokens": self._tokens.copy(), "positions": positions,
               "active": act, "write_off": wo}
-        u = self._positions // BS
+        u = positions // BS
         # the narrowest rung whose tables hold every active lane's
         # blocks, this boundary's growth included (an active lane's
         # position lies in a block it holds, so the program's mask never
@@ -1019,11 +1037,7 @@ class PagedGenerativeServer(GenerativeServer):
         # the wholesale reset already dropped the prefix cache — a
         # pending hot-reload flush is thereby satisfied
         self._prefix_flush_pending.clear()
-        self._slots.reset()
-        self._slot_reqs = [None] * self.max_slots
-        self._tokens[:] = 0
-        self._positions[:] = 0
-        self._active[:] = False
+        self._reset_slots()
 
     # -- AOT warmup -----------------------------------------------------
     def warmup(self, buckets: Optional[Sequence[int]] = None) -> dict:
@@ -1109,6 +1123,16 @@ class PagedGenerativeServer(GenerativeServer):
                     "write_off": _abs((S,), jnp.int32, io_sh),
                     **_tier_io("tables", (S,), S, rung)},
                    f"paged_decode_s{S}r{rung}")
+        if self._cut_tokens is not None:
+            self._cut_tokens = self._cut_program().lower(_abs(
+                (S + len(self._program_counters),), jnp.int32)).compile()
+        if self.tp > 1:
+            # the step launched ahead hands the program its own next
+            # tokens back: only if they come out laid over the mesh as
+            # the warmed program takes its io
+            self._feed_on_device = all(
+                c.output_shardings[2].is_equivalent_to(self._io_sharding, 1)
+                for c in self._decode_disp.aot.values())
         for b in bucket_list:
             _build(self._prefill_disp,
                    {"tokens": _abs((int(b),), jnp.int32, io_sh),

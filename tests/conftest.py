@@ -82,6 +82,33 @@ def served_batches():
     return ServedBatches
 
 
+@pytest.fixture(scope="session")
+def lively():
+    """``lively(spec)``: a generative or paged spec of the tiny GPT over
+    other parameters. The seeded tiny model with its tied head only
+    repeats its last token, whatever its caches and positions hold. With
+    noise on every product's weights and the positional embedding eight
+    times as large, the greedy next token follows the position and the
+    whole context, so a row written or read in the wrong place changes
+    the tokens. The programs are the spec's own; the copy compiles them
+    once more."""
+    import dataclasses
+
+    def make(spec, seed=5):
+        rng = np.random.default_rng(seed)
+        params = {}
+        for name, a in spec.params().items():
+            a = np.asarray(a)
+            if name == "wpe":
+                a = a * 8
+            elif a.ndim == 2 and name != "wte":
+                a = a + rng.normal(0, 0.35, a.shape).astype(a.dtype)
+            params[name] = a
+        return dataclasses.replace(spec, params=lambda: params)
+
+    return make
+
+
 CHAOS_DEFAULT_TIMEOUT = 120
 
 

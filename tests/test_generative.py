@@ -798,6 +798,246 @@ class TestSeededSampling:
 
 
 # ----------------------------------------------------------------------
+def ahead_steps(srv):
+    """``(decode steps launched ahead, decode steps)`` so far."""
+    c = srv.metrics.counters
+    assert 0 <= c["decode_ahead_steps"] <= c["decode_steps"]
+    return c["decode_ahead_steps"], c["decode_steps"]
+
+
+def wait_idle(srv, timeout=10.0):
+    """The worker's last pass ends after the last future resolves."""
+    end = time.monotonic() + timeout
+    while (srv._n_active() or srv._ahead is not None) \
+            and time.monotonic() < end:
+        time.sleep(0.005)
+    time.sleep(0.02)
+
+
+class TestOneStepAhead:
+    """ISSUE 33: the decode loop launches step n+1, fed step n's next
+    tokens on the device, before it reads them, at every boundary where
+    every lane is greedy, no slot is free and no lane ends at step n by
+    something the host knows. Requests are queued on a server that has
+    not started, so that they are admitted together and the count of
+    steps launched ahead follows from the budgets: with every slot full
+    from step 1 and the first lane ending after ``b`` tokens, steps 2 to
+    ``b - 1`` run ahead (``b - 2`` of them)."""
+
+    P = [np.asarray(p, np.int32) for p in
+         ([3, 1, 4], [1, 5, 9, 2, 6], [5, 3, 5, 8, 9, 7, 9])]
+
+    @pytest.fixture(scope="class")
+    def spec(self, gpt_sd, lively):
+        # the module's model only repeats its last token: here a row in
+        # the wrong place has to change the tokens
+        return lively(gpt_generative_spec(gpt_sd, CFG))
+
+    def served(self, spec, jobs, **kw):
+        """``jobs``: ``(prompt, budget, submit kwargs)``. Returns the
+        handles (resolved or failed), the counts, and the server's
+        counters."""
+        srv = make_server(spec, start=False, **kw)
+        try:
+            hs = [srv.submit(p, max_new_tokens=n, **k) for p, n, k in jobs]
+            srv.start()
+            for h in hs:
+                try:
+                    h.result(timeout=120)
+                except Exception:     # noqa: BLE001 — the test reads it
+                    pass
+            wait_idle(srv)
+            assert srv._slots.free_count() == srv.max_slots
+            assert srv._ahead is None and srv._unemitted is None
+            return hs, ahead_steps(srv), dict(srv.metrics.counters)
+        finally:
+            srv.shutdown()
+
+    def test_budgets_that_end_at_different_steps(self, spec):
+        budgets = (5, 9, 12)
+        hs, (ahead, steps), c = self.served(
+            spec, [(p, n, {}) for p, n in zip(self.P, budgets)],
+            max_slots=3)
+        assert [h.result() for h in hs] == [
+            ref_tokens(spec, p, n) for p, n in zip(self.P, budgets)]
+        # three lanes for steps 1-4, then a slot is free for good
+        assert (ahead, steps) == (budgets[0] - 2, budgets[-1] - 1)
+        assert c["tokens_generated"] == sum(budgets)
+
+    def test_a_queued_request_takes_the_freed_slot_and_it_engages_again(
+            self, spec):
+        budgets = (4, 10, 6)
+        hs, (ahead, steps), _ = self.served(
+            spec, [(p, n, {}) for p, n in zip(self.P, budgets)],
+            max_slots=2)
+        assert [h.result() for h in hs] == [
+            ref_tokens(spec, p, n) for p, n in zip(self.P, budgets)]
+        # steps 2-3 beside the first lane; the third request is placed
+        # at the boundary after step 3 with nothing in the air, step 4
+        # is launched behind its prefill, steps 5-8 run ahead until it
+        # ends at step 8; step 9 has a free slot beside it
+        assert (ahead, steps) == (2 + 4, 9)
+
+    def test_the_sequences_end_is_known_beforehand(self, spec):
+        ps = [np.arange(26, dtype=np.int32) % CFG.vocab_size,
+              (np.arange(26, dtype=np.int32) * 3) % CFG.vocab_size]
+        hs, (ahead, steps), _ = self.served(
+            spec, [(p, 50, {}) for p in ps], max_slots=2)
+        got = [h.result() for h in hs]
+        assert got == [ref_tokens(spec, p, 50) for p in ps]
+        assert [len(g) for g in got] == [6, 6]
+        # 26 positions filled, 32 in all: step 5 writes the last row
+        assert (ahead, steps) == (4, 5)
+
+    def test_an_eos_drops_the_token_of_the_step_in_the_air(self, spec):
+        full = [ref_tokens(spec, p, 12) for p in self.P[:2]]
+        # lane 0 ends on its token j, the first that it has not seen
+        j = next(k for k in range(3, 12)
+                 if full[0][k] not in full[0][:k]) + 1
+        eos = full[0][j - 1]
+        seen = []
+        hs, (ahead, steps), c = self.served(
+            spec, [(self.P[0], 12, {"eos_id": eos,
+                                    "on_token": seen.append}),
+                   (self.P[1], 12, {})], max_slots=2)
+        assert hs[0].result() == seen == full[0][:j]
+        assert hs[1].result() == full[1]
+        # the host cannot know: step j is in the air when token j ends
+        # the lane, and its token for the lane goes nowhere
+        assert (ahead, steps) == (j - 1, 11)
+        assert c["tokens_generated"] == j + 12
+
+    @pytest.mark.parametrize("ending", ["cancel", "deadline", "raise"])
+    def test_a_lane_that_ends_with_a_step_in_the_air(self, spec, ending):
+        full = [ref_tokens(spec, p, 12) for p in self.P[:2]]
+        seen, box = [], {}
+
+        class Boom(RuntimeError):
+            pass
+
+        def on_token(tok):
+            seen.append(tok)
+            if len(seen) == 3:
+                if ending == "cancel":
+                    box["h"].cancel()
+                elif ending == "deadline":
+                    time.sleep(1.1)
+                else:
+                    raise Boom("client callback fails")
+
+        srv = make_server(spec, start=False, max_slots=2)
+        try:
+            kw = {"timeout_ms": 1000.0} if ending == "deadline" else {}
+            # compiled before the deadline's clock starts
+            greedy = [ref_tokens(spec, p, 2) for p in self.P[:2]]
+            assert greedy == [f[:2] for f in full]
+            box["h"] = h0 = srv.submit(self.P[0], max_new_tokens=12,
+                                       on_token=on_token, **kw)
+            h1 = srv.submit(self.P[1], max_new_tokens=12)
+            srv.start()
+            assert h1.result(timeout=120) == full[1]
+            if ending == "cancel":
+                assert h0.result(timeout=120) == full[0][:3]
+            elif ending == "deadline":
+                with pytest.raises(ServingTimeoutError) as ei:
+                    h0.result(timeout=120)
+                assert ei.value.tokens == full[0][:3]
+            else:
+                with pytest.raises(Boom):
+                    h0.result(timeout=120)
+            wait_idle(srv)
+            ahead, steps = ahead_steps(srv)
+            c = dict(srv.metrics.counters)
+        finally:
+            srv.shutdown()
+        # the token of the step in the air never reaches the client
+        assert seen == h0.partial() == full[0][:3]
+        assert c["tokens_generated"] == 3 + 12
+        # a cancel or a failing callback is on the books at the next
+        # boundary (steps 2-3 ran ahead); a deadline is only seen at
+        # the delivery after it, with step 4 in the air too
+        assert (ahead, steps) == (3 if ending == "deadline" else 2, 11)
+
+    def test_a_sampled_lane_joining_holds_the_boundary_back(self, spec):
+        jobs = [(self.P[0], 4, {}), (self.P[1], 14, {}),
+                (self.P[2], 5, {"temperature": 0.8, "seed": 5})]
+        hs, (ahead, steps), _ = self.served(spec, jobs, max_slots=2)
+        alone, _, _ = self.served(spec, jobs[2:], max_slots=2)
+        assert [h.result() for h in hs[:2]] == [
+            ref_tokens(spec, self.P[0], 4), ref_tokens(spec, self.P[1], 14)]
+        assert hs[2].result() == alone[0].result()
+        # steps 2-3 beside the first lane; none while the sampled lane
+        # runs (its token is drawn on the host), none beside a free slot
+        assert (ahead, steps) == (2, 13)
+
+    def test_a_free_slot_holds_it_back(self, spec):
+        hs, (ahead, steps), _ = self.served(
+            spec, [(p, 8, {}) for p in self.P[:2]], max_slots=3)
+        assert [h.result() for h in hs] == [
+            ref_tokens(spec, p, 8) for p in self.P[:2]]
+        assert (ahead, steps) == (0, 7)
+
+    def test_a_draft_armed_leaves_the_rounds_as_they_are(self, spec,
+                                                         draft_spec):
+        hs, (ahead, _), c = self.served(
+            spec, [(p, 10, {}) for p in self.P[:2]], max_slots=2,
+            draft_spec=draft_spec, speculate_k=3)
+        assert [h.result() for h in hs] == [
+            ref_tokens(spec, p, 10) for p in self.P[:2]]
+        assert ahead == 0 and c["spec_rounds"] > 0
+
+    def test_no_program_is_built_for_the_tokens_on_the_device(self, gpt_sd,
+                                                              lively):
+        """A device array in ``tokens``' place takes the warmed decode
+        program: nothing compiles under traffic, engaged."""
+        from deeplearning4j_tpu.compilecache import COMPILE_STATS
+        fresh = lively(gpt_generative_spec(gpt_sd, CFG))
+        with GenerativeServer(fresh, max_slots=2, max_seq_len=MSL,
+                              warmup=True, start=False) as srv:
+            mark = COMPILE_STATS.mark()
+            hs = [srv.submit(p, max_new_tokens=9) for p in self.P[:2]]
+            srv.start()
+            got = [h.result(timeout=120) for h in hs]
+            wait_idle(srv)
+            assert ahead_steps(srv) == (7, 8)
+            assert srv.metrics.counters["compiles"] == 0
+            assert COMPILE_STATS.delta(mark)["backend_compiles"] == 0
+        assert got == [ref_tokens(fresh, p, 9) for p in self.P[:2]]
+
+    def test_a_crash_with_a_step_in_the_air_requeues_and_replays(self,
+                                                                 spec):
+        """The worker dies in the launch of a step that runs ahead: the
+        step before it is unread, its tokens are lost with the worker,
+        and both requests re-enter at prefill with what they were
+        handed, exactly once."""
+        full = [ref_tokens(spec, p, 12) for p in self.P[:2]]
+        srv = make_server(spec, start=False, max_slots=2,
+                          resilience=ResilienceConfig(
+                              worker_backoff_base_s=0.01))
+        try:
+            real, calls = srv._decode_disp, []
+
+            class CrashOnce:
+                def __call__(self, *args):
+                    calls.append(type(args[3]["tokens"]).__name__)
+                    if len(calls) == 4:
+                        raise RuntimeError("chaos: decode worker dies")
+                    return real(*args)
+
+            srv._decode_disp = CrashOnce()
+            hs = [srv.submit(p, max_new_tokens=12) for p in self.P[:2]]
+            srv.start()
+            assert [h.result(timeout=120) for h in hs] == full
+            wait_idle(srv)
+        finally:
+            srv.shutdown()
+        # the first launch is fed from the host, the ones ahead of their
+        # step's tokens from the device; the fourth died in the air
+        assert calls[0] == "ndarray" and "ndarray" not in calls[1:4]
+        assert srv.metrics.counters["worker_restarts"] == 1
+        assert all(h._req.requeues == 1 for h in hs)
+
+
 class TestContinuousVsStatic:
     def test_continuous_2x_tokens_per_step_on_skewed_trace(self, spec):
         """The perf mechanism, pinned deterministically: on a trace of

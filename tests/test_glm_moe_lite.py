@@ -69,6 +69,7 @@ def logits_served(srv, prompts, new_tokens):
     srv._resolve_token = keep
     srv._sampled_active = lambda: True       # decode hands the logits over
     hs = [srv.submit(p, max_new_tokens=new_tokens) for p in prompts]
+    srv.start()                              # where the test held it back
     toks = [h.result(timeout=300) for h in hs]
     return toks, [np.stack(seen[h.id]) for h in hs]
 
@@ -403,6 +404,41 @@ def test_the_pool_holds_one_row_a_token_and_every_block_comes_back(spec):
         assert srv._worst_case_blocks(30, 20) == [13]
 
 
+def test_one_step_ahead_serves_what_the_synchronous_loop_serves(spec):
+    """ISSUE 33 over the latent pool: three lanes on three slots from
+    the first step, with the decode loop one step ahead and with every
+    boundary held synchronous (the loop of before, over the same
+    programs and lanes). The step launched ahead takes the packed
+    array's first entries as its tokens, cut on the device, and its
+    block from positions alone. Tokens, logits and the router's own
+    counts are the same, to the bit; the pool invariant is checked at
+    every step (``debug_leaks``)."""
+    prompts = [prompt(5, 1), prompt(21, 2), prompt(9, 3)]
+    n = 22
+    runs = []
+    for ahead in (True, False):
+        with server(spec, start=False) as srv:
+            if not ahead:
+                srv._may_run_ahead = lambda: False
+            toks, lg = logits_served(srv, prompts, n)
+            while srv._n_active() or srv._ahead is not None:
+                time.sleep(0.005)
+            c = dict(srv.metrics.counters)
+            (tier,) = srv._tiers
+            assert tier.pool.held_count() == 0 and not tier.stop.any()
+        assert c["decode_steps"] == n - 1
+        # every lane has the same budget: steps 2 to n - 1 run ahead
+        assert c["decode_ahead_steps"] == (n - 2 if ahead else 0)
+        assert c["blocks_allocated"] == c["blocks_released"]
+        runs.append((toks, lg, {k: v for k, v in c.items()
+                                if k.startswith(("moe_", "blocks_",
+                                                 "decode_table"))}))
+    (t1, l1, c1), (t2, l2, c2) = runs
+    assert t1 == t2 and c1 == c2 and c1["moe_layer_steps"] > 0
+    for a, b in zip(l1, l2):
+        assert np.array_equal(a, b)
+
+
 def test_a_row_wider_than_a_lane_tile_is_laid_out_in_whole_tiles():
     """A row under the TPU's 128 lanes is cached as wide as it is (the
     tiny block's 20); from there on in whole tiles, the rest zeros (the
@@ -492,13 +528,13 @@ def test_a_spec_of_k_and_v_gets_the_pair_it_always_got():
         rep = srv.memory_report()
         assert rep["kv_slab_shape"] == [2, blocks, 8, 32]
         assert rep["kv_leaves"] == {"k": 32, "v": 32}
-        real = srv._dispatch
+        real = srv._launch
 
-        def spy(disp, io, span, **kw):
+        def spy(disp, io, span, *draft):
             ios[span].append({k: np.shape(v) for k, v in io.items()})
-            return real(disp, io, span, **kw)
+            return real(disp, io, span, *draft)
 
-        srv._dispatch = spy
+        srv._launch = spy
         p = prompt(11, 4) % 64
         got = srv.submit(p, max_new_tokens=5).result(timeout=120)
         # nothing compiled under traffic: the warmed programs are the

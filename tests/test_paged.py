@@ -39,7 +39,8 @@ from deeplearning4j_tpu.serving.paged import (NULL_BLOCK, BlockPool,
                                               PoolExhaustedError,
                                               blocks_for_tokens,
                                               prefix_block_hashes)
-from deeplearning4j_tpu.serving.queue import ServerOverloadedError
+from deeplearning4j_tpu.serving.queue import (ServerOverloadedError,
+                                              ServingTimeoutError)
 from deeplearning4j_tpu.serving.resilience import ResilienceConfig
 from deeplearning4j_tpu.zoo.gpt import (GPTConfig, build_gpt,
                                         gpt_generative_spec,
@@ -531,6 +532,199 @@ class TestLifecycleRelease:
 
 
 # ----------------------------------------------------------------------
+class TestOneStepAhead:
+    """ISSUE 33 over the block pool: the step launched ahead gets its
+    blocks and its table width from positions alone, while the step
+    before it is unread, under the every-step pool invariant
+    (``debug_leaks``). Blocks of 4, so that lanes cross a block's edge
+    every fourth step; the requests are queued before the server starts,
+    so that the count of steps launched ahead follows from the budgets
+    (tests/test_generative.py, the class of the same name)."""
+
+    P = [np.asarray(p, np.int32) for p in
+         ([3, 1, 4], [1, 5, 9, 2, 6, 5], [5, 3, 5, 8, 9, 7, 9])]
+
+    @pytest.fixture(scope="class")
+    def specs(self, gpt_sd, lively):
+        # a model whose tokens follow positions and context (conftest):
+        # the paged programs and the dense reference over one draw
+        return (lively(gpt_paged_spec(gpt_sd, CFG)),
+                lively(gpt_generative_spec(gpt_sd, CFG)))
+
+    def served(self, spec, jobs, **kw):
+        kw.setdefault("block_size", 4)
+        kw.setdefault("max_slots", 2)
+        srv = make_server(spec, start=False, **kw)
+        try:
+            hs = [srv.submit(p, max_new_tokens=n, **k) for p, n, k in jobs]
+            srv.start()
+            for h in hs:
+                try:
+                    h.result(timeout=120)
+                except Exception:     # noqa: BLE001 — the test reads it
+                    pass
+            deadline = time.monotonic() + 10
+            while (srv._n_active() or srv._ahead is not None) \
+                    and time.monotonic() < deadline:
+                time.sleep(0.005)
+            wait_uncommitted(srv)
+            c = dict(srv.metrics.counters)
+            assert 0 <= c["decode_ahead_steps"] <= c["decode_steps"]
+            assert srv._ahead is None and srv._unemitted is None
+            assert srv.pool.held_count() == 0 and srv._committed == 0
+            assert c["blocks_allocated"] == c["blocks_released"]
+            srv.pool.check_invariant(tables=[])
+            return hs, c, srv
+        finally:
+            srv.shutdown()
+
+    def test_lanes_cross_block_edges_with_a_step_in_the_air(self, specs):
+        spec, dense = specs
+        budgets = (14, 17)
+        hs, c, _ = self.served(
+            spec, [(p, n, {}) for p, n in zip(self.P, budgets)])
+        assert [h.result() for h in hs] == [
+            ref_tokens(dense, p, n) for p, n in zip(self.P, budgets)]
+        # both lanes from step 1; the first ends on its token 14
+        assert (c["decode_ahead_steps"], c["decode_steps"]) == (12, 16)
+        # 3 + 13 and 6 + 16 rows written: 4 and 6 blocks of 4
+        assert c["blocks_allocated"] == 4 + 6
+
+    @pytest.mark.parametrize("ending", ["eos", "cancel", "deadline",
+                                        "raise"])
+    def test_a_lane_that_ends_with_a_step_in_the_air_gives_its_blocks_back(
+            self, specs, ending):
+        spec, dense = specs
+        full = [ref_tokens(dense, p, 14) for p in self.P[:2]]
+        # lane 0 ends on its token j: by an EOS it has not seen before,
+        # or by what its callback does at the third token
+        j = next(k for k in range(3, 14)
+                 if full[0][k] not in full[0][:k]) + 1 \
+            if ending == "eos" else 3
+        seen, box = [], {}
+
+        class Boom(RuntimeError):
+            pass
+
+        def on_token(tok):
+            seen.append(tok)
+            if len(seen) == 3 and ending == "cancel":
+                box["h"].cancel()
+            elif len(seen) == 3 and ending == "deadline":
+                time.sleep(1.1)
+            elif len(seen) == 3 and ending == "raise":
+                raise Boom("client callback fails")
+
+        kw = {"on_token": on_token}
+        if ending == "eos":
+            kw["eos_id"] = full[0][j - 1]
+        if ending == "deadline":
+            kw["timeout_ms"] = 1000.0
+        srv = make_server(spec, start=False, block_size=4, max_slots=2)
+        try:
+            box["h"] = h0 = srv.submit(self.P[0], max_new_tokens=14, **kw)
+            h1 = srv.submit(self.P[1], max_new_tokens=14)
+            srv.start()
+            assert h1.result(timeout=120) == full[1]
+            try:
+                h0.result(timeout=120)
+            except (ServingTimeoutError, Boom):
+                assert ending in ("deadline", "raise")
+            wait_uncommitted(srv)
+            c = dict(srv.metrics.counters)
+            assert srv.pool.held_count() == 0
+            srv.pool.check_invariant(tables=[])
+        finally:
+            srv.shutdown()
+        # the dropped token is never delivered, and the lane's books are
+        # those of the tokens it was handed
+        assert seen == h0.partial() == full[0][:j]
+        assert c["tokens_generated"] == j + 14
+        assert c["blocks_allocated"] == c["blocks_released"]
+        ahead = {"eos": j - 1, "cancel": 2, "raise": 2, "deadline": 3}
+        assert (c["decode_ahead_steps"], c["decode_steps"]) == \
+            (ahead[ending], 13)
+
+    def test_a_continuation_still_hits_over_the_generated_span(self, specs):
+        """Lane 0 ends on an EOS with the next step in the air, which
+        writes one row past the lane's books into its last block. The
+        full blocks of ``prompt + generated`` are registered as ever, a
+        request over that span finds them, and what they hold serves
+        the reference's tokens."""
+        spec, dense = specs
+        full = ref_tokens(dense, self.P[0], 14)
+        j = next(k for k in range(6, 14) if full[k] not in full[:k]) + 1
+        srv = make_server(spec, start=False, block_size=4, max_slots=2)
+        try:
+            h0 = srv.submit(self.P[0], max_new_tokens=14,
+                            eos_id=full[j - 1])
+            h1 = srv.submit(self.P[1], max_new_tokens=16)
+            srv.start()
+            assert h0.result(timeout=120) == full[:j]
+            assert h1.result(timeout=120) == ref_tokens(dense, self.P[1], 16)
+            assert srv.metrics.counters["decode_ahead_steps"] >= j - 1
+            hit0 = srv.metrics.counters["prefix_blocks_hit"]
+            span = np.concatenate([self.P[0],
+                                   np.asarray(full[:j], np.int32)])
+            got = srv.submit(span, max_new_tokens=5).result(timeout=120)
+            hits = srv.metrics.counters["prefix_blocks_hit"] - hit0
+        finally:
+            srv.shutdown()
+        # every full block under the lane's books: rows [0, 3 + j - 1)
+        assert hits == (len(span) - 1) // 4 >= 2
+        assert got == ref_tokens(dense, span, 5)
+
+    def test_a_free_slot_or_a_draft_holds_it_back(self, specs, draft_spec):
+        spec, dense = specs
+        jobs = [(p, 9, {}) for p in self.P[:2]]
+        want = [ref_tokens(dense, p, 9) for p in self.P[:2]]
+        hs, c, _ = self.served(spec, jobs, max_slots=3)
+        assert [h.result() for h in hs] == want
+        assert (c["decode_ahead_steps"], c["decode_steps"]) == (0, 8)
+        hs, c, _ = self.served(spec, jobs, draft_spec=draft_spec,
+                               speculate_k=3)
+        assert [h.result() for h in hs] == want
+        assert c["decode_ahead_steps"] == 0 and c["spec_rounds"] > 0
+
+    def test_warmed_programs_take_the_tokens_on_the_device(self, specs):
+        from deeplearning4j_tpu.compilecache import COMPILE_STATS
+        spec, dense = specs
+        srv = make_server(spec, start=False, block_size=4, max_slots=2,
+                          warmup=True)
+        try:
+            mark = COMPILE_STATS.mark()
+            hs = [srv.submit(p, max_new_tokens=12) for p in self.P[:2]]
+            srv.start()
+            got = [h.result(timeout=120) for h in hs]
+            c = dict(srv.metrics.counters)
+            assert COMPILE_STATS.delta(mark)["backend_compiles"] == 0
+        finally:
+            srv.shutdown()
+        assert got == [ref_tokens(dense, p, 12) for p in self.P[:2]]
+        assert c["compiles"] == 0 and c["decode_ahead_steps"] == 10
+
+    def test_tp2_feeds_the_mesh_its_own_tokens(self, specs):
+        """Over a mesh the step launched ahead takes the next tokens as
+        the warmed program lays them out; the server reads off the
+        compiled programs whether it may."""
+        import jax
+        if len(jax.devices()) < 2:
+            pytest.skip("needs >= 2 devices")
+        spec, dense = specs
+        srv = make_server(spec, start=False, block_size=4, max_slots=2,
+                          tp=2, warmup=True)
+        try:
+            assert srv._feed_on_device
+            hs = [srv.submit(p, max_new_tokens=12) for p in self.P[:2]]
+            srv.start()
+            got = [h.result(timeout=120) for h in hs]
+            c = dict(srv.metrics.counters)
+        finally:
+            srv.shutdown()
+        assert got == [ref_tokens(dense, p, 12) for p in self.P[:2]]
+        assert c["compiles"] == 0 and c["decode_ahead_steps"] == 10
+
+
 class TestTensorParallel:
     @pytest.mark.slow
     def test_tp2_bit_identical_greedy(self, spec, dense_spec):

@@ -75,8 +75,8 @@ def serve(srv, steps=None):
     if steps is not None:
         real = srv._decode_io
 
-        def spy():
-            io = real()
+        def spy(*lead):
+            io = real(*lead)
             if io is not None:
                 (tier,) = srv._tiers
                 steps.append((io["tables"].shape[1],
@@ -184,6 +184,55 @@ def test_the_span_carries_the_width(spec):
     # 28 tokens and seven more: 8 blocks, then a ninth
     assert len(spans) == 7
     assert {sp.args["table_entries"] for sp in spans} == {8, 16}
+
+
+def test_a_lane_crosses_a_rung_with_the_step_before_it_in_the_air(
+        gpt_sd, lively, monkeypatch):
+    """ISSUE 33: three lanes on three slots from the first step, each
+    long enough to pass 8 and 16 blocks. The step launched ahead of its
+    predecessor's tokens is given its width from positions alone, the
+    boundary's growth included, so a lane's first step on a wider table
+    is built while the last step on the narrower one is unread; the
+    tokens are those of the whole table and of the dense path, over a
+    model whose tokens follow positions (conftest's ``lively``)."""
+    spec = lively(gpt_paged_spec(gpt_sd, CFG))
+    jobs = list(zip(prompts()[:3], (70, 74, 66)))
+    steps = []
+
+    def served(srv):
+        real = srv._decode_io
+
+        def spy(*lead):
+            io = real(*lead)
+            (tier,) = srv._tiers
+            steps.append((io["tables"].shape[1],
+                          int(tier.stop[io["active"]].max()), sum(lead)))
+            return io
+
+        srv._decode_io = spy
+        hs = [srv.submit(p, max_new_tokens=n) for p, n in jobs]
+        srv.start()
+        return [h.result(timeout=300) for h in hs]
+
+    with make_server(spec, start=False) as srv:
+        got = served(srv)
+        c = dict(srv.metrics.counters)
+    assert len(steps) == c["decode_steps"] == 73
+    # all three lanes until the shortest ends on its token 66
+    assert c["decode_ahead_steps"] == 64 == sum(a for _, _, a in steps)
+    for sent, held, _ in steps:
+        assert sent == min(w for w in LADDER if w >= held)
+    wider = [(a, b) for a, b in zip(steps, steps[1:]) if b[0] > a[0]]
+    assert [(a[0], b[0]) for a, b in wider] == [(8, 16), (16, 24)]
+    assert all(b[2] == 1 for _, b in wider)
+    ahead = list(steps)
+    whole_tables(monkeypatch)
+    with make_server(spec, start=False) as srv:
+        assert got == served(srv)
+    assert {w for w, _, _ in steps[len(ahead):]} == {24}
+    dense = lively(gpt_generative_spec(gpt_sd, CFG))
+    assert got == [greedy_decode(dense, p, n, max_seq_len=MSL)
+                   for p, n in jobs]
 
 
 def abstract_decode_args(spec, width):

@@ -62,6 +62,7 @@ def logits_served(srv, prompts, new_tokens):
     srv._resolve_token = keep
     srv._sampled_active = lambda: True       # decode hands the logits over
     hs = [srv.submit(p, max_new_tokens=new_tokens) for p in prompts]
+    srv.start()                              # where the test held it back
     toks = [h.result(timeout=300) for h in hs]
     return toks, [np.stack(seen[h.id]) for h in hs]
 
@@ -167,6 +168,44 @@ def test_the_window_tier_stays_in_its_bound_and_both_tiers_end_empty(spec):
             <= 4 * c["slots_active_sum"]
 
 
+def test_one_step_ahead_serves_what_the_synchronous_loop_serves(spec):
+    """ISSUE 33: three lanes on three slots from the first step, decoded
+    past three windows, with the decode loop one step ahead and with
+    every boundary held synchronous (the loop of before, over the same
+    programs and lanes). The step launched ahead advances the window
+    tier's ring, gives back the block behind the window and takes its
+    fresh block from positions alone, while the step before it, which
+    still reads that block, is unread; its tokens are the packed
+    array's first entries, cut on the device. Tokens, logits, the
+    program's own counts and the blocks given back are the same, to the
+    bit; the pool invariant is checked at every step (``debug_leaks``)."""
+    prompts = [prompt(5, 1), prompt(21, 2), prompt(9, 3)]
+    n = 3 * WINDOW + 4
+    runs = []
+    for ahead in (True, False):
+        with server(spec, start=False) as srv:
+            if not ahead:
+                srv._may_run_ahead = lambda: False
+            toks, lg = logits_served(srv, prompts, n)
+            while srv._n_active() or srv._ahead is not None:
+                time.sleep(0.005)
+            c = dict(srv.metrics.counters)
+            for ts in srv._tiers:
+                assert ts.pool.held_count() == 0 and not ts.stop.any()
+        assert c["decode_steps"] == n - 1
+        # every lane has the same budget: steps 2 to n - 1 run ahead
+        assert c["decode_ahead_steps"] == (n - 2 if ahead else 0)
+        assert c["window_blocks_released"] > 0
+        assert c["blocks_allocated"] == c["blocks_released"]
+        runs.append((toks, lg, {k: v for k, v in c.items()
+                                if k.startswith(("moe_", "window_",
+                                                 "blocks_", "decode_table"))}))
+    (t1, l1, c1), (t2, l2, c2) = runs
+    assert t1 == t2 and c1 == c2
+    for a, b in zip(l1, l2):
+        assert np.array_equal(a, b)
+
+
 def test_a_window_spec_refuses_the_prefix_cache_typed(spec):
     with pytest.raises(PrefixCacheUnsupportedError):
         server(spec, prefix_cache=True)
@@ -207,8 +246,8 @@ def test_the_global_tiers_table_is_cut_to_the_lanes_and_the_ring_is_not(
         assert glob.widths == (8, 16, 16) and win.widths == (3, 3, 3)
         real = srv._decode_io
 
-        def spy():
-            io = real()
+        def spy(*lead):
+            io = real(*lead)
             if io is not None:
                 shapes.append((io["tables.global"].shape[1],
                                io["tables.window"].shape[1],
@@ -338,13 +377,13 @@ def test_a_one_tier_specs_tables_and_programs_are_what_they_were():
         # only a spec that names program counters has them registered
         assert spec.program_counters == () and not any(
             c.startswith("moe_") for c in srv.metrics.counters)
-        real = srv._dispatch
+        real = srv._launch
 
-        def spy(disp, io, span, **kw):
+        def spy(disp, io, span, *draft):
             ios[span].append({k: np.shape(v) for k, v in io.items()})
-            return real(disp, io, span, **kw)
+            return real(disp, io, span, *draft)
 
-        srv._dispatch = spy
+        srv._launch = spy
         p = prompt(11, 4) % 64
         got = srv.submit(p, max_new_tokens=5).result(timeout=120)
         # nothing compiled under traffic: the warmed programs are the

@@ -18,7 +18,13 @@ the profiler's own clock above the device's lines. Pinned here:
   the sync;
 - (e) tokens are bit-identical with the ring on, off and under a
   profiler session;
-- (f) the base and the paged server give the same span tree.
+- (f) the base and the paged server give the same span tree;
+- (g) the decode loop one step ahead (ISSUE 33): a step launched while
+  the step before it is unread has its ``serving.launch`` inside that
+  step's ``serving.decode``, opens its own where that one closes, hands
+  out that step's tokens first, and is counted in
+  ``decode_ahead_steps``; prefill, decode and host time partition the
+  working steps' wall time, engaged and not.
 """
 import glob
 import os
@@ -50,7 +56,10 @@ SERVING_TREE = {
     "serving.decode": {"serving.step"},
     "serving.launch": {"serving.prefill", "serving.decode"},
     "serving.sync": {"serving.prefill", "serving.decode"},
-    "serving.emit": {"serving.admit", "serving.step"},
+    # a step's tokens go out under serving.step, or, where the next step
+    # was launched ahead of them, first thing under that step's
+    # serving.decode
+    "serving.emit": {"serving.admit", "serving.step", "serving.decode"},
     "serving.reply": {"serving.emit"},
 }
 FIT_TREE = {
@@ -342,7 +351,7 @@ class TestTheDisabledPath:
 # ----------------------------------------------------------------------
 # (c) (d) (f): the ring's spans against the counters and each other
 
-def ring_run(make, spec, n_tokens=6):
+def ring_run(make, spec, n_tokens=6, **submit_kw):
     """Serve the prompts with the ring on; returns ``(spans of the
     worker thread, counter deltas, requests, on_token times)``."""
     stamps = []
@@ -356,12 +365,13 @@ def ring_run(make, spec, n_tokens=6):
         m = srv.metrics
         c0 = dict(m.counters, prefill=m.prefill_ms.total_ms,
                   decode=m.exec_ms.total_ms)
-        hs = finished(srv, n_tokens, on_token=on_token)
+        hs = finished(srv, n_tokens, on_token=on_token, **submit_kw)
         c1 = dict(m.counters, prefill=m.prefill_ms.total_ms,
                   decode=m.exec_ms.total_ms)
         disable_tracing()
         spans = [s for s in TRACER.spans()
-                 if s.thread_name.startswith("GenerativeServer")]
+                 if s.thread_name.startswith("GenerativeServer")
+                 and s.name.startswith("serving.")]
         reqs = [h._req for h in hs]
     return spans, {k: c1[k] - c0[k] for k in c1}, reqs, stamps
 
@@ -524,9 +534,12 @@ class TestSpanEdges:
             assert outer.name in ("serving.prefill", "serving.decode")
             launch = [s for s in by_name(spans, "serving.launch")
                       if s.parent == outer.sid]
-            assert len(launch) == 1
-            assert outer.t0 <= launch[0].t0
-            assert launch[0].t0 + launch[0].dur <= sync.t0
+            # a prefill holds its own launch; a decode step its own,
+            # unless it was launched ahead, and its successor's, where
+            # that one is: every one of them before the sync
+            assert len(launch) == 1 or outer.name == "serving.decode"
+            assert all(outer.t0 <= s.t0 and s.t0 + s.dur <= sync.t0
+                       for s in launch)
             assert sync.t0 + sync.dur <= outer.t0 + outer.dur
 
     def test_base_and_paged_give_the_same_span_tree(self, paged_run,
@@ -537,6 +550,76 @@ class TestSpanEdges:
                          for p in ps}
         for name, _ in paged:
             assert name in SPAN_CATALOG
+
+
+# ----------------------------------------------------------------------
+# (g) the decode loop one step ahead
+
+def sampled_run(spec):
+    """The same prompts with sampled lanes: no boundary may run ahead."""
+    try:
+        return ring_run(paged_server, spec, temperature=0.7, seed=11)
+    finally:
+        disable_tracing()
+
+
+class TestOneStepAhead:
+    @pytest.mark.parametrize("run_name", ["paged_run", "dense_run"])
+    def test_an_ahead_steps_launch_lies_in_the_decode_before_it(
+            self, run_name, request):
+        spans, d, _, _ = request.getfixturevalue(run_name)
+        decodes = sorted(by_name(spans, "serving.decode"),
+                         key=lambda s: s.t0)
+        ahead = [bool(s.args.get("ahead")) for s in decodes]
+        # four slots full of greedy lanes with budget left: it engages
+        assert 0 < sum(ahead) == d["decode_ahead_steps"] \
+            <= d["decode_steps"] == len(decodes)
+        assert not ahead[0]
+        launches = by_name(spans, "serving.launch")
+        children = {}
+        for s in spans:
+            children.setdefault(s.parent, []).append(s)
+        busy = [s for s in spans
+                if s.name in ("serving.admit", "serving.prefill")]
+        for k, dec in enumerate(decodes):
+            follows = k + 1 < len(decodes) and ahead[k + 1]
+            mine = [s for s in launches if s.parent == dec.sid]
+            # its own launch unless that lay in the step before, and its
+            # successor's where that one runs ahead
+            assert len(mine) == (not ahead[k]) + follows, (k, ahead)
+            kids = sorted(children[dec.sid], key=lambda s: s.t0)
+            if ahead[k]:
+                prev = decodes[k - 1]
+                end = prev.t0 + prev.dur
+                # it opens where the step before closes: nothing of the
+                # scheduler's lies between them
+                assert end <= dec.t0
+                assert not [s for s in busy if end <= s.t0 <= dec.t0]
+                # and the tokens of the step before go out first
+                assert kids[0].name == "serving.emit"
+                assert [s.name for s in kids[1:]] == \
+                    ["serving.launch"] * follows + ["serving.sync"]
+            else:
+                assert [s.name for s in kids] == \
+                    ["serving.launch"] * (1 + follows) + ["serving.sync"]
+
+    def test_sampled_lanes_hold_every_boundary_back(self, paged_spec):
+        spans, d, _, _ = sampled_run(paged_spec)
+        assert d["decode_steps"] > 0 and d["decode_ahead_steps"] == 0
+        assert not [s for s in by_name(spans, "serving.decode")
+                    if "ahead" in s.args]
+        assert ("serving.emit", "serving.decode") not in tree_of(spans)
+
+    @pytest.mark.parametrize("engaged", [True, False])
+    def test_prefill_decode_and_host_partition_the_steps(
+            self, engaged, paged_run, paged_spec):
+        spans, d, _, _ = paged_run if engaged else sampled_run(paged_spec)
+        assert bool(d["decode_ahead_steps"]) == engaged
+        wall = sum(s.dur for s in by_name(spans, "serving.step")) * 1e3
+        parts = d["prefill"] + d["decode"] + d["sched_host_ms_sum"]
+        assert parts == pytest.approx(wall, rel=0.02)
+        assert d["sched_host_ms_sum"] > 0
+        assert 0 < d["decode_launch_ms_sum"] <= d["decode"]
 
 
 # ----------------------------------------------------------------------
