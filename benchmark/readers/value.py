@@ -6,11 +6,14 @@ from __future__ import annotations
 
 
 def lookup(record, path: str):
+    """``a.b|c.d`` names alternatives: the first that resolves (two
+    families that call one size by two keys)."""
+    first, _, rest = path.partition("|")
     node = record
-    for part in path.split("."):
+    for part in first.split("."):
         if not isinstance(node, dict) or part not in node \
                 or node[part] is None:
-            return None
+            return lookup(record, rest) if rest else None
         node = node[part]
     return node
 

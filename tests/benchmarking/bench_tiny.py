@@ -95,3 +95,17 @@ def write_root(root: str) -> str:
 
 
 CPU_STAMP = {"platform": "cpu", "kind": "cpu", "count": 1}
+
+#: the scheduler's metrics that every serving cell lists (PR 34)
+SCHED_METRICS = ("sched_host_share.tpot", "decode_launch_ms",
+                 "decode_ahead_share.tpot")
+
+
+def check_sched_metrics(got: dict, counters: dict) -> None:
+    """A traced line's scheduler metrics against the record's counters:
+    the counters are every family's server's, not one cell's."""
+    assert set(SCHED_METRICS) <= set(got)
+    ahead = 100.0 * counters["decode_ahead_steps"] / counters["decode_steps"]
+    assert abs(got["decode_ahead_share.tpot"]["value"] - ahead) < 1e-9
+    assert 0 < got["sched_host_share.tpot"]["value"] < 100
+    assert got["decode_launch_ms"]["value"] > 0

@@ -1,10 +1,11 @@
 """The GLM-4.7-Flash cell on the CPU at a tiny size, through
 ``benchmark.run`` on a root of this file's own (a tiny configuration of
-the family, a ``closed_mix`` traffic file, the cell's new metric file
-copied from the package, limits): a ``--dry`` run prints the result line,
-the new per-layer metric reads the program's counters, and the controls
-(lower precision, the router's two new rules wrong) fail a tight limit
-at the same prompts and positions."""
+the family, a ``closed_mix`` traffic file, the metric files the two
+expert cells share copied from the package, limits): a ``--dry`` run
+prints the result line, the experts-touched share reads the program's
+counters over this family's key for the number of routed experts, and
+the controls (lower precision, the router's two new rules wrong) fail a
+tight limit at the same prompts and positions."""
 import json
 import os
 import shutil
@@ -15,6 +16,8 @@ import pytest
 
 from benchmark import harness
 from benchmark.drivers import serve
+
+from bench_tiny import SCHED_METRICS, check_sched_metrics
 
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -49,8 +52,10 @@ MIX = {"kind": "closed_mix", "clients": 3, "round": 6, "lengths_seed": 1,
                   "buckets": [4, 8]},
        "check": {"sample": 6}}
 
-NEW_METRIC = "moe_routed_touched_share.tpot"
-RUN_METRIC = "prefill_run_ms.tpot"
+#: one metric and one file for both expert families since PR 34 (they
+#: were ``moe_routed_touched_share.tpot`` and ``prefill_run_ms.tpot``)
+NEW_METRIC = "moe_experts_touched_share.tpot"
+RUN_METRIC = "prefill_chunk_ms"
 #: set from readings at this size on the CPU (the program rounds operands
 #: to bfloat16 there as on the chip), over the positions where the
 #: reference's two routers chose by a clear margin (``ref.CLEAR_MARGIN``:
@@ -76,7 +81,7 @@ def write_root(root: str) -> str:
     put("traffic/tiny_mix.json", MIX)
     for name in ("tpot_mean_ms", "setup_s", "kv_pool_held_share.tpot",
                  "decode_step_ms", "decode_table_share.tpot", NEW_METRIC,
-                 RUN_METRIC):
+                 RUN_METRIC) + SCHED_METRICS:
         shutil.copy(os.path.join(REPO, "benchmark", "metrics",
                                  name + ".json"),
                     os.path.join(data, "metrics", name + ".json"))
@@ -86,7 +91,8 @@ def write_root(root: str) -> str:
     layer = {"kv_pool_held_share.tpot": "KV memory tier",
              "decode_table_share.tpot": "KV memory tier",
              NEW_METRIC: "expert layer", "decode_step_ms": "model step",
-             RUN_METRIC: "serving scheduler"}
+             RUN_METRIC: "serving scheduler",
+             **dict.fromkeys(SCHED_METRICS, "serving scheduler")}
     bench = {
         "command": ["python3", "-m", "benchmark.run"],
         "paths": ["bench_data"], "run_seconds": 1,
@@ -157,6 +163,7 @@ def test_a_run_reads_the_new_metric_and_its_sample_leads_with_a_document(
     assert got[RUN_METRIC]["value"] == pytest.approx(
         c["prefill_ms_sum"] / c["prefill_runs"])
     assert 0 < got["decode_table_share.tpot"]["value"] <= 100
+    check_sched_metrics(got, c)
 
 
 @pytest.fixture(scope="module")

@@ -15,6 +15,8 @@ import pytest
 from benchmark import harness
 from benchmark.drivers import serve
 
+from bench_tiny import SCHED_METRICS, check_sched_metrics
+
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 CPU_STAMP = {"platform": "cpu", "kind": "cpu", "count": 1}
@@ -66,7 +68,8 @@ def write_root(root: str) -> str:
     put("configs/st_tiny.json", TINY)
     put("traffic/tiny_mix.json", MIX)
     for name in ("tpot_mean_ms", "ttft_p50_ms", "setup_s",
-                 "kv_pool_held_share.tpot", "decode_step_ms") + NEW_METRICS:
+                 "kv_pool_held_share.tpot", "decode_step_ms") + NEW_METRICS \
+            + SCHED_METRICS:
         shutil.copy(os.path.join(REPO, "benchmark", "metrics",
                                  name + ".json"),
                     os.path.join(data, "metrics", name + ".json"))
@@ -77,7 +80,8 @@ def write_root(root: str) -> str:
              "kv_window_held_share.tpot": "KV memory tier",
              "moe_experts_touched_share.tpot": "expert layer",
              "prefill_chunk_ms": "serving scheduler",
-             "decode_step_ms": "model step"}
+             "decode_step_ms": "model step",
+             **dict.fromkeys(SCHED_METRICS, "serving scheduler")}
     bench = {
         "command": ["python3", "-m", "benchmark.run"],
         "paths": ["bench_data"], "run_seconds": 1,
@@ -149,6 +153,7 @@ def test_a_run_reads_the_new_metrics_and_its_sample_crossed_the_window(
     assert c["prefill_runs"] > c["prefills"] > 0
     assert got["prefill_chunk_ms"]["value"] == pytest.approx(
         c["prefill_ms_sum"] / c["prefill_runs"])
+    check_sched_metrics(got, c)
 
 
 @pytest.fixture(scope="module")
