@@ -490,9 +490,11 @@ SPAN_CATALOG: Dict[str, Tuple[str, Tuple[str, ...]]] = {
     # program_counters: the expert layers' moe_layer_steps,
     # moe_experts_touched_sum, moe_tokens_routed_sum,
     # moe_peak_expert_tokens_sum and, for a sigmoid router with a
-    # correction bias, moe_bias_moved_sum), packed behind the next tokens
+    # correction bias, moe_bias_moved_sum; a two-store cache's
+    # kv_rows_attended_sum), packed behind the next tokens; ``turns``:
+    # lanes whose tumbling window was given back at this step's boundary
     "serving.decode": ("serving", ("active", "slots", "table_entries",
-                                   "ahead")),
+                                   "ahead", "turns")),
     "serving.draft": ("serving", ("active", "step", "slots", "phase",
                                   "bucket", "slot")),
     "serving.verify": ("serving", ("active", "window", "slots")),

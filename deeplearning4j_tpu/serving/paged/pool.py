@@ -335,12 +335,19 @@ class KVLeaf:
     many of the row's numbers the model fills where that is fewer than
     ``width`` (the rest completes a lane tile and holds zeros; 0: all of
     them): the pool is sized and admits by ``width``, and
-    ``memory_report`` gives both."""
+    ``memory_report`` gives both.
+
+    ``tier`` None: the leaf lies, layer by layer, in the one tier that
+    names the layer. A leaf that NAMES its tier lies in that tier for
+    every layer of it: two tiers may then cover the same layers, each
+    with leaves of its own (a layer of exact K and V rows beside summary
+    rows caches four leaves on two tiers)."""
 
     name: str
     width: int
     heads: int = 0
     filled: int = 0
+    tier: Optional[str] = None
 
 
 class KVLeafUnsupportedError(ValueError):
@@ -361,7 +368,18 @@ class KVTier:
     at the next step boundary, and the table is a RING of
     :meth:`table_blocks` entries with block ``u`` in entry ``u %
     table_blocks``: a request's table, and what a program gathers
-    through it, stop growing with the request.
+    through it, stop growing with the request. A window that ``tumbles``
+    does not slide with the query: a query at ``p`` reads the positions
+    from ``window * (p // window)`` on, so the whole window is given
+    back at once when ``p`` reaches the next multiple (a whole number of
+    blocks: the server refuses another ``block_size``), and a prompt's
+    runs are cut at those multiples.
+
+    ``row_tokens``: how many tokens one row of the tier stands for. 1 is
+    a row a token; with ``c`` the tier takes one row for every ``c``
+    tokens, when the last of them is written (a summary of a chunk), so
+    ``n`` tokens are ``n // c`` rows and the tier's blocks, tables and
+    reservations count those.
 
     ``name`` suffixes the tier's keys in a program's io
     (``tables.<name>``); the one unnamed tier of a model whose layers
@@ -370,15 +388,40 @@ class KVTier:
     name: str
     layers: Tuple[int, ...]
     window: Optional[int] = None
+    row_tokens: int = 1
+    tumbles: bool = False
+
+    def __post_init__(self):
+        if self.row_tokens < 1:
+            raise ValueError(f"row_tokens must be >= 1, got "
+                             f"{self.row_tokens}")
+        if self.tumbles and (self.window is None or self.row_tokens != 1):
+            raise ValueError("a tier that tumbles has a window of rows "
+                             "that stand for one token each")
+        if self.window is not None and self.row_tokens != 1:
+            raise ValueError("a window is counted in rows of one token")
 
     def key(self, base: str) -> str:
         return f"{base}.{self.name}" if self.name else base
 
+    def rows(self, n_tokens):
+        """Rows that ``n_tokens`` tokens (a number or an array) have
+        written."""
+        return n_tokens // self.row_tokens
+
+    def blocks(self, n_tokens: int, block_size: int) -> int:
+        """Blocks that hold the rows of ``n_tokens`` tokens."""
+        return blocks_for_tokens(self.rows(int(n_tokens)), block_size)
+
     def table_blocks(self, block_size: int, max_blocks: int) -> int:
         """Entries of a request's table: every block of the longest
-        request, or the most blocks one query's window can touch."""
+        request (``max_blocks`` blocks of one token a row), or the most
+        blocks one query's window can touch."""
         if self.window is None:
-            return int(max_blocks)
+            return self.blocks(int(max_blocks) * int(block_size), block_size)
+        if self.tumbles:
+            return min(int(max_blocks),
+                       blocks_for_tokens(self.window, block_size))
         return min(int(max_blocks),
                    blocks_for_tokens(self.window, block_size) + 1)
 
@@ -386,6 +429,9 @@ class KVTier:
         """The lowest block a query at ``position`` or later reads."""
         if self.window is None:
             return 0
+        if self.tumbles:
+            return self.window * (int(position) // self.window) \
+                // int(block_size)
         return max(0, int(position) - self.window + 1) // int(block_size)
 
     def peak_blocks(self, n_tokens: int, block_size: int,
@@ -393,10 +439,13 @@ class KVTier:
         """The most blocks a request of ``n_tokens`` holds at once when
         its rows arrive in runs of at most ``run_tokens`` (a prompt's
         chunks; one token a decode step): a run's own blocks and those
-        its first query still reads."""
-        whole = blocks_for_tokens(n_tokens, block_size)
+        its first query still reads. Under a window that tumbles a run
+        lies in one window, so that is the window's blocks."""
+        whole = self.blocks(n_tokens, block_size)
         if self.window is None:
             return whole
+        if self.tumbles:
+            return min(whole, blocks_for_tokens(self.window, block_size))
         return min(whole, blocks_for_tokens(
             self.window + int(run_tokens), block_size) + 1)
 

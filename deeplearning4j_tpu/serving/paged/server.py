@@ -56,7 +56,12 @@ What a layer caches a token is the spec's to say (``pool.KVLeaf``): K
 and V rows of ``heads x head_dim`` where it says nothing, ONE compressed
 row for a latent-attention model (``zoo/glm_moe_lite.py``), whose pool has
 one array a layer and no V at all; allocation, the bytes accounted and
-admission follow the row.
+admission follow the row. A leaf may NAME its tier: two tiers then cover
+the same layers, a layer caches four leaves, a tier's row may stand for
+several tokens (``KVTier.row_tokens``: one summary row a chunk, beside
+the exact rows) and a window may TUMBLE, given back whole when the query
+enters the next one (``zoo/evabyte.py``); blocks, tables, reservations
+and reports count ROWS, tier by tier.
 
 The DECODE program reads as many table entries as its longest lane
 holds: at every step boundary the server sends each tier's tables cut to
@@ -135,11 +140,20 @@ class PagedGenerativeSpec:
       blocks and block size are then read, the heads and head size not.
       A leaf without heads has nothing for ``tp`` to split, no int8
       scales and no dense draft beside it: each is refused typed
-      (``pool.KVLeafUnsupportedError``).
+      (``pool.KVLeafUnsupportedError``). More than two leaves come in
+      (K-like, V-like) PAIRS, every leaf naming its tier
+      (``KVLeaf.tier``): the programs are then handed ``(the first
+      leaves of the pairs, the second leaves)``, each a tuple of one
+      tuple of arrays a leaf, a leaf's arrays one a layer of its tier.
     - ``kv_tiers`` says, layer by layer, which tier a leaf belongs to
       and the tier's window (``pool.KVTier``); ``None`` is one unnamed
       tier of every layer. A leaf has its TIER's ``num_blocks``, and the
-      programs take one table per tier under the tier's keys.
+      programs take one table per tier under the tier's keys. A tier
+      whose rows stand for several tokens (``KVTier.row_tokens``) is
+      told where the rows go that a run completes: ``write_block.<t>``
+      holds one block a ROW the run can complete (the null block for a
+      row it does not), and the program finds the offset from the
+      positions.
     - ``program_counters`` names what the decode program counts on the
       device (an expert layer's routing): its next tokens are then
       ``[max_slots + len(program_counters)]``, the counts of the step
@@ -325,7 +339,20 @@ class PagedMetrics(GenerativeMetrics):
                   # table entries a lane the decode program was handed,
                   # and the whole table's, summed over plain decode steps
                   # and the tiers without a window
-                  "decode_table_entries_sum", "decode_table_capacity_sum"):
+                  "decode_table_entries_sum", "decode_table_capacity_sum",
+                  # rows BY KIND, a plain decode step over its active
+                  # lanes, each in rows x layers: what the lanes' tiers
+                  # hold, the positions they stand at (what a row a
+                  # token and layer would hold), what the program was
+                  # handed through every tier's table; what a program
+                  # counts itself (kv_rows_attended_sum) comes with its
+                  # next tokens (PagedGenerativeSpec.program_counters)
+                  "kv_rows_held_sum", "kv_positions_sum",
+                  "kv_rows_gathered_sum",
+                  # rows of a tier whose row stands for several tokens
+                  # (one a lane and completed chunk), and windows that
+                  # tumbled (one a lane and turn)
+                  "summary_rows_written", "window_turns"):
             self.counters[c] = 0
         self._pool_stats: Dict[str, int] = {}
 
@@ -349,6 +376,15 @@ class PagedMetrics(GenerativeMetrics):
             self.counters["decode_table_entries_sum"] += int(entries)
             self.counters["decode_table_capacity_sum"] += int(capacity)
 
+    def observe_rows(self, held: int, positions: int,
+                     gathered: int) -> None:
+        """One decode step's rows over its active lanes and the layers:
+        held by the tiers, one a position, handed to the program."""
+        with self._lock:
+            self.counters["kv_rows_held_sum"] += int(held)
+            self.counters["kv_positions_sum"] += int(positions)
+            self.counters["kv_rows_gathered_sum"] += int(gathered)
+
     def observe_prefix(self, looked_up: bool, blocks_hit: int) -> None:
         with self._lock:
             if looked_up:
@@ -358,13 +394,15 @@ class PagedMetrics(GenerativeMetrics):
                 self.counters["prefix_blocks_hit"] += int(blocks_hit)
 
     def observe_blocks(self, allocated: int = 0, released: int = 0,
-                       behind_window: int = 0) -> None:
+                       behind_window: int = 0, turns: int = 0) -> None:
         """``behind_window`` of the ``released`` were a window tier's,
-        given back while their request ran."""
+        given back while their request ran, ``turns`` times a whole
+        window at once."""
         with self._lock:
             self.counters["blocks_allocated"] += int(allocated)
             self.counters["blocks_released"] += int(released)
             self.counters["window_blocks_released"] += int(behind_window)
+            self.counters["window_turns"] += int(turns)
 
     def observe_request_blocks(self, n: int) -> None:
         with self._lock:
@@ -471,6 +509,7 @@ class PagedGenerativeServer(GenerativeServer):
         self._kv_sharding = None
         self._commit_lock = threading.Lock()
         self._reserved: List[int] = []   # worst-case blocks, a tier
+        self._turns = 0       # windows that tumbled at the last boundary
         # hot-reload fence: set by update_model(), consumed by the
         # worker at its next step boundary (the pool is worker-owned)
         self._prefix_flush_pending = threading.Event()
@@ -525,9 +564,13 @@ class PagedGenerativeServer(GenerativeServer):
         leaves = tuple(spec.kv_leaves) if spec.kv_leaves is not None \
             else (KVLeaf("k", heads * head_dim, heads),
                   KVLeaf("v", heads * head_dim, heads))
-        if not 1 <= len(leaves) <= 2:
-            raise ValueError(f"a layer caches one or two leaves, the spec "
-                             f"names {[lf.name for lf in leaves]}")
+        on_tier = [lf.tier is not None for lf in leaves]
+        if not (1 <= len(leaves) <= 2 and not any(on_tier)
+                or len(leaves) % 2 == 0 and all(on_tier)):
+            raise ValueError(
+                f"a layer caches one or two leaves, or pairs of leaves "
+                f"that each name their tier; the spec names "
+                f"{[(lf.name, lf.tier) for lf in leaves]}")
         for lf in leaves:
             asked = None if lf.heads else (
                 f"tp={self.tp}" if self.tp > 1 else
@@ -539,13 +582,48 @@ class PagedGenerativeServer(GenerativeServer):
                     f"heads) cannot serve {asked}: that is for a K-and-V "
                     f"pair of heads")
         self._kv_leaves = leaves
-        row = sum(lf.width for lf in leaves)
-        self.kv_bytes_per_token = layers * row * itemsize
         tiers = tuple(spec.kv_tiers) if spec.kv_tiers is not None \
             else (KVTier("", tuple(range(layers))),)
-        if sorted(i for t in tiers for i in t.layers) != list(range(layers)):
+        if all(on_tier):
+            if {lf.tier for lf in leaves} != {t.name for t in tiers} \
+                    or any(a.tier != b.tier
+                           for a, b in zip(leaves[0::2], leaves[1::2])):
+                raise ValueError(
+                    f"leaves {[(lf.name, lf.tier) for lf in leaves]} do "
+                    f"not pair up over the tiers {[t.name for t in tiers]}")
+        elif sorted(i for t in tiers for i in t.layers) \
+                != list(range(layers)):
             raise ValueError("kv_tiers must name every layer once")
-        plain = len(tiers) == 1 and tiers[0].window is None
+        # a prompt's runs start at multiples of the largest bucket and
+        # of a window that tumbles: both must end on a row of every tier,
+        # and such a window on a block
+        starts = [self._buckets.max_rows] + [t.window for t in tiers
+                                             if t.tumbles]
+        for t in tiers:
+            if t.tumbles and t.window % BS:
+                raise ValueError(
+                    f"tier {t.name!r} gives a window of {t.window} rows "
+                    f"back whole: not a whole number of blocks of {BS}")
+            if any(n % t.row_tokens for n in starts):
+                raise ValueError(
+                    f"tier {t.name!r} has a row every {t.row_tokens} "
+                    f"tokens: the largest bucket and a window that "
+                    f"tumbles ({starts}) must be multiples of it")
+        self._tumbling = tuple(starts[1:])
+
+        tier_leaves = self._tier_leaves
+
+        def bytes_per_token(width_of):
+            # rows x layers a token of a request that keeps them all
+            return sum(len(t.layers) * width_of(lf) * itemsize
+                       // t.row_tokens
+                       for t in tiers for lf in tier_leaves(t))
+
+        self.kv_bytes_per_token = bytes_per_token(lambda lf: lf.width)
+        self._kv_bytes_per_token_filled = bytes_per_token(
+            lambda lf: lf.filled or lf.width)
+        plain = len(tiers) == 1 and tiers[0].window is None \
+            and tiers[0].row_tokens == 1
         if self._prefix_cache_arg and not plain:
             raise PrefixCacheUnsupportedError(
                 "the prefix cache serves one tier that keeps every block; "
@@ -560,7 +638,8 @@ class PagedGenerativeServer(GenerativeServer):
                              "every block")
 
         def per_block(t):
-            return len(t.layers) * BS * row * itemsize
+            return len(t.layers) * BS * itemsize * sum(
+                lf.width for lf in tier_leaves(t))
 
         self.bytes_per_block = sum(per_block(t) for t in tiers)
         self._tiers: List[_TierState] = []
@@ -579,8 +658,8 @@ class PagedGenerativeServer(GenerativeServer):
                 t, BS, t.table_blocks(BS, self._maxb), num_blocks,
                 self.max_slots))
             self.kv_slab_bytes += num_blocks * per_block(t)
-        self._tier_of = {i: ts for ts in self._tiers
-                         for i in ts.tier.layers}
+        by_name = {ts.tier.name: ts for ts in self._tiers}
+        tier_of = {i: ts for ts in self._tiers for i in ts.tier.layers}
         self._window_tiers = [ts for ts in self._tiers
                               if ts.tier.window is not None]
         self._ladder_tiers = [ts for ts in self._tiers
@@ -623,9 +702,16 @@ class PagedGenerativeServer(GenerativeServer):
                                               PartitionSpec())
             mesh_key = (self.tp,
                         tuple(str(d) for d in strat.mesh.mesh.devices.flat))
+        # a leaf's arrays: one a layer, of the layer's tier; or one a
+        # layer of the tier the leaf names
         self._kv_leaf_shapes = tuple(
-            tuple((self._tier_of[i].pool.num_blocks, BS, lf.width)
-                  for i in range(layers)) for lf in leaves)
+            tuple((ts.pool.num_blocks, BS, lf.width)
+                  for ts in ([tier_of[i] for i in range(layers)]
+                             if lf.tier is None
+                             else [by_name[lf.tier]]
+                             * len(by_name[lf.tier].tier.layers)))
+            for lf in leaves)
+        self._kv_layers = layers
         self._kc, self._vc = self._fresh_leaves()
         AllocationsTracker.get_instance().allocate("kv_slab",
                                                    self.kv_slab_bytes)
@@ -677,10 +763,23 @@ class PagedGenerativeServer(GenerativeServer):
         then V), each array made where it will live; a spec of ONE leaf
         has nothing on the second side, ``()``."""
         import jax.numpy as jnp
-        sides = tuple(
+        return self._two_sides(tuple(
             tuple(jnp.zeros(shape, self._kv_dtype, device=self._kv_sharding)
-                  for shape in side) for side in self._kv_leaf_shapes)
-        return sides + ((),) * (2 - len(sides))
+                  for shape in side) for side in self._kv_leaf_shapes))
+
+    def _tier_leaves(self, tier: KVTier) -> List[KVLeaf]:
+        """The leaves whose rows lie in ``tier``."""
+        return [lf for lf in self._kv_leaves
+                if lf.tier in (None, tier.name)]
+
+    @staticmethod
+    def _two_sides(sides: tuple) -> tuple:
+        """One entry a leaf as the programs' two arguments: the leaf and
+        ``()``, the two leaves, or of pairs of leaves the first ones and
+        the second ones."""
+        if len(sides) <= 2:
+            return sides + ((),) * (2 - len(sides))
+        return sides[0::2], sides[1::2]
 
     # -- block-commitment admission (submit thread) ---------------------
     def _peak_blocks(self, tier: KVTier, n_tokens: int) -> int:
@@ -783,7 +882,8 @@ class PagedGenerativeServer(GenerativeServer):
     def _prefill_runs(self, s: int, prefix: np.ndarray, L: int):
         """Slot ``s``'s table starts with what the prefix cache holds of
         the prefix; the rest runs through the program in chunks of the
-        largest bucket."""
+        largest bucket, cut besides where a window that tumbles ends (a
+        run's rows then lie in one window)."""
         BS = self.block_size
         self._hashes = []
         hit: List[int] = []
@@ -798,8 +898,13 @@ class PagedGenerativeServer(GenerativeServer):
             self.metrics.observe_prefix(True, len(hit))
             self._tables[s, :len(hit)] = hit
             self._nblocks[s] = len(hit)
-        hist, run = len(hit) * BS, self._buckets.max_rows
-        return [(a, min(a + run, L)) for a in range(hist, L, run)]
+        runs, a = [], len(hit) * BS
+        while a < L:
+            b = min([a + self._buckets.max_rows, L]
+                    + [w * (a // w + 1) for w in self._tumbling])
+            runs.append((a, b))
+            a = b
+        return runs
 
     def _prefill_io(self, s: int, prefix: np.ndarray, L: int,
                     start: int, stop: int):
@@ -808,7 +913,7 @@ class PagedGenerativeServer(GenerativeServer):
         failed allocation leaves what it took on the slot's books, which
         the retirement that follows gives back."""
         BS = self.block_size
-        fresh = sum(ts.grow(s, blocks_for_tokens(stop, BS))
+        fresh = sum(ts.grow(s, ts.tier.blocks(stop, BS))
                     for ts in self._tiers)
         self.metrics.observe_blocks(allocated=fresh)
         bucket, padded = self._pad_to_bucket(prefix[start:stop])
@@ -817,7 +922,17 @@ class PagedGenerativeServer(GenerativeServer):
         for ts in self._tiers:
             t = ts.tier
             io[t.key("table")] = ts.tables[s].copy()
-            if t.name:
+            if t.row_tokens > 1:
+                # one block a row the run can complete, the null block
+                # for the rows it does not (a run starts on a row)
+                r0, r1 = t.rows(start), t.rows(stop)
+                wb = np.full(blocks_for_tokens(bucket, t.row_tokens),
+                             NULL_BLOCK, np.int32)
+                wb[:r1 - r0] = [ts.block_at(s, r // BS)
+                                for r in range(r0, r1)]
+                io[t.key("write_block")] = wb
+                self.metrics.inc("summary_rows_written", r1 - r0)
+            elif t.name:
                 # a window tier's ring still holds what the run reads,
                 # so where the run's own rows go comes beside the table
                 # (the unnamed tier's program finds it in the table)
@@ -842,10 +957,18 @@ class PagedGenerativeServer(GenerativeServer):
 
         return io, {"bucket": bucket, "hist": start}, filled
 
-    def _advance(self, s: int, position: int) -> None:
-        gone = sum(ts.advance(s, position) for ts in self._window_tiers)
+    def _advance(self, s: int, position: int) -> int:
+        """Slot ``s``'s next query is at ``position``: its window tiers
+        move on. Returns how many windows were given back whole."""
+        gone = turns = 0
+        for ts in self._window_tiers:
+            n = ts.advance(s, position)
+            gone += n
+            turns += bool(n and ts.tier.tumbles)
         if gone:
-            self.metrics.observe_blocks(released=gone, behind_window=gone)
+            self.metrics.observe_blocks(released=gone, behind_window=gone,
+                                        turns=turns)
+        return turns
 
     def _decode_io(self, lead: int = 0) -> Optional[dict]:
         BS = self.block_size
@@ -860,19 +983,23 @@ class PagedGenerativeServer(GenerativeServer):
         # device runs programs in the order they were launched, so a
         # block given back here is read by that step before any later
         # program writes it
+        self._turns = 0
         for s in np.flatnonzero(self._active):
             s = int(s)
             pos = int(self._positions[s]) + lead
             if self._window_tiers:
-                self._advance(s, pos)
-            if pos // BS >= int(self._nblocks[s]):
-                try:
-                    grown = sum(ts.grow(s, pos // BS + 1)
-                                for ts in self._tiers)
-                except PoolExhaustedError as e:   # pragma: no cover
-                    self._retire(s, error=e)
-                    continue
-                self.metrics.observe_blocks(allocated=grown)
+                self._turns += self._advance(s, pos)
+            for ts in self._tiers:
+                # the blocks of the rows written once this step's is (a
+                # tier of one row a token: the block of ``pos``)
+                need = ts.tier.blocks(pos + 1, BS)
+                if need > ts.stop[s]:
+                    try:
+                        grown = ts.grow(s, need)
+                    except PoolExhaustedError as e:   # pragma: no cover
+                        self._retire(s, error=e)
+                        break
+                    self.metrics.observe_blocks(allocated=grown)
         if not self._active.any():
             return None
         act = self._active.copy()
@@ -882,30 +1009,52 @@ class PagedGenerativeServer(GenerativeServer):
         wo[~act] = 0
         io = {"tokens": self._tokens.copy(), "positions": positions,
               "active": act, "write_off": wo}
-        u = positions // BS
+        n_act = int(act.sum())
+        written = positions + 1
         # the narrowest rung whose tables hold every active lane's
         # blocks, this boundary's growth included (an active lane's
         # position lies in a block it holds, so the program's mask never
         # reaches past the width)
         rung = max((bisect_left(ts.widths, int(ts.stop[act].max()))
                     for ts in self._ladder_tiers), default=0)
+        held = gathered = 0
         for ts in self._tiers:
-            # an idle lane's table is all null blocks, wherever it points
+            t = ts.tier
+            # the block of the row this step writes: the last of the rows
+            # written once it has (an idle lane's table is all null
+            # blocks, wherever it points)
+            rows = t.rows(written)
+            u = (rows - 1) // BS
             wb = ts.tables[self._lane_ids, u % ts.entries]
-            if ts.tier.window is not None:
+            if t.window is not None:
                 for s in np.flatnonzero(act):
                     if ts.pending[s]:
                         wb[s] = ts.pending[s].get(int(u[s]), wb[s])
-            io[ts.tier.key("tables")] = ts.tables[:, :ts.widths[rung]].copy()
-            io[ts.tier.key("write_block")] = wb
+            if t.row_tokens > 1:
+                # a row of several tokens is written by its last one
+                done = act & (rows > t.rows(positions))
+                wb[~done] = NULL_BLOCK
+                self.metrics.inc("summary_rows_written", int(done.sum()))
+            io[t.key("tables")] = ts.tables[:, :ts.widths[rung]].copy()
+            io[t.key("write_block")] = wb
+            held += len(t.layers) * int(
+                (rows[act] - ts.first[act] * BS).sum())
+            gathered += len(t.layers) * ts.widths[rung]
         self.metrics.observe_tables(
             sum(ts.widths[rung] for ts in self._ladder_tiers),
             self._ladder_capacity)
+        self.metrics.observe_rows(
+            held, self._kv_layers * int(written[act].sum()),
+            gathered * BS * n_act)
         return io
 
     def _decode_span_args(self, io: dict) -> dict:
-        return {"table_entries": sum(
+        args = {"table_entries": sum(
             io[ts.tier.key("tables")].shape[1] for ts in self._ladder_tiers)}
+        if self._turns:
+            # lanes whose window tumbled at this step's boundary
+            args["turns"] = self._turns
+        return args
 
     def _sample_pool(self) -> None:
         windows = [ts.pool for ts in self._window_tiers]
@@ -1073,10 +1222,9 @@ class PagedGenerativeServer(GenerativeServer):
                     self._strategy.param_sharding(n, np.ndim(a))
                     if self._strategy is not None else None)
             for n, a in self._params.items()}
-        kv_abs = tuple(
+        kv_abs = self._two_sides(tuple(
             tuple(_abs(shape, self._kv_dtype, self._kv_sharding)
-                  for shape in side) for side in self._kv_leaf_shapes)
-        kv_abs += ((),) * (2 - len(kv_abs))
+                  for shape in side) for side in self._kv_leaf_shapes))
         S, MAXB = self.max_slots, self._maxb
 
         def _tier_io(table_key, lead, rows, rung=-1):
@@ -1090,9 +1238,13 @@ class PagedGenerativeServer(GenerativeServer):
                 t = ts.tier
                 out[t.key(table_key)] = _abs(lead + (ts.widths[rung],),
                                              jnp.int32, io_sh)
-                if lead or t.name:
+                if lead:
                     out[t.key("write_block")] = _abs((rows,), jnp.int32,
                                                      io_sh)
+                elif t.name or t.row_tokens > 1:
+                    out[t.key("write_block")] = _abs(
+                        (blocks_for_tokens(rows, t.row_tokens),),
+                        jnp.int32, io_sh)
             return out
 
         mark = COMPILE_STATS.mark()
@@ -1233,7 +1385,6 @@ class PagedGenerativeServer(GenerativeServer):
         for ts in self._tiers[1:]:
             for k, v in ts.pool.stats().items():
                 st[k] += v
-        row = sum(lf.width for lf in self._kv_leaves)
         return {"kv_slab_bytes": self.kv_slab_bytes,
                 "kv_slab_shape": [len(self._kv_leaf_shapes[0]),
                                   *self._kv_leaf_shapes[0][0]],
@@ -1244,11 +1395,14 @@ class PagedGenerativeServer(GenerativeServer):
                 "kv_leaves_filled": {lf.name: lf.filled or lf.width
                                      for lf in self._kv_leaves},
                 "kv_bytes_per_token_filled":
-                    self.kv_bytes_per_token // row * sum(
-                        lf.filled or lf.width for lf in self._kv_leaves),
+                    self._kv_bytes_per_token_filled,
                 "kv_tiers": {ts.tier.name or "all": {
                     "layers": len(ts.tier.layers),
+                    "leaves": [lf.name
+                               for lf in self._tier_leaves(ts.tier)],
                     "window": ts.tier.window,
+                    "tumbles": ts.tier.tumbles,
+                    "row_tokens": ts.tier.row_tokens,
                     "table_entries": ts.entries,
                     "num_blocks": ts.pool.capacity,
                     "blocks_held": ts.pool.held_count()}

@@ -33,7 +33,7 @@ import numpy as np
 import pytest
 
 from deeplearning4j_tpu.serving.generative import greedy_decode
-from deeplearning4j_tpu.serving.paged import (NULL_BLOCK, BlockPool,
+from deeplearning4j_tpu.serving.paged import (NULL_BLOCK, BlockPool, KVTier,
                                               PagedGenerativeServer,
                                               PagedMetrics,
                                               PoolExhaustedError,
@@ -985,3 +985,84 @@ class TestSpeculativeAndQuant:
         finally:
             f32.shutdown()
             q.shutdown()
+
+
+class TestTierRows:
+    """``KVTier`` by hand (ISSUE 35): a tier whose row stands for ``c``
+    tokens counts rows; a window that tumbles is given back whole at its
+    multiples; a sliding tier and a one-leaf pool read as before."""
+
+    BS = 4
+
+    def test_a_row_tier_counts_rows_not_tokens(self):
+        t = KVTier("summary", (0, 1), row_tokens=4)
+        assert [t.rows(n) for n in (0, 3, 4, 7, 8, 130)] \
+            == [0, 0, 1, 1, 2, 32]
+        assert list(t.rows(np.array([3, 4, 9]))) == [0, 1, 2]
+        # 15 tokens are 3 rows, a block; 17 are 4 rows, still one; 20 are 5
+        assert [t.blocks(n, self.BS) for n in (3, 4, 15, 17, 19, 20, 256)] \
+            == [0, 1, 1, 1, 1, 2, 16]
+        # the table of a request of 64 blocks of tokens: 256 / 4 rows
+        assert t.table_blocks(self.BS, 64) == 16
+        assert t.first_live_block(1000, self.BS) == 0
+        assert t.peak_blocks(130, self.BS, 16) == 8
+        assert t.peak_blocks(3, self.BS, 16) == 0
+
+    @pytest.mark.parametrize("position, first", [
+        (0, 0), (31, 0), (32, 8), (33, 8), (63, 8), (64, 16), (200, 48)])
+    def test_a_tumbling_window_is_live_from_its_own_multiple(self, position,
+                                                             first):
+        t = KVTier("exact", (0,), 32, tumbles=True)
+        assert t.first_live_block(position, self.BS) == first
+
+    def test_a_tumbling_tier_holds_one_window_at_most(self):
+        t = KVTier("exact", (0,), 32, tumbles=True)
+        assert t.table_blocks(self.BS, 64) == 8        # a ring of a window
+        assert t.table_blocks(self.BS, 5) == 5         # or the whole table
+        # a run lies in one window (the server cuts a prompt's runs at
+        # the multiples), so no run adds to the window's blocks
+        assert [t.peak_blocks(n, self.BS, 16) for n in (1, 20, 32, 33, 500)] \
+            == [1, 5, 8, 8, 8]
+        assert t.blocks(33, self.BS) == 9
+
+    def test_a_sliding_tier_reads_as_before(self):
+        t = KVTier("window", (1, 2, 3), 8)
+        assert (t.row_tokens, t.tumbles) == (1, False)
+        assert t.table_blocks(self.BS, 16) == 3
+        assert [t.first_live_block(p, self.BS) for p in (0, 7, 8, 11, 12)] \
+            == [0, 0, 0, 1, 1]
+        assert t.peak_blocks(60, self.BS, 8) == 5
+        assert t.blocks(9, self.BS) == blocks_for_tokens(9, self.BS) == 3
+        plain = KVTier("", (0, 1))
+        assert plain.table_blocks(self.BS, 16) == 16
+        assert plain.peak_blocks(60, self.BS, 8) == 15
+
+    @pytest.mark.parametrize("kw", [
+        dict(row_tokens=0), dict(tumbles=True),
+        dict(window=8, tumbles=True, row_tokens=4),
+        dict(window=8, row_tokens=4)])
+    def test_a_tier_that_cannot_be_is_refused(self, kw):
+        with pytest.raises(ValueError):
+            KVTier("t", (0,), **kw)
+
+    def test_advance_at_a_windows_end_gives_back_the_whole_window(self):
+        from deeplearning4j_tpu.serving.paged.server import _TierState
+        ts = _TierState(KVTier("exact", (0,), 32, tumbles=True), self.BS,
+                        8, 17, 1)
+        ts.grow(0, 8)                       # a run that ends on position 32
+        assert ts.advance(0, 31) == 0       # position window - 1: all live
+        assert len(ts.blocks(0)) == 8 and ts.pool.held_count() == 8
+        assert ts.advance(0, 32) == 8       # position window: all given back
+        assert ts.blocks(0) == [] and ts.pool.held_count() == 0
+        assert int(ts.first[0]) == int(ts.stop[0]) == 8
+        ts.grow(0, 9)                       # the first block of the next one
+        assert ts.advance(0, 33) == 0 and ts.blocks(0) == [ts.tables[0, 0]]
+        ts.clear(0)
+        ts.pool.check_invariant(tables=[])
+
+    def test_one_leaf_and_two_leaf_pools_are_handed_over_as_before(self):
+        two = PagedGenerativeServer._two_sides
+        assert two((("a",),)) == (("a",), ())
+        assert two((("k",), ("v",))) == (("k",), ("v",))
+        assert two((("k",), ("v",), ("ks",), ("vs",))) \
+            == ((("k",), ("ks",)), (("v",), ("vs",)))
