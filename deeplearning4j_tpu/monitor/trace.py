@@ -492,9 +492,13 @@ SPAN_CATALOG: Dict[str, Tuple[str, Tuple[str, ...]]] = {
     # moe_peak_expert_tokens_sum and, for a sigmoid router with a
     # correction bias, moe_bias_moved_sum; a two-store cache's
     # kv_rows_attended_sum), packed behind the next tokens; ``turns``:
-    # lanes whose tumbling window was given back at this step's boundary
+    # lanes whose tumbling window was given back at this step's boundary;
+    # ``table_entries``: the table entries a lane the step was sent, over
+    # the tiers on the ladder, and ``table_entries.<tier>`` each named
+    # tier's own (every tier takes the rung of its own longest lane)
     "serving.decode": ("serving", ("active", "slots", "table_entries",
-                                   "ahead", "turns")),
+                                   "table_entries.<tier>", "ahead",
+                                   "turns")),
     "serving.draft": ("serving", ("active", "step", "slots", "phase",
                                   "bucket", "slot")),
     "serving.verify": ("serving", ("active", "window", "slots")),

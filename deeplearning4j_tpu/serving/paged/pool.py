@@ -298,26 +298,32 @@ def blocks_for_tokens(n_tokens: int, block_size: int) -> int:
     return -(-int(n_tokens) // int(block_size))
 
 
-#: how many widths a decode program's table comes in, and the multiple
-#: of entries each is rounded up to (:func:`table_widths`)
+#: how many widths a decode program's table comes in (a ring that
+#: tumbles: ``RING_RUNGS``), and the multiple of entries each is rounded
+#: up to (:func:`table_widths`)
 TABLE_RUNGS = 3
+RING_RUNGS = 2
 TABLE_WIDTH_MULTIPLE = 8
 
 
-def table_widths(entries: int) -> Tuple[int, ...]:
+def table_widths(entries: int, rungs: int = TABLE_RUNGS) -> Tuple[int, ...]:
     """The ladder of widths in which the decode program is handed a
-    table of ``entries`` blocks a request: ``TABLE_RUNGS`` of them,
-    rising, each an equal share of the table rounded up to a multiple of
+    table of ``entries`` blocks a request: ``rungs`` of them, rising,
+    each an equal share of the table rounded up to a multiple of
     ``TABLE_WIDTH_MULTIPLE`` entries, the last the whole table (24: 8,
-    16, 24; 512: 176, 344, 512). At every step boundary the server sends
-    the narrowest that covers the longest active lane, so a program
-    gathers, masks and multiplies that many blocks a lane and not
-    ``max_seq_len``'s. The rule reads the table alone. A table too short
-    to split gives the same width on every rung, which is one program."""
-    entries = int(entries)
+    16, 24; 512: 176, 344, 512; 128 on two rungs: 64, 128). At every
+    step boundary the server sends the narrowest that covers the longest
+    active lane, so a program gathers, masks and multiplies that many
+    blocks a lane and not ``max_seq_len``'s. The rule reads the table
+    alone. A table too short to split gives the same width on every
+    rung, which is one program. Every rung is one more decode program to
+    warm, and the tiers of one spec multiply theirs, so a second tier on
+    the ladder (a ring that tumbles, beside the tier that keeps every
+    block) takes ``RING_RUNGS``: half and whole."""
+    entries, rungs = int(entries), int(rungs)
     widths = []
-    for k in range(1, TABLE_RUNGS + 1):
-        share = blocks_for_tokens(entries * k, TABLE_RUNGS)
+    for k in range(1, rungs + 1):
+        share = blocks_for_tokens(entries * k, rungs)
         rounded = TABLE_WIDTH_MULTIPLE * blocks_for_tokens(
             share, TABLE_WIDTH_MULTIPLE)
         widths.append(min(entries, rounded))
@@ -452,5 +458,6 @@ class KVTier:
 
 __all__ = ["BlockPool", "PoolExhaustedError", "NULL_BLOCK", "KVTier",
            "KVLeaf", "KVLeafUnsupportedError",
-           "TABLE_RUNGS", "TABLE_WIDTH_MULTIPLE", "table_widths",
+           "TABLE_RUNGS", "RING_RUNGS", "TABLE_WIDTH_MULTIPLE",
+           "table_widths",
            "prefix_block_hashes", "blocks_for_tokens"]

@@ -65,10 +65,16 @@ and reports count ROWS, tier by tier.
 
 The DECODE program reads as many table entries as its longest lane
 holds: at every step boundary the server sends each tier's tables cut to
-the narrowest of ``pool.table_widths`` (three widths, a rule on the
-table's entries alone) that covers the longest active lane, and warm-up
-builds the program once a width. A window tier's ring is addressed
-``u % entries`` and is never cut; prefill and verify take whole tables.
+the narrowest of ``pool.table_widths`` (three widths, two for a ring
+that tumbles, a rule on the table's entries alone) that covers THAT
+TIER's longest active lane, a rung a tier, and warm-up builds the
+program once a combination of the tiers' widths. A tier is on this
+ladder if it keeps every block or if its window TUMBLES: such a ring
+refills from entry 0 after every turn, so the entries a lane's query can
+see are the first ``stop - first`` of it, as in a tier that keeps every
+block. A window that SLIDES is addressed ``u % entries`` with every entry
+live once a lane has wrapped, and is never cut; prefill and verify take
+whole tables.
 
 Correctness contract: with ``max_blocks_per_req * block_size ==
 max_seq`` the gathered paged context is elementwise identical to the
@@ -82,6 +88,7 @@ from __future__ import annotations
 import threading
 from bisect import bisect_left
 from dataclasses import dataclass
+from itertools import product
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -93,7 +100,8 @@ from deeplearning4j_tpu.serving.generative import (GenerationHandle,
                                                    GenerativeServer,
                                                    SlotAllocator)
 from deeplearning4j_tpu.serving.metrics import safe_ratio
-from deeplearning4j_tpu.serving.paged.pool import (NULL_BLOCK, TABLE_RUNGS,
+from deeplearning4j_tpu.serving.paged.pool import (NULL_BLOCK, RING_RUNGS,
+                                                   TABLE_RUNGS,
                                                    BlockPool, KVLeaf,
                                                    KVLeafUnsupportedError,
                                                    KVTier,
@@ -117,11 +125,15 @@ class PagedGenerativeSpec:
       dispatchers per geometry, so every server over the same model +
       geometry shares one compile set). Io contracts are documented on
       ``zoo.gpt.gpt_paged_decode_fns``. ``decode_fn`` takes the width of
-      a tier's table from its INPUT: for a tier without a window the
-      server hands it ``tables[:, :W]`` with ``W <=
-      max_blocks_per_req`` covering every active lane's blocks
-      (``pool.table_widths``), and the program must read no further; a
-      window tier's ring, and every table of ``prefill_fn`` and
+      a tier's table from its INPUT: for a tier without a window, and
+      for one whose window tumbles, the server hands it ``tables[:,
+      :W]`` with ``W`` no more than the tier's entries and covering
+      every active lane's live blocks (``pool.table_widths``; each tier
+      its own ``W``), and the program must read no further. A tumbling
+      ring is still addressed by the RING's entries (block ``u`` in
+      entry ``u % entries``), of which the table handed over is the
+      first ``W``: an entry beyond holds nothing a query sees. A
+      sliding window's ring, and every table of ``prefill_fn`` and
       ``verify_fn``, come whole.
     - ``kv_shape(num_blocks, block_size)`` gives the pool's five
       numbers ``(layers, num_blocks, heads, block_size, head_dim)``.
@@ -191,14 +203,22 @@ class _TierState:
     entries they will take still hold blocks the run reads, so fresh
     blocks wait in ``pending`` (the program is told where its rows go)
     until :meth:`advance`. ``widths`` are the widths the decode program
-    reads the table in, rung by rung (``pool.table_widths``); a ring is
-    read whole on every rung."""
+    reads the table in, rung by rung (``pool.table_widths``). A tier is
+    ``on_ladder`` if it keeps every block or its window tumbles (the
+    ring then refills from entry 0 after every turn, ``first`` a
+    multiple of the ring: a lane's live blocks sit in the first ``stop -
+    first`` entries; on ``pool.RING_RUNGS`` rungs, since the tiers'
+    widths multiply the programs to warm); a ring that slides is read
+    whole on every rung."""
 
     def __init__(self, tier: KVTier, block_size: int, entries: int,
                  num_blocks: int, max_slots: int):
         self.tier, self.BS, self.entries = tier, int(block_size), int(entries)
-        self.widths = table_widths(self.entries) if tier.window is None \
-            else (self.entries,) * TABLE_RUNGS
+        self.on_ladder = tier.window is None or tier.tumbles
+        self.widths = (
+            table_widths(self.entries) if tier.window is None
+            else table_widths(self.entries, RING_RUNGS) if tier.tumbles
+            else (self.entries,) * TABLE_RUNGS)
         self.pool = BlockPool(num_blocks, block_size)
         self.tables = np.zeros((max_slots, self.entries), np.int32)
         self.first = np.zeros(max_slots, np.int32)
@@ -248,6 +268,17 @@ class _TierState:
             self.tables[s, e] = b
         self.pending[s].clear()
         return n
+
+    def decode_width(self, active: np.ndarray) -> int:
+        """Entries of the table the decode program is handed for the
+        ``active`` lanes: the narrowest of ``widths`` that covers the
+        entries the longest of them takes, its live blocks ``stop -
+        first`` (one still in ``pending`` counted: its entry is the next
+        one); the whole ring where it slides."""
+        if not self.on_ladder:
+            return self.entries
+        need = int((self.stop[active] - self.first[active]).max())
+        return self.widths[bisect_left(self.widths, need)]
 
     def blocks(self, s: int) -> List[int]:
         """The blocks slot ``s`` holds, in order."""
@@ -338,7 +369,8 @@ class PagedMetrics(GenerativeMetrics):
                   "window_blocks_released",
                   # table entries a lane the decode program was handed,
                   # and the whole table's, summed over plain decode steps
-                  # and the tiers without a window
+                  # and the tiers on the ladder (every tier but a window
+                  # that slides)
                   "decode_table_entries_sum", "decode_table_capacity_sum",
                   # rows BY KIND, a plain decode step over its active
                   # lanes, each in rows x layers: what the lanes' tiers
@@ -371,7 +403,7 @@ class PagedMetrics(GenerativeMetrics):
 
     def observe_tables(self, entries: int, capacity: int) -> None:
         """One decode step's tables: ``entries`` a lane sent of
-        ``capacity``, over the tiers without a window."""
+        ``capacity``, over the tiers on the ladder."""
         with self._lock:
             self.counters["decode_table_entries_sum"] += int(entries)
             self.counters["decode_table_capacity_sum"] += int(capacity)
@@ -662,8 +694,7 @@ class PagedGenerativeServer(GenerativeServer):
         tier_of = {i: ts for ts in self._tiers for i in ts.tier.layers}
         self._window_tiers = [ts for ts in self._tiers
                               if ts.tier.window is not None]
-        self._ladder_tiers = [ts for ts in self._tiers
-                              if ts.tier.window is None]
+        self._ladder_tiers = [ts for ts in self._tiers if ts.on_ladder]
         self._ladder_capacity = sum(ts.entries for ts in self._ladder_tiers)
         memstats.check_headroom(
             self.kv_slab_bytes,
@@ -1011,15 +1042,14 @@ class PagedGenerativeServer(GenerativeServer):
               "active": act, "write_off": wo}
         n_act = int(act.sum())
         written = positions + 1
-        # the narrowest rung whose tables hold every active lane's
-        # blocks, this boundary's growth included (an active lane's
-        # position lies in a block it holds, so the program's mask never
-        # reaches past the width)
-        rung = max((bisect_left(ts.widths, int(ts.stop[act].max()))
-                    for ts in self._ladder_tiers), default=0)
-        held = gathered = 0
+        held = gathered = sent = 0
         for ts in self._tiers:
             t = ts.tier
+            # the tier's own rung: the narrowest width that holds every
+            # active lane's live blocks, this boundary's growth and turn
+            # included (an active lane's position lies in a block it
+            # holds, so the program's mask never reaches past the width)
+            width = ts.decode_width(act)
             # the block of the row this step writes: the last of the rows
             # written once it has (an idle lane's table is all null
             # blocks, wherever it points)
@@ -1035,22 +1065,26 @@ class PagedGenerativeServer(GenerativeServer):
                 done = act & (rows > t.rows(positions))
                 wb[~done] = NULL_BLOCK
                 self.metrics.inc("summary_rows_written", int(done.sum()))
-            io[t.key("tables")] = ts.tables[:, :ts.widths[rung]].copy()
+            io[t.key("tables")] = ts.tables[:, :width].copy()
             io[t.key("write_block")] = wb
             held += len(t.layers) * int(
                 (rows[act] - ts.first[act] * BS).sum())
-            gathered += len(t.layers) * ts.widths[rung]
-        self.metrics.observe_tables(
-            sum(ts.widths[rung] for ts in self._ladder_tiers),
-            self._ladder_capacity)
+            gathered += len(t.layers) * width
+            if ts.on_ladder:
+                sent += width
+        self.metrics.observe_tables(sent, self._ladder_capacity)
         self.metrics.observe_rows(
             held, self._kv_layers * int(written[act].sum()),
             gathered * BS * n_act)
         return io
 
     def _decode_span_args(self, io: dict) -> dict:
-        args = {"table_entries": sum(
-            io[ts.tier.key("tables")].shape[1] for ts in self._ladder_tiers)}
+        # the widths sent, tier by tier, and their sum (the one unnamed
+        # tier's field IS the sum)
+        sent = {ts.tier.key("table_entries"):
+                io[ts.tier.key("tables")].shape[1]
+                for ts in self._ladder_tiers}
+        args = {"table_entries": sum(sent.values()), **sent}
         if self._turns:
             # lanes whose window tumbled at this step's boundary
             args["turns"] = self._turns
@@ -1191,7 +1225,8 @@ class PagedGenerativeServer(GenerativeServer):
     # -- AOT warmup -----------------------------------------------------
     def warmup(self, buckets: Optional[Sequence[int]] = None) -> dict:
         """Paged analogue of :meth:`GenerativeServer.warmup`: one
-        decode shape + one prefill shape per bucket, lowered with the
+        decode shape per combination of the tiers' table widths + one
+        prefill shape per bucket, lowered with the
         mesh shardings when ``tp > 1`` so the AOT executables match the
         live sharded arguments (a mismatch would silently fall back to
         lazy jit — the AOTDispatch ValueError path)."""
@@ -1227,16 +1262,17 @@ class PagedGenerativeServer(GenerativeServer):
                   for shape in side) for side in self._kv_leaf_shapes))
         S, MAXB = self.max_slots, self._maxb
 
-        def _tier_io(table_key, lead, rows, rung=-1):
+        def _tier_io(table_key, lead, rows, widths=None):
             """The tiers' part of a program's io: a table a request
-            (``lead`` requests; whole, or for decode at ``rung`` of the
-            tiers' widths) and, where the program is told (decode; a
-            named tier's prefill), the block of each of its ``rows``
+            (``lead`` requests; whole, or for decode of the ``widths``
+            given tier by tier) and, where the program is told (decode;
+            a named tier's prefill), the block of each of its ``rows``
             fresh rows."""
             out = {}
-            for ts in self._tiers:
+            for ts, width in zip(self._tiers, widths or
+                                 [ts.entries for ts in self._tiers]):
                 t = ts.tier
-                out[t.key(table_key)] = _abs(lead + (ts.widths[rung],),
+                out[t.key(table_key)] = _abs(lead + (width,),
                                              jnp.int32, io_sh)
                 if lead:
                     out[t.key("write_block")] = _abs((rows,), jnp.int32,
@@ -1265,16 +1301,18 @@ class PagedGenerativeServer(GenerativeServer):
                     self._shapes_seen.add((role, sig))
                     self.metrics.inc("warmup_compiles")
 
-        # one decode program a rung of the tiers' widths (rungs of one
-        # width are one signature, built once)
-        for rung in range(TABLE_RUNGS):
+        # one decode program a combination of the tiers' widths: each
+        # tier takes a rung of its own (_decode_io), so the product of
+        # their distinct widths (a ring that slides has one)
+        for k, widths in enumerate(product(
+                *(sorted(set(ts.widths)) for ts in self._tiers))):
             _build(self._decode_disp,
                    {"tokens": _abs((S,), jnp.int32, io_sh),
                     "positions": _abs((S,), jnp.int32, io_sh),
                     "active": _abs((S,), jnp.bool_, io_sh),
                     "write_off": _abs((S,), jnp.int32, io_sh),
-                    **_tier_io("tables", (S,), S, rung)},
-                   f"paged_decode_s{S}r{rung}")
+                    **_tier_io("tables", (S,), S, widths)},
+                   f"paged_decode_s{S}r{k}")
         if self._cut_tokens is not None:
             self._cut_tokens = self._cut_program().lower(_abs(
                 (S + len(self._program_counters),), jnp.int32)).compile()

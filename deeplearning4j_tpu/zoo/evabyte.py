@@ -156,30 +156,35 @@ class _TwoStoreCache:
     positions each request has cached, and where fresh rows go:
     ``(write_block, write_off)`` one a fresh token (request-major) for
     the exact store, ``summary_block`` one a chunk the run can complete
-    (the null block for a chunk it does not)."""
+    (the null block for a chunk it does not). The exact store's table is
+    a ring of ``ring`` entries that refills from entry 0 at every turn
+    of the window; what is handed over may be its first entries only, as
+    many as hold every block a query of the program sees."""
 
     def __init__(self, kl, vl, sl, ul, table, write_block, summary_table,
-                 summary_block, hist, write_off, block_size):
+                 summary_block, hist, write_off, block_size, ring):
         self.kl, self.vl, self.sl, self.ul = kl, vl, sl, ul
         self.table, self.write_block = table, write_block
         self.summary_table, self.summary_block = summary_table, summary_block
         self.hist, self.write_off = hist, write_off
-        self.BS = int(block_size)
+        self.BS, self.ring = int(block_size), int(ring)
 
     def read_exact(self):
         """``K, V [R, T, width]`` and ``pos [R, T]``, the position of
-        each row (negative: nothing yet). The table is a ring: entry
-        ``e`` of E holds the block ``u = e (mod E)`` among the last E
-        blocks up to that of position ``hist - 1``."""
+        each row (negative: nothing yet). Entry ``e`` of the ring's E
+        holds the block ``u = e (mod E)`` among the last E blocks up to
+        that of position ``hist - 1``; the table's entries are the first
+        of the ring's."""
         import jax.numpy as jnp
-        R, E = self.table.shape
+        R, n = self.table.shape
         last = jnp.floor_divide(self.hist - 1, self.BS)[:, None]
-        u = last - jnp.mod(last - jnp.arange(E, dtype=jnp.int32)[None], E)
+        u = last - jnp.mod(last - jnp.arange(n, dtype=jnp.int32)[None],
+                           self.ring)
         pos = (u[:, :, None] * self.BS
                + jnp.arange(self.BS, dtype=jnp.int32)[None, None])
-        pos = jnp.where(u[:, :, None] >= 0, pos, -1).reshape(R, E * self.BS)
-        K = self.kl[self.table].reshape(R, E * self.BS, -1)
-        V = self.vl[self.table].reshape(R, E * self.BS, -1)
+        pos = jnp.where(u[:, :, None] >= 0, pos, -1).reshape(R, n * self.BS)
+        K = self.kl[self.table].reshape(R, n * self.BS, -1)
+        V = self.vl[self.table].reshape(R, n * self.BS, -1)
         return K, V, pos
 
     def read_summaries(self):
@@ -193,12 +198,12 @@ class _TwoStoreCache:
 
     def read_rows(self, pos):
         """The cached exact rows at ``pos [R, n]`` (positions of the
-        live window; a caller masks what is not cached): ``K, V [R, n,
-        width]``."""
+        live window; a caller masks what is not cached, and a position
+        whose entry lies beyond the table reads a row of its last
+        entry): ``K, V [R, n, width]``."""
         import jax.numpy as jnp
-        E = self.table.shape[1]
-        entry = jnp.mod(jnp.floor_divide(pos, self.BS), E)
-        block = jnp.take_along_axis(self.table, entry, axis=1)
+        entry = jnp.mod(jnp.floor_divide(pos, self.BS), self.ring)
+        block = jnp.take_along_axis(self.table, entry, axis=1, mode="clip")
         off = jnp.mod(pos, self.BS)
         return self.kl[block, off], self.vl[block, off]
 
@@ -437,10 +442,12 @@ def evabyte_paged_decode_fns(cfg: EvaByteConfig, block_size: int,
       not)}``; returns ``(kc, vc, next token, logits [vocab])`` from
       position ``hist + length - 1``, head 0's.
     - ``decode_fn``: ``io = {"tokens", "positions", "active": [S],
-      "tables.exact": [S, window blocks], "write_block.exact": [S],
-      "write_off": [S], "tables.summary": [S, E] (any ``E`` that holds
-      every active lane's blocks), "write_block.summary": [S] (the block
-      of the chunk a lane's token completes, else the null block)}``;
+      "tables.exact": [S, E'] (the first ``E'`` of the ring's entries,
+      any that holds every active lane's blocks of its own window),
+      "write_block.exact": [S], "write_off": [S], "tables.summary": [S,
+      E] (any ``E`` that holds every active lane's blocks),
+      "write_block.summary": [S] (the block of the chunk a lane's token
+      completes, else the null block)}``;
       returns ``(kc, vc, next [S + 1], logits [S, vocab])``: behind the S
       next tokens comes the step's :data:`PROGRAM_COUNTERS`.
     """
@@ -450,12 +457,15 @@ def evabyte_paged_decode_fns(cfg: EvaByteConfig, block_size: int,
     L, V = cfg.num_layers, cfg.vocab_size
     BS = int(block_size)
     exact, summary = cfg.kv_tiers()
+    # the ring's entries are the configuration's (window // block_size
+    # under the pool's geometry), whatever width its table is handed in
+    ring = exact.table_blocks(BS, max_blocks_per_req)
     rmsnorm, mm, layer = _functions(cfg)
 
     def _block(lp, x, qpos, valid, kl, vl, sl, ul, table, wb, stable, swb,
                hist, write_off, tail):
         cache = _TwoStoreCache(kl, vl, sl, ul, table, wb, stable, swb, hist,
-                               write_off, BS)
+                               write_off, BS, ring)
         x, seen = layer(lp, x, qpos, valid, cache, hist, tail)
         return x, seen, cache.kl, cache.vl, cache.sl, cache.ul
 
@@ -471,8 +481,11 @@ def evabyte_paged_decode_fns(cfg: EvaByteConfig, block_size: int,
         for t in (exact, summary):
             table = lift(io[t.key(table_key)])
             entries = t.table_blocks(BS, max_blocks_per_req)
+            # a decode step's table may be the first entries of either
+            # tier's (the summaries keep every block, the ring refills
+            # from entry 0); a prefill run's comes whole
             if table.shape[1] > entries or (
-                    t.window is not None and table.shape[1] != entries):
+                    not tail and table.shape[1] != entries):
                 raise ValueError(
                     f"{t.key(table_key)} has {table.shape[1]} entries, the "
                     f"tier's table {entries}")

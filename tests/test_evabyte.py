@@ -4,7 +4,10 @@ plain ``forward`` and the server's own programs against the plain
 reference's full forward IN LOGITS, through both stores, across window
 turns, in chunks, with slots reused, with 8 lanes at different windows
 in one step and with the decode loop ahead of its sync; the two tiers'
-books; the counters of rows by kind.
+books; the counters of rows by kind. At a ring large enough to split
+(window 96: 24 entries, read 16 or 24 wide; ``SPLIT``): each tier is
+sent the rung of its own longest lane, the logits are the whole ring's
+to the bit, and warm-up builds the product of the two tiers' widths.
 
 THE TOLERANCE (``TOL`` of the reference logits' standard deviation): the
 program rounds every product's operands to bfloat16 (2**-9 relative
@@ -22,6 +25,7 @@ import pytest
 
 from benchmark.adapters import evabyte as adapter
 from benchmark.reference import evabyte as ref
+from deeplearning4j_tpu.compilecache import COMPILE_STATS
 from deeplearning4j_tpu.monitor.trace import TRACER
 from deeplearning4j_tpu.serving.paged import (PagedGenerativeServer,
                                               PoolExhaustedError,
@@ -243,7 +247,7 @@ def test_eight_lanes_at_different_windows_in_one_step(spec):
         srv._decode_io = spy
         toks, got = logits_served(srv, prompts, budgets)
         drained(srv)
-        assert {ts.widths for ts in srv._tiers} == {(8, 8, 8), (8, 16, 16)}
+        assert {ts.widths for ts in srv._tiers} == {(8, 8), (8, 16, 16)}
     assert max(windows) >= 4
     assert_close(got, reference_logits(prompts, toks))
 
@@ -289,7 +293,7 @@ def test_both_tiers_stay_in_their_bounds_and_end_empty(spec):
     with server(spec) as srv:
         exact, summary = srv._tiers
         assert (exact.entries, summary.entries) == (WINDOW // BS, 16)
-        assert exact.widths == (8, 8, 8) and summary.widths == (8, 16, 16)
+        assert exact.widths == (8, 8) and summary.widths == (8, 16, 16)
         held = []
         sample = srv._sample_pool
 
@@ -406,3 +410,233 @@ def test_a_geometry_the_two_stores_cannot_hold_is_refused(spec, bad):
     kw.update(bad)
     with pytest.raises(ValueError):
         PagedGenerativeServer(spec, **kw)
+
+
+# -- a ring large enough to split: each tier on a rung of its own ---------
+
+#: window 96 in blocks of 4 is a ring of 24 entries, half and whole (16,
+#: 24); 512 positions are 128 summary rows, 32 entries (16, 24, 32)
+SPLIT = dict(CFG, window_size=96, max_position_embeddings=512)
+RING, EXACT_W, SUMMARY_W = 96, (16, 24), (16, 24, 32)
+
+
+@pytest.fixture(scope="module")
+def split_spec():
+    return evabyte_paged_spec(adapter.program_config(SPLIT),
+                              adapter.program_params(SPLIT, SEED))
+
+
+def split_server(spec, **kw):
+    kw.setdefault("warmup", False)
+    kw.setdefault("max_slots", 3)
+    return PagedGenerativeServer(spec, block_size=BS, max_seq_len=512,
+                                 buckets=[16, 32], debug_leaks=True, **kw)
+
+
+def narrowest(widths, need):
+    return min(w for w in widths if w >= need)
+
+
+def spy_widths(srv, steps):
+    """Every decode step's ``(exact width, summary width, positions of
+    the active lanes, lead, lanes that turned)`` into ``steps``."""
+    real = srv._decode_io
+
+    def spy(*lead):
+        io = real(*lead)
+        if io is not None:
+            steps.append((io["tables.exact"].shape[1],
+                          io["tables.summary"].shape[1],
+                          io["positions"][io["active"]].tolist(),
+                          sum(lead), srv._turns))
+        return io
+
+    srv._decode_io = spy
+
+
+def test_each_tier_is_sent_the_rung_of_its_own_longest_lane(split_spec):
+    """By hand: a lane whose query stands at ``p`` holds ``(p % 96) // 4
+    + 1`` blocks of its window (the first entries of the ring, which
+    refills from entry 0 at every turn) and ``ceil(((p + 1) // 4) / 4)``
+    summary blocks. Three lanes in different windows, one of them 300
+    bytes in so that the summary tier stands on its upper rungs while the
+    ring is on its lowest; two more admitted once the first retire."""
+    jobs = [(5, 120), (130, 60), (300, 110), (7, 30), (40, 50)]
+    steps = []
+    with split_server(split_spec) as srv:
+        exact, summary = srv._tiers
+        assert exact.on_ladder and summary.on_ladder
+        assert (exact.entries, exact.widths) == (24, EXACT_W)
+        assert (summary.entries, summary.widths) == (32, SUMMARY_W)
+        spy_widths(srv, steps)
+        # the pool is three slots' worst case: the late two are offered
+        # once an early one has given its reservation back
+        hs = [srv.submit(prompt(n, n), max_new_tokens=m)
+              for n, m in jobs[:3]]
+        for (n, m), done in zip(jobs[3:], (hs[1], hs[2])):
+            done.result(timeout=600)
+            hs.append(srv.submit(prompt(n, n), max_new_tokens=m))
+        for h in hs:
+            h.result(timeout=600)
+        drained(srv)
+        c = dict(srv.metrics.counters)
+    assert len(steps) == c["decode_steps"] > 150
+    for we, ws, ps, _, _ in steps:
+        assert we == narrowest(EXACT_W, max((p % RING) // BS + 1
+                                            for p in ps))
+        assert ws == narrowest(SUMMARY_W, max(
+            -(-((p + 1) // CHUNK) // BS) for p in ps))
+    # both tiers stood on every rung, and not on the same one: the ring
+    # fell back to its half at each turn while the summaries stayed wide
+    assert {we for we, *_ in steps} == set(EXACT_W)
+    assert {ws for _, ws, *_ in steps} == set(SUMMARY_W)
+    assert (16, 32) in {(we, ws) for we, ws, *_ in steps}
+    sent = [we for we, *_ in steps]
+    assert any(b < a for a, b in zip(sent, sent[1:]))
+    # the counters sum over BOTH tiers
+    assert c["decode_table_entries_sum"] == sum(we + ws
+                                                for we, ws, *_ in steps)
+    assert c["decode_table_capacity_sum"] == (24 + 32) * len(steps)
+    assert c["kv_rows_gathered_sum"] == LAYERS * BS * sum(
+        (we + ws) * len(ps) for we, ws, ps, _, _ in steps)
+    assert 0 < c["kv_rows_attended_sum"] < c["kv_rows_gathered_sum"]
+
+
+def test_the_ladders_logits_are_the_whole_rings_to_the_bit(split_spec):
+    """Three lanes in different windows across three turns each, the
+    loop one step ahead from the second step on, so that every rung
+    crossing and every turn is built with the step before in the air:
+    the server that sends each tier its own rung against the same server
+    with the ring sent whole at every step. A narrower table leaves out
+    entries whose rows no query sees (their weights are exact zeros), so
+    tokens, logits and the rows the program counted are equal to the
+    bit; against the reference they lie under the tolerance."""
+    # windows 0, 1 and 2, within 9 positions of one another in them, so
+    # that the ring's lower rung is reached after every round of turns
+    prompts = [prompt(5, 1), prompt(110, 2), prompt(205, 3)]
+    n = 3 * RING + 6
+    runs = []
+    for ladder in (True, False):
+        steps = []
+        with split_server(split_spec, start=False) as srv:
+            if not ladder:
+                srv._tiers[0].widths = (24,) * 3
+            spy_widths(srv, steps)
+            toks, lg = logits_served(srv, prompts, n)
+            drained(srv)
+            c = dict(srv.metrics.counters)
+        assert c["decode_steps"] == n - 1
+        assert c["decode_ahead_steps"] == n - 2
+        # three turns a lane at decode; one and two in the prefills
+        assert c["window_turns"] == 3 + 4 + 5
+        runs.append((toks, lg, c, steps))
+    (t1, l1, c1, s1), (t2, l2, c2, s2) = runs
+    assert {we for we, *_ in s2} == {24}
+    assert {we for we, *_ in s1} == set(EXACT_W)
+    # with the step before in the air: a lane crossed to a wider rung,
+    # and a lane's turn let the ring fall to a narrower one
+    ahead = [(a[0], b[0], b[4]) for a, b in zip(s1, s1[1:]) if b[3] == 1]
+    assert any(wide > narrow for narrow, wide, _ in ahead)
+    assert any(after < before and turned for before, after, turned in ahead)
+    assert t1 == t2
+    for a, b in zip(l1, l2):
+        assert np.array_equal(a, b)
+    assert c1["kv_rows_attended_sum"] == c2["kv_rows_attended_sum"]
+    assert c1["kv_rows_held_sum"] == c2["kv_rows_held_sum"]
+    assert c1["kv_rows_gathered_sum"] < c2["kv_rows_gathered_sum"]
+    seqs = [np.concatenate([p, t])[:-1] for p, t in zip(prompts, t1)]
+    spans = [np.arange(len(p) - 1, len(p) + len(t) - 1)
+             for p, t in zip(prompts, t1)]
+    want = [np.asarray(w) for w in ref.logits(SPLIT, SEED, seqs, spans,
+                                              heads=1)]
+    assert_close(l1, want)
+
+
+def test_warmup_builds_the_product_of_the_tiers_widths_and_no_step_compiles(
+        split_spec):
+    with split_server(split_spec, warmup=True) as srv:
+        rep = srv.warmup_report
+        assert rep["decode_table_widths"] == {"exact": list(EXACT_W),
+                                              "summary": list(SUMMARY_W)}
+        # two by three decode programs and one prefill program a bucket
+        assert len(srv._decode_disp.aot) == 6
+        assert srv.metrics.counters["warmup_compiles"] == \
+            6 + len(rep["prefill_buckets"])
+        steps = []
+        spy_widths(srv, steps)
+        mark = COMPILE_STATS.mark()
+        hs = [srv.submit(prompt(n, n), max_new_tokens=m)
+              for n, m in [(5, 100), (300, 110), (60, 30)]]
+        for h in hs:
+            h.result(timeout=600)
+        drained(srv)
+        assert COMPILE_STATS.delta(mark)["backend_compiles"] == 0
+        assert srv.metrics.counters["compiles"] == 0
+    assert len({(we, ws) for we, ws, *_ in steps}) >= 4
+
+
+def test_the_span_names_each_tiers_width_beside_the_sum(split_spec):
+    TRACER.reset().enable()
+    try:
+        with split_server(split_spec, max_slots=1) as srv:
+            srv.submit(prompt(60, 6), max_new_tokens=50).result(timeout=600)
+            drained(srv)
+        spans = [s for s in TRACER.spans() if s.name == "serving.decode"]
+    finally:
+        TRACER.disable().reset()
+    assert len(spans) == 49
+    for sp, p in zip(spans, range(60, 109)):
+        assert sp.args["table_entries.exact"] == narrowest(
+            EXACT_W, (p % RING) // BS + 1)
+        assert sp.args["table_entries.summary"] == 16
+        assert sp.args["table_entries"] == \
+            sp.args["table_entries.exact"] + 16
+    # 16 wide to position 63, 24 wide to the turn at 96, then 16 again
+    assert [sp.args["table_entries.exact"] for sp in spans] == \
+        [16] * 4 + [24] * 32 + [16] * 13
+
+
+def split_program_args(spec, program, exact, summary):
+    """What a program of the split geometry is traced on, as shapes:
+    three lanes or one run of 16, the tables ``exact`` and ``summary``
+    entries wide."""
+    import jax
+    import jax.numpy as jnp
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+    if program == "decode":
+        io = {"tokens": i32(3), "positions": i32(3), "write_off": i32(3),
+              "active": jax.ShapeDtypeStruct((3,), jnp.bool_),
+              "tables.exact": i32(3, exact), "write_block.exact": i32(3),
+              "tables.summary": i32(3, summary),
+              "write_block.summary": i32(3)}
+    else:
+        io = {"tokens": i32(16), "length": i32(), "hist": i32(),
+              "table.exact": i32(exact), "write_block.exact": i32(16),
+              "table.summary": i32(summary), "write_block.summary": i32(4)}
+    params = {n: jax.ShapeDtypeStruct(np.shape(a), a.dtype)
+              for n, a in spec.params().items()}
+    leaf = jax.ShapeDtypeStruct((9, BS, 64), jnp.bfloat16)
+    side = ((leaf,) * LAYERS,) * 2
+    return params, side, side, io
+
+
+@pytest.mark.parametrize("program, exact, summary, ok", [
+    ("decode", 24, 32, True), ("decode", 16, 16, True),
+    ("decode", 16, 32, True), ("decode", 25, 32, False),
+    ("decode", 24, 33, False), ("prefill", 24, 32, True),
+    ("prefill", 16, 32, False), ("prefill", 24, 24, False),
+    ("prefill", 25, 32, False)])
+def test_the_program_takes_the_first_entries_of_a_ring_at_decode_alone(
+        split_spec, program, exact, summary, ok):
+    """A decode table of either tier may be narrower than the tier's and
+    never wider; a prefill run's tables come whole."""
+    import jax
+    fn = dict(zip(("prefill", "decode"), split_spec.make_fns(BS, 128)))[
+        program]
+    args = split_program_args(split_spec, program, exact, summary)
+    if ok:
+        out = jax.eval_shape(fn, *args)
+        assert out[3].shape[-1] == VOCAB
+    else:
+        with pytest.raises(ValueError, match="entries, the tier's table"):
+            jax.eval_shape(fn, *args)

@@ -10,13 +10,20 @@ they run and whose longest lane retires so that the width falls again;
 every step is sent the narrowest width that covers its longest active
 lane, and the counters say so; no step compiles after warm-up; a table
 too short to split has one width; ``tp = 2`` serves the same tokens.
+
+Which tiers are on the ladder is read off ``KVTier`` alone: one that
+keeps every block and one whose window tumbles are, each on a rung of
+its own; a ring that slides is sent whole (SmallThinker's, at a ring
+long enough to split); warm-up builds one decode program a combination
+of the tiers' widths, which for every family with one ladder tier is
+the programs it always built.
 """
 import numpy as np
 import pytest
 
 from deeplearning4j_tpu.compilecache import COMPILE_STATS
 from deeplearning4j_tpu.serving.generative import greedy_decode
-from deeplearning4j_tpu.serving.paged import (PagedGenerativeServer,
+from deeplearning4j_tpu.serving.paged import (KVTier, PagedGenerativeServer,
                                               table_widths)
 from deeplearning4j_tpu.serving.paged import server as paged_server
 from deeplearning4j_tpu.serving.paged.pool import TABLE_RUNGS
@@ -95,6 +102,12 @@ def serve(srv, steps=None):
 def test_the_ladder_is_thirds_rounded_up_to_eight_entries(entries, widths):
     assert table_widths(entries) == widths
     assert widths[-1] == entries and list(widths) == sorted(widths)
+
+
+@pytest.mark.parametrize("entries, widths", [
+    (128, (64, 128)), (24, (16, 24)), (20, (16, 20)), (8, (8, 8))])
+def test_on_two_rungs_the_ladder_is_half_and_whole(entries, widths):
+    assert table_widths(entries, 2) == widths
 
 
 def test_tokens_are_those_of_the_whole_table_and_of_the_dense_path(
@@ -304,3 +317,125 @@ def test_tp2_serves_the_same_tokens_with_the_ladder_as_without(
         assert got == serve(srv)
     with make_server(spec) as srv:
         assert got == serve(srv)
+
+
+# -- which tiers are on the ladder, each on a rung of its own -------------
+
+@pytest.mark.parametrize("tier, entries, widths", [
+    (KVTier("", (0, 1)), 24, LADDER),
+    (KVTier("summary", (0, 1), row_tokens=4), 24, LADDER),
+    (KVTier("exact", (0, 1), 96, tumbles=True), 24, (16, 24)),
+    (KVTier("window", (0, 1), 92), 24, (24, 24, 24)),
+    (KVTier("exact", (0, 1), 32, tumbles=True), 8, (8, 8))])
+def test_a_tier_is_on_the_ladder_unless_its_window_slides(tier, entries,
+                                                          widths):
+    """The rule reads the tier alone. On the ladder a lane needs the
+    entries its live blocks take, ``stop - first``: ``first`` stays 0
+    where every block is kept and is a multiple of the ring where the
+    window tumbles, so a lane three turns on needs what it holds of its
+    own window. A ring that tumbles takes two rungs, half and whole: it
+    is a second tier on the ladder, and the tiers' widths multiply the
+    programs to warm."""
+    ts = paged_server._TierState(tier, BS, entries, 64, max_slots=3)
+    assert ts.widths == widths
+    assert ts.on_ladder == (tier.window is None or tier.tumbles)
+    act = np.array([True, True, False])
+    turned = entries * 3 if tier.tumbles else 0
+    for held in (1, 8, 9, 16, 17, 24):
+        if held > entries:
+            continue
+        ts.first[:] = [turned, 0, 0]
+        # the idle lane's books are no part of the need
+        ts.stop[:] = [turned + held, min(held, 3), entries]
+        assert ts.decode_width(act) == min(w for w in widths if w >= held)
+
+
+def family_spec(family):
+    """The tiny spec of a family's own tests, with its geometry there."""
+    if family == "gpt":
+        return (gpt_paged_spec(build_gpt(CFG, batch=2, seq_len=8, seed=0),
+                               CFG),
+                dict(max_seq_len=MSL, num_blocks=128))
+    if family == "smallthinker":
+        import test_smallthinker as t
+        from deeplearning4j_tpu.zoo.smallthinker import \
+            smallthinker_paged_spec as make
+        kw = dict(max_seq_len=64, buckets=[4, 8], num_blocks=None)
+    elif family == "glm":
+        import test_glm_moe_lite as t
+        from deeplearning4j_tpu.zoo.glm_moe_lite import \
+            glm_moe_lite_paged_spec as make
+        kw = dict(max_seq_len=64, buckets=[4, 8])
+    else:
+        import test_evabyte as t
+        from deeplearning4j_tpu.zoo.evabyte import evabyte_paged_spec as make
+        kw = dict(max_seq_len=256, buckets=[8, 16], num_blocks=None)
+    return make(t.adapter.program_config(t.CFG),
+                t.adapter.program_params(t.CFG, t.SEED)), kw
+
+
+@pytest.mark.parametrize("family, widths", [
+    ("gpt", {"all": [8, 16, 24]}),
+    ("smallthinker", {"global": [8, 16], "window": [3]}),
+    ("glm", {"all": [8, 16]}),
+    ("evabyte", {"exact": [8], "summary": [8, 16]})])
+def test_a_spec_with_one_tier_to_cut_warms_the_programs_it_always_did(
+        family, widths):
+    """One decode program a combination of the tiers' distinct widths:
+    with one tier that has more than one width, one a width, as before
+    each tier took a rung of its own."""
+    spec, kw = family_spec(family)
+    with make_server(spec, warmup=True, **kw) as srv:
+        rep = srv.warmup_report
+        assert rep["decode_table_widths"] == widths
+        programs = int(np.prod([len(w) for w in widths.values()]))
+        assert programs == max(len(w) for w in widths.values())
+        assert len(srv._decode_disp.aot) == programs
+        assert srv.metrics.counters["warmup_compiles"] == \
+            programs + len(rep["prefill_buckets"])
+
+
+def test_a_sliding_ring_long_enough_to_split_is_still_sent_whole():
+    """SmallThinker with a window of 60 in blocks of 4: a ring of 16
+    entries, which ``table_widths`` would cut to 8 and 16. Block ``u``
+    sits in entry ``u % 16`` and every entry is live once a lane has
+    wrapped, so the ring comes whole at every step while the global
+    tier beside it is cut to its lanes."""
+    import test_smallthinker as t
+    from deeplearning4j_tpu.zoo.smallthinker import smallthinker_paged_spec
+    cfg = dict(t.CFG, sliding_window_size=60)
+    spec = smallthinker_paged_spec(t.adapter.program_config(cfg),
+                                   t.adapter.program_params(cfg, t.SEED))
+    shapes = []
+    with make_server(spec, max_seq_len=128, buckets=[4, 8],
+                     num_blocks=None, warmup=True) as srv:
+        glob, win = srv._tiers
+        assert glob.on_ladder and not win.on_ladder
+        assert table_widths(win.entries) == (8, 16, 16)
+        assert win.widths == (16, 16, 16) and glob.widths == (16, 24, 32)
+        assert srv.warmup_report["decode_table_widths"] == {
+            "global": [16, 24, 32], "window": [16]}
+        assert len(srv._decode_disp.aot) == 3
+        real = srv._decode_io
+
+        def spy(*lead):
+            io = real(*lead)
+            if io is not None:
+                shapes.append((io["tables.global"].shape[1],
+                               io["tables.window"].shape[1]))
+            return io
+
+        srv._decode_io = spy
+        rng = np.random.default_rng(3)
+        hs = [srv.submit(rng.integers(0, 61, n).astype(np.int32),
+                         max_new_tokens=m)
+              for n, m in ((5, 20), (21, 60), (9, 8))]
+        for h in hs:
+            h.result(timeout=300)
+        c = srv.metrics.counters
+        assert c["compiles"] == 0
+        # the ring is no part of the ladder's sums
+        assert c["decode_table_entries_sum"] == sum(g for g, _ in shapes)
+        assert c["decode_table_capacity_sum"] == 32 * len(shapes)
+    assert {w for _, w in shapes} == {16}
+    assert {g for g, _ in shapes} == {16, 24}
