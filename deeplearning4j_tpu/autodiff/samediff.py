@@ -23,6 +23,7 @@ an interpreter pass.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple, Union
 
@@ -34,11 +35,34 @@ from deeplearning4j_tpu.autodiff.variable import SDVariable, VariableType
 from deeplearning4j_tpu.compilecache.aot import (AOTDispatch,
                                                  AOTOutput as _AOTOutput,
                                                  ph_shape_sig)
+from deeplearning4j_tpu.compilecache.cache import COMPILE_STATS
 from deeplearning4j_tpu.monitor import memstats
 from deeplearning4j_tpu.monitor.trace import TRACER as _tracer
 from deeplearning4j_tpu.ndarray.dtype import DataType
 from deeplearning4j_tpu.ndarray.ndarray import NDArray
 from deeplearning4j_tpu.ops import registry
+
+
+#: what fit() opens in place of fit.build on a graph it has run
+_NOT_BUILDING = contextlib.nullcontext()
+
+
+def _steady_dispatch(epoch: int):
+    return _tracer.span("fit.dispatch", cat="train", epoch=epoch)
+
+
+@contextlib.contextmanager
+def _first_dispatch(epoch: int):
+    """The ``fit.dispatch`` that builds its program: its self time is
+    ``build_seconds``, and it carries the program's row of a warm-up's
+    table (``CompileStats.program_row``)."""
+    at = COMPILE_STATS.mark()
+    with COMPILE_STATS.span("fit.dispatch", cat="train", epoch=epoch,
+                            first=1) as phase:
+        yield phase
+        row = COMPILE_STATS.program_row("", at)
+        phase.set(**{k: row[k] for k in ("trace_s", "lower_s", "backend_s",
+                                         "cache_hit")})
 
 
 class NumericsException(ArithmeticError):
@@ -123,6 +147,10 @@ class SameDiff:
         # (tier, dispatches_per_epoch, window sizes/compiles) — consumed
         # by ui/stats StatsListener
         self.last_fit_stats = None
+        # the graph version fit() last prepared: what a fit does before
+        # its first fit.stage at a new version is a phase of the start
+        # (fit.build, compilecache/cache.py)
+        self._fit_built = None
         # which path each attention site of the train step traced last
         # took (monitor/attention.py AttentionSites; None before any)
         self.attention_sites = None
@@ -1413,17 +1441,17 @@ class SameDiff:
         builds themselves become cache hits on a warm restart, so
         restart-to-first-step approaches data-loading time.
 
-        Returns a summary dict (targets built/reused, wall seconds, and
-        the process-wide backend-compile / cache-hit / cache-miss deltas
-        this call produced). Precompiled executables live in the same
+        Returns a summary dict (targets built/reused, wall seconds, the
+        process-wide backend-compile / cache-hit / cache-miss deltas
+        this call produced, and ``programs``: a server warm-up's row for
+        each target built). Precompiled executables live in the same
         version-keyed cache as lazy compiles: any graph mutation
         invalidates them, and unpredicted shapes (a ragged final BATCH)
         still compile lazily exactly as before — outputs are
         bit-identical either way (tests/test_cold_start.py).
         """
         import time as _time
-        from deeplearning4j_tpu.compilecache import (COMPILE_STATS,
-                                                     install_compile_watcher)
+        from deeplearning4j_tpu.compilecache import install_compile_watcher
         from deeplearning4j_tpu.environment import environment
         tc = self.training_config
         if tc is None:
@@ -1488,20 +1516,22 @@ class SameDiff:
         mark = COMPILE_STATS.mark()
         t0 = _time.perf_counter()
         built = reused = 0
+        programs = []
 
         def _build(disp, args, sig, label, seen=None, steps=1):
             nonlocal built, reused
             if sig in disp.aot:
                 reused += 1
                 return
-            with _tracer.span("compile.precompile", cat="compile",
-                              target=label):
+            at = COMPILE_STATS.mark()
+            with COMPILE_STATS.precompile(label):
                 disp.aot[sig] = disp.lower(*args).compile()
             # static memory & compute plan (monitor/memstats.py): the
             # executable exists — reading memory_analysis/cost_analysis
-            # here is free observability
+            # here is cheap observability (plan_analyze_seconds)
             memstats.capture_plan(label, sig, compiled=disp.aot[sig],
                                   steps=steps, graph=self)
+            programs.append(COMPILE_STATS.program_row(label, at))
             if seen is not None:
                 # pre-register the trace signature so the window
                 # executor's compile accounting reports 0 for shapes
@@ -1564,7 +1594,8 @@ class SameDiff:
                 "seconds": round(_time.perf_counter() - t0, 4),
                 "backend_compiles": delta["backend_compiles"],
                 "cache_hits": delta["cache_hits"],
-                "cache_misses": delta["cache_misses"]}
+                "cache_misses": delta["cache_misses"],
+                "programs": programs}
         # remembered so FaultTolerantFit can re-AOT after a retrace
         # (lr_rescale) instead of paying the compile inside the first
         # retry window (faults/recovery.py)
@@ -1624,17 +1655,16 @@ class SameDiff:
         params_abs = _abstract({**self.trainable_params(),
                                 **self.state_vars_map()})
         consts_abs = _abstract(self.constants_map())
-        with _tracer.span("compile.precompile", cat="compile",
-                          target="output"):
-            compiled = _AOTOutput(
-                jax.jit(fn).lower(params_abs, consts_abs, ph_specs,
-                                  jax.random.key(0)).compile())
         # per-bucket serving memory plan (monitor/memstats.py): label
         # carries the row count so /report can show the footprint
         # ladder across warmup buckets
         rows = next(iter(ph_specs.values())).shape
-        rows = rows[0] if rows else 1
-        memstats.capture_plan(f"output_b{rows}", ph_shape_sig(ph_specs),
+        label = f"output_b{rows[0] if rows else 1}"
+        with COMPILE_STATS.precompile(label):
+            compiled = _AOTOutput(
+                jax.jit(fn).lower(params_abs, consts_abs, ph_specs,
+                                  jax.random.key(0)).compile())
+        memstats.capture_plan(label, ph_shape_sig(ph_specs),
                               compiled=compiled.compiled, graph=self)
         self._fn_cache[cache_key] = compiled
         return compiled
@@ -1667,51 +1697,61 @@ class SameDiff:
         tc = self.training_config
         if tc is None:
             raise ValueError("set sd.training_config = TrainingConfig(...) first")
-        # the persistent compilation cache, placed where the
-        # environment says (environment.py): a restarted fit pays
-        # deserialisation, not XLA
-        from deeplearning4j_tpu.environment import environment
-        environment().apply_compilation_cache()
-        # pre-compile static analysis (analyze/): named diagnostics
-        # BEFORE tier selection, mesh placement, or any XLA compile —
-        # strict mode raises here (docs/static_analysis.md)
-        self._maybe_analyze(has_listeners=bool(listeners))
-        # seekable streaming pipeline (datapipe/): register it on the
-        # graph so checkpoint captures embed its PipelineState at flush
-        # boundaries and anchor its pass starts to absolute iterations —
-        # a mid-epoch restore then SEEKS instead of replaying the pass
-        # (docs/data_pipeline.md). Cleared (None) for plain iterators so
-        # a previous fit's pipeline can't leak into this fit's snapshots.
-        from deeplearning4j_tpu.datapipe.pipeline import find_pipeline
-        _dp = find_pipeline(dataset_iterator)
-        self._active_datapipe = _dp
-        if _dp is not None and hasattr(_dp, "bind_iteration_source"):
-            _dp.bind_iteration_source(
-                lambda: int(getattr(tc, "iteration_count", 0) or 0))
-            _dp.bind_epoch_source(
-                lambda: int(getattr(tc, "epoch_count", 0) or 0))
-        if getattr(tc, "sharding", None) is not None:
-            # declarative mesh sharding: place params/state on the
-            # spec's mesh and pre-shard batches BEFORE tier selection,
-            # so every tier below (scanned / fused windows / per-step)
-            # trains under the mesh. A ParallelTrainer front end arrives
-            # here with an already-sharded iterator (its explicit
-            # strategy wins) and this is a no-op.
-            from deeplearning4j_tpu.parallel.trainer import ensure_sharded
-            wrapped = ensure_sharded(self, tc.sharding, dataset_iterator)
-            if wrapped is not dataset_iterator:
-                self._verbose_log(
-                    f"fit: sharded over mesh "
-                    f"{dict(wrapped._strategy.mesh.mesh.shape)} "
-                    f"(TrainingConfig.sharding)")
-            dataset_iterator = wrapped
+        # on a graph not fitted at this version, what comes before the
+        # first fit.stage is a phase of the start: fit.build, here and
+        # around the epoch function's building (_fit_scanned_body)
+        building = self._fit_built != self._version
+        with COMPILE_STATS.span("fit.build", cat="train") if building \
+                else _NOT_BUILDING:
+            # the persistent compilation cache, placed where the
+            # environment says (environment.py): a restarted fit pays
+            # deserialisation, not XLA
+            from deeplearning4j_tpu.environment import environment
+            environment().apply_compilation_cache()
+            # pre-compile static analysis (analyze/): named diagnostics
+            # BEFORE tier selection, mesh placement, or any XLA compile —
+            # strict mode raises here (docs/static_analysis.md)
+            self._maybe_analyze(has_listeners=bool(listeners))
+            # seekable streaming pipeline (datapipe/): register it on
+            # the graph so checkpoint captures embed its PipelineState at
+            # flush boundaries and anchor its pass starts to absolute
+            # iterations — a mid-epoch restore then SEEKS instead of
+            # replaying the pass (docs/data_pipeline.md). Cleared (None)
+            # for plain iterators so a previous fit's pipeline can't leak
+            # into this fit's snapshots.
+            from deeplearning4j_tpu.datapipe.pipeline import find_pipeline
+            _dp = find_pipeline(dataset_iterator)
+            self._active_datapipe = _dp
+            if _dp is not None and hasattr(_dp, "bind_iteration_source"):
+                _dp.bind_iteration_source(
+                    lambda: int(getattr(tc, "iteration_count", 0) or 0))
+                _dp.bind_epoch_source(
+                    lambda: int(getattr(tc, "epoch_count", 0) or 0))
+            if getattr(tc, "sharding", None) is not None:
+                # declarative mesh sharding: place params/state on the
+                # spec's mesh and pre-shard batches BEFORE tier
+                # selection, so every tier below (scanned / fused windows
+                # / per-step) trains under the mesh. A ParallelTrainer
+                # front end arrives here with an already-sharded iterator
+                # (its explicit strategy wins) and this is a no-op.
+                from deeplearning4j_tpu.parallel.trainer import \
+                    ensure_sharded
+                wrapped = ensure_sharded(self, tc.sharding,
+                                         dataset_iterator)
+                if wrapped is not dataset_iterator:
+                    self._verbose_log(
+                        f"fit: sharded over mesh "
+                        f"{dict(wrapped._strategy.mesh.mesh.shape)} "
+                        f"(TrainingConfig.sharding)")
+                dataset_iterator = wrapped
+        self._fit_built = self._version
         fused = max(1, int(getattr(tc, "fused_steps", 1) or 1))
         accum = max(1, int(getattr(tc, "accum_steps", 1) or 1))
         if not listeners and hasattr(dataset_iterator, "stacked_batches") \
                 and fused <= 1 and accum <= 1:
             self._verbose_log("fit: scanned whole-epoch path "
                               "(one dispatch per epoch)")
-            return self._fit_scanned(dataset_iterator, epochs)
+            return self._fit_scanned(dataset_iterator, epochs, building)
         if fused > 1 or accum > 1:
             from deeplearning4j_tpu.autodiff.window import fit_windowed
             self._verbose_log(
@@ -2021,21 +2061,25 @@ class SameDiff:
             l.on_training_end(self)
         return history
 
-    def _fit_scanned(self, dataset_iterator, epochs: int):
+    def _fit_scanned(self, dataset_iterator, epochs: int, building: bool):
         """fit() fast path: epochs of lax.scan over device-stacked batches."""
         with _tracer.span("fit", cat="train", tier="scanned_epoch",
                           epochs=epochs) as fit_span:
-            return self._fit_scanned_body(dataset_iterator, epochs, fit_span)
+            return self._fit_scanned_body(dataset_iterator, epochs, fit_span,
+                                          building)
 
-    def _fit_scanned_body(self, dataset_iterator, epochs: int, fit_span):
+    def _fit_scanned_body(self, dataset_iterator, epochs: int, fit_span,
+                          building: bool):
         from deeplearning4j_tpu.autodiff.training import History
         tc = self.training_config
         use_sentinel = bool(getattr(tc, "sentinel", False))
         fp_on = bool(getattr(tc, "fingerprints", False))
         self._device_fingerprint = None
-        epoch_step = self.make_train_epoch(
-            unroll=getattr(tc, "scan_unroll", 1) or 1,
-            sentinel=use_sentinel, fingerprint=fp_on)
+        with COMPILE_STATS.span("fit.build", cat="train") if building \
+                else _NOT_BUILDING:
+            epoch_step = self.make_train_epoch(
+                unroll=getattr(tc, "scan_unroll", 1) or 1,
+                sentinel=use_sentinel, fingerprint=fp_on)
         with _tracer.span("fit.stage", cat="train"):
             params = jax.tree_util.tree_map(jnp.copy,
                                             self.trainable_params())
@@ -2074,15 +2118,21 @@ class SameDiff:
         epoch_means = []
         last_fp = None                 # device uint32, fetched at fit end
         panic = self._nan_panic_active(tc)
+        # the dispatch that traces, lowers and compiles or loads this
+        # program is the last phase of the start; every one after it
+        # opens the plain span
+        dispatch_span = _steady_dispatch if scan_sig in epoch_step.ran \
+            else _first_dispatch
         for epoch in range(epochs):
             try:
-                with _tracer.span("fit.dispatch", cat="train", epoch=epoch):
+                with dispatch_span(epoch):
                     res = epoch_step(params, svars, state, it_dev,
                                      constants, stacked, base_key)
             except Exception as e:
                 memstats.reraise_oom(e, program=scan_label,
                                      step=iteration, epoch=epoch)
                 raise
+            dispatch_span = _steady_dispatch
             # positional layout (make_train_window): p, sv, st, it,
             # losses [, bad] [, fp]
             params, svars, state, it_dev, losses = res[:5]
@@ -2115,6 +2165,7 @@ class SameDiff:
                 "accum_steps": 1, "steps_per_epoch": n_steps,
                 "dispatches_per_epoch": 1, "window_sizes": {n_steps: 1},
                 "window_compiles": 0}
+        epoch_step.ran.add(scan_sig)
         # ONE device fetch for all epoch means at fit end
         with _tracer.span("fit.sync", cat="train"):
             fetched = np.asarray(jnp.stack(epoch_means))

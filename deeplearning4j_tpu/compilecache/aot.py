@@ -48,12 +48,15 @@ class AOTDispatch:
     no AOT entries (the default) the overhead is one attribute check.
     """
 
-    __slots__ = ("jit_fn", "aot", "ph_arg")
+    __slots__ = ("jit_fn", "aot", "ph_arg", "ran")
 
     def __init__(self, jit_fn: Callable, ph_arg: int):
         self.jit_fn = jit_fn
         self.aot: Dict[Tuple, Any] = {}   # shape sig -> jax Compiled
         self.ph_arg = int(ph_arg)         # index of the placeholder dict
+        # shape sigs the scanned fit has dispatched: the first dispatch
+        # of one builds its program (samediff._first_dispatch)
+        self.ran: set = set()
 
     def __call__(self, *args):
         if self.aot:

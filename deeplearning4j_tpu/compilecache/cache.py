@@ -27,6 +27,14 @@ invisible; this module makes it a wired, observable part of the runtime:
   (StableHLO emission), ``compile.backend`` (XLA compile OR cache
   retrieval, with a ``cache_hit`` arg) — so a Perfetto trace of a cold
   start shows exactly where the seconds went.
+- The phases of a start that are not the compiler's — building a
+  model's graph or spec, a server's constructor, its warm-up, what a
+  first ``fit`` does before it runs — are spans opened through
+  :meth:`CompileStats.span`: a tracer span and, on the same two edges,
+  the span's SELF time into ``build_seconds``. ``build``, ``trace``,
+  ``lower``, ``backend_compile`` and ``plan_analyze`` seconds partition
+  the wall time under such a span: no second is in two of them
+  (docs/cold_start.md "Where a start's seconds go").
 
 What is cacheable: the persistent cache keys on the serialized HLO +
 compile options + backend/runtime version, so entries survive process
@@ -37,15 +45,24 @@ so they cache fine. See docs/cold_start.md.
 """
 from __future__ import annotations
 
+import functools
 import threading
 import time
 from typing import Dict, Optional
 
 from deeplearning4j_tpu.monitor.trace import TRACER as _tracer
 
-_STAT_KEYS = ("backend_compiles", "cache_hits", "cache_misses",
-              "backend_compile_seconds", "trace_seconds", "lower_seconds",
-              "saved_seconds")
+_COUNT_KEYS = ("backend_compiles", "cache_hits", "cache_misses",
+               "precompiles")
+_STAT_KEYS = _COUNT_KEYS + (
+    "backend_compile_seconds", "cache_load_seconds", "trace_seconds",
+    "lower_seconds", "build_seconds", "plan_analyze_seconds",
+    "saved_seconds")
+
+#: closed intervals a thread keeps for :func:`_own_seconds`; an interval
+#: with more uncovered ones before it than this over-reads, as every
+#: nested one did before there was a list
+_CLOSED_KEPT = 4096
 
 
 class CompileStats:
@@ -56,7 +73,12 @@ class CompileStats:
     deserializes, so the number of *expensive* compiles is
     ``miss_compiles()`` (= ``backend_compiles - cache_hits``; with the
     cache disabled no hit/miss events fire and every backend compile is
-    a real one).
+    a real one). ``cache_load_seconds`` is the part of
+    ``backend_compile_seconds`` those hits took.
+
+    ``build_seconds`` and ``plan_analyze_seconds`` are the self times
+    of the spans opened through :meth:`span`; ``precompiles`` counts the
+    programs built ahead of time (:meth:`precompile`).
     """
 
     def __init__(self):
@@ -64,9 +86,13 @@ class CompileStats:
         self.backend_compiles = 0
         self.cache_hits = 0
         self.cache_misses = 0
+        self.precompiles = 0
         self.backend_compile_seconds = 0.0
+        self.cache_load_seconds = 0.0
         self.trace_seconds = 0.0
         self.lower_seconds = 0.0
+        self.build_seconds = 0.0
+        self.plan_analyze_seconds = 0.0
         self.saved_seconds = 0.0    # compile time the cache saved (jax est.)
 
     # -- recording (called from jax.monitoring listeners) ---------------
@@ -74,6 +100,42 @@ class CompileStats:
         with self._lock:
             for k, v in fields.items():
                 setattr(self, k, getattr(self, k) + v)
+
+    # -- the phases of a start (module docstring) -------------------------
+    def span(self, name: str, cat: str = "", into: str = "build_seconds",
+             **args) -> "_Phase":
+        """``TRACER.span(name, cat, **args)`` whose self time is also
+        counted, ring on or off: its length less the spans of this kind
+        and the compile events inside it goes to the counter ``into``.
+        Two clock reads; for a start's scale, not a step's."""
+        return _Phase(_tracer.span(name, cat=cat, **args), into)
+
+    def precompile(self, label: str) -> "_Phase":
+        """The ``compile.precompile`` span of one program built ahead
+        of time, counted in ``precompiles`` when it ends well."""
+        return _Phase(_tracer.span("compile.precompile", cat="compile",
+                                   target=label),
+                      "build_seconds", counts="precompiles")
+
+    def model_build(self, family: str):
+        """Decorator: a ``model.build`` span around a zoo builder."""
+        def deco(fn):
+            @functools.wraps(fn)
+            def wrapper(*a, **kw):
+                with self.span("model.build", cat="model", family=family):
+                    return fn(*a, **kw)
+            return wrapper
+        return deco
+
+    def program_row(self, label: str, mark: Dict[str, float]) -> dict:
+        """One row of a warm-up's table: what building the program
+        ``label`` added to the counters since ``mark``."""
+        d = self.delta(mark)
+        return {"label": label, "trace_s": d["trace_seconds"],
+                "lower_s": d["lower_seconds"],
+                "backend_s": d["backend_compile_seconds"],
+                "cache_hit": bool(d["cache_hits"]),
+                "plan_analyze_s": d["plan_analyze_seconds"]}
 
     # -- readout ---------------------------------------------------------
     def snapshot(self) -> Dict[str, float]:
@@ -88,7 +150,7 @@ class CompileStats:
         """Counters accumulated since ``mark`` (a prior snapshot)."""
         now = self.snapshot()
         out = {k: now[k] - mark.get(k, 0) for k in _STAT_KEYS}
-        for k in ("backend_compiles", "cache_hits", "cache_misses"):
+        for k in _COUNT_KEYS:
             out[k] = int(out[k])
         return out
 
@@ -120,6 +182,80 @@ _installed = False
 _tls = threading.local()
 
 
+def _thread_list(name: str) -> list:
+    got = getattr(_tls, name, None)
+    if got is None:
+        got = []
+        setattr(_tls, name, got)
+    return got
+
+
+def _close(start: float, dur: float) -> float:
+    """Put an interval among those this thread has closed, in place of
+    the ones it covers (the last ones closed, each begun at or after
+    ``start``), and return the seconds those held."""
+    closed = _thread_list("closed")
+    inside = 0.0
+    while closed and closed[-1][0] >= start:
+        inside += closed.pop()[1]
+    closed.append((start, dur))
+    if len(closed) > _CLOSED_KEPT:
+        del closed[:_CLOSED_KEPT // 2]
+    return inside
+
+
+def _own_seconds(dur: float) -> float:
+    """The part of an event that ends NOW on this thread, ``dur``
+    seconds long, which no interval closed inside it has counted: a
+    function jitted inside a traced function fires a trace event of its
+    own, and the outer event holds those seconds again. What is left is
+    also taken from the self time of the :class:`_Phase` open around
+    it."""
+    own = max(0.0, dur - _close(time.perf_counter() - dur, dur))
+    phases = _thread_list("phases")
+    if phases:
+        phases[-1].inside += own
+    return own
+
+
+class _Phase:
+    """A span of :meth:`CompileStats.span`: the tracer's span, and
+    inside its two edges a clock of its own, so that the counter moves
+    with the ring off too. ``dur`` is its length once it has closed."""
+
+    __slots__ = ("_span", "_into", "counts", "t0", "dur", "inside")
+
+    def __init__(self, span, into: str, counts: Optional[str] = None):
+        self._span, self._into, self.counts = span, into, counts
+        self.t0 = self.dur = self.inside = 0.0
+
+    def __enter__(self) -> "_Phase":
+        self._span.__enter__()
+        _thread_list("phases").append(self)
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        self.dur = time.perf_counter() - self.t0
+        phases = _thread_list("phases")
+        if self in phases:
+            del phases[phases.index(self):]
+        fields = {self._into: max(0.0, self.dur - self.inside)}
+        if self.counts is not None and exc_type is None:
+            fields[self.counts] = 1
+        COMPILE_STATS._add(**fields)
+        if phases:
+            phases[-1].inside += self.dur
+        # every second of it is counted now: it stands for the
+        # intervals closed inside it, should an event cover it in turn
+        _close(self.t0, self.dur)
+        return self._span.__exit__(exc_type, exc, tb)
+
+    def set(self, **args) -> "_Phase":
+        self._span.set(**args)
+        return self
+
+
 def _on_event(event: str, **kw) -> None:
     if event.endswith("/compilation_cache/cache_hits"):
         COMPILE_STATS._add(cache_hits=1)
@@ -140,18 +276,19 @@ def _on_duration(event: str, duration: float, **kw) -> None:
             event.endswith("backend_compile_time_sec"):
         hit = bool(getattr(_tls, "pending_hit", False))
         _tls.pending_hit = False
-        COMPILE_STATS._add(backend_compiles=1,
-                           backend_compile_seconds=float(duration))
+        own = _own_seconds(float(duration))
+        COMPILE_STATS._add(backend_compiles=1, backend_compile_seconds=own,
+                           cache_load_seconds=own if hit else 0.0)
         _tracer.record_completed("compile.backend", cat="compile",
-                                 dur=float(duration), cache_hit=hit)
+                                 dur=own, cache_hit=hit)
     elif event.endswith("jaxpr_trace_duration"):
-        COMPILE_STATS._add(trace_seconds=float(duration))
-        _tracer.record_completed("compile.trace", cat="compile",
-                                 dur=float(duration))
+        own = _own_seconds(float(duration))
+        COMPILE_STATS._add(trace_seconds=own)
+        _tracer.record_completed("compile.trace", cat="compile", dur=own)
     elif event.endswith("jaxpr_to_mlir_module_duration"):
-        COMPILE_STATS._add(lower_seconds=float(duration))
-        _tracer.record_completed("compile.lower", cat="compile",
-                                 dur=float(duration))
+        own = _own_seconds(float(duration))
+        COMPILE_STATS._add(lower_seconds=own)
+        _tracer.record_completed("compile.lower", cat="compile", dur=own)
     elif event.endswith("compile_time_saved_sec"):
         # jax reports compile_time - retrieval_time; can be negative for
         # programs that compile faster than they deserialize

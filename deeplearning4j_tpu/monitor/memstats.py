@@ -186,8 +186,13 @@ class MemoryPlans:
         signature; re-capture refreshes). ``graph`` is the owning
         SameDiff (attribution — see :func:`graph_key`). Never raises —
         plan capture must not be able to break a compile path."""
+        # compilecache imports this package's tracer: not at the top
+        from deeplearning4j_tpu.compilecache.cache import COMPILE_STATS
         try:
-            fields = _analyze(compiled=compiled, lowered=lowered)
+            with COMPILE_STATS.span("compile.plan_analyze", cat="compile",
+                                    into="plan_analyze_seconds",
+                                    target=str(label)):
+                fields = _analyze(compiled=compiled, lowered=lowered)
             if not fields:
                 return None
             key = self._sig_key(sig)

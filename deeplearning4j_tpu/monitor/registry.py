@@ -568,13 +568,18 @@ class MetricsRegistry:
         if hasattr(rec, "to_record"):
             rec = rec.to_record()
         for key in ("backend_compiles", "cache_hits", "cache_misses",
-                    "miss_compiles"):
+                    "miss_compiles", "precompiles"):
             if key in rec:
                 self.set_gauge(f"compile_{key}_total", rec[key],
                                help="XLA compiles by persistent-cache "
-                                    "outcome (compilecache/)")
-        for key in ("backend_compile_seconds", "trace_seconds",
-                    "lower_seconds", "saved_seconds"):
+                                    "outcome, and programs built ahead "
+                                    "of time (compilecache/)")
+        # build, trace, lower, backend_compile and plan_analyze seconds
+        # partition a start's wall time; cache_load is the part of
+        # backend_compile that persistent-cache hits took
+        for key in ("backend_compile_seconds", "cache_load_seconds",
+                    "trace_seconds", "lower_seconds", "build_seconds",
+                    "plan_analyze_seconds", "saved_seconds"):
             if key in rec:
                 self.set_gauge(f"compile_{key}", rec[key],
                                help="cumulative compile-phase wall time")

@@ -438,12 +438,38 @@ SPAN_CATALOG: Dict[str, Tuple[str, Tuple[str, ...]]] = {
     # fit.commit (arrays, updater state and counters written back)
     "fit": ("train", ("tier", "steps", "epochs")),
     "fit.stage": ("train", ()),
-    "fit.dispatch": ("train", ("epoch",)),
+    # first=1: the dispatch that traces, lowers and compiles or loads
+    # the epoch's program; it carries that program's row (trace_s,
+    # lower_s, backend_s, cache_hit) and its self time is build_seconds
+    "fit.dispatch": ("train", ("epoch", "first", "trace_s", "lower_s",
+                               "backend_s", "cache_hit")),
     "fit.sync": ("train", ()),
     "fit.commit": ("train", ()),
-    # compile pipeline (compilecache/, samediff precompile, memstats)
+    # the phases of a start (CompileStats.span: each one's self time is
+    # COMPILE_STATS.build_seconds, compile.plan_analyze's is
+    # plan_analyze_seconds; with the compile events below they partition
+    # the wall time under them). fit.build: what a fit does before its
+    # first fit.stage on a graph it has not run at this version, once in
+    # fit() (cache placement, static analysis, mesh placement) and once
+    # under fit (step parts, the jitted epoch function). model.build: a
+    # zoo builder of a graph, a spec or a spec's programs. serving.build:
+    # a generative server's constructor up to its worker's start >
+    # serving.build.params (the spec's parameters; their placement on a
+    # mesh, under serving.build.pool), serving.build.pool (tiers, pool
+    # arrays, dispatchers, the draft's) and serving.warmup > one
+    # compile.precompile and one compile.plan_analyze a program
+    "fit.build": ("train", ()),
+    "model.build": ("model", ("family",)),
+    "serving.build": ("serving", ()),
+    "serving.build.params": ("serving", ()),
+    "serving.build.pool": ("serving", ()),
+    # compile pipeline (compilecache/, samediff precompile, memstats).
+    # compile.trace / .lower / .backend are markers of jax's own events,
+    # each as long as the part of its event that no event inside it has
+    # counted (a jit traced inside a trace)
     "compile.precompile": ("compile", ("target",)),
     "compile.plan_capture": ("compile", ("target",)),
+    "compile.plan_analyze": ("compile", ("target",)),
     "compile.backend": ("compile", ("cache_hit",)),
     "compile.trace": ("compile", ()),
     "compile.lower": ("compile", ()),
@@ -466,7 +492,7 @@ SPAN_CATALOG: Dict[str, Tuple[str, Tuple[str, ...]]] = {
     "serving.exec": ("serving", ("rows", "padding")),
     "serving.reply": ("serving", ("id", "requests", "trace_id",
                                   "segment")),
-    "serving.warmup": ("serving", ("bucket",)),
+    "serving.warmup": ("serving", ()),
     "serving.reload": ("serving", ("step", "arrays")),
     # the generative scheduler's worker thread (serving/generative.py):
     # serving.step > serving.admit > serving.prefill, and serving.step >

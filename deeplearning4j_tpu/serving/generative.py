@@ -67,6 +67,7 @@ from typing import (Callable, Dict, List, NamedTuple, Optional, Sequence,
 import numpy as np
 
 from deeplearning4j_tpu.compilecache.aot import AOTDispatch, ph_shape_sig
+from deeplearning4j_tpu.compilecache.cache import COMPILE_STATS
 from deeplearning4j_tpu.monitor.trace import TRACER as _tracer
 from deeplearning4j_tpu.serving.batching import BucketSpec, pow2_buckets
 from deeplearning4j_tpu.serving.metrics import (LatencyHistogram,
@@ -537,128 +538,134 @@ class GenerativeServer:
                  draft_spec=None,
                  speculate_k: int = 4,
                  start: bool = True):
-        spec = self._coerce_spec(spec)
-        self.spec = spec
-        self.max_slots = int(max_slots)
-        self.max_seq_len = int(max_seq_len or spec.max_seq_len)
-        if self.max_seq_len > spec.max_seq_len:
-            raise ValueError(
-                f"max_seq_len {self.max_seq_len} exceeds the model's "
-                f"positional capacity {spec.max_seq_len}")
-        # speculative decoding: a small DRAFT model proposes K-1 tokens
-        # per slot per round, the target verifies the whole window in
-        # one dispatch. The draft always runs DENSE (its slabs are tiny)
-        # even under a paged target. Misconfigurations that can never
-        # work fail here, not mid-decode (analyze/servingpass.py lints
-        # the same contract statically)
-        self.speculate_k = int(speculate_k)
-        self.draft_spec = None
-        self.draft_slab_bytes = 0
-        if draft_spec is not None:
-            if not isinstance(draft_spec, GenerativeSpec):
-                if hasattr(draft_spec, "generative_spec"):
-                    draft_spec = draft_spec.generative_spec()
-                else:
-                    raise TypeError(
-                        f"{type(draft_spec).__name__} is not usable as "
-                        f"a draft: pass a dense GenerativeSpec (the "
-                        f"draft always runs dense, even under a paged "
-                        f"target)")
-            if int(draft_spec.vocab_size) != int(spec.vocab_size):
+        # everything up to the worker's start is one phase of the start
+        # (compilecache/cache.py): its self time is build_seconds
+        with COMPILE_STATS.span("serving.build", cat="serving"):
+            spec = self._coerce_spec(spec)
+            self.spec = spec
+            self.max_slots = int(max_slots)
+            self.max_seq_len = int(max_seq_len or spec.max_seq_len)
+            if self.max_seq_len > spec.max_seq_len:
                 raise ValueError(
-                    f"draft vocab_size {draft_spec.vocab_size} != "
-                    f"target vocab_size {spec.vocab_size}: speculation "
-                    f"compares token ids, the vocabularies must match")
-            if int(draft_spec.max_seq_len) < self.max_seq_len:
+                    f"max_seq_len {self.max_seq_len} exceeds the model's "
+                    f"positional capacity {spec.max_seq_len}")
+            # speculative decoding: a small DRAFT model proposes K-1 tokens
+            # per slot per round, the target verifies the whole window in
+            # one dispatch. The draft always runs DENSE (its slabs are tiny)
+            # even under a paged target. Misconfigurations that can never
+            # work fail here, not mid-decode (analyze/servingpass.py lints
+            # the same contract statically)
+            self.speculate_k = int(speculate_k)
+            self.draft_spec = None
+            self.draft_slab_bytes = 0
+            if draft_spec is not None:
+                if not isinstance(draft_spec, GenerativeSpec):
+                    if hasattr(draft_spec, "generative_spec"):
+                        draft_spec = draft_spec.generative_spec()
+                    else:
+                        raise TypeError(
+                            f"{type(draft_spec).__name__} is not usable as "
+                            f"a draft: pass a dense GenerativeSpec (the "
+                            f"draft always runs dense, even under a paged "
+                            f"target)")
+                if int(draft_spec.vocab_size) != int(spec.vocab_size):
+                    raise ValueError(
+                        f"draft vocab_size {draft_spec.vocab_size} != "
+                        f"target vocab_size {spec.vocab_size}: speculation "
+                        f"compares token ids, the vocabularies must match")
+                if int(draft_spec.max_seq_len) < self.max_seq_len:
+                    raise ValueError(
+                        f"draft max_seq_len {draft_spec.max_seq_len} < "
+                        f"served max_seq_len {self.max_seq_len}: the draft "
+                        f"must cover every position the target can reach")
+                if self.speculate_k < 2:
+                    raise ValueError(
+                        f"speculate_k must be >= 2, got {self.speculate_k} "
+                        f"(a window of 1 holds only the already-emitted "
+                        f"token and drafts nothing)")
+                self.draft_spec = draft_spec
+            self.eos_id = eos_id if eos_id is not None else spec.eos_id
+            self.default_timeout_ms = default_timeout_ms
+            self.max_queue_len = int(max_queue_len)
+            self.stats_storage = stats_storage
+            self.metrics = self._make_metrics()
+            # pow2 prefill bucket ladder (serving/batching.py machinery):
+            # halving down from max_seq_len to 1 — ≤ log2(max_seq)+1
+            # compiled prefill shapes for ANY prompt-length mix
+            self._buckets = BucketSpec(
+                buckets if buckets is not None
+                else pow2_buckets(
+                    self.max_seq_len,
+                    n_buckets=int(self.max_seq_len).bit_length()))
+            if self._buckets.max_rows > self.max_seq_len:
                 raise ValueError(
-                    f"draft max_seq_len {draft_spec.max_seq_len} < "
-                    f"served max_seq_len {self.max_seq_len}: the draft "
-                    f"must cover every position the target can reach")
-            if self.speculate_k < 2:
-                raise ValueError(
-                    f"speculate_k must be >= 2, got {self.speculate_k} "
-                    f"(a window of 1 holds only the already-emitted "
-                    f"token and drafts nothing)")
-            self.draft_spec = draft_spec
-        self.eos_id = eos_id if eos_id is not None else spec.eos_id
-        self.default_timeout_ms = default_timeout_ms
-        self.max_queue_len = int(max_queue_len)
-        self.stats_storage = stats_storage
-        self.metrics = self._make_metrics()
-        # pow2 prefill bucket ladder (serving/batching.py machinery):
-        # halving down from max_seq_len to 1 — ≤ log2(max_seq)+1
-        # compiled prefill shapes for ANY prompt-length mix
-        self._buckets = BucketSpec(
-            buckets if buckets is not None
-            else pow2_buckets(self.max_seq_len,
-                              n_buckets=int(self.max_seq_len).bit_length()))
-        if self._buckets.max_rows > self.max_seq_len:
-            raise ValueError(
-                f"largest prefill bucket {self._buckets.max_rows} exceeds "
-                f"max_seq_len {self.max_seq_len}: its KV rows would not "
-                f"fit the slab")
-        # resilience (serving/resilience.py): the generative tier uses
-        # p99 decode-step time for TTFT estimates (ISSUE 15 / Orca-style
-        # step scheduling makes tail steps the binding constraint)
-        if resilience is True:
-            resilience = ResilienceConfig(percentile=99.0)
-        self.resilience = ResilienceConfig.normalize(resilience)
-        self.admission: Optional[AdmissionController] = None
-        if self.resilience is not None and self.resilience.admission:
-            self.admission = AdmissionController(
-                window=self.resilience.window,
-                percentile=self.resilience.percentile,
-                min_samples=self.resilience.min_exec_samples)
-        self._queue = RequestQueue(
-            self.max_queue_len,
-            on_timeout=lambda req: self.metrics.record_timeout("deadline"))
-        self._exec_lock = threading.Lock()
-        self._state_lock = threading.Lock()
-        self._shapes_seen: set = set()
-        self._req_id = 0
-        self._id_lock = threading.Lock()
-        self._closed = False
-        self._killed = False         # abort(): fail in-flight, no drain
-        # dispatch-to-sync ms inside the current _step (worker thread)
-        self._step_busy_ms = 0.0
-        # the decode loop one step ahead (worker thread): the step that
-        # is launched and unread when a pass ends, and the step before
-        # it, read and not yet handed out (_decode_once)
-        self._ahead: Optional[_Flight] = None
-        self._unemitted: Optional[Tuple[_Flight, np.ndarray]] = None
-        self._dirty = False          # a respawned worker must reset state
-        self._mem_every = (max(1, int(memory_sample_every))
-                           if memory_sample_every else None)
-        # parameters: by-name sync from the training graph, cached as
-        # one dict so every dispatch shares the same device arrays
-        self._params = dict(spec.params())
-        # KV slabs + host scheduler state + dispatchers — the memory
-        # tier. Overridden by serving/paged's PagedGenerativeServer,
-        # which replaces the dense per-slot slabs with a block pool and
-        # admits on free BLOCKS rather than free slots
-        self._init_kv()
-        self._init_draft()
-        self.telemetry = None
-        if telemetry_port is not None:
-            from deeplearning4j_tpu.monitor.server import TelemetryServer
-            self.telemetry = TelemetryServer(storage=stats_storage,
-                                             port=telemetry_port)
-            self.telemetry.add_scrape_hook(
-                lambda reg: reg.fold_serving(self.metrics))
-            self.telemetry.add_health_provider("generative",
-                                               self._telemetry_health)
-        self.warmup_report: Optional[dict] = None
-        if warmup:
-            self.warmup()
-        self._workers: List[threading.Thread] = []
-        self._supervisor: Optional[WorkerSupervisor] = None
-        # gate on the CONFIG, not self._supervisor: the supervisor's
-        # constructor spawns the worker before the attribute assignment
-        # completes (the PR-9 construction race)
-        self._supervised = (self.resilience is not None
-                            and self.resilience.supervise)
-        self._cur_slot: Optional[InflightSlot] = None
-        self._started = False
+                    f"largest prefill bucket {self._buckets.max_rows} exceeds "
+                    f"max_seq_len {self.max_seq_len}: its KV rows would not "
+                    f"fit the slab")
+            # resilience (serving/resilience.py): the generative tier uses
+            # p99 decode-step time for TTFT estimates (ISSUE 15 / Orca-style
+            # step scheduling makes tail steps the binding constraint)
+            if resilience is True:
+                resilience = ResilienceConfig(percentile=99.0)
+            self.resilience = ResilienceConfig.normalize(resilience)
+            self.admission: Optional[AdmissionController] = None
+            if self.resilience is not None and self.resilience.admission:
+                self.admission = AdmissionController(
+                    window=self.resilience.window,
+                    percentile=self.resilience.percentile,
+                    min_samples=self.resilience.min_exec_samples)
+            self._queue = RequestQueue(
+                self.max_queue_len,
+                on_timeout=lambda req: self.metrics.record_timeout("deadline"))
+            self._exec_lock = threading.Lock()
+            self._state_lock = threading.Lock()
+            self._shapes_seen: set = set()
+            self._req_id = 0
+            self._id_lock = threading.Lock()
+            self._closed = False
+            self._killed = False         # abort(): fail in-flight, no drain
+            # dispatch-to-sync ms inside the current _step (worker thread)
+            self._step_busy_ms = 0.0
+            # the decode loop one step ahead (worker thread): the step that
+            # is launched and unread when a pass ends, and the step before
+            # it, read and not yet handed out (_decode_once)
+            self._ahead: Optional[_Flight] = None
+            self._unemitted: Optional[Tuple[_Flight, np.ndarray]] = None
+            self._dirty = False          # a respawned worker must reset state
+            self._mem_every = (max(1, int(memory_sample_every))
+                               if memory_sample_every else None)
+            # parameters: by-name sync from the training graph, cached as
+            # one dict so every dispatch shares the same device arrays
+            with COMPILE_STATS.span("serving.build.params", cat="serving"):
+                self._params = dict(spec.params())
+            # KV slabs + host scheduler state + dispatchers — the memory
+            # tier. Overridden by serving/paged's PagedGenerativeServer,
+            # which replaces the dense per-slot slabs with a block pool and
+            # admits on free BLOCKS rather than free slots
+            with COMPILE_STATS.span("serving.build.pool", cat="serving"):
+                self._init_kv()
+                self._init_draft()
+            self.telemetry = None
+            if telemetry_port is not None:
+                from deeplearning4j_tpu.monitor.server import TelemetryServer
+                self.telemetry = TelemetryServer(storage=stats_storage,
+                                                 port=telemetry_port)
+                self.telemetry.add_scrape_hook(
+                    lambda reg: reg.fold_serving(self.metrics))
+                self.telemetry.add_health_provider("generative",
+                                                   self._telemetry_health)
+            self.warmup_report: Optional[dict] = None
+            if warmup:
+                self.warmup()
+            self._workers: List[threading.Thread] = []
+            self._supervisor: Optional[WorkerSupervisor] = None
+            # gate on the CONFIG, not self._supervisor: the supervisor's
+            # constructor spawns the worker before the attribute assignment
+            # completes (the PR-9 construction race)
+            self._supervised = (self.resilience is not None
+                                and self.resilience.supervise)
+            self._cur_slot: Optional[InflightSlot] = None
+            self._started = False
         if start:
             self.start()
 
@@ -820,14 +827,22 @@ class GenerativeServer:
         log2(max_seq)+1 prefill shapes. With a persistent compilation
         cache configured every entry is a cache hit on a warm restart
         and warmup is ~free. Returns (and stores as ``warmup_report``)
-        the shape list, wall seconds and the compile/cache-hit deltas."""
-        import time as _time
+        the shape list, the compile/cache-hit deltas, ``seconds`` (the
+        length of the ``serving.warmup`` span) and ``programs``: one row
+        a program built by this call, in order (``label, trace_s,
+        lower_s, backend_s, cache_hit, plan_analyze_s``), which sum to
+        what the call added to ``COMPILE_STATS``."""
+        with COMPILE_STATS.span("serving.warmup", cat="serving") as phase:
+            report = self._warmup(buckets)
+        report["seconds"] = round(phase.dur, 4)
+        self.warmup_report = report
+        return report
 
+    def _warmup(self, buckets: Optional[Sequence[int]]) -> dict:
         import jax
         import jax.numpy as jnp
 
-        from deeplearning4j_tpu.compilecache import (COMPILE_STATS,
-                                                     install_compile_watcher)
+        from deeplearning4j_tpu.compilecache import install_compile_watcher
         from deeplearning4j_tpu.environment import environment
         from deeplearning4j_tpu.monitor import memstats
         environment().apply_compilation_cache()
@@ -840,19 +855,20 @@ class GenerativeServer:
                                       self._kc.dtype)
         S = self.max_slots
         mark = COMPILE_STATS.mark()
-        t0 = _time.perf_counter()
+        programs: List[dict] = []
 
         def _build(disp, io_abs, label, params_abs=params_abs,
                    kv_abs=kv_abs, role="target"):
             sig = ph_shape_sig(io_abs)
             with self._exec_lock:
                 if sig not in disp.aot:
-                    with _tracer.span("compile.precompile", cat="compile",
-                                      target=label):
+                    at = COMPILE_STATS.mark()
+                    with COMPILE_STATS.precompile(label):
                         disp.aot[sig] = disp.lower(
                             params_abs, kv_abs, kv_abs, io_abs).compile()
                     memstats.capture_plan(label, sig,
                                           compiled=disp.aot[sig])
+                    programs.append(COMPILE_STATS.program_row(label, at))
                 # mark INSIDE the lock hold: a live dispatch between
                 # compile and mark must not count a spurious lazy
                 # compile for a just-warmed shape (PR-6 round-6 rule).
@@ -899,15 +915,14 @@ class GenerativeServer:
                         "slot": jax.ShapeDtypeStruct((), jnp.int32)},
                        f"draft_prefill_b{int(b)}", params_abs=dparams_abs,
                        kv_abs=dkv_abs, role="draft")
-        self.warmup_report = {
+        return {
             "decode_slots": S,
             "prefill_buckets": bucket_list,
             "speculative": self.draft_spec is not None,
-            "seconds": round(_time.perf_counter() - t0, 4),
+            "programs": programs,
             **{k: v for k, v in COMPILE_STATS.delta(mark).items()
                if k in ("backend_compiles", "cache_hits",
                         "cache_misses")}}
-        return self.warmup_report
 
     # -- client API -----------------------------------------------------
     def _validate_submit(self, prompt, max_new_tokens: int) -> np.ndarray:

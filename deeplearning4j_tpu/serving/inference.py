@@ -32,6 +32,7 @@ from typing import Callable, List, NamedTuple, Optional, Sequence
 
 import numpy as np
 
+from deeplearning4j_tpu.compilecache.cache import COMPILE_STATS
 from deeplearning4j_tpu.monitor.trace import TRACER as _tracer
 from deeplearning4j_tpu.serving.batching import (Batch, DynamicBatcher,
                                                  pad_to_bucket,
@@ -279,13 +280,20 @@ class ParallelInference:
         (SEQUENTIAL/INPLACE — where requests execute at their own row
         count, so only warmed sizes are covered; off-ladder sizes still
         compile lazily). Requires static feature dims on every input.
-        Returns (and stores as ``warmup_report``) the bucket list, wall
-        seconds, and the compile/cache-hit accounting — on a warm
+        Returns (and stores as ``warmup_report``) the bucket list, the
+        compile/cache-hit accounting, ``seconds`` (the length of the
+        ``serving.warmup`` span) and ``programs``, one row a bucket
+        built by this call (``GenerativeServer.warmup``) — on a warm
         restart with a persistent cache configured, every entry is a
         cache hit and warmup is ~free."""
-        import time as _time
-        from deeplearning4j_tpu.compilecache import (COMPILE_STATS,
-                                                     install_compile_watcher)
+        with COMPILE_STATS.span("serving.warmup", cat="serving") as phase:
+            report = self._warmup(buckets)
+        report["seconds"] = round(phase.dur, 4)
+        self.warmup_report = report
+        return report
+
+    def _warmup(self, buckets: Optional[Sequence[int]]) -> dict:
+        from deeplearning4j_tpu.compilecache import install_compile_watcher
         install_compile_watcher()
         if buckets is None:
             if self._batcher is not None:
@@ -307,7 +315,7 @@ class ParallelInference:
                     f"are not static — pass concrete shapes to the "
                     f"model, or skip warmup for this graph")
         mark = COMPILE_STATS.mark()
-        t0 = _time.perf_counter()
+        programs = []
         for b in bucket_list:
             ph = {name: (b,) + tuple(int(d) for d in shp[1:])
                   for name, shp in zip(self._spec.input_names,
@@ -315,11 +323,14 @@ class ParallelInference:
             # _exec_lock: warmup() is public and may be called on a LIVE
             # server (pre-warming a new bucket) — the graph's compile
             # caches are only safe under the same lock _execute holds
-            with self._exec_lock, \
-                    _tracer.span("serving.warmup", cat="serving", bucket=b):
+            with self._exec_lock:
                 from deeplearning4j_tpu.monitor import memstats
+                at = COMPILE_STATS.mark()
                 self._spec.sd.precompile_output(ph,
                                                 self._spec.output_names)
+                if COMPILE_STATS.precompiles > at["precompiles"]:
+                    programs.append(
+                        COMPILE_STATS.program_row(f"output_b{b}", at))
                 # headroom guard (docs/serving.md "Resilience"): refuse
                 # to mark a bucket warm whose compiled plan (temps +
                 # outputs — arguments are the already-resident params)
@@ -347,12 +358,10 @@ class ParallelInference:
                 if sig not in self._shapes_seen:
                     self._shapes_seen.add(sig)
                     self.metrics.inc("warmup_compiles")
-        self.warmup_report = {
-            "buckets": bucket_list,
-            "seconds": round(_time.perf_counter() - t0, 4),
+        return {
+            "buckets": bucket_list, "programs": programs,
             **{k: v for k, v in COMPILE_STATS.delta(mark).items()
                if k in ("backend_compiles", "cache_hits", "cache_misses")}}
-        return self.warmup_report
 
     def _prepare(self, x) -> tuple:
         """-> (list of per-input arrays with a batch dim, squeeze flag)."""

@@ -94,6 +94,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from deeplearning4j_tpu.compilecache.aot import AOTDispatch, ph_shape_sig
+from deeplearning4j_tpu.compilecache.cache import COMPILE_STATS
 from deeplearning4j_tpu.serving.generative import (GenerationHandle,
                                                    GenerationRequest,
                                                    GenerativeMetrics,
@@ -722,9 +723,11 @@ class PagedGenerativeServer(GenerativeServer):
                         for n, a in self._params.items()},
                 device_count=len(devices))
             self._strategy = strat = sspec.build(devices=devices)
-            self._params = {
-                n: jax.device_put(a, strat.param_sharding(n, np.ndim(a)))
-                for n, a in self._params.items()}
+            with COMPILE_STATS.span("serving.build.params", cat="serving"):
+                self._params = {
+                    n: jax.device_put(a,
+                                      strat.param_sharding(n, np.ndim(a)))
+                    for n, a in self._params.items()}
             # leaf layout contract: the last axis is heads x head_dim,
             # heads outermost, so an even split of it is a split by head
             self._kv_sharding = NamedSharding(
@@ -1223,23 +1226,19 @@ class PagedGenerativeServer(GenerativeServer):
         self._reset_slots()
 
     # -- AOT warmup -----------------------------------------------------
-    def warmup(self, buckets: Optional[Sequence[int]] = None) -> dict:
-        """Paged analogue of :meth:`GenerativeServer.warmup`: one
+    def _warmup(self, buckets: Optional[Sequence[int]]) -> dict:
+        """Paged analogue of :meth:`GenerativeServer.warmup`'s body: one
         decode shape per combination of the tiers' table widths + one
         prefill shape per bucket, lowered with the
         mesh shardings when ``tp > 1`` so the AOT executables match the
         live sharded arguments (a mismatch would silently fall back to
         lazy jit — the AOTDispatch ValueError path)."""
-        import time as _time
-
         import jax
         import jax.numpy as jnp
 
-        from deeplearning4j_tpu.compilecache import (COMPILE_STATS,
-                                                     install_compile_watcher)
+        from deeplearning4j_tpu.compilecache import install_compile_watcher
         from deeplearning4j_tpu.environment import environment
         from deeplearning4j_tpu.monitor import memstats
-        from deeplearning4j_tpu.monitor.trace import TRACER as _tracer
         environment().apply_compilation_cache()
         install_compile_watcher()
         bucket_list = sorted({int(b) for b in buckets}) \
@@ -1284,19 +1283,20 @@ class PagedGenerativeServer(GenerativeServer):
             return out
 
         mark = COMPILE_STATS.mark()
-        t0 = _time.perf_counter()
+        programs: List[dict] = []
 
         def _build(disp, io_abs, label, params_abs=params_abs,
                    kv_abs=kv_abs, role="target"):
             sig = ph_shape_sig(io_abs)
             with self._exec_lock:
                 if sig not in disp.aot:
-                    with _tracer.span("compile.precompile", cat="compile",
-                                      target=label):
+                    at = COMPILE_STATS.mark()
+                    with COMPILE_STATS.precompile(label):
                         disp.aot[sig] = disp.lower(
                             params_abs, *kv_abs, io_abs).compile()
                     memstats.capture_plan(label, sig,
                                           compiled=disp.aot[sig])
+                    programs.append(COMPILE_STATS.program_row(label, at))
                 if (role, sig) not in self._shapes_seen:
                     self._shapes_seen.add((role, sig))
                     self.metrics.inc("warmup_compiles")
@@ -1314,8 +1314,12 @@ class PagedGenerativeServer(GenerativeServer):
                     **_tier_io("tables", (S,), S, widths)},
                    f"paged_decode_s{S}r{k}")
         if self._cut_tokens is not None:
-            self._cut_tokens = self._cut_program().lower(_abs(
-                (S + len(self._program_counters),), jnp.int32)).compile()
+            at, label = COMPILE_STATS.mark(), f"paged_cut_tokens_s{S}"
+            with COMPILE_STATS.precompile(label):
+                self._cut_tokens = self._cut_program().lower(_abs(
+                    (S + len(self._program_counters),),
+                    jnp.int32)).compile()
+            programs.append(COMPILE_STATS.program_row(label, at))
         if self.tp > 1:
             # the step launched ahead hands the program its own next
             # tokens back: only if they come out laid over the mesh as
@@ -1359,18 +1363,17 @@ class PagedGenerativeServer(GenerativeServer):
                         "slot": _abs((), jnp.int32)},
                        f"draft_prefill_b{int(b)}", params_abs=dparams_abs,
                        kv_abs=dkv_abs, role="draft")
-        self.warmup_report = {
+        return {
             "decode_slots": S,
             "decode_table_widths": {
                 ts.tier.name or "all": sorted(set(ts.widths))
                 for ts in self._tiers},
             "prefill_buckets": bucket_list,
             "speculative": self.draft_spec is not None,
-            "seconds": round(_time.perf_counter() - t0, 4),
+            "programs": programs,
             **{k: v for k, v in COMPILE_STATS.delta(mark).items()
                if k in ("backend_compiles", "cache_hits",
                         "cache_misses")}}
-        return self.warmup_report
 
     def update_model(self) -> None:
         """Re-pull trained parameters; under ``tp > 1`` the fresh

@@ -589,7 +589,15 @@ td,th{{border:1px solid #ccc;padding:3px 8px}}</style></head><body>
             f"compiles — {c.get('cache_hits', 0)} persistent-cache hits, "
             f"{misses} real (miss) compiles; "
             f"{c.get('backend_compile_seconds', 0.0):.2f}s in the "
-            f"backend, {c.get('trace_seconds', 0.0):.2f}s tracing, "
+            f"backend ({c.get('cache_load_seconds', 0.0):.2f}s of it "
+            f"loading cache hits), "
+            f"{c.get('trace_seconds', 0.0):.2f}s tracing, "
+            f"{c.get('lower_seconds', 0.0):.2f}s lowering, "
+            f"{c.get('build_seconds', 0.0):.2f}s building models, "
+            f"servers and first fits around "
+            f"{c.get('precompiles', 0)} programs built ahead of time, "
+            f"{c.get('plan_analyze_seconds', 0.0):.2f}s reading their "
+            f"memory plans, "
             f"{c.get('saved_seconds', 0.0):.2f}s saved by the cache "
             f"(compilecache/, docs/cold_start.md)</p>")
 

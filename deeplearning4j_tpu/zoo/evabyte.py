@@ -52,6 +52,8 @@ from typing import Dict
 
 import numpy as np
 
+from deeplearning4j_tpu.compilecache.cache import COMPILE_STATS
+
 
 @dataclasses.dataclass(frozen=True)
 class EvaByteConfig:
@@ -425,6 +427,7 @@ def forward(cfg: EvaByteConfig, params, tokens):
     return mm(rmsnorm(x[0], params["norm_f"]), params["lm_head"])
 
 
+@COMPILE_STATS.model_build("evabyte")
 def evabyte_paged_decode_fns(cfg: EvaByteConfig, block_size: int,
                              max_blocks_per_req: int):
     """``(prefill_fn, decode_fn)`` over the two-tier paged pool, both
@@ -525,6 +528,7 @@ def evabyte_paged_decode_fns(cfg: EvaByteConfig, block_size: int,
     return prefill_fn, decode_fn
 
 
+@COMPILE_STATS.model_build("evabyte")
 def evabyte_paged_spec(cfg: EvaByteConfig, params):
     """A :class:`~deeplearning4j_tpu.serving.paged.PagedGenerativeSpec`
     over ``params`` (a dict by :func:`evabyte_param_names`, or a callable

@@ -59,6 +59,7 @@ from typing import Dict
 
 import numpy as np
 
+from deeplearning4j_tpu.compilecache.cache import COMPILE_STATS
 from deeplearning4j_tpu.parallel.moe import (GROUPED_TILE_COLS,
                                              GROUPED_TILE_ROWS,
                                              dropless_topk_ffn,
@@ -266,6 +267,7 @@ class _LatentCache:
             rows.astype(self.leaf.dtype))
 
 
+@COMPILE_STATS.model_build("glm_moe_lite")
 def glm_moe_lite_paged_decode_fns(cfg: GlmMoeLiteConfig, block_size: int,
                                   max_blocks_per_req: int):
     """``(prefill_fn, decode_fn)`` over the paged pool, both ``fn(params,
@@ -503,6 +505,7 @@ def glm_moe_lite_paged_decode_fns(cfg: GlmMoeLiteConfig, block_size: int,
     return prefill_fn, decode_fn
 
 
+@COMPILE_STATS.model_build("glm_moe_lite")
 def glm_moe_lite_paged_spec(cfg: GlmMoeLiteConfig, params):
     """A :class:`~deeplearning4j_tpu.serving.paged.PagedGenerativeSpec`
     over ``params`` (a dict by :func:`glm_moe_lite_param_names`, or a

@@ -30,6 +30,8 @@ import dataclasses
 
 import numpy as np
 
+from deeplearning4j_tpu.compilecache.cache import COMPILE_STATS
+
 
 @dataclasses.dataclass
 class GPTConfig:
@@ -74,6 +76,7 @@ def _dense(sd, rng, scope, x, n_in, n_out, std):
     return sd.invoke("bias_add", [h, b], name=f"{scope}/bias")
 
 
+@COMPILE_STATS.model_build("gpt")
 def build_gpt(cfg: GPTConfig, batch: int, seq_len: int, seed: int = 0):
     """Build the decoder LM as a SameDiff graph.
 
@@ -183,6 +186,7 @@ def gpt_param_names(cfg: GPTConfig):
     return names
 
 
+@COMPILE_STATS.model_build("gpt")
 def gpt_decode_fns(cfg: GPTConfig, quantize_weights: bool = False,
                    kv_scales=None):
     """Pure-jax ``(prefill_fn, decode_fn, verify_fn)`` mirroring
@@ -475,6 +479,7 @@ def gpt_decode_fns(cfg: GPTConfig, quantize_weights: bool = False,
     return prefill_fn, decode_fn, verify_fn
 
 
+@COMPILE_STATS.model_build("gpt")
 def gpt_paged_decode_fns(cfg: GPTConfig, block_size: int,
                          max_blocks_per_req: int,
                          quantize_weights: bool = False, kv_scales=None):
@@ -932,6 +937,7 @@ def _params_pull(sd, cfg: GPTConfig, names, quantize_weights: bool):
     return lambda: {n: sd._arrays[n] for n in names}
 
 
+@COMPILE_STATS.model_build("gpt")
 def gpt_paged_spec(sd, cfg: GPTConfig, quantize_weights: bool = False,
                    quantize_kv: bool = False, calibration_prompts=None):
     """The PAGED decode-mode graph hook: a
@@ -967,6 +973,7 @@ def gpt_paged_spec(sd, cfg: GPTConfig, quantize_weights: bool = False,
         kv_dtype="int8" if quantize_kv else "float32")
 
 
+@COMPILE_STATS.model_build("gpt")
 def gpt_generative_spec(sd, cfg: GPTConfig, quantize_weights: bool = False,
                         quantize_kv: bool = False,
                         calibration_prompts=None):

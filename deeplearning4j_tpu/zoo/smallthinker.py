@@ -45,6 +45,7 @@ from typing import Dict, Tuple
 
 import numpy as np
 
+from deeplearning4j_tpu.compilecache.cache import COMPILE_STATS
 from deeplearning4j_tpu.parallel.moe import dropless_topk_ffn, topk_route
 
 
@@ -187,6 +188,7 @@ class _PagedCache:
         self.vl = self.vl.at[at].set(v.astype(self.vl.dtype))
 
 
+@COMPILE_STATS.model_build("smallthinker")
 def smallthinker_paged_decode_fns(cfg: SmallThinkerConfig, block_size: int,
                                   max_blocks_per_req: int):
     """``(prefill_fn, decode_fn)`` over the two-tier paged pool, both
@@ -374,6 +376,7 @@ def smallthinker_paged_decode_fns(cfg: SmallThinkerConfig, block_size: int,
     return prefill_fn, decode_fn
 
 
+@COMPILE_STATS.model_build("smallthinker")
 def smallthinker_paged_spec(cfg: SmallThinkerConfig, params):
     """A :class:`~deeplearning4j_tpu.serving.paged.PagedGenerativeSpec`
     over ``params`` (a dict by :func:`smallthinker_param_names`, or a
