@@ -31,6 +31,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from deeplearning4j_tpu.autodiff.staging import stage_fit_state
 from deeplearning4j_tpu.autodiff.variable import SDVariable, VariableType
 from deeplearning4j_tpu.compilecache.aot import (AOTDispatch,
                                                  AOTOutput as _AOTOutput,
@@ -1786,15 +1787,7 @@ class SameDiff:
         from deeplearning4j_tpu.integrity.watchdog import guard as _wd_guard
         # step() donates param/state buffers; work on copies so the graph's
         # stored arrays stay valid for output()/save() during training
-        params = jax.tree_util.tree_map(jnp.copy, self.trainable_params())
-        svars = jax.tree_util.tree_map(jnp.copy, self.state_vars_map())
-        # restored state only reusable if the trainable set hasn't changed
-        # (e.g. convert_to_constant between fits); otherwise re-init
-        if self._updater_state is not None and \
-                set(self._updater_state.keys()) == set(params.keys()):
-            state = jax.tree_util.tree_map(jnp.copy, self._updater_state)
-        else:
-            state = tc.updater.init(params)
+        params, svars, state, staged = stage_fit_state(self, tc)
         constants = self.constants_map()
         iteration = getattr(tc, "iteration_count", 0)
         it_dev = jnp.asarray(iteration, jnp.int32)    # one transfer per fit
@@ -2029,7 +2022,7 @@ class SameDiff:
                 "steps_per_epoch": iteration - epoch_start_iter,
                 "dispatches_per_epoch": iteration - epoch_start_iter,
                 "window_sizes": {1: iteration - epoch_start_iter},
-                "window_compiles": 0}
+                "window_compiles": 0, **staged}
             if listeners:
                 # sync current params/state into the graph (copies — the next
                 # step donates the working buffers) so listeners can save/eval
@@ -2080,16 +2073,10 @@ class SameDiff:
             epoch_step = self.make_train_epoch(
                 unroll=getattr(tc, "scan_unroll", 1) or 1,
                 sentinel=use_sentinel, fingerprint=fp_on)
-        with _tracer.span("fit.stage", cat="train"):
-            params = jax.tree_util.tree_map(jnp.copy,
-                                            self.trainable_params())
-            svars = jax.tree_util.tree_map(jnp.copy, self.state_vars_map())
-            if self._updater_state is not None and \
-                    set(self._updater_state.keys()) == set(params.keys()):
-                state = jax.tree_util.tree_map(jnp.copy,
-                                               self._updater_state)
-            else:
-                state = tc.updater.init(params)
+        with _tracer.span("fit.stage", cat="train") as stage_span:
+            params, svars, state, staged = stage_fit_state(self, tc)
+            stage_span.set(programs=staged["stage_programs"],
+                           leaves=staged["stage_leaves"])
             constants = self.constants_map()
             iteration = getattr(tc, "iteration_count", 0)
             it_dev = jnp.asarray(iteration, jnp.int32)
@@ -2164,7 +2151,7 @@ class SameDiff:
                 "tier": "scanned_epoch", "fused_steps": n_steps,
                 "accum_steps": 1, "steps_per_epoch": n_steps,
                 "dispatches_per_epoch": 1, "window_sizes": {n_steps: 1},
-                "window_compiles": 0}
+                "window_compiles": 0, **staged}
         epoch_step.ran.add(scan_sig)
         # ONE device fetch for all epoch means at fit end
         with _tracer.span("fit.sync", cat="train"):

@@ -48,6 +48,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from deeplearning4j_tpu.autodiff.staging import stage_fit_state
 from deeplearning4j_tpu.compilecache.aot import ph_shape_sig
 from deeplearning4j_tpu.integrity.watchdog import guard as _wd_guard
 from deeplearning4j_tpu.monitor import memstats
@@ -270,13 +271,7 @@ def fit_windowed(sd, dataset_iterator, epochs: int = 1, listeners=()):
                                      fingerprint=fp_on)
     # window_fn donates param/state buffers; work on copies so the
     # graph's stored arrays stay valid for output()/save() mid-fit
-    params = jax.tree_util.tree_map(jnp.copy, sd.trainable_params())
-    svars = jax.tree_util.tree_map(jnp.copy, sd.state_vars_map())
-    if sd._updater_state is not None and \
-            set(sd._updater_state.keys()) == set(params.keys()):
-        state = jax.tree_util.tree_map(jnp.copy, sd._updater_state)
-    else:
-        state = tc.updater.init(params)
+    params, svars, state, staged = stage_fit_state(sd, tc)
     constants = sd.constants_map()
     iteration = int(getattr(tc, "iteration_count", 0))
     it_dev = jnp.asarray(iteration, jnp.int32)
@@ -697,7 +692,7 @@ def fit_windowed(sd, dataset_iterator, epochs: int = 1, listeners=()):
             "dispatches_per_epoch": dispatches,
             "window_sizes": sizes, "window_compiles": compiles,
             "sentinel": use_sentinel, "fingerprints": fp_on,
-            "replay_probes": probes_total}
+            "replay_probes": probes_total, **staged}
         if listeners:
             # sync current training state into the graph (copies — the
             # next window donates the working buffers)

@@ -432,12 +432,14 @@ SPAN_CATALOG: Dict[str, Tuple[str, Tuple[str, ...]]] = {
     "h2d_stage": ("train", ("k",)),
     "integrity.replay_probe": ("integrity", ("k",)),
     # the scanned-epoch tier's boundary (SameDiff._fit_scanned): fit >
-    # fit.stage (copies of parameters, state and updater state; the
-    # stacked batches), fit.dispatch (each scanned epoch), fit.sync
-    # (sentinel and panic reads, the one fetch of the epoch means),
-    # fit.commit (arrays, updater state and counters written back)
+    # fit.stage (the working copies of parameters, state and updater
+    # state, made by `programs` dispatches over `leaves` arrays:
+    # autodiff/staging.py; the stacked batches), fit.dispatch (each
+    # scanned epoch), fit.sync (sentinel and panic reads, the one fetch
+    # of the epoch means), fit.commit (arrays, updater state and
+    # counters written back)
     "fit": ("train", ("tier", "steps", "epochs")),
-    "fit.stage": ("train", ()),
+    "fit.stage": ("train", ("programs", "leaves")),
     # first=1: the dispatch that traces, lowers and compiles or loads
     # the epoch's program; it carries that program's row (trace_s,
     # lower_s, backend_s, cache_hit) and its self time is build_seconds

@@ -24,6 +24,7 @@ from typing import Dict, List, Optional, Sequence
 import numpy as np
 
 from deeplearning4j_tpu.autodiff import SameDiff, TrainingConfig
+from deeplearning4j_tpu.autodiff.staging import stage_fit_state
 from deeplearning4j_tpu.nn.conf import MultiLayerConfiguration
 from deeplearning4j_tpu.nn.layers import (
     BaseLayer, BuildContext, ConvolutionLayer, DenseLayer, EmbeddingLayer,
@@ -306,14 +307,9 @@ class MultiLayerNetwork:
         step = sd.make_train_step(sentinel=use_sentinel)
         window_fn = sd.make_train_window(sentinel=use_sentinel)
         tc = sd.training_config
-        params = jax.tree_util.tree_map(jnp.copy, sd.trainable_params())
-        svars = jax.tree_util.tree_map(jnp.copy, sd.state_vars_map())
-        # persist optimizer state across calls, like fit()
-        if sd._updater_state is not None and \
-                set(sd._updater_state.keys()) == set(params.keys()):
-            state = jax.tree_util.tree_map(jnp.copy, sd._updater_state)
-        else:
-            state = tc.updater.init(params)
+        # working copies (the step and the window donate them); the
+        # optimizer state persists across calls, like fit()
+        params, svars, state, staged = stage_fit_state(sd, tc)
         constants = sd.constants_map()
         iteration = getattr(tc, "iteration_count", 0)
         it_dev = jnp.asarray(iteration, jnp.int32)
@@ -409,6 +405,15 @@ class MultiLayerNetwork:
                 self._sd_train._arrays[sn] = arr   # e.g. BN running stats
         sd._updater_state = state
         tc.iteration_count = iteration
+        # dispatch accounting, as fit()'s tiers give it: a batch is one
+        # window of its full-length chunks and one step of a ragged tail
+        batches = n // batch_size
+        sd.last_fit_stats = self._sd_train.last_fit_stats = {
+            "tier": "tbptt", "fused_steps": max(n_full, 1),
+            "accum_steps": 1,
+            "steps_per_epoch": batches * (n_full + bool(rem)),
+            "dispatches_per_epoch": batches * (bool(n_full) + bool(rem)),
+            "window_compiles": 0, **staged}
         self._score = history.final_loss()
         return history
 
