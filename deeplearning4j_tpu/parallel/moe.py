@@ -152,23 +152,28 @@ def sigmoid_bias_route(x, router_w, bias, k: int, scale: float = 1.0):
     """Dropless top-k routing by sigmoid scores with a correction bias
     (the load balancing without an auxiliary loss of DeepSeek-V3,
     ``noaux_tc`` in the published configurations). x: (N, d); router_w:
-    (d, E); bias: (E,). With ``s = sigmoid(x @ router_w)`` (float32 at
-    the highest precision, as :func:`topk_route` and for its reason) the
-    k experts of a token are those with the largest ``s + bias``; their
-    weights are ``scale * s / (sum of the k s + 1e-20)``: the bias
-    steers the CHOICE and never enters a weight. Returns ``(idx (N, k)
-    int32, weights (N, k) float32, moved (N,) int32)``, the first two as
-    :func:`topk_route` gives them; ``moved`` counts the token's choices
-    that are not among the k largest of ``s`` alone, which is how hard
-    the correction steers."""
+    (d, E); bias: (E,), or None for a router without one (the k largest
+    ``s`` alone; nothing is added). With ``s = sigmoid(x @ router_w)``
+    (float32 at the highest precision, as :func:`topk_route` and for its
+    reason) the k experts of a token are those with the largest ``s +
+    bias``; their weights are ``scale * s / (sum of the k s + 1e-20)``:
+    the bias steers the CHOICE and never enters a weight. Returns
+    ``(idx (N, k) int32, weights (N, k) float32, moved (N,) int32)``,
+    the first two as :func:`topk_route` gives them; ``moved`` counts the
+    token's choices that are not among the k largest of ``s`` alone,
+    which is how hard the correction steers (0 without a bias)."""
     k = int(k)
     logits = jnp.matmul(x.astype(jnp.float32), router_w.astype(jnp.float32),
                         precision=jax.lax.Precision.HIGHEST)
     s = jax.nn.sigmoid(logits)
-    _, idx = jax.lax.top_k(s + bias.astype(jnp.float32), k)
+    _, idx = jax.lax.top_k(s if bias is None
+                           else s + bias.astype(jnp.float32), k)
     chosen = jnp.take_along_axis(s, idx, axis=-1)
     weights = scale * chosen / (jnp.sum(chosen, axis=-1, keepdims=True)
                                 + 1e-20)
+    if bias is None:
+        return idx.astype(jnp.int32), weights, \
+            jnp.zeros(s.shape[0], jnp.int32)
     # a choice the bias moved scores under the k-th largest bare score
     moved = jnp.sum(chosen < jax.lax.top_k(s, k)[0][:, -1:], axis=-1)
     return idx.astype(jnp.int32), weights, moved.astype(jnp.int32)

@@ -16,6 +16,7 @@ from deeplearning4j_tpu.zoo.bert import BERT_BASE, BERT_TINY, BertConfig, bert_b
 from deeplearning4j_tpu.zoo.gpt import GPT_MEDIUM, GPT_TINY, GPTConfig, build_gpt
 from deeplearning4j_tpu.zoo.smallthinker import SmallThinkerConfig
 from deeplearning4j_tpu.zoo.glm_moe_lite import GlmMoeLiteConfig
+from deeplearning4j_tpu.zoo.cohere2_moe import Cohere2MoeConfig
 
 __all__ = ["LeNet", "SimpleCNN", "AlexNet", "VGG16", "ResNet50",
            "TextGenLSTM", "TransformerEncoder", "SqueezeNet", "UNet",
@@ -23,4 +24,4 @@ __all__ = ["LeNet", "SimpleCNN", "AlexNet", "VGG16", "ResNet50",
            "FaceNet", "NASNet", "YOLO2", "BertConfig", "BERT_BASE",
            "BERT_TINY", "bert_base", "GPTConfig", "GPT_MEDIUM", "GPT_TINY",
            "build_gpt", "SmallThinkerConfig",
-           "GlmMoeLiteConfig"]
+           "GlmMoeLiteConfig", "Cohere2MoeConfig"]

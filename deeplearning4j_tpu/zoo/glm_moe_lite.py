@@ -65,6 +65,8 @@ from deeplearning4j_tpu.parallel.moe import (GROUPED_TILE_COLS,
                                              dropless_topk_ffn,
                                              sigmoid_bias_route,
                                              tiled_grouped_dot)
+from deeplearning4j_tpu.zoo.paged_attend import (NEG, over_spans,
+                                                 softmax_merge)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -342,20 +344,14 @@ def glm_moe_lite_paged_decode_fns(cfg: GlmMoeLiteConfig, block_size: int,
         qf = jnp.concatenate(
             [q_lat, q_rope, jnp.zeros(q_rope.shape[:-1] + (PAD,))],
             axis=-1).astype(dt)
-        neg = jnp.float32(-1e30)
+        neg = jnp.float32(NEG)
 
         def merge(carry, s, seen, rows):
             """One more set of scores ``s [R, A, Q, T]`` over ``rows [R,
-            T, W]`` into the running softmax: the largest score so far,
-            the sum of the weights under it and the weighted rows."""
-            top, total, o = carry
-            new = jnp.maximum(top, jnp.max(jnp.where(seen, s, neg), axis=-1))
-            e = jnp.where(seen, jnp.exp(s - new[..., None]),
-                          jnp.float32(0.0))
-            keep = jnp.exp(top - new)
-            return (new, total * keep + jnp.sum(e, axis=-1),
-                    o * keep[..., None]
-                    + _ein("raqt,rtw->raqw", e.astype(dt), rows))
+            T, W]`` into the running softmax (``paged_attend``)."""
+            return softmax_merge(
+                carry, s, seen,
+                lambda e: _ein("raqt,rtw->raqw", e.astype(dt), rows), neg)
 
         def over_cached(i, carry):
             rows, held = cache.read(i * span, span)
@@ -364,14 +360,8 @@ def glm_moe_lite_paged_decode_fns(cfg: GlmMoeLiteConfig, block_size: int,
 
         carry = (jnp.full((R, A, Q), neg), jnp.zeros((R, A, Q), jnp.float32),
                  jnp.zeros((R, A, Q, W), jnp.float32))
-        spans = cache.table.shape[1] // span
-        if spans == 1:
-            carry = over_cached(0, carry)
-        else:
-            held_rows = jnp.max(cache.hist)
-            carry = jax.lax.fori_loop(
-                0, (held_rows + span * BS - 1) // (span * BS), over_cached,
-                carry)
+        carry = over_spans(over_cached, carry, cache.table.shape[1] // span,
+                           span * BS, cache.hist)
         # a row sees itself whether or not it is valid, so that an idle
         # lane or a padded row has a finite result (it lands in the null
         # block, which every table's unused entries point at)

@@ -5,14 +5,19 @@ The server is stood up through the benchmark's own adapter and its own
 ``warmup``, with ``AOTDispatch.lower`` wrapped to keep ``as_text()`` and
 hand back a stand-in for the executable, so nothing is compiled or run
 and no chip is needed (the weights are drawn at the cell's real size:
-some 12 GB of host memory, a minute or two). Run on the CPU from the
-root of a checkout, once in the parent's and once in the change's:
+some 12 GB of host memory, a minute or two; with ``shapes`` for the
+directory the weights are their shapes alone, ``jax.eval_shape`` of the
+adapter's own draw, and a cell at its real size lowers in a few hundred
+MB: compare two trees in the same mode). Run on the CPU from the root
+of a checkout, once in the parent's and once in the change's:
 ``JAX_PLATFORMS=cpu PYTHONPATH=. python experiments/lowered_text.py
-<cell> [<dir for the texts>]``; prints one JSON object."""
+<cell> [<dir for the texts> | shapes]``; prints one JSON object."""
 import hashlib
 import json
 import os
 import sys
+
+import jax
 
 from benchmark import harness
 from deeplearning4j_tpu.compilecache import aot
@@ -41,6 +46,11 @@ def main(cell_name: str, out_dir: str = "") -> None:
     aot.AOTDispatch.lower = lower
     memstats.capture_plan = lambda *a, **k: None    # wants an executable
     cell = harness.Cell(os.getcwd(), cell_name)
+    if out_dir == "shapes":
+        out_dir, draw = "", cell.adapter.program_params
+        cell.adapter.program_params = lambda cfg, seed: jax.eval_shape(
+            lambda: draw(cfg, seed))
+        memstats.check_headroom = lambda *a, **k: None
     server = cell.adapter.build_server(cell.config, cell.traffic["server"],
                                        seed=1234567891)
     server.shutdown(drain=False)

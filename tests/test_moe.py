@@ -454,3 +454,77 @@ def test_the_layer_takes_a_named_grouped_product():
         with pytest.raises(ValueError, match="whole tiles"):
             tiled_grouped_dot(x[:rows], up[:, :, :cols],
                               jnp.zeros(e, jnp.int32))
+
+
+# ----------------------------------------------------------------------
+# a router without a correction bias, and a chip that holds a few of the
+# experts it routes over (Command A+ at one chip's share of a layer)
+def test_the_sigmoid_router_without_a_bias_takes_the_k_largest_sigmoids():
+    """``bias=None``: the k largest sigmoids, weighed as the rule says;
+    what a bias of zeros chooses, with nothing added to the scores and
+    ``moved`` 0."""
+    x, router, bias = _biased_router(13)
+    idx, w, moved = sigmoid_bias_route(x, router, None, 3)
+    want_idx, want_w, _ = _route_loop(x, router, np.zeros(8), 3, 1.0)
+    order = np.argsort(np.asarray(idx), axis=1)
+    np.testing.assert_array_equal(
+        np.take_along_axis(np.asarray(idx), order, 1),
+        np.sort(want_idx, axis=1))
+    np.testing.assert_allclose(
+        np.take_along_axis(np.asarray(w), order, 1),
+        np.take_along_axis(want_w, np.argsort(want_idx, axis=1), 1),
+        rtol=1e-5)
+    assert moved.shape == (x.shape[0],) and not np.asarray(moved).any()
+    zero_idx, zero_w, _ = sigmoid_bias_route(x, router, jnp.zeros(8), 3)
+    np.testing.assert_array_equal(np.asarray(idx), np.asarray(zero_idx))
+    np.testing.assert_array_equal(np.asarray(w), np.asarray(zero_w))
+
+    def adds(b):
+        jaxpr = jax.make_jaxpr(
+            lambda v: sigmoid_bias_route(v, router, b, 3))(x)
+        return sum(e.primitive.name == "add" for e in jaxpr.eqns)
+    assert adds(None) < adds(jnp.zeros(8))
+
+
+@pytest.mark.parametrize("first,held,grouped", [
+    (0, 2, None), (6, 2, None), (4, 4, None), (2, 2, "tiled")])
+def test_a_chip_that_holds_few_of_the_routed_experts_gives_their_part(
+        first, held, grouped):
+    """16 experts under a sigmoid router without a bias, 4 a token; a
+    chip holds ``held`` of them from ``first``: its part is, token by
+    token, the chosen experts it holds and no other, whatever the others
+    were chosen, and it serves exactly the pairs routed to its experts.
+    With the tiled grouped product (interpreted here) the pairs routed
+    elsewhere, most of the rows, ride behind the last held expert and
+    give nothing."""
+    rng = np.random.default_rng(14 + first)
+    n, e, k = 64, 16, 4
+    d = f = 512 if grouped else 8
+    gate, up, down = (jnp.asarray(a, jnp.float32)
+                      for a in _reglu_experts(rng, d, f, e))
+    x = jnp.asarray(rng.normal(size=(n, d)), jnp.float32)
+    idx, w, _ = sigmoid_bias_route(
+        x, jnp.asarray(rng.normal(size=(d, e)) * 0.3, jnp.float32), None, k)
+    mine = slice(first, first + held)
+    y, served = dropless_topk_ffn(
+        x, idx, w, gate[mine], up[mine], down[mine], first_expert=first,
+        activation=jax.nn.silu,
+        grouped=tiled_grouped_dot if grouped else None)
+    i64 = np.asarray(idx)
+    in_share = (i64 >= first) & (i64 < first + held)
+    assert 0 < int(served.sum()) == int(in_share.sum()) < n * k
+    np.testing.assert_array_equal(
+        np.asarray(served),
+        np.bincount(i64[in_share] - first, minlength=held))
+    x64, g64, u64, d64 = (np.asarray(a, np.float64)
+                          for a in (x, gate, up, down))
+    want = np.zeros((n, d))
+    for t in range(n):
+        for ex, we in zip(i64[t], np.asarray(w[t])):
+            if first <= ex < first + held:
+                h = _silu(x64[t] @ g64[ex]) * (x64[t] @ u64[ex])
+                want[t] += float(we) * (h @ d64[ex])
+    tol = 2e-2 * np.abs(want).max() if grouped else 1e-5
+    np.testing.assert_allclose(np.asarray(y), want, rtol=1e-4, atol=tol)
+    # a token that chose none of the held experts gets nothing here
+    assert not np.asarray(y)[~in_share.any(axis=1)].any()
