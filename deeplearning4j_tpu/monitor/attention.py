@@ -1,4 +1,5 @@
-"""Which path each attention site of a traced train step took.
+"""Which path each attention site of a traced train step, or of a
+paged decode program, took.
 
 ``scaled_dot_product_attention`` (ops/nn_ops.py) computes attention
 tile by tile where its inputs and the program's place allow it, and
@@ -12,6 +13,11 @@ arrays span, and hands it to the op through
 site. The newest record is ``sd.attention_sites`` on the model and
 :func:`last_train_step` here, for a report, ``chip_smoke.py`` or a test
 to read without a profiler trace.
+
+A paged decode program that can read its pages in place through a kernel
+(``zoo.cohere2_moe``, ``zoo.paged_attend.kernel_refusal``) keeps the same
+record, one site a layer, opened each time such a program is traced:
+:func:`last_decode_program`.
 """
 from __future__ import annotations
 
@@ -21,7 +27,8 @@ from typing import Optional
 
 @dataclasses.dataclass
 class AttentionSites:
-    """The attention sites of ONE traced train step.
+    """The attention sites of ONE traced program (a train step, or a
+    paged decode program).
 
     ``devices`` is what the tracer of the step knows and the op cannot
     see from its arrays: how many devices the program is traced for.
@@ -50,6 +57,7 @@ class AttentionSites:
 
 
 _LAST: Optional[AttentionSites] = None
+_LAST_DECODE: Optional[AttentionSites] = None
 
 
 def open_train_step(devices: int) -> AttentionSites:
@@ -66,4 +74,21 @@ def last_train_step() -> Optional[AttentionSites]:
     return _LAST
 
 
-__all__ = ["AttentionSites", "open_train_step", "last_train_step"]
+def open_decode_program() -> AttentionSites:
+    """A fresh record for a paged decode program that is about to be
+    traced; it becomes :func:`last_decode_program`."""
+    global _LAST_DECODE
+    _LAST_DECODE = AttentionSites()
+    return _LAST_DECODE
+
+
+def last_decode_program() -> Optional[AttentionSites]:
+    """The record of the paged decode program traced last in this
+    process (None before any): ``counts()`` is (layers that read their
+    pages through the kernel, layers that gathered the table, why the
+    first of those did)."""
+    return _LAST_DECODE
+
+
+__all__ = ["AttentionSites", "open_train_step", "last_train_step",
+           "open_decode_program", "last_decode_program"]

@@ -35,13 +35,23 @@ The stream, the norms, the router's product (float32 at the highest
 precision) and the softmax are float32; every other product takes its
 operands in the dtype the parameters are handed over in (bfloat16 as
 published) and accumulates in float32, and K and V are cached in that
-dtype. The K/V of window and global layers live in two tiers of the
-paged pool (``serving.paged.KVTier``), as SmallThinker's do; the block is
-handed a cache that reads its layer's rows a span of table entries at a
-time into a running softmax (``zoo.paged_attend``), so that a prefill
-chunk of 512 queries over 128 heads never holds the scores of a whole
-context. Fresh rows are attended to as they will lie in the cache
-(rounded to its dtype) and written afterwards.
+dtype. A layer caches ONE row a token, its K and V heads interleaved
+(``[k0, v0, k1, v1, ...]``, each ``head_dim`` wide: one
+``serving.paged.KVLeaf`` without heads), and the rows of window and
+global layers live in two tiers of the paged pool
+(``serving.paged.KVTier``), as SmallThinker's do. The decode program
+writes a step's fresh rows first and then reads each lane's own pages
+in place through a Pallas kernel with the contract of JAX's ragged
+paged-attention kernel (``zoo.paged_attend.paged_decode``), where the
+chip can take it
+(``paged_attend.kernel_refusal``; ``monitor.attention
+.last_decode_program()`` says which way the program went). Elsewhere,
+and in the prefill program, the block is handed a cache that reads its
+layer's rows a span of table entries at a time into a running softmax
+(``zoo.paged_attend``), so that a prefill chunk of 512 queries over 128
+heads never holds the scores of a whole context; fresh rows are there
+attended to as they will lie in the cache (rounded to its dtype) and
+written afterwards.
 
 There is no training graph for this block; :func:`cohere2_moe_paged_spec`
 serves parameters handed over by name.
@@ -54,8 +64,10 @@ from typing import Dict, Tuple
 import numpy as np
 
 from deeplearning4j_tpu.compilecache.cache import COMPILE_STATS
+from deeplearning4j_tpu.monitor import attention
 from deeplearning4j_tpu.parallel.moe import (dropless_topk_ffn,
                                              sigmoid_bias_route)
+from deeplearning4j_tpu.zoo import paged_attend
 from deeplearning4j_tpu.zoo.paged_attend import (NEG, over_spans,
                                                  softmax_merge)
 
@@ -196,12 +208,16 @@ class Cohere2MoeConfig:
 #: what the decode program counts a step, summed over its layers:
 #: SmallThinker's four under their names (layers run, HELD experts with a
 #: token of an active lane, the (token, expert) pairs the routers chose
-#: over ALL the router's experts, the fullest held expert's tokens) and
-#: the pairs whose expert is held here (docs/serving.md says what an
-#: operator reads from each)
+#: over ALL the router's experts, the fullest held expert's tokens), the
+#: pairs whose expert is held here, and the pages the active lanes'
+#: attention read (the kernel: each lane's own, from the rows it was
+#: handed; the plain path: every entry of the table it gathered);
+#: ``block_size`` x the last over the server's ``kv_rows_gathered_sum``
+#: is the share of what the tables sent that was read (docs/serving.md
+#: says what an operator reads from each)
 PROGRAM_COUNTERS = ("moe_layer_steps", "moe_experts_touched_sum",
                     "moe_tokens_routed_sum", "moe_peak_expert_tokens_sum",
-                    "moe_held_pairs_sum")
+                    "moe_held_pairs_sum", "kv_pages_read_sum")
 
 #: table entries a prefill run reads its cached rows through at a time,
 #: as GLM-4.7-Flash's (``glm_moe_lite.PREFILL_SPAN``): the scores of a
@@ -237,21 +253,22 @@ def cohere2_moe_param_names(cfg: Cohere2MoeConfig):
 
 
 class _PagedCache:
-    """What a block sees of the paged pool: its layer's leaves ``kl``,
-    ``vl`` ``[num_blocks, block_size, kv_heads * head_dim]``, its tier's
-    ``table [R, entries]`` (R requests in the program), ``hist`` [R], how
-    many positions each request has cached, and where the N fresh rows
-    (request-major) go: ``(write_block [N], write_off [N])``."""
+    """What a block sees of the paged pool: its layer's leaf ``kv``
+    ``[num_blocks, block_size, 2 * kv_heads * head_dim]`` (K and V
+    interleaved head by head), its tier's ``table [R, entries]`` (R
+    requests in the program), ``hist`` [R], how many positions each
+    request has cached, and where the N fresh rows (request-major) go:
+    ``(write_block [N], write_off [N])``."""
 
-    def __init__(self, kl, vl, table, write_block, hist, write_off,
+    def __init__(self, kv, table, write_block, hist, write_off,
                  block_size):
-        self.kl, self.vl, self.table = kl, vl, table
+        self.kv, self.table = kv, table
         self.write_block, self.write_off = write_block, write_off
         self.hist, self.BS = hist, int(block_size)
 
     def read(self, first, entries: int, counted_from):
         """The rows of ``entries`` entries of every request's table from
-        entry ``first`` (which may be traced): ``K, V [R, T, kv_heads *
+        entry ``first`` (which may be traced): ``[R, T, 2 * kv_heads *
         head_dim]``, the position of each row ``[R, T]`` and whether it
         is one of the request's cached positions ``[R, T]`` (an entry
         below ``counted_from``, which an earlier span read, counts as
@@ -272,15 +289,17 @@ class _PagedCache:
                + jnp.arange(self.BS, dtype=jnp.int32)[None, None])
         held = (u >= 0)[:, :, None] & (pos < self.hist[:, None, None]) \
             & (e >= counted_from)[None, :, None]
-        K = self.kl[part].reshape(R, n * self.BS, -1)
-        V = self.vl[part].reshape(R, n * self.BS, -1)
-        return K, V, pos.reshape(R, -1), held.reshape(R, -1)
+        rows = self.kv[part].reshape(R, n * self.BS, -1)
+        return rows, pos.reshape(R, -1), held.reshape(R, -1)
 
     def write(self, k, v):
-        """The fresh rows ``k, v [N, kv_heads * head_dim]``, in place."""
-        at = (self.write_block, self.write_off)
-        self.kl = self.kl.at[at].set(k.astype(self.kl.dtype))
-        self.vl = self.vl.at[at].set(v.astype(self.vl.dtype))
+        """The fresh rows ``k, v [N, kv_heads, head_dim]``, interleaved,
+        in place."""
+        import jax.numpy as jnp
+        N = k.shape[0]
+        row = jnp.stack([k, v], axis=2).reshape(N, -1)
+        self.kv = self.kv.at[self.write_block, self.write_off].set(
+            row.astype(self.kv.dtype))
 
 
 def _programs(cfg: Cohere2MoeConfig, block_size: int,
@@ -301,6 +320,8 @@ def _programs(cfg: Cohere2MoeConfig, block_size: int,
         cfg.rope_theta ** (-np.arange(0, D, 2, dtype=np.float64) / D),
         jnp.float32)
     tier_of = {i: t for t in cfg.kv_tiers() for i in t.layers}
+    widest = max(t.table_blocks(BS, max_blocks_per_req)
+                 for t in cfg.kv_tiers())
 
     def _layernorm(x, g):
         x = x.astype(jnp.float32)
@@ -335,7 +356,7 @@ def _programs(cfg: Cohere2MoeConfig, block_size: int,
         running softmax; 0 is the whole table in one read. Returns ``[R,
         Q, A * D]``."""
         R, Q = qpos.shape
-        dt = cache.kl.dtype
+        dt = cache.kv.dtype
         qg = q.reshape(R, Q, KV, G, D).astype(dt)
         E = cache.table.shape[1]
         span = E if not span else min(int(span), E)
@@ -348,9 +369,10 @@ def _programs(cfg: Cohere2MoeConfig, block_size: int,
 
         def over_cached(i, carry):
             first = jnp.minimum(i * span, E - span)
-            Kc, Vc, cpos, held = cache.read(first, span, i * span)
+            rows, cpos, held = cache.read(first, span, i * span)
             T = cpos.shape[1]
-            Kc, Vc = Kc.reshape(R, T, KV, D), Vc.reshape(R, T, KV, D)
+            rows = rows.reshape(R, T, KV, 2, D)
+            Kc, Vc = rows[:, :, :, 0], rows[:, :, :, 1]
             seen = held[:, None, :]
             if window:
                 seen = seen & near(cpos)
@@ -379,18 +401,36 @@ def _programs(cfg: Cohere2MoeConfig, block_size: int,
         o = o / total[..., None]                           # [R, KV, G, Q, D]
         return jnp.transpose(o, (0, 3, 1, 2, 4)).reshape(R, Q, A * D)
 
-    def _block(lp, x, qpos, valid, kl, vl, table, wb, hist, write_off,
-               window, span):
+    def _attend_paged(window, q, qpos, valid, cache):
+        """One query a request, ``q [R, 1, A, D]``, over its own pages
+        in place, its fresh row already written: ``([R, 1, A * D], the
+        pages the active requests read)``."""
+        R = q.shape[0]
+        pages, rows = paged_attend.decode_pages(
+            cache.table, qpos[:, 0], valid[:, 0], cache.write_block, BS,
+            ring=bool(window))
+        o = paged_attend.paged_decode(
+            q[:, 0].astype(cache.kv.dtype), cache.kv, pages, rows, scale,
+            W if window else None, width=widest)
+        read = jnp.sum(jnp.where(valid[:, 0], -(-rows // BS), 0),
+                       dtype=jnp.int32)
+        return o.reshape(R, 1, A * D), read
+
+    def _block(lp, x, qpos, valid, kv, table, wb, hist, write_off,
+               window, span, paged):
         """One layer on the stream ``x [R, Q, H]`` (R requests, Q fresh
         rows each): ``lp`` its parameters under their names within the
-        layer, ``kl``/``vl`` its leaves, ``(table, wb)`` its tier's.
+        layer, ``kv`` its leaf, ``(table, wb)`` its tier's; ``paged``
+        (one query a request) reads the pages through the kernel.
         Returns the stream, what its router did (the tokens each held
         expert served ``[held]`` and, behind them, the pairs the router
-        chose over all its experts) and the leaves. Jitted on its own
-        (``window`` and ``span`` static), so that a program's trace and
-        lowering hold each kind of layer once and call it: the decode
-        program is built once a table width."""
-        cache = _PagedCache(kl, vl, table, wb, hist, write_off, BS)
+        chose over all its experts), the pages its attention read (the
+        requests with a valid row: their own through the kernel, the
+        table's width each on the plain path) and the leaf. Jitted on its
+        own (``window``, ``span`` and ``paged`` static), so that a
+        program's trace and lowering hold each kind of layer once and
+        call it: the decode program is built once a table width."""
+        cache = _PagedCache(kv, table, wb, hist, write_off, BS)
         R, Q, _ = x.shape
         n = _layernorm(x, lp["/norm"])
         flat, ok = n.reshape(R * Q, H), valid.reshape(R * Q)
@@ -400,8 +440,14 @@ def _programs(cfg: Cohere2MoeConfig, block_size: int,
         v = _mm(n, lp["/attn/v"]).reshape(R, Q, KV, D)
         if window:
             q, k = _rope(q, qpos), _rope(k, qpos)
-        att = _attend(window, q, k, v, qpos, valid, cache, span)
-        cache.write(k.reshape(R * Q, KV * D), v.reshape(R * Q, KV * D))
+        if paged:
+            cache.write(k.reshape(R * Q, KV, D), v.reshape(R * Q, KV, D))
+            att, read = _attend_paged(window, q, qpos, valid, cache)
+        else:
+            att = _attend(window, q, k, v, qpos, valid, cache, span)
+            cache.write(k.reshape(R * Q, KV, D), v.reshape(R * Q, KV, D))
+            read = jnp.sum(jnp.any(valid, axis=1), dtype=jnp.int32) \
+                * table.shape[1]
         # the EXPERTS, on the same n: the router over all its experts,
         # the held experts' part, the shared experts' mean. The grouped
         # products are ragged_dot's at every run, a prefill chunk's too:
@@ -423,28 +469,30 @@ def _programs(cfg: Cohere2MoeConfig, block_size: int,
         did = jnp.concatenate(
             [served, (jnp.sum(ok, dtype=jnp.int32) * K)[None]])
         x = x + _mm(att, lp["/attn/o"]) + y.reshape(R, Q, H)
-        return x, did, cache.kl, cache.vl
+        return x, did, read, cache.kv
 
-    block = jax.jit(_block, static_argnames=("window", "span"))
+    block = jax.jit(_block, static_argnames=("window", "span", "paged"))
 
-    def _stack(p, tokens, qpos, valid, kc, vc, tiers, hist, write_off,
-               span):
-        """Every layer over the stream; ``tiers`` maps a layer to its
-        tier's ``(table, write_block)``. Returns the stream (before the
-        last norm), what each layer's router did ``[L, held + 1]``, and
-        the leaves."""
-        kc, vc = list(kc), list(vc)
+    def _stack(p, tokens, qpos, valid, kc, tiers, hist, write_off, span,
+               paged=False):
+        """Every layer over the stream; ``kc`` is one leaf a layer,
+        ``tiers`` maps a layer to its tier's ``(table, write_block)``.
+        Returns the stream (before the last norm), what each layer's
+        router did ``[L, held + 1]``, the pages the layers' attention
+        read, summed, and the leaves."""
+        kc = list(kc)
         x = jnp.take(p["embed"], tokens, axis=0).astype(jnp.float32)
-        did = []
+        did, read = [], jnp.int32(0)
         for i in range(L):
             sc = f"h{i}"
             lp = {n[len(sc):]: a for n, a in p.items()
                   if n.startswith(sc + "/")}
-            x, d, kc[i], vc[i] = block(
-                lp, x, qpos, valid, kc[i], vc[i], *tiers[i], hist,
-                write_off, window=bool(cfg.window_layout[i]), span=span)
+            x, d, r, kc[i] = block(
+                lp, x, qpos, valid, kc[i], *tiers[i], hist, write_off,
+                window=bool(cfg.window_layout[i]), span=span, paged=paged)
             did.append(d)
-        return x, jnp.stack(did), tuple(kc), tuple(vc)
+            read = read + r
+        return x, jnp.stack(did), read, tuple(kc)
 
     def _head(p, x):
         """The last norm and the tied head on the stream ``x [..., H]``."""
@@ -472,8 +520,8 @@ def _programs(cfg: Cohere2MoeConfig, block_size: int,
         Lb = tokens.shape[0]
         g = hist + jnp.arange(Lb, dtype=jnp.int32)
         valid = jnp.arange(Lb) < length
-        x, _, kc, vc = _stack(
-            params, tokens[None], g[None], valid[None], kc, vc,
+        x, _, _, kc = _stack(
+            params, tokens[None], g[None], valid[None], kc,
             _tiers(io, "table", lambda t: t[None]), hist[None], g % BS,
             PREFILL_SPAN)
         h_last = jax.lax.dynamic_slice_in_dim(
@@ -483,16 +531,23 @@ def _programs(cfg: Cohere2MoeConfig, block_size: int,
 
     def decode_fn(params, kc, vc, io):
         tokens, pos, active = io["tokens"], io["positions"], io["active"]
-        x, did, kc, vc = _stack(
-            params, tokens[:, None], pos[:, None], active[:, None], kc, vc,
-            _tiers(io, "tables", lambda t: t), pos, io["write_off"], 0)
+        # the way the context is read, decided once a traced program from
+        # what it can see (the backend, the leaf's dtype, the heads)
+        why = paged_attend.kernel_refusal(kc[0].dtype, A, KV, D)
+        sites = attention.open_decode_program()
+        for _ in range(L):
+            sites.note(why)
+        x, did, read, kc = _stack(
+            params, tokens[:, None], pos[:, None], active[:, None], kc,
+            _tiers(io, "tables", lambda t: t), pos, io["write_off"], 0,
+            paged=why is None)
         logits = _head(params, x[:, 0])
         served = did[:, :-1]
         counted = jnp.stack([                  # PROGRAM_COUNTERS' order
             jnp.int32(L), jnp.sum(served > 0, dtype=jnp.int32),
             jnp.sum(did[:, -1], dtype=jnp.int32),
             jnp.sum(jnp.max(served, axis=1), dtype=jnp.int32),
-            jnp.sum(served, dtype=jnp.int32)])
+            jnp.sum(served, dtype=jnp.int32), read])
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         return kc, vc, jnp.concatenate([nxt, counted]), logits
 
@@ -503,12 +558,12 @@ def _programs(cfg: Cohere2MoeConfig, block_size: int,
         row fresh (its K and V go to a block of its own and are
         dropped)."""
         T = tokens.shape[0]
-        leaf = jnp.zeros((1, BS, KV * D), params["embed"].dtype)
+        leaf = jnp.zeros((1, BS, 2 * KV * D), params["embed"].dtype)
         none = (jnp.zeros((1, 1), jnp.int32), jnp.zeros((T,), jnp.int32))
         g = jnp.arange(T, dtype=jnp.int32)
         x, did, _, _ = _stack(params, tokens[None], g[None],
                               jnp.ones((1, T), bool), (leaf,) * L,
-                              (leaf,) * L, dict.fromkeys(range(L), none),
+                              dict.fromkeys(range(L), none),
                               jnp.zeros((1,), jnp.int32), g % BS, 0)
         return _head(params, x[0]), x[0], did
 
@@ -520,9 +575,12 @@ def _programs(cfg: Cohere2MoeConfig, block_size: int,
 def cohere2_moe_paged_decode_fns(cfg: Cohere2MoeConfig, block_size: int,
                                  max_blocks_per_req: int):
     """``(prefill_fn, decode_fn)`` over the two-tier paged pool, both
-    ``fn(params, kc, vc, io)`` with ``kc``/``vc`` a tuple of one leaf a
-    layer, donated and returned. With ``<t>`` a tier's name (``global``,
-    ``window``; :meth:`Cohere2MoeConfig.kv_tiers`):
+    ``fn(params, kc, vc, io)`` with ``kc`` a tuple of one leaf a layer
+    ``[num_blocks, block_size, 2 * kv_heads * head_dim]`` (K and V
+    interleaved head by head), donated and returned, and ``vc`` ``()``
+    (the pool's second side, empty for a spec of one leaf). With ``<t>``
+    a tier's name (``global``, ``window``;
+    :meth:`Cohere2MoeConfig.kv_tiers`):
 
     - ``prefill_fn``: ``io = {"tokens": [Lb] (a run of the prompt,
       padded to its bucket), "length": () real tokens of the run,
@@ -536,9 +594,14 @@ def cohere2_moe_paged_decode_fns(cfg: Cohere2MoeConfig, block_size: int,
       "tables.<t>": [S, E_t] (a window tier's ring: its ``entries_t``;
       the global tier: any ``E_t <= entries_t`` that holds every active
       lane's blocks, read whole), "write_block.<t>": [S], "write_off":
-      [S]}``; returns ``(kc, vc, next [S + 5], logits [S, vocab])``:
+      [S]}``; returns ``(kc, vc, next [S + 6], logits [S, vocab])``:
       behind the S next tokens come the step's :data:`PROGRAM_COUNTERS`
-      (idle lanes route nothing).
+      (idle lanes route and read nothing). Each layer writes the step's
+      fresh rows and then reads every active lane's own pages through
+      the decode kernel (the global table as handed; the ring from the
+      oldest block its window can see), or, where
+      ``paged_attend.kernel_refusal`` names a reason, gathers the whole
+      table as the prefill program does.
     """
     fns = _programs(cfg, block_size, max_blocks_per_req)
     return fns["prefill_fn"], fns["decode_fn"]
@@ -558,9 +621,13 @@ def cohere2_moe_paged_spec(cfg: Cohere2MoeConfig, params):
     """A :class:`~deeplearning4j_tpu.serving.paged.PagedGenerativeSpec`
     over ``params`` (a dict by :func:`cohere2_moe_param_names`, or a
     callable that gives one: ``update_model`` calls it again). K and V
-    are cached in the dtype of the parameters, on the two tiers of
-    :meth:`Cohere2MoeConfig.kv_tiers`."""
-    from deeplearning4j_tpu.serving.paged import PagedGenerativeSpec
+    are cached in the dtype of the parameters, as ONE leaf a layer
+    (``kv``: the heads' K and V interleaved, ``2 x kv_heads x head_dim``
+    wide, the bytes of a K leaf and a V leaf), on the two tiers of
+    :meth:`Cohere2MoeConfig.kv_tiers`. A leaf without heads: ``tp > 1``,
+    int8 rows and a dense draft are refused
+    (``serving.paged.KVLeafUnsupportedError``)."""
+    from deeplearning4j_tpu.serving.paged import KVLeaf, PagedGenerativeSpec
     pull = params if callable(params) else (lambda: params)
     got, want = pull(), cohere2_moe_param_shapes(cfg)
     if set(got) != set(want):
@@ -578,7 +645,8 @@ def cohere2_moe_paged_spec(cfg: Cohere2MoeConfig, params):
         vocab_size=cfg.vocab_size, max_seq_len=cfg.max_seq_len,
         num_heads=cfg.num_kv_heads,
         kv_dtype=np.dtype(got["embed"].dtype).name,
-        kv_tiers=cfg.kv_tiers(), program_counters=PROGRAM_COUNTERS)
+        kv_tiers=cfg.kv_tiers(), program_counters=PROGRAM_COUNTERS,
+        kv_leaves=(KVLeaf("kv", 2 * cfg.num_kv_heads * cfg.head_dim),))
 
 
 __all__ = ["Cohere2MoeConfig", "Cohere2MoeUnsupportedError",

@@ -144,3 +144,83 @@ def test_a_gpt_step_at_the_cells_widths_holds_no_score_matrix(one_chip,
     assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"",
                           text)) == 6
     assert "[16,16,1024,1024]" not in text
+
+
+@pytest.mark.parametrize("entries,window", [(672, None), (257, 4096)])
+def test_the_paged_decode_kernel_compiles_for_a_v5e(one_chip, as_tpu,
+                                                    entries, window):
+    """Command A+'s decode read at the cell's shapes (32 lanes, 128 query
+    heads over 8 K/V heads of 128, the pool's interleaved leaf in blocks
+    of 16, bf16): the global table's 672 entries, the ring's 257 under
+    the window of 4,096. The leaf is read in place: no copy of it into
+    another layout (JAX's shipped kernel, over the same leaf as its
+    ``[pages, 16, 16, 128]``, gets one: the last lines)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental.pallas.ops.tpu.ragged_paged_attention import \
+        ragged_paged_attention
+    from deeplearning4j_tpu.zoo import paged_attend
+    nb = 32 * (672 if window is None else 289) + 1
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=one_chip)
+    args = (sds((32, 128, 128), jnp.bfloat16),
+            sds((nb, 16, 2048), jnp.bfloat16),
+            sds((32, entries), jnp.int32), sds((32,), jnp.int32))
+    relayout = rf"= bf16\[({nb}|{2 * nb}),[0-9,]+\]\S* (copy|fusion)"
+    text = jax.jit(
+        lambda q, leaf, pages, rows: paged_attend.paged_decode(
+            q, leaf, pages, rows, 128 ** -0.5, window)).lower(
+        *args).compile().as_text()
+    assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"",
+                          text)) == 1
+    assert not re.search(relayout, text)
+    shipped = jax.jit(
+        lambda q, leaf, pages, rows: ragged_paged_attention(
+            q, leaf.reshape(nb, 16, 16, 128), rows, pages,
+            jnp.arange(33, dtype=jnp.int32), jnp.full((1,), 32, jnp.int32),
+            sm_scale=128 ** -0.5, sliding_window=window,
+            num_kv_pages_per_block=16, num_queries_per_block=1)).lower(
+        *args).compile().as_text()
+    assert re.search(relayout, shipped)
+
+
+def test_the_cells_decode_program_reads_its_pages_in_place(one_chip,
+                                                           as_tpu):
+    """The whole decode program of `cmda_mixed_closed` (4 layers at the
+    published widths, 16 experts held, 32 lanes, the global table at its
+    top rung), lowered for one v5e: every layer takes the decode kernel,
+    no table is gathered (no ``[32, 672, 16, ...]`` or ``[32, 257, 16,
+    ...]`` array) and no leaf is copied, so the temporaries stay under
+    0.5 GB (0.29; the gathered tables took 1.60 GB by the same compile,
+    the shipped kernel's copy of every leaf into its layout 1.42)."""
+    import json
+    import os
+    import jax
+    import jax.numpy as jnp
+    from deeplearning4j_tpu.monitor import attention
+    from deeplearning4j_tpu.zoo import cohere2_moe as zoo
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "command-a-plus-05-2026.json")) as f:
+        pc = zoo.Cohere2MoeConfig.from_dict(json.load(f))
+    S = 32
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=one_chip)
+    params = {n: sds(s, jnp.bfloat16)
+              for n, s in zoo.cohere2_moe_param_shapes(pc).items()}
+    kc = tuple(sds(((32 * 289 if w else 32 * 672) + 1, 16, 2048),
+                   jnp.bfloat16) for w in pc.window_layout)
+    io = {"tokens": sds((S,), jnp.int32), "positions": sds((S,), jnp.int32),
+          "write_off": sds((S,), jnp.int32), "active": sds((S,), jnp.bool_),
+          "tables.global": sds((S, 672), jnp.int32),
+          "tables.window": sds((S, 257), jnp.int32),
+          "write_block.global": sds((S,), jnp.int32),
+          "write_block.window": sds((S,), jnp.int32)}
+    _, decode_fn = zoo.cohere2_moe_paged_decode_fns(pc, 16, 672)
+    compiled = jax.jit(decode_fn, donate_argnums=(1, 2)).lower(
+        params, kc, (), io).compile()
+    assert attention.last_decode_program().counts() == (4, 0, None)
+    text = compiled.as_text()
+    assert len(re.findall(r"paged_decode_attention[.0-9]* = ", text)) == 4
+    assert "[32,672,16," not in text and "[32,257,16," not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9

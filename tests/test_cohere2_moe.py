@@ -4,7 +4,9 @@ experts, a 61-row vocabulary), seeded: the server's own programs and the
 whole-sequence forward against the plain reference, the controls that
 must fail the same tolerance, the shares of a layer that add up to the
 uncut layer, the program's counters against a count by hand, and the
-vocabulary slice."""
+vocabulary slice; the decode program's read of each lane's own pages
+through the ragged kernel (the shipped reference in the kernel's place)
+against the plain path, and its counter."""
 import numpy as np
 import pytest
 
@@ -13,7 +15,9 @@ from benchmark.counts import cohere2_moe as counts
 from benchmark.generators import closed_mix
 from benchmark.reference import cohere2_moe as ref
 from deeplearning4j_tpu.serving.paged import PagedGenerativeServer
+from deeplearning4j_tpu.monitor import attention
 from deeplearning4j_tpu.zoo import cohere2_moe as zoo
+from deeplearning4j_tpu.zoo import paged_attend
 from deeplearning4j_tpu.zoo.cohere2_moe import (PROGRAM_COUNTERS,
                                                 Cohere2MoeConfig,
                                                 Cohere2MoeUnsupportedError,
@@ -69,8 +73,9 @@ def prompt(n, seed=0):
 
 
 def logits_served(srv, prompts, new_tokens):
-    """Serve ``prompts`` together and keep the logits every token was
-    chosen from, as the server's own programs returned them."""
+    """Serve ``prompts`` together (``new_tokens`` each, or one number
+    for all) and keep the logits every token was chosen from, as the
+    server's own programs returned them."""
     seen = {}
     real = srv._resolve_token
 
@@ -80,7 +85,10 @@ def logits_served(srv, prompts, new_tokens):
 
     srv._resolve_token = keep
     srv._sampled_active = lambda: True       # decode hands the logits over
-    hs = [srv.submit(p, max_new_tokens=new_tokens) for p in prompts]
+    if np.ndim(new_tokens) == 0:
+        new_tokens = [new_tokens] * len(prompts)
+    hs = [srv.submit(p, max_new_tokens=int(n))
+          for p, n in zip(prompts, new_tokens)]
     srv.start()                              # where the test held it back
     toks = [h.result(timeout=300) for h in hs]
     return toks, [np.stack(seen[h.id]) for h in hs]
@@ -311,9 +319,9 @@ def test_the_program_counts_what_a_count_by_hand_gives(spec):
         io[t.key("tables")] = np.zeros((S, t.table_blocks(BS, 16)),
                                        np.int32)
         io[t.key("write_block")] = np.array([1, 2, 3], np.int32)
-    leaf = jnp.zeros((8, BS, pc.num_kv_heads * pc.head_dim), jnp.bfloat16)
-    _, _, nxt, logits = jax.jit(decode_fn)(params, (leaf,) * 4, (leaf,) * 4,
-                                           io)
+    leaf = jnp.zeros((8, BS, 2 * pc.num_kv_heads * pc.head_dim),
+                     jnp.bfloat16)
+    _, _, nxt, logits = jax.jit(decode_fn)(params, (leaf,) * 4, (), io)
     got = dict(zip(PROGRAM_COUNTERS, np.asarray(nxt)[S:].tolist()))
     served = np.zeros((4, held), np.int64)
     for tok, on in zip(tokens, active):
@@ -329,7 +337,10 @@ def test_the_program_counts_what_a_count_by_hand_gives(spec):
         "moe_experts_touched_sum": int((served > 0).sum()),
         "moe_tokens_routed_sum": 2 * 2 * 4,          # k x lanes x layers
         "moe_peak_expert_tokens_sum": int(served.max(axis=1).sum()),
-        "moe_held_pairs_sum": int(served.sum())}
+        "moe_held_pairs_sum": int(served.sum()),
+        # the plain path (the CPU) reads every entry it gathered: the
+        # global table's 16 and the ring's 3 on three layers, a lane
+        "kv_pages_read_sum": 2 * (16 + 3 * 3)}
     # layer 0 by hand in float64: the router reads the norm of the
     # embedding row, which program and reference compute alike
     emb = np.asarray(params["embed"], np.float64)
@@ -468,9 +479,296 @@ def test_both_programs_call_the_one_block_a_kind_of_layer(spec, program):
             "write_block.global": i32(8), "write_block.window": i32(8)}
     params = {n: jax.ShapeDtypeStruct(np.shape(a), a.dtype)
               for n, a in spec.params().items()}
-    side = tuple(jax.ShapeDtypeStruct((9, BS, 2 * 8), jnp.bfloat16)
+    side = tuple(jax.ShapeDtypeStruct((9, BS, 2 * 2 * 8), jnp.bfloat16)
                  for _ in range(4))
-    jaxpr = jax.make_jaxpr(fn)(params, side, side, io)
+    jaxpr = jax.make_jaxpr(fn)(params, side, (), io)
     blocks = [e for e in jaxpr.eqns if e.params.get("name") == "_block"]
     assert len(blocks) == 4
     assert len({id(e.params["jaxpr"]) for e in blocks}) == 2
+
+
+# -- the decode program's read through the ragged kernel -----------------
+#: window 64 over blocks of 16: a ring of 5 entries
+CFG_KERNEL = dict(CFG, sliding_window=64, max_position_embeddings=256)
+BS_KERNEL = 16
+
+
+def _reference_kernel(q, leaf, pages, rows, reach, *, scale, per_block):
+    """JAX's shipped ragged paged-attention kernel's reference in the
+    decode kernel's place, fed the program's exact inputs (the leaf as
+    its ``kv_pages``; the decode kernel is a TPU program, the reference
+    runs anywhere, eagerly, so the program calls it back)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental.pallas.ops.tpu.ragged_paged_attention import \
+        ref_ragged_paged_attention
+    R, _, D = q.shape
+    kv_pages = leaf.reshape(leaf.shape[0], leaf.shape[1], -1, D)
+
+    def run(q, kv_pages, rows, pages, reach):
+        window = int(reach[0])
+        return np.asarray(ref_ragged_paged_attention(
+            q, kv_pages, rows, pages, np.arange(R + 1, dtype=np.int32),
+            np.asarray([R], np.int32), sm_scale=scale,
+            sliding_window=None if window == paged_attend._EVERY_ROW
+            else window))
+
+    return jax.pure_callback(run, jax.ShapeDtypeStruct(q.shape, q.dtype),
+                             q, kv_pages, rows, pages, reach)
+
+
+def _through_kernel(monkeypatch, window_start=None):
+    """The decode program takes the kernel path on the CPU, with the
+    kernel's reference in the kernel's place."""
+    monkeypatch.setattr(paged_attend, "kernel_refusal", lambda *a: None)
+    monkeypatch.setattr(paged_attend, "paged_kernel",
+                        lambda: _reference_kernel)
+    if window_start is not None:
+        monkeypatch.setattr(paged_attend, "window_start", window_start)
+
+
+#: lanes that cover the ring's cases at window 64, block 16: a lane in
+#: its first window (positions 20-39), a lane whose ring has turned
+#: (positions 90-109: entry 0 holds block 5 from the first step, at 90,
+#: which is no block boundary; it crosses the boundary at 96), a lane
+#: that retires after 6 tokens and idles, and a slot never used
+KERNEL_PROMPTS = (20, 90, 70)
+KERNEL_NEW = (20, 20, 6)
+
+
+def _served_wide(prompts, new):
+    spec = cohere2_moe_paged_spec(
+        adapter.program_config(CFG_KERNEL),
+        adapter.program_params(CFG_KERNEL, SEED))
+    with PagedGenerativeServer(spec, max_slots=4, block_size=BS_KERNEL,
+                               max_seq_len=128, buckets=[16, 64],
+                               warmup=False, debug_leaks=True) as srv:
+        assert srv._tiers[1].entries == 5
+        toks, got = logits_served(srv, prompts, new)
+    return toks, got, attention.last_decode_program().counts()
+
+
+@pytest.fixture(scope="module")
+def plain_served():
+    prompts = [prompt(n, 40 + n) for n in KERNEL_PROMPTS]
+    return prompts, _served_wide(prompts, KERNEL_NEW)
+
+
+@pytest.mark.parametrize("start", ["window_start", "a_block_early"])
+def test_the_kernel_path_gives_the_plain_paths_decode_logits(
+        plain_served, monkeypatch, start):
+    """The decode program through the kernel's inputs (the interleaved
+    leaf, the global table as handed, the ring laid out from the oldest
+    block the window sees with its rows shifted by as many blocks, idle
+    lanes on one row of the null block), with the shipped reference in
+    the kernel's place, gives the plain path's logits at every served
+    token, within :data:`TOL` of their spread; the plain path is the
+    CPU's own. Read from one block earlier (``last - 5`` where the ring
+    has 5 entries: its newest block laid out first, every lane's rows
+    shifted one block too far), the same comparison fails: the test sees
+    a wrong ring order."""
+    prompts, (want_toks, want, sites) = plain_served
+    assert sites == (0, 4, "backend cpu")
+    early = (lambda last, entries: last - entries) \
+        if start == "a_block_early" else None
+    _through_kernel(monkeypatch, early)
+    toks, got, sites = _served_wide(prompts, KERNEL_NEW)
+    assert sites == (4, 0, None)
+    gaps = [off_by(g, w) for g, w in zip(got, want)]
+    if start == "window_start":
+        assert toks == want_toks
+        assert max(gaps) < TOL, gaps
+    else:
+        # the lane in its first window reads its rows shifted past the
+        # table's end, the turned lane its newest block as its oldest
+        assert gaps[0] > TOL and gaps[1] > TOL, gaps
+
+
+def test_the_cells_decode_shapes_pass_the_kernels_validation(monkeypatch):
+    """The cell's decode program, traced at its own widths (32 lanes,
+    128 query heads over 8 K/V heads of 128, blocks of 16 in bf16, the
+    global table at its top rung of 672 entries and the ring of 257),
+    hands the decode kernel inputs that the shipped ragged kernel's
+    ``static_validate_inputs`` accepts, the leaf as its ``kv_pages``: the
+    block is traced once a kind of layer, the window layers' with the
+    window of 4,096."""
+    import jax
+    import jax.numpy as jnp
+    import json
+    import os
+    from jax.experimental.pallas.ops.tpu.ragged_paged_attention import \
+        kernel as rpa
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "command-a-plus-05-2026.json")) as f:
+        pc = Cohere2MoeConfig.from_dict(json.load(f))
+    calls = []
+
+    def validate(q, leaf, pages, rows, reach, *, scale, per_block):
+        kv_pages = leaf.reshape(leaf.shape[0], leaf.shape[1], -1,
+                                q.shape[2])
+        rpa.static_validate_inputs(
+            q, kv_pages, rows, pages,
+            jnp.arange(q.shape[0] + 1, dtype=jnp.int32),
+            jnp.full((1,), q.shape[0], jnp.int32), sm_scale=scale,
+            sliding_window=4096, num_kv_pages_per_block=per_block)
+        calls.append((q.shape, q.dtype, kv_pages.shape, pages.shape,
+                      reach.shape, per_block))
+        return jnp.zeros(q.shape, q.dtype)
+
+    monkeypatch.setattr(paged_attend, "kernel_refusal", lambda *a: None)
+    monkeypatch.setattr(paged_attend, "paged_kernel", lambda: validate)
+    S, width = 32, 2 * pc.num_kv_heads * pc.head_dim
+    _, decode_fn = zoo.cohere2_moe_paged_decode_fns(pc, 16, 672)
+    params = {n: jax.ShapeDtypeStruct(s, jnp.bfloat16)
+              for n, s in cohere2_moe_param_shapes(pc).items()}
+    kc = tuple(jax.ShapeDtypeStruct(((32 * 289 if w else 32 * 672) + 1,
+                                     16, width), jnp.bfloat16)
+               for w in pc.window_layout)
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa
+    io = {"tokens": i32(S), "positions": i32(S), "write_off": i32(S),
+          "active": jax.ShapeDtypeStruct((S,), jnp.bool_),
+          "tables.global": i32(S, 672), "tables.window": i32(S, 257),
+          "write_block.global": i32(S), "write_block.window": i32(S)}
+    out = jax.eval_shape(decode_fn, params, kc, (), io)
+    assert out[2].shape == (S + len(PROGRAM_COUNTERS),)
+    # the ring's 257 pages padded to the global table's 672, the window
+    # a number: one kernel for both kinds of layer
+    win = (S, 128, 128), jnp.bfloat16, (9249, 16, 16, 128), (S, 672), \
+        (1,), paged_attend.KV_PAGES_PER_BLOCK
+    glob = (S, 128, 128), jnp.bfloat16, (21505, 16, 16, 128), (S, 672), \
+        (1,), paged_attend.KV_PAGES_PER_BLOCK
+    assert calls == [win, glob]
+
+
+def test_kv_pages_read_sum_is_a_count_by_hand(spec, monkeypatch):
+    """One decode step of four lanes (window 8, blocks of 4: a ring of 3
+    entries, a global table of 16), three active at positions 0, 37 and
+    22 and one idle: the kernel path counts each active lane's own pages
+    a layer, the global layer's up to its position and the ring's from
+    the oldest block its window sees; the plain path counts every entry
+    it gathered."""
+    import jax
+    import jax.numpy as jnp
+    pc = adapter.program_config(CFG)
+    tiers = pc.kv_tiers()
+    S = 4
+    io = {"tokens": np.array([5, 17, 40, 3], np.int32),
+          "positions": np.array([0, 37, 22, 9], np.int32),
+          "active": np.array([True, True, True, False]),
+          "write_off": np.array([0, 1, 2, 0], np.int32)}
+    for t in tiers:
+        io[t.key("tables")] = np.zeros((S, t.table_blocks(BS, 16)),
+                                       np.int32)
+        io[t.key("write_block")] = np.array([1, 2, 3, 0], np.int32)
+    leaf = jnp.zeros((8, BS, 2 * pc.num_kv_heads * pc.head_dim),
+                     jnp.bfloat16)
+
+    def read(kernel):
+        if kernel:
+            _through_kernel(monkeypatch)
+        _, decode_fn = cohere2_moe_paged_spec(
+            pc, spec.params()).make_fns(BS, 16)
+        _, _, nxt, _ = jax.jit(decode_fn)(spec.params(), (leaf,) * 4, (),
+                                          io)
+        return dict(zip(PROGRAM_COUNTERS, np.asarray(nxt)[S:].tolist()))
+
+    plain = read(False)["kv_pages_read_sum"]
+    kernel = read(True)["kv_pages_read_sum"]
+    # by hand: a lane at position p holds blocks 0 to p // 4 of the
+    # global table; the ring of 3 is read from block max(0, p // 4 - 2)
+    hand_global = sum(p // 4 + 1 for p in (0, 37, 22))       # 1 + 10 + 6
+    hand_ring = sum(min(p // 4, 2) + 1 for p in (0, 37, 22))  # 1 + 3 + 3
+    assert kernel == hand_global + 3 * hand_ring == 38
+    assert plain == 3 * (16 + 3 * 3)
+
+
+def test_the_one_leaf_keeps_the_cells_bytes_and_memory_report():
+    """Command A+'s one interleaved leaf a layer against the K-and-V pair
+    of before, at the cell's KV geometry (4 layers, one global and three
+    window, 8 K/V heads of 128, window 4,096, blocks of 16, 10,752
+    positions, bf16; the widths no leaf depends on cut, and one slot):
+    the same bytes a token, a block and a pool, the same tiers, and the
+    same ``memory_report`` but for the leaves' names and widths. A leaf
+    without heads refuses ``tp`` typed."""
+    import dataclasses
+    import json
+    import os
+    from deeplearning4j_tpu.serving.paged import KVLeafUnsupportedError
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "command-a-plus-05-2026.json")) as f:
+        cell = json.load(f)
+    cut = dict(cell, hidden_size=64, intermediate_size=8, num_experts=2,
+               vocab_size=64)
+    one = cohere2_moe_paged_spec(adapter.program_config(cut),
+                                 adapter.program_params(cut, SEED))
+    pair = dataclasses.replace(one, kv_leaves=None)
+    reports = []
+    for sp in (one, pair):
+        with PagedGenerativeServer(sp, max_slots=1, block_size=16,
+                                   max_seq_len=10752, buckets=[16],
+                                   warmup=False) as srv:
+            reports.append(srv.memory_report())
+    got, want = reports
+    assert got["kv_leaves"] == {"kv": 2048}
+    assert want["kv_leaves"] == {"k": 1024, "v": 1024}
+    # 4 layers x 2,048 numbers x 2 bytes: the cell's 16 KB a token
+    assert got["kv_bytes_per_token"] == want["kv_bytes_per_token"] \
+        == 4 * 2048 * 2
+    # the first leaf's arrays: one row of 2,048 numbers where K's was
+    # 1,024 wide beside V's
+    assert got["kv_slab_shape"][:3] == want["kv_slab_shape"][:3]
+    assert (got["kv_slab_shape"][3], want["kv_slab_shape"][3]) \
+        == (2048, 1024)
+    names = ("kv_leaves", "kv_leaves_filled", "kv_slab_shape")
+    for r in reports:
+        for t in r["kv_tiers"].values():
+            del t["leaves"]
+    assert {k: v for k, v in got.items() if k not in names} \
+        == {k: v for k, v in want.items() if k not in names}
+    with pytest.raises(KVLeafUnsupportedError):
+        PagedGenerativeServer(one, max_slots=1, block_size=16,
+                              max_seq_len=64, buckets=[16], warmup=False,
+                              tp=2)
+
+
+@pytest.mark.parametrize("window,per_block", [(64, 1), (64, 2), (None, 2),
+                                              (None, 5)])
+def test_the_decode_kernel_gives_the_shipped_kernels_reference(window,
+                                                               per_block):
+    """The repo's decode kernel, run by the TPU interpreter on the CPU,
+    against the shipped ragged kernel's reference over the same inputs
+    (the leaf as its ``kv_pages``): 4 lanes of 32 query heads over 2 K/V
+    heads of 128, blocks of 16, a ring of 5 (window 64) or a table of 5;
+    a lane in its first window, one whose ring has turned, one at the
+    end of a block, an idle one; a page or two a block, or the whole
+    table in one. Within the rounding of the bf16 result."""
+    import jax.numpy as jnp
+    from jax.experimental.pallas import tpu as pltpu
+    from jax.experimental.pallas.ops.tpu.ragged_paged_attention import \
+        ref_ragged_paged_attention
+    R, KV, G, D, B, E, nb = 4, 2, 16, 128, 16, 5, 40
+    rng = np.random.default_rng(7)
+    leaf = jnp.asarray(rng.standard_normal((nb, B, 2 * KV * D)),
+                       jnp.bfloat16)
+    q = jnp.asarray(rng.standard_normal((R, KV * G, D)), jnp.bfloat16)
+    table = jnp.asarray(rng.permutation(np.arange(1, nb))[:R * E]
+                        .reshape(R, E), jnp.int32)
+    pos = jnp.asarray([20, 90, 79, 9] if window else [20, 70, 79, 0],
+                      jnp.int32)
+    active = jnp.asarray([True, True, True, False])
+    wb = table[jnp.arange(R), (pos // B) % E]
+    pages, rows = paged_attend.decode_pages(table, pos, active, wb, B,
+                                            window is not None)
+    want = np.asarray(ref_ragged_paged_attention(
+        q, leaf.reshape(nb, B, 2 * KV, D), rows, pages,
+        jnp.arange(R + 1, dtype=jnp.int32), jnp.asarray([R], jnp.int32),
+        sm_scale=D ** -0.5, sliding_window=window), np.float32)
+    reach = jnp.asarray([window or paged_attend._EVERY_ROW], jnp.int32)
+    with pltpu.force_tpu_interpret_mode():
+        got = np.asarray(paged_attend.paged_kernel()(
+            q, leaf, pages, rows, reach, scale=D ** -0.5,
+            per_block=per_block), np.float32)
+    # bf16 output: 2**-9 of a value, a few deviations out
+    assert np.abs(got - want).max() < 0.02 * want.std()
