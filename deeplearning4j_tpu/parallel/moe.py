@@ -148,7 +148,8 @@ def topk_route(x, router_w, k: int):
     return idx.astype(jnp.int32), jax.nn.softmax(top, axis=-1)
 
 
-def sigmoid_bias_route(x, router_w, bias, k: int, scale: float = 1.0):
+def sigmoid_bias_route(x, router_w, bias, k: int, scale: float = 1.0,
+                       eps: float = 1e-20):
     """Dropless top-k routing by sigmoid scores with a correction bias
     (the load balancing without an auxiliary loss of DeepSeek-V3,
     ``noaux_tc`` in the published configurations). x: (N, d); router_w:
@@ -156,8 +157,9 @@ def sigmoid_bias_route(x, router_w, bias, k: int, scale: float = 1.0):
     ``s`` alone; nothing is added). With ``s = sigmoid(x @ router_w)``
     (float32 at the highest precision, as :func:`topk_route` and for its
     reason) the k experts of a token are those with the largest ``s +
-    bias``; their weights are ``scale * s / (sum of the k s + 1e-20)``:
-    the bias steers the CHOICE and never enters a weight. Returns
+    bias``; their weights are ``scale * s / (sum of the k s + eps)``
+    (a family's own divisor: LFM2's code adds 1e-6): the bias steers the
+    CHOICE and never enters a weight. Returns
     ``(idx (N, k) int32, weights (N, k) float32, moved (N,) int32)``,
     the first two as :func:`topk_route` gives them; ``moved`` counts the
     token's choices that are not among the k largest of ``s`` alone,
@@ -170,7 +172,7 @@ def sigmoid_bias_route(x, router_w, bias, k: int, scale: float = 1.0):
                            else s + bias.astype(jnp.float32), k)
     chosen = jnp.take_along_axis(s, idx, axis=-1)
     weights = scale * chosen / (jnp.sum(chosen, axis=-1, keepdims=True)
-                                + 1e-20)
+                                + eps)
     if bias is None:
         return idx.astype(jnp.int32), weights, \
             jnp.zeros(s.shape[0], jnp.int32)

@@ -677,6 +677,10 @@ class GenerativeServer:
     #: ``tokens`` where they lie on the device (the paged tier reads it
     #: off the compiled programs of a mesh)
     _feed_on_device = True
+    #: a per-lane state every program takes after its io and gives back
+    #: (the paged tier holds one where its spec names state leaves);
+    #: None: the programs take and give none
+    _state = None
 
     def _coerce_spec(self, spec):
         if not isinstance(spec, GenerativeSpec):
@@ -1294,8 +1298,13 @@ class GenerativeServer:
                         # prompt fails ITS request, not the decode worker
                         self._retire(s, error=e)
                 req = self._take()
-            sp.set(requests=admitted)
+            sp.set(requests=admitted, **self._admit_span_args(admitted))
         return admitted > 0
+
+    def _admit_span_args(self, admitted: int) -> dict:
+        """What the ``serving.admit`` span says beside the requests it
+        placed (paged: the lanes whose state started from zeros)."""
+        return {}
 
     def _pad_to_bucket(self, tokens: np.ndarray):
         """``(bucket, tokens zero-padded to it)``."""
@@ -1745,14 +1754,18 @@ class GenerativeServer:
                         kc, vc, nxt, logits = disp(
                             self._draft_params, self._dkc, self._dvc, io)
                     else:
-                        kc, vc, nxt, logits = disp(
-                            self._params, self._kc, self._vc, io)
+                        held = () if self._state is None \
+                            else (self._state,)
+                        kc, vc, nxt, logits, *state = disp(
+                            self._params, self._kc, self._vc, io, *held)
             except Exception as e:
                 raise self._wrap_exec_error(e, what) from e
             if draft:
                 self._dkc, self._dvc = kc, vc
             else:
                 self._kc, self._vc = kc, vc
+                if state:
+                    self._state = state[0]
             launch_ms = (time.perf_counter() - t_launch) * 1000.0
         return nxt, logits, launch_ms
 

@@ -10,6 +10,8 @@ from deeplearning4j_tpu.serving.paged.pool import (NULL_BLOCK, BlockPool,
                                                    KVLeafUnsupportedError,
                                                    KVTier,
                                                    PoolExhaustedError,
+                                                   StateLeaf,
+                                                   StateUnsupportedError,
                                                    blocks_for_tokens,
                                                    prefix_block_hashes,
                                                    table_widths)
@@ -18,7 +20,8 @@ from deeplearning4j_tpu.serving.paged.server import (
     PrefixCacheUnsupportedError)
 
 __all__ = ["BlockPool", "PoolExhaustedError", "NULL_BLOCK", "KVTier",
-           "KVLeaf", "KVLeafUnsupportedError",
+           "KVLeaf", "KVLeafUnsupportedError", "StateLeaf",
+           "StateUnsupportedError",
            "prefix_block_hashes", "blocks_for_tokens", "table_widths",
            "PagedGenerativeSpec", "PagedGenerativeServer", "PagedMetrics",
            "PrefixCacheUnsupportedError"]

@@ -363,6 +363,28 @@ class KVLeafUnsupportedError(ValueError):
 
 
 @dataclass(frozen=True)
+class StateLeaf:
+    """What a layer keeps of a request that is NO function of a block's
+    tokens: a state of fixed size a lane, whatever the length of its
+    context (a short convolution's last inputs). The server holds one
+    array a layer ``[max_slots + 1, *shape]`` in the spec's ``kv_dtype``,
+    row ``s`` slot ``s``'s and the last a spare row that nothing reads;
+    ``layers`` are the model's layers that keep it, in the order of the
+    arrays."""
+
+    name: str
+    layers: Tuple[int, ...]
+    shape: Tuple[int, ...]
+
+
+class StateUnsupportedError(ValueError):
+    """Asked of a spec with a per-lane state what cannot bring a state
+    back: a split over ``tp`` chips, or a draft and its verify round (a
+    rejected token cannot be taken back out of the state without a
+    snapshot)."""
+
+
+@dataclass(frozen=True)
 class KVTier:
     """The layers of a model whose KV leaves share one block pool and
     one table a request.
@@ -457,7 +479,8 @@ class KVTier:
 
 
 __all__ = ["BlockPool", "PoolExhaustedError", "NULL_BLOCK", "KVTier",
-           "KVLeaf", "KVLeafUnsupportedError",
+           "KVLeaf", "KVLeafUnsupportedError", "StateLeaf",
+           "StateUnsupportedError",
            "TABLE_RUNGS", "RING_RUNGS", "TABLE_WIDTH_MULTIPLE",
            "table_widths",
            "prefix_block_hashes", "blocks_for_tokens"]

@@ -61,7 +61,10 @@ the same layers, a layer caches four leaves, a tier's row may stand for
 several tokens (``KVTier.row_tokens``: one summary row a chunk, beside
 the exact rows) and a window may TUMBLE, given back whole when the query
 enters the next one (``zoo/evabyte.py``); blocks, tables, reservations
-and reports count ROWS, tier by tier.
+and reports count ROWS, tier by tier. A layer may keep besides a state
+of FIXED size a lane (``pool.StateLeaf``: a short convolution's last
+inputs, ``zoo/lfm2_moe.py``), which the server holds one row a slot and
+hands every program after its io (``PagedGenerativeSpec.state_leaves``).
 
 The DECODE program reads as many table entries as its longest lane
 holds: at every step boundary the server sends each tier's tables cut to
@@ -107,6 +110,8 @@ from deeplearning4j_tpu.serving.paged.pool import (NULL_BLOCK, RING_RUNGS,
                                                    KVLeafUnsupportedError,
                                                    KVTier,
                                                    PoolExhaustedError,
+                                                   StateLeaf,
+                                                   StateUnsupportedError,
                                                    blocks_for_tokens,
                                                    prefix_block_hashes,
                                                    table_widths)
@@ -172,6 +177,24 @@ class PagedGenerativeSpec:
       ``[max_slots + len(program_counters)]``, the counts of the step
       behind the tokens in this order, and the server adds them to its
       counters of these names at the sync the tokens pay for.
+    - ``state_leaves`` names what a lane keeps that is no function of a
+      block's tokens (``pool.StateLeaf``: a state of fixed size a layer,
+      a short convolution's last inputs). The server then holds one
+      array a leaf and layer ``[max_slots + 1, *shape]``, allocated once
+      for every slot, and hands every program the tuple of them (a tuple
+      a leaf, an array a layer) AFTER its io, donated; the program gives
+      it back last: ``fn(params, kc, vc, io, state) -> (kc, vc, next,
+      logits, state)``. A prefill's io then holds its ``slot`` (). What
+      the programs must hold to, whatever the host's order: a run whose
+      first position (``hist``) is 0 reads zeros and not the store, so
+      that a slot is reused without a reset; a run that starts later
+      reads the lane's row; the row after a run holds what the run's
+      last REAL positions leave (no bucket's padding); a decode step
+      writes its active lanes' rows and leaves an idle lane's as it was
+      (its write may land in the spare row). A spec without state
+      leaves hands ``()`` here and its programs take four arguments and
+      give four. The prefix cache, ``tp > 1`` and a draft are refused
+      (``PrefixCacheUnsupportedError``, ``pool.StateUnsupportedError``).
     """
 
     params: Callable[[], Dict[str, object]]
@@ -185,12 +208,15 @@ class PagedGenerativeSpec:
     kv_tiers: Optional[Sequence[KVTier]] = None
     program_counters: Tuple[str, ...] = ()
     kv_leaves: Optional[Sequence[KVLeaf]] = None
+    state_leaves: Sequence[StateLeaf] = ()
 
 
 class PrefixCacheUnsupportedError(ValueError):
     """Asked for the prefix cache over a spec with a window tier or more
     than one tier: a cached prefix would have to bring back the window
-    tier's blocks too, which were given back while the request ran."""
+    tier's blocks too, which were given back while the request ran; or
+    over a spec with state leaves: a cached prefix's blocks carry no
+    state."""
 
 
 class _TierState:
@@ -332,11 +358,13 @@ def _paged_dispatchers(spec: PagedGenerativeSpec, kv_shape: tuple,
         fns = spec.make_fns(int(block_size), int(max_blocks))
         prefill_fn, decode_fn = fns[0], fns[1]
         verify_fn = fns[2] if len(fns) > 2 else None
+        # the pool's two sides and, after the io, a state where there is
+        donate = (1, 2, 4) if spec.state_leaves else (1, 2)
         pair = {
             "decode": AOTDispatch(
-                jax.jit(decode_fn, donate_argnums=(1, 2)), ph_arg=3),
+                jax.jit(decode_fn, donate_argnums=donate), ph_arg=3),
             "prefill": AOTDispatch(
-                jax.jit(prefill_fn, donate_argnums=(1, 2)), ph_arg=3)}
+                jax.jit(prefill_fn, donate_argnums=donate), ph_arg=3)}
         if verify_fn is not None:
             pair["verify"] = AOTDispatch(
                 jax.jit(verify_fn, donate_argnums=(1, 2)), ph_arg=3)
@@ -385,7 +413,12 @@ class PagedMetrics(GenerativeMetrics):
                   # rows of a tier whose row stands for several tokens
                   # (one a lane and completed chunk), and windows that
                   # tumbled (one a lane and turn)
-                  "summary_rows_written", "window_turns"):
+                  "summary_rows_written", "window_turns",
+                  # a spec's per-lane state: prefill runs that began a
+                  # lane's state from zeros, and those that began from
+                  # the state the run before left (a prompt's later
+                  # chunks)
+                  "state_lanes_started", "state_runs_carried"):
             self.counters[c] = 0
         self._pool_stats: Dict[str, int] = {}
 
@@ -604,6 +637,8 @@ class PagedGenerativeServer(GenerativeServer):
                 f"a layer caches one or two leaves, or pairs of leaves "
                 f"that each name their tier; the spec names "
                 f"{[(lf.name, lf.tier) for lf in leaves]}")
+        # what a state cannot serve is refused before what a leaf cannot
+        self._init_state(layers)
         for lf in leaves:
             asked = None if lf.heads else (
                 f"tp={self.tp}" if self.tp > 1 else
@@ -661,8 +696,12 @@ class PagedGenerativeServer(GenerativeServer):
             raise PrefixCacheUnsupportedError(
                 "the prefix cache serves one tier that keeps every block; "
                 f"this spec has {[(t.name, t.window) for t in tiers]}")
-        self.prefix_cache_enabled = plain and self._prefix_cache_arg \
-            is not False
+        if self._state_leaves:
+            # a lane's state in rows of the pool's bytes a layer and token
+            self._state_rows = self.state_bytes_per_lane / (
+                self.kv_bytes_per_token / layers)
+        self.prefix_cache_enabled = plain and not self._state_leaves \
+            and self._prefix_cache_arg is not False
         if not plain and (self.tp > 1 or self.draft_spec is not None
                           or self._kv_hbm_bytes_arg is not None
                           or self._num_blocks_arg is not None):
@@ -698,10 +737,12 @@ class PagedGenerativeServer(GenerativeServer):
         self._ladder_tiers = [ts for ts in self._tiers if ts.on_ladder]
         self._ladder_capacity = sum(ts.entries for ts in self._ladder_tiers)
         memstats.check_headroom(
-            self.kv_slab_bytes,
+            self.kv_slab_bytes + self.state_bytes,
             "paged KV pool (" + ", ".join(
                 f"{ts.tier.name or 'all'}: {ts.pool.num_blocks} blocks"
-                for ts in self._tiers) + f" x {BS} tokens)")
+                for ts in self._tiers) + f" x {BS} tokens)"
+            + (f" and a state of {self.state_bytes_per_lane} bytes a lane"
+               if self.state_bytes else ""))
         shape = tuple(spec.kv_shape(self._tiers[0].pool.num_blocks, BS))
         mesh_key = None
         if self.tp > 1:
@@ -745,10 +786,13 @@ class PagedGenerativeServer(GenerativeServer):
                              else [by_name[lf.tier]]
                              * len(by_name[lf.tier].tier.layers)))
             for lf in leaves)
-        self._kv_layers = layers
         self._kc, self._vc = self._fresh_leaves()
         AllocationsTracker.get_instance().allocate("kv_slab",
                                                    self.kv_slab_bytes)
+        if self._state_leaves:
+            self._state = self._fresh_state()
+            AllocationsTracker.get_instance().allocate("state_store",
+                                                       self.state_bytes)
         # host scheduler state (worker thread owns mutation). The first
         # tier's pool, tables and block counts under the names they had
         # when there was one tier (the prefix cache and speculation,
@@ -782,6 +826,45 @@ class PagedGenerativeServer(GenerativeServer):
         # (warm-up compiles it; without warm-up it compiles like the rest)
         self._cut_tokens = self._cut_program() \
             if self._program_counters else None
+
+    def _init_state(self, layers: int) -> None:
+        """The books of the spec's state leaves (``pool.StateLeaf``):
+        what each takes, and the model's layers, the pool's and the
+        state's (``kv_positions_sum`` counts a row a position on every one
+        of them, and ``kv_rows_held_sum`` a lane's state at the rows of
+        the pool's bytes a layer and token that its bytes make). What
+        cannot bring a state back is refused here."""
+        import jax.numpy as jnp
+        leaves = tuple(self.spec.state_leaves)
+        self._state_leaves = leaves
+        self.state_bytes_per_lane = jnp.dtype(self._kv_dtype).itemsize * sum(
+            len(lf.layers) * int(np.prod(lf.shape)) for lf in leaves)
+        self.state_bytes = (self.max_slots + 1) * self.state_bytes_per_lane
+        self._model_layers = layers + len(
+            {i for lf in leaves for i in lf.layers})
+        self._state_rows = 0.0
+        if not leaves:
+            return
+        if self._prefix_cache_arg:
+            raise PrefixCacheUnsupportedError(
+                "a cached prefix's blocks carry no state: the spec names "
+                f"state leaves {[lf.name for lf in leaves]}")
+        asked = (f"tp={self.tp}" if self.tp > 1 else
+                 "a draft" if self.draft_spec is not None else None)
+        if asked:
+            raise StateUnsupportedError(
+                f"state leaves {[lf.name for lf in leaves]} cannot serve "
+                f"{asked}: no state is split over chips, and a rejected "
+                f"token cannot be taken back out of a state")
+
+    def _fresh_state(self) -> tuple:
+        """Every slot's state, zeroed: a tuple a leaf of one array a
+        layer ``[max_slots + 1, *shape]``."""
+        import jax.numpy as jnp
+        return tuple(
+            tuple(jnp.zeros((self.max_slots + 1,) + tuple(lf.shape),
+                            self._kv_dtype) for _ in lf.layers)
+            for lf in self._state_leaves)
 
     def _cut_program(self):
         import jax
@@ -913,6 +996,11 @@ class PagedGenerativeServer(GenerativeServer):
         self._consume_prefix_flush()
         return super()._step(slot)
 
+    def _admit_span_args(self, admitted: int) -> dict:
+        # with no prefix cache every request placed starts its lane's
+        # state from zeros
+        return {"state_started": admitted} if self._state_leaves else {}
+
     def _prefill_runs(self, s: int, prefix: np.ndarray, L: int):
         """Slot ``s``'s table starts with what the prefix cache holds of
         the prefix; the rest runs through the program in chunks of the
@@ -953,6 +1041,16 @@ class PagedGenerativeServer(GenerativeServer):
         bucket, padded = self._pad_to_bucket(prefix[start:stop])
         io = {"tokens": padded, "length": np.int32(stop - start),
               "hist": np.int32(start)}
+        attrs = {"bucket": bucket, "hist": start}
+        if self._state_leaves:
+            # the program reads the slot's row of the state store, or
+            # zeros where the run starts the request (hist 0)
+            io["slot"] = np.int32(s)
+            if start:
+                attrs["carried"] = 1
+                self.metrics.inc("state_runs_carried")
+            else:
+                self.metrics.inc("state_lanes_started")
         for ts in self._tiers:
             t = ts.tier
             io[t.key("table")] = ts.tables[s].copy()
@@ -989,7 +1087,7 @@ class PagedGenerativeServer(GenerativeServer):
                 self.pool.register(self._hashes[u], int(self._tables[s, u]))
             self._advance(s, stop)
 
-        return io, {"bucket": bucket, "hist": start}, filled
+        return io, attrs, filled
 
     def _advance(self, s: int, position: int) -> int:
         """Slot ``s``'s next query is at ``position``: its window tiers
@@ -1076,8 +1174,11 @@ class PagedGenerativeServer(GenerativeServer):
             if ts.on_ladder:
                 sent += width
         self.metrics.observe_tables(sent, self._ladder_capacity)
+        # a lane's state at the rows its bytes make (_init_state), and a
+        # row a position on every layer of the model
         self.metrics.observe_rows(
-            held, self._kv_layers * int(written[act].sum()),
+            held + self._state_rows * n_act,
+            self._model_layers * int(written[act].sum()),
             gathered * BS * n_act)
         return io
 
@@ -1217,6 +1318,8 @@ class PagedGenerativeServer(GenerativeServer):
         commitment (their futures are unresolved) and re-enter at
         prefill."""
         self._kc, self._vc = self._fresh_leaves()
+        if self._state_leaves:
+            self._state = self._fresh_state()
         self._reset_draft_slabs()
         for ts in self._tiers:
             ts.reset()
@@ -1259,6 +1362,9 @@ class PagedGenerativeServer(GenerativeServer):
         kv_abs = self._two_sides(tuple(
             tuple(_abs(shape, self._kv_dtype, self._kv_sharding)
                   for shape in side) for side in self._kv_leaf_shapes))
+        # a state store goes after the io (none: nothing goes)
+        state_abs = () if self._state is None else (jax.tree_util.tree_map(
+            lambda a: _abs(a.shape, a.dtype), self._state),)
         S, MAXB = self.max_slots, self._maxb
 
         def _tier_io(table_key, lead, rows, widths=None):
@@ -1293,7 +1399,7 @@ class PagedGenerativeServer(GenerativeServer):
                     at = COMPILE_STATS.mark()
                     with COMPILE_STATS.precompile(label):
                         disp.aot[sig] = disp.lower(
-                            params_abs, *kv_abs, io_abs).compile()
+                            params_abs, *kv_abs, io_abs, *state_abs).compile()
                     memstats.capture_plan(label, sig,
                                           compiled=disp.aot[sig])
                     programs.append(COMPILE_STATS.program_row(label, at))
@@ -1327,12 +1433,13 @@ class PagedGenerativeServer(GenerativeServer):
             self._feed_on_device = all(
                 c.output_shardings[2].is_equivalent_to(self._io_sharding, 1)
                 for c in self._decode_disp.aot.values())
+        slot_io = {"slot": _abs((), jnp.int32)} if state_abs else {}
         for b in bucket_list:
             _build(self._prefill_disp,
                    {"tokens": _abs((int(b),), jnp.int32, io_sh),
                     "length": _abs((), jnp.int32, io_sh),
                     "hist": _abs((), jnp.int32, io_sh),
-                    **_tier_io("table", (), int(b))},
+                    **_tier_io("table", (), int(b)), **slot_io},
                    f"paged_prefill_b{int(b)}")
         if self.draft_spec is not None:
             W = self.speculate_k
@@ -1449,6 +1556,13 @@ class PagedGenerativeServer(GenerativeServer):
                     "blocks_held": ts.pool.held_count()}
                     for ts in self._tiers},
                 "kv_bytes_per_block": self.bytes_per_block,
+                # the per-lane state store, every slot's row and the
+                # spare one allocated at start (pool.StateLeaf)
+                "state_leaves": {lf.name: {"layers": len(lf.layers),
+                                           "shape": list(lf.shape)}
+                                 for lf in self._state_leaves},
+                "state_bytes_per_lane": self.state_bytes_per_lane,
+                "state_bytes": self.state_bytes,
                 "block_size": self.block_size,
                 "num_blocks": st["capacity"],
                 "blocks_free": st["free"],

@@ -528,3 +528,45 @@ def test_a_chip_that_holds_few_of_the_routed_experts_gives_their_part(
     np.testing.assert_allclose(np.asarray(y), want, rtol=1e-4, atol=tol)
     # a token that chose none of the held experts gets nothing here
     assert not np.asarray(y)[~in_share.any(axis=1)].any()
+
+
+@pytest.mark.parametrize("family,bias,scale", [("glm", True, 1.8),
+                                               ("cmda", False, 1.0)])
+def test_the_divisors_epsilon_leaves_the_routers_that_were_bit_for_bit(
+        family, bias, scale):
+    """The epsilon in the chosen weights' divisor is an argument (LFM2's
+    code adds 1e-6); its default is the 1e-20 GLM-4.7-Flash's router
+    (with its bias) and Command A+'s (without) were written with: both
+    route bit for bit as that rule does, in the same lowered program."""
+    x, router, b = _biased_router()
+    b = b if bias else None
+
+    def before(x, router, b):
+        # the rule as written before the epsilon was an argument
+        s = jax.nn.sigmoid(jnp.matmul(
+            x.astype(jnp.float32), router.astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST))
+        _, idx = jax.lax.top_k(s if b is None else s + b, 3)
+        chosen = jnp.take_along_axis(s, idx, axis=-1)
+        return idx, scale * chosen / (jnp.sum(chosen, axis=-1,
+                                              keepdims=True) + 1e-20)
+
+    idx, w, _ = sigmoid_bias_route(x, router, b, 3, scale)
+    want_idx, want_w = before(x, router, b)
+    np.testing.assert_array_equal(np.asarray(idx), np.asarray(want_idx))
+    np.testing.assert_array_equal(np.asarray(w), np.asarray(want_w))
+    text = jax.jit(lambda x, r: sigmoid_bias_route(x, r, b, 3, scale)
+                   ).lower(x, router).as_text()
+    named = jax.jit(lambda x, r: sigmoid_bias_route(x, r, b, 3, scale,
+                                                    eps=1e-20)
+                    ).lower(x, router).as_text()
+    assert text == named
+    # LFM2's divisor: the weights of the loop's rule with 1e-6
+    got = np.asarray(sigmoid_bias_route(x, router, b, 3, scale,
+                                        eps=1e-6)[1])
+    s = 1.0 / (1.0 + np.exp(-(np.asarray(x, np.float64)
+                              @ np.asarray(router, np.float64))))
+    picked = np.take_along_axis(s, np.asarray(idx), axis=1)
+    np.testing.assert_allclose(
+        got, scale * picked / (picked.sum(1, keepdims=True) + 1e-6),
+        rtol=1e-5)
