@@ -43,10 +43,13 @@ The convolution's ``v`` is rounded to the state's dtype where it is made,
 so that a run that reads its first taps from the store computes what one
 run over the whole prompt would. A prefill run reads its cached rows
 :data:`READ_SPAN` table entries at a time into a running softmax
-(``zoo.paged_attend``); a decode step reads each lane's own pages through
-``paged_attend.paged_decode`` where ``paged_attend.kernel_refusal`` names
-no reason (``monitor.attention.last_decode_program()`` says which way the
-program went), else the whole table it was handed, in one read.
+(``zoo.paged_attend``). A decode step on one TPU reads each lane's own
+pages in place through ``paged_attend.paged_decode``, whose kernel takes
+a head of 64 as one 128-lane column ``[k_h | v_h]`` and pads a group of 4
+query heads to whole sublane tiles; where ``paged_attend.kernel_refusal``
+names a reason (the CPU) it reads the table it was handed the same way
+as a prefill run, span by span (``monitor.attention.last_decode_program()``
+says which way the program went).
 
 There is no training graph for this block; :func:`lfm2_moe_paged_spec`
 serves parameters handed over by name.
@@ -185,23 +188,26 @@ class Lfm2MoeConfig:
         return layer < self.num_dense_layers
 
 
-#: what the decode program counts a step, summed over its expert layers:
+#: what the decode program counts a step: over its expert layers,
 #: GLM-4.7-Flash's five under their names (layers run, experts with a
 #: token of an active lane, (token, expert) pairs, the fullest expert's
 #: tokens, the choices the expert bias moved against the largest bare
-#: sigmoids; docs/serving.md says what an operator reads from each)
+#: sigmoids), and over its attention layers the pages the active lanes
+#: read, as Command A+ counts them (the kernel: each lane's own, from the
+#: rows it was handed; the plain path: every entry of the spans it
+#: gathered); docs/serving.md says what an operator reads from each
 PROGRAM_COUNTERS = ("moe_layer_steps", "moe_experts_touched_sum",
                     "moe_tokens_routed_sum", "moe_peak_expert_tokens_sum",
-                    "moe_bias_moved_sum")
+                    "moe_bias_moved_sum", "kv_pages_read_sum")
 
-#: table entries a run of either program reads its cached rows through
-#: at a time, where they divide the table it is handed (else the whole
-#: table in one read): the scores of a 512-token chunk over 32 heads and
-#: a span of 512 positions are 34 MB in float32, and a decode step of 64
-#: lanes gathers 67 MB a span where the whole table of 288 entries would
-#: be 604 MB a layer (3.7 GB of temporaries in all, past a v5e's memory
-#: beside the cell's 13.2 GB of weights and pool, by a compile for the
-#: chip)
+#: table entries a prefill run, and a decode step that does not take the
+#: kernel, reads its cached rows through at a time, where they divide the
+#: table it is handed (else the whole table in one read): the scores of a
+#: 512-token chunk over 32 heads and a span of 512 positions are 34 MB in
+#: float32, and a decode step of 64 lanes gathers 67 MB a span where the
+#: whole table of 288 entries would be 604 MB a layer (3.7 GB of
+#: temporaries in all, past a v5e's memory beside the cell's 13.2 GB of
+#: weights and pool, by a compile for the chip)
 READ_SPAN = 32
 
 
@@ -394,6 +400,9 @@ def _programs(cfg: Lfm2MoeConfig, block_size: int,
         return jnp.transpose(o, (0, 3, 1, 2, 4)).reshape(R, Q, A * D)
 
     def _attention(lp, u, qpos, valid, cache, span, paged):
+        """The attention mix on ``u [R, Q, H]`` and the pages the
+        requests with a valid row read: their own through the kernel,
+        every entry of the spans gathered on the plain path."""
         R, Q, _ = u.shape
         q = _mm(u, lp["/attn/q"]).reshape(R, Q, A, D)
         k = _mm(u, lp["/attn/k"]).reshape(R, Q, KV, D)
@@ -411,10 +420,16 @@ def _programs(cfg: Lfm2MoeConfig, block_size: int,
             att = paged_attend.paged_decode(
                 q[:, 0].astype(cache.kv.dtype), cache.kv, pages, rows, scale,
                 width=int(max_blocks_per_req)).reshape(R, Q, A * D)
+            read = jnp.sum(jnp.where(valid[:, 0], -(-rows // BS), 0),
+                           dtype=jnp.int32)
         else:
             att = _attend(q, k, v, qpos, valid, cache, span)
             cache.write(k.reshape(R * Q, KV, D), v.reshape(R * Q, KV, D))
-        return _mm(att, lp["/attn/o"])
+            E = cache.table.shape[1]
+            read = jnp.sum(jnp.any(valid, axis=1), dtype=jnp.int32) * span \
+                * paged_attend.spans_held(E // span, span * BS, cache.hist,
+                                          cap=E * BS)
+        return _mm(att, lp["/attn/o"]), read
 
     def _conv(lp, u, state):
         """The gated short convolution on ``u [R, Q, H]``, its first taps
@@ -441,23 +456,26 @@ def _programs(cfg: Lfm2MoeConfig, block_size: int,
         rows, :class:`_ConvState`) or its pool leaf. Returns the stream,
         what its router did (the tokens each expert served ``[E]`` and,
         behind them, the choices the bias moved; nothing for a dense
-        layer) and the store. Jitted on its own (``conv``, ``dense``,
+        layer), the pages its attention read (nothing for a conv layer)
+        and the store. Jitted on its own (``conv``, ``dense``,
         ``span`` and ``paged`` static), so that a program's trace and
         lowering hold each kind of layer once and call it."""
         R, Q, _ = x.shape
         u = _rmsnorm(x, lp["/norm_op"])
+        read = None
         if conv:
             cache = _ConvState(store, *rows)
             x = x + _conv(lp, u, cache)
             store = cache.store
         else:
             cache = _PagedCache(store, table, wb, write_off, hist, BS)
-            x = x + _attention(lp, u, qpos, valid, cache, span, paged)
+            att, read = _attention(lp, u, qpos, valid, cache, span, paged)
+            x = x + att
             store = cache.kv
         m = _rmsnorm(x, lp["/norm_ffn"])
         if dense:
             return x + _gated(m, lp["/mlp/gate"], lp["/mlp/up"],
-                              lp["/mlp/down"]), None, store
+                              lp["/mlp/down"]), None, read, store
         flat, ok = m.reshape(R * Q, H), valid.reshape(R * Q)
         idx, wts, moved = sigmoid_bias_route(
             flat, lp["/router"], lp["/router_bias"], K, cfg.routed_scale,
@@ -470,7 +488,7 @@ def _programs(cfg: Lfm2MoeConfig, block_size: int,
             lp["/experts/down"], valid=ok, activation=jax.nn.silu)
         did = jnp.concatenate(
             [served, jnp.sum(jnp.where(ok, moved, 0), dtype=jnp.int32)[None]])
-        return x + y.reshape(R, Q, H), did, store
+        return x + y.reshape(R, Q, H), did, read, store
 
     block = jax.jit(_block,
                     static_argnames=("conv", "dense", "span", "paged"))
@@ -480,10 +498,11 @@ def _programs(cfg: Lfm2MoeConfig, block_size: int,
         """Every layer over the stream; ``kc`` is one pool leaf an
         attention layer, ``state`` one array a conv layer. Returns the
         stream (before the last norm), what each expert layer's router
-        did ``[expert layers, E + 1]``, the leaves and the state."""
+        did ``[expert layers, E + 1]``, the pages the attention layers
+        read, summed, the leaves and the state."""
         kc, st = list(kc), list(state)
         x = jnp.take(p["embed"], tokens, axis=0).astype(jnp.float32)
-        did = []
+        did, read = [], jnp.int32(0)
         for i in range(L):
             sc = f"h{i}"
             lp = {n[len(sc):]: a for n, a in p.items()
@@ -491,13 +510,15 @@ def _programs(cfg: Lfm2MoeConfig, block_size: int,
             conv = not cfg.attention_layout[i]
             store = st if conv else kc
             j = (cfg.conv_layers if conv else cfg.attention_layers).index(i)
-            x, d, store[j] = block(
+            x, d, r, store[j] = block(
                 lp, x, qpos, valid, store[j], table, wb, hist, write_off,
                 rows, conv=conv, dense=cfg.is_dense(i), span=span,
                 paged=paged)
             if d is not None:
                 did.append(d)
-        return x, jnp.stack(did), tuple(kc), tuple(st)
+            if r is not None:
+                read = read + r
+        return x, jnp.stack(did), read, tuple(kc), tuple(st)
 
     def _head(p, x):
         """The last norm and the tied head on the stream ``x [..., H]``."""
@@ -517,7 +538,7 @@ def _programs(cfg: Lfm2MoeConfig, block_size: int,
         entries = table.shape[0]
         span = READ_SPAN if entries % READ_SPAN == 0 else entries
         rows = (slot[None], slot[None], (hist == 0)[None], length[None])
-        x, _, kc, conv = _stack(
+        x, _, _, kc, conv = _stack(
             params, tokens[None], g[None], valid[None], kc, state[0],
             table[None], wb, hist[None], g % BS, rows, span)
         h_last = jax.lax.dynamic_slice_in_dim(
@@ -541,7 +562,7 @@ def _programs(cfg: Lfm2MoeConfig, block_size: int,
         rows = (lanes, jnp.where(active, lanes, S), pos == 0,
                 jnp.ones(S, jnp.int32))
         width = io["tables"].shape[1]
-        x, did, kc, conv = _stack(
+        x, did, read, kc, conv = _stack(
             params, tokens[:, None], pos[:, None], active[:, None], kc,
             state[0], io["tables"], io["write_block"], pos, io["write_off"],
             rows, READ_SPAN if width % READ_SPAN == 0 else width,
@@ -552,7 +573,7 @@ def _programs(cfg: Lfm2MoeConfig, block_size: int,
             jnp.int32(n_moe), jnp.sum(served > 0, dtype=jnp.int32),
             jnp.sum(served, dtype=jnp.int32),
             jnp.sum(jnp.max(served, axis=1), dtype=jnp.int32),
-            jnp.sum(did[:, -1], dtype=jnp.int32)])
+            jnp.sum(did[:, -1], dtype=jnp.int32), read])
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         return kc, vc, jnp.concatenate([nxt, counted]), logits, (conv,)
 
@@ -569,7 +590,7 @@ def _programs(cfg: Lfm2MoeConfig, block_size: int,
         g = jnp.arange(T, dtype=jnp.int32)
         zero = jnp.zeros((1,), jnp.int32)
         rows = (zero, zero, jnp.ones((1,), bool), jnp.full((1,), T))
-        x, did, _, _ = _stack(
+        x, did, _, _, _ = _stack(
             params, tokens[None], g[None], jnp.ones((1, T), bool),
             (leaf,) * len(cfg.attention_layers),
             (store,) * len(cfg.conv_layers), jnp.zeros((1, 1), jnp.int32),
@@ -603,10 +624,12 @@ def lfm2_moe_paged_decode_fns(cfg: Lfm2MoeConfig, block_size: int,
     - ``decode_fn``: ``io = {"tokens", "positions", "active": [S],
       "tables": [S, E] (any ``E <= max_blocks_per_req`` that holds every
       active lane's blocks), "write_block": [S], "write_off": [S]}``;
-      returns ``(kc, vc, next [S + 5], logits [S, vocab], state)``:
-      behind the S next tokens come the step's :data:`PROGRAM_COUNTERS`
-      (idle lanes route nothing, and their state rows stay as they
-      were)."""
+      on one TPU each lane's attention reads its own pages through
+      ``paged_attend.paged_decode``, elsewhere the table it was handed,
+      :data:`READ_SPAN` entries at a time; returns ``(kc, vc, next,
+      logits [S, vocab], state)``, ``next [S + 6]``: behind the S next
+      tokens come the step's :data:`PROGRAM_COUNTERS` (idle lanes route
+      and read nothing, and their state rows stay as they were)."""
     fns = _programs(cfg, block_size, max_blocks_per_req)
     return fns["prefill_fn"], fns["decode_fn"]
 

@@ -20,7 +20,14 @@ operand ``[pages, block_size, 2 * kv_heads, head_dim]`` is tiled by
 would copy the whole pool into the kernel's layout at every step (1.54 s
 of 8 in `cmda_mixed_closed` on a TPU v5e, PERF.md section 6). This one
 fetches a page ``[block_size, 2 * kv_heads * head_dim]`` as the pool
-holds it and takes each head's K and V as 128-lane columns of it.
+holds it and takes each head's K and V as 128-lane columns of it: a head
+of 128 or more as two columns of its own, a head of 64 as ONE column
+``[k_h | v_h]``, scored by the query widened with zeros over V
+(``[q_h | 0] . [k_h | v_h] = q_h . k_h``) and weighed whole, its V half
+kept. A group of query heads that does not fill whole sublane tiles (16
+rows in bf16, 8 in float32) is padded with zero rows, which are dropped
+from the result. The kernel chooses this layout from the shapes it is
+handed; a head of 128 and a group of 16 in bf16 take none of it.
 :func:`decode_pages` lays a tier's table out in the order the kernel
 reads it, and :func:`kernel_refusal` says why a program cannot take the
 kernel."""
@@ -62,17 +69,25 @@ def softmax_merge(carry, s, seen, weighted, neg):
             o * keep[..., None] + weighted(e))
 
 
-def over_spans(over, carry, spans: int, span_rows: int, hist, cap=None):
-    """``over(i, carry)`` for each span ``i`` that holds a cached row:
-    once where one span is the whole table, else as many times as the
-    longest request's ``hist`` rows (at most ``cap``) fill spans of
-    ``span_rows`` (a loop whose length the device reads off ``hist``)."""
+def spans_held(spans: int, span_rows: int, hist, cap=None):
+    """How many spans :func:`over_spans` reads: 1 where one span is the
+    whole table, else as many as the longest request's ``hist`` rows (at
+    most ``cap``) fill spans of ``span_rows``."""
     if spans == 1:
-        return over(0, carry)
+        return 1
     held_rows = jnp.max(hist)
     if cap is not None:
         held_rows = jnp.minimum(held_rows, cap)
-    return jax.lax.fori_loop(0, (held_rows + span_rows - 1) // span_rows,
+    return (held_rows + span_rows - 1) // span_rows
+
+
+def over_spans(over, carry, spans: int, span_rows: int, hist, cap=None):
+    """``over(i, carry)`` for each span ``i`` that holds a cached row
+    (:func:`spans_held`; a loop whose length the device reads off
+    ``hist`` where the table is more than one span)."""
+    if spans == 1:
+        return over(0, carry)
+    return jax.lax.fori_loop(0, spans_held(spans, span_rows, hist, cap),
                              over, carry)
 
 
@@ -81,20 +96,30 @@ def kernel_refusal(dtype, heads: int, kv_heads: int,
     """Why a decode program whose leaf is in ``dtype``, with ``heads``
     query heads over ``kv_heads`` K/V heads of ``head_dim``, cannot read
     its pages through :func:`paged_decode`; None where it can. The kernel
-    is a TPU program over bf16 or float32 rows, takes a head's K and V as
-    whole 128-lane columns and a group of query heads as whole sublane
-    rows."""
+    is a TPU program over bf16 or float32 rows; it takes a head's K and V
+    as whole 128-lane columns, two of them or, for a head of 64, one
+    (:func:`_layout`), and any whole group of query heads."""
     backend = jax.default_backend()
     if backend != "tpu":
         return f"backend {backend}"
     dt = jnp.dtype(dtype)
     if dt not in (jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.float32)):
         return f"dtype {dt.name}"
-    if head_dim % 128:
+    if head_dim % 128 and head_dim != 64:
         return f"head size {head_dim}"
-    if heads % kv_heads or (heads // kv_heads) % (8 * 4 // dt.itemsize):
+    if heads % kv_heads:
         return f"heads {heads} over {kv_heads}"
     return None
+
+
+def _layout(dtype, group: int, head_dim: int):
+    """How :func:`paged_decode`'s kernel lays out a group of ``group``
+    query heads of ``head_dim``: ``(rows, lanes)``, the group padded to
+    whole sublane tiles of ``dtype`` and a query widened to the column
+    its head's K is read from (a head of 64 shares its 128 lanes with its
+    V)."""
+    tile = 8 * 4 // jnp.dtype(dtype).itemsize
+    return -(-group // tile) * tile, 128 if head_dim == 64 else head_dim
 
 
 def window_start(last, entries: int):
@@ -143,20 +168,24 @@ def _lanes(x, width: int):
 def _decode_kernel(pages_ref, rows_ref, reach_ref, q_ref, kv_hbm, o_ref,
                    buf, sem, m_ref, l_ref, acc_ref, *, scale: float,
                    kv_heads: int, per_block: int):
-    """One lane (grid step ``s``): its query heads ``q_ref [1, A, D]``
-    over its ``rows_ref[s]`` rows (and, of them, those within
-    ``reach_ref[0]`` of its own), fetched ``per_block`` pages at a time
-    from ``kv_hbm [num_blocks, BS, 2 * kv_heads * D]`` through its pages
+    """One lane (grid step ``s``): its query heads ``q_ref [1, KV * G,
+    C]`` (:func:`_layout`: ``G`` rows a group, ``C`` lanes a query) over
+    its ``rows_ref[s]`` rows (and, of them, those within ``reach_ref[0]``
+    of its own), fetched ``per_block`` pages at a time from ``kv_hbm
+    [num_blocks, BS, 2 * kv_heads * D]`` through its pages
     ``pages_ref[s]`` into one of two VMEM buffers while the other is
     scored; a running softmax a K/V head (``m_ref``, ``l_ref`` ``[KV,
-    G, 128]``, every lane alike; ``acc_ref [A, D]`` the weighted rows,
-    unnormalised)."""
+    G, 128]``, every lane alike; ``acc_ref [KV * G, C]`` the weighted
+    rows, unnormalised). Where ``C`` is twice the head size, a head's K
+    and V are one column ``[k_h | v_h]`` and the weighted rows' V half is
+    the result."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     s = pl.program_id(0)
     _, BS, width = kv_hbm.shape
-    _, A, D = q_ref.shape
+    _, A, C = q_ref.shape
     G, T = A // kv_heads, per_block * BS
+    D = width // (2 * kv_heads)
     rows = rows_ref[s]
     n_pages = (rows + BS - 1) // BS
     n_blocks = (n_pages + per_block - 1) // per_block
@@ -193,8 +222,11 @@ def _decode_kernel(pages_ref, rows_ref, reach_ref, q_ref, kv_hbm, o_ref,
         pos = blk * T + jax.lax.broadcasted_iota(jnp.int32, (G, T), 1)
         seen = jnp.logical_and(pos < rows, pos > rows - 1 - reach_ref[0])
         for h in range(kv_heads):
-            k = kv[:, 2 * h * D:(2 * h + 1) * D]
-            v = kv[:, (2 * h + 1) * D:(2 * h + 2) * D]
+            if C == D:
+                k = kv[:, 2 * h * D:(2 * h + 1) * D]
+                v = kv[:, (2 * h + 1) * D:(2 * h + 2) * D]
+            else:
+                k = v = kv[:, h * C:(h + 1) * C]
             q = q_ref[0, h * G:(h + 1) * G, :]
             sc = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
@@ -211,15 +243,15 @@ def _decode_kernel(pages_ref, rows_ref, reach_ref, q_ref, kv_hbm, o_ref,
             pv = jnp.dot(p.astype(v.dtype), v,
                          preferred_element_type=jnp.float32)
             rows_h = slice(h * G, (h + 1) * G)
-            acc_ref[rows_h, :] = _lanes(alpha, D) * acc_ref[rows_h, :] \
-                + _lanes(beta, D) * pv
+            acc_ref[rows_h, :] = _lanes(alpha, C) * acc_ref[rows_h, :] \
+                + _lanes(beta, C) * pv
         return carry
 
     jax.lax.fori_loop(0, n_blocks, block, 0)
     for h in range(kv_heads):
         rows_h = slice(h * G, (h + 1) * G)
         o_ref[0, rows_h, :] = (acc_ref[rows_h, :]
-                               / _lanes(l_ref[h], D)).astype(o_ref.dtype)
+                               / _lanes(l_ref[h], C)).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "per_block"))
@@ -230,8 +262,16 @@ def _paged_decode_kernel(q, leaf, pages, rows, reach, *, scale: float,
     R, A, D = q.shape
     _, BS, width = leaf.shape
     kv_heads = width // (2 * D)
-    lane = pl.BlockSpec((1, A, D), lambda s, *_: (s, 0, 0))
-    return pl.pallas_call(
+    G = A // kv_heads
+    Gp, C = _layout(q.dtype, G, D)
+    laid = q
+    if (Gp, C) != (G, D):
+        # zero rows up to whole sublane tiles, zero lanes over V
+        laid = jnp.pad(q.reshape(R, kv_heads, G, D),
+                       ((0, 0), (0, 0), (0, Gp - G), (0, C - D))
+                       ).reshape(R, kv_heads * Gp, C)
+    lane = pl.BlockSpec((1, kv_heads * Gp, C), lambda s, *_: (s, 0, 0))
+    out = pl.pallas_call(
         functools.partial(_decode_kernel, scale=scale, kv_heads=kv_heads,
                           per_block=per_block),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -241,14 +281,19 @@ def _paged_decode_kernel(q, leaf, pages, rows, reach, *, scale: float,
             scratch_shapes=[
                 pltpu.VMEM((2, per_block, BS, width), leaf.dtype),
                 pltpu.SemaphoreType.DMA((2,)),
-                pltpu.VMEM((kv_heads, A // kv_heads, 128), jnp.float32),
-                pltpu.VMEM((kv_heads, A // kv_heads, 128), jnp.float32),
-                pltpu.VMEM((A, D), jnp.float32)]),
+                pltpu.VMEM((kv_heads, Gp, 128), jnp.float32),
+                pltpu.VMEM((kv_heads, Gp, 128), jnp.float32),
+                pltpu.VMEM((kv_heads * Gp, C), jnp.float32)]),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct(laid.shape, q.dtype),
         name="paged_decode_attention",
-    )(pages, rows, reach, q, leaf)
+    )(pages, rows, reach, laid, leaf)
+    if laid is q:
+        return out
+    # the group's own rows, and of a [k_h | v_h] column its V half
+    return out.reshape(R, kv_heads, Gp, C)[:, :, :G, C - D:].reshape(
+        R, A, D)
 
 
 def paged_kernel():
@@ -283,6 +328,6 @@ def paged_decode(q, leaf, pages, rows, scale: float,
                           per_block=min(KV_PAGES_PER_BLOCK, pages.shape[1]))
 
 
-__all__ = ["NEG", "softmax_merge", "over_spans", "kernel_refusal",
-           "window_start", "decode_pages", "paged_kernel", "paged_decode",
-           "KV_PAGES_PER_BLOCK"]
+__all__ = ["NEG", "softmax_merge", "spans_held", "over_spans",
+           "kernel_refusal", "window_start", "decode_pages", "paged_kernel",
+           "paged_decode", "KV_PAGES_PER_BLOCK"]

@@ -224,3 +224,53 @@ def test_the_cells_decode_program_reads_its_pages_in_place(one_chip,
     assert len(re.findall(r"paged_decode_attention[.0-9]* = ", text)) == 4
     assert "[32,672,16," not in text and "[32,257,16," not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9
+
+
+def test_lfm2s_decode_program_reads_its_pages_in_place(one_chip, as_tpu):
+    """The whole decode program of `lfm2_mixed_closed` (16 layers at the
+    published widths, 4 of them attention with 32 query heads over 8 K/V
+    heads of 64, 64 lanes, the table at its top rung of 288 entries),
+    lowered for one v5e: every attention layer takes the decode kernel
+    (a head of 64 as one 128-lane column, the group of 4 padded to 16
+    rows), no span of the table is gathered (no ``[64, 32, 16, ...]``
+    array) nor split head-major (no ``[64, 512, 8, 2, 64]``), and a leaf
+    is only ever written in place (the fresh row's scatter), so the
+    temporaries stay under 0.2 GB (0.034; the span loop took 0.45 GB by
+    the same compile)."""
+    import json
+    import os
+    import jax
+    import jax.numpy as jnp
+    from deeplearning4j_tpu.monitor import attention
+    from deeplearning4j_tpu.zoo import lfm2_moe as zoo
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "lfm2-8b-a1b.json")) as f:
+        pc = zoo.Lfm2MoeConfig.from_dict(json.load(f))
+    S, E = 64, 288
+    nb = S * E + 1
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=one_chip)
+    params = {n: sds(s, jnp.bfloat16)
+              for n, s in zoo.lfm2_moe_param_shapes(pc).items()}
+    kc = tuple(sds((nb, 16, 1024), jnp.bfloat16)
+               for _ in pc.attention_layers)
+    state = (tuple(sds((S + 1, 2, pc.hidden_size), jnp.bfloat16)
+                   for _ in pc.conv_layers),)
+    io = {"tokens": sds((S,), jnp.int32), "positions": sds((S,), jnp.int32),
+          "write_off": sds((S,), jnp.int32), "active": sds((S,), jnp.bool_),
+          "tables": sds((S, E), jnp.int32),
+          "write_block": sds((S,), jnp.int32)}
+    _, decode_fn = zoo.lfm2_moe_paged_decode_fns(pc, 16, E)
+    compiled = jax.jit(decode_fn, donate_argnums=(1, 2, 4)).lower(
+        params, kc, (), io, state).compile()
+    assert attention.last_decode_program().counts() == (4, 0, None)
+    text = compiled.as_text()
+    assert len(re.findall(r"paged_decode_attention[.0-9]* = ", text)) == 4
+    assert "[64,512,8,2,64]" not in text and "[64,32,16," not in text
+    made = re.findall(rf"= bf16\[{nb},16,1024\]\S* ([\w-]+)\((.*)$", text,
+                      re.M)
+    assert {op for op, _ in made} == {"parameter", "fusion", "scatter"}
+    assert all(re.search(r'op_name="[^"]*scatter"', rest)
+               for op, rest in made if op == "fusion")
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.2e9

@@ -733,26 +733,48 @@ def test_the_one_leaf_keeps_the_cells_bytes_and_memory_report():
                               tp=2)
 
 
-@pytest.mark.parametrize("window,per_block", [(64, 1), (64, 2), (None, 2),
-                                              (None, 5)])
-def test_the_decode_kernel_gives_the_shipped_kernels_reference(window,
-                                                               per_block):
+#: (K/V heads, query heads a group, head size, dtype): Command A+'s group
+#: of 16 over heads of 128 in bf16, which the kernel takes as it comes;
+#: LFM2-8B-A1B's 4 over 8 heads of 64, one column ``[k_h | v_h]`` a head
+#: and the group padded to 16 rows (8 in float32); SmallThinker's 7 over
+#: heads of 128, padded; a group of 4 over heads of 128 in float32
+CMDA, LFM2 = (2, 16, 128, "bfloat16"), (8, 4, 64, "bfloat16")
+LFM2_F32, ST = (8, 4, 64, "float32"), (4, 7, 128, "bfloat16")
+G4_F32 = (2, 4, 128, "float32")
+
+
+@pytest.mark.parametrize("window,per_block,heads", [
+    pytest.param(64, 1, CMDA, id="64-1"),
+    pytest.param(64, 2, CMDA, id="64-2"),
+    pytest.param(None, 2, CMDA, id="None-2"),
+    pytest.param(None, 5, CMDA, id="None-5"),
+    pytest.param(64, 1, LFM2, id="lfm2-64-1"),
+    pytest.param(64, 2, LFM2, id="lfm2-64-2"),
+    pytest.param(None, 1, LFM2, id="lfm2-None-1"),
+    pytest.param(None, 2, LFM2, id="lfm2-None-2"),
+    pytest.param(None, 2, LFM2_F32, id="lfm2-f32-None-2"),
+    pytest.param(64, 2, ST, id="st-64-2"),
+    pytest.param(64, 1, G4_F32, id="g4-f32-64-1"),
+])
+def test_the_decode_kernel_gives_the_shipped_kernels_reference(
+        window, per_block, heads):
     """The repo's decode kernel, run by the TPU interpreter on the CPU,
     against the shipped ragged kernel's reference over the same inputs
-    (the leaf as its ``kv_pages``): 4 lanes of 32 query heads over 2 K/V
-    heads of 128, blocks of 16, a ring of 5 (window 64) or a table of 5;
-    a lane in its first window, one whose ring has turned, one at the
-    end of a block, an idle one; a page or two a block, or the whole
-    table in one. Within the rounding of the bf16 result."""
+    (the leaf as its ``kv_pages``): 4 lanes of a group of query heads
+    over K/V heads (``heads``), blocks of 16, a ring of 5 (window 64) or
+    a table of 5; a lane in its first window, one whose ring has turned,
+    one at the end of a block, an idle one; a page or two a block, or the
+    whole table in one. Within the rounding of the result's dtype."""
     import jax.numpy as jnp
     from jax.experimental.pallas import tpu as pltpu
     from jax.experimental.pallas.ops.tpu.ragged_paged_attention import \
         ref_ragged_paged_attention
-    R, KV, G, D, B, E, nb = 4, 2, 16, 128, 16, 5, 40
+    KV, G, D, dtype = heads
+    R, B, E, nb = 4, 16, 5, 40
+    dt = jnp.dtype(dtype)
     rng = np.random.default_rng(7)
-    leaf = jnp.asarray(rng.standard_normal((nb, B, 2 * KV * D)),
-                       jnp.bfloat16)
-    q = jnp.asarray(rng.standard_normal((R, KV * G, D)), jnp.bfloat16)
+    leaf = jnp.asarray(rng.standard_normal((nb, B, 2 * KV * D)), dt)
+    q = jnp.asarray(rng.standard_normal((R, KV * G, D)), dt)
     table = jnp.asarray(rng.permutation(np.arange(1, nb))[:R * E]
                         .reshape(R, E), jnp.int32)
     pos = jnp.asarray([20, 90, 79, 9] if window else [20, 70, 79, 0],
@@ -767,8 +789,10 @@ def test_the_decode_kernel_gives_the_shipped_kernels_reference(window,
         sm_scale=D ** -0.5, sliding_window=window), np.float32)
     reach = jnp.asarray([window or paged_attend._EVERY_ROW], jnp.int32)
     with pltpu.force_tpu_interpret_mode():
-        got = np.asarray(paged_attend.paged_kernel()(
+        got = paged_attend.paged_kernel()(
             q, leaf, pages, rows, reach, scale=D ** -0.5,
-            per_block=per_block), np.float32)
+            per_block=per_block)
+    assert got.shape == q.shape and got.dtype == q.dtype
     # bf16 output: 2**-9 of a value, a few deviations out
-    assert np.abs(got - want).max() < 0.02 * want.std()
+    assert np.abs(np.asarray(got, np.float32) - want).max() \
+        < 0.02 * want.std()

@@ -416,7 +416,8 @@ def test_the_program_counts_what_a_count_by_hand_gives(spec, pc):
     """One decode step of three lanes, two of them active at position 0
     (nothing cached, a state of zeros): the counters behind the tokens
     against a count by hand from each lane's own forward, and its logits
-    the forward's."""
+    the forward's; the plain read gathers the whole table of 16 entries,
+    one span, for each active lane on each attention layer."""
     import jax.numpy as jnp
     _, decode, leaf, state = _programs(spec, pc)
     state = (tuple(jnp.zeros_like(a) for a in state[0]),)
@@ -445,7 +446,8 @@ def test_the_program_counts_what_a_count_by_hand_gives(spec, pc):
         "moe_experts_touched_sum": int((served > 0).sum()),
         "moe_tokens_routed_sum": 2 * 2 * 6,          # k x lanes x layers
         "moe_peak_expert_tokens_sum": int(served.max(axis=1).sum()),
-        "moe_bias_moved_sum": moved}
+        "moe_bias_moved_sum": moved,
+        "kv_pages_read_sum": 2 * 16 * 2}      # lanes x entries x layers
     assert served.sum() == 2 * 2 * 6
 
 
@@ -522,18 +524,70 @@ def test_the_kernel_path_gives_the_plain_paths_decode_logits(monkeypatch):
         assert off_by(g, w) < 0.05
 
 
-def test_the_cells_head_size_takes_the_plain_read_on_the_chip():
-    """At the published head size of 64 the kernel, which takes a head's
-    K and V as whole 128-lane columns, refuses a TPU program: the cell's
-    decode reads through the plain span read (PERF.md section 5)."""
+@pytest.mark.parametrize("dtype,heads,kv_heads,head_dim,why", [
+    ("bfloat16", 32, 8, 64, None),        # the cell: a head of 64, G 4
+    ("bfloat16", 128, 8, 128, None),      # Command A+'s
+    ("float32", 32, 8, 64, None),         # G 4 pads to 8 rows
+    ("bfloat16", 28, 4, 128, None),       # SmallThinker's 7 pads to 16
+    ("bfloat16", 32, 8, 32, "head size 32"),
+    ("bfloat16", 32, 8, 96, "head size 96"),
+    ("float16", 32, 8, 64, "dtype float16"),
+    ("bfloat16", 30, 8, 64, "heads 30 over 8"),
+])
+def test_kernel_refusal_on_a_tpu(dtype, heads, kv_heads, head_dim, why):
+    """On a TPU the decode kernel takes the cell's head of 64 (one
+    128-lane column ``[k_h | v_h]`` a head) and its group of 4 query
+    heads (padded to whole sublane tiles), and still refuses another
+    head size, a leaf in another dtype and heads that do not group."""
     import jax.numpy as jnp
     monkey = paged_attend.jax.default_backend
     try:
         paged_attend.jax.default_backend = lambda: "tpu"
-        assert paged_attend.kernel_refusal(jnp.bfloat16, 32, 8, 64) == \
-            "head size 64"
+        assert paged_attend.kernel_refusal(
+            jnp.dtype(dtype), heads, kv_heads, head_dim) == why
     finally:
         paged_attend.jax.default_backend = monkey
+
+
+def test_kv_pages_read_sum_is_a_count_by_hand(spec, pc, monkeypatch):
+    """One decode step of four lanes over a table of 64 entries (two
+    spans of 32, blocks of 4), three active at positions 0, 150 and 22
+    and one idle: the kernel path counts each active lane's own pages on
+    each of the 2 attention layers, ``p // 4 + 1``; the plain path every
+    entry it gathered, as many spans as the longest lane fills (150 rows
+    of 128 a span: 2) for each active lane."""
+    import jax
+    import jax.numpy as jnp
+    S = 4
+    io = {"tokens": np.array([5, 17, 40, 3], np.int32),
+          "positions": np.array([0, 150, 22, 9], np.int32),
+          "active": np.array([True, True, True, False]),
+          "write_off": np.array([0, 2, 2, 1], np.int32),
+          "tables": np.zeros((S, 64), np.int32),
+          "write_block": np.array([1, 2, 3, 0], np.int32)}
+    leaf = tuple(jnp.zeros((8, BS, 2 * 2 * 16), jnp.bfloat16)
+                 for _ in pc.attention_layers)
+    state = (tuple(jnp.zeros((S + 1, 2, 64), jnp.bfloat16)
+                   for _ in pc.conv_layers),)
+
+    def read(kernel):
+        if kernel:
+            monkeypatch.setattr(paged_attend, "kernel_refusal",
+                                lambda *a: None)
+            monkeypatch.setattr(paged_attend, "paged_kernel",
+                                lambda: _reference_kernel)
+        _, decode_fn = lfm2_moe_paged_spec(pc, spec.params()).make_fns(BS,
+                                                                       64)
+        _, _, nxt, _, _ = jax.jit(decode_fn)(spec.params(), leaf, (), io,
+                                             state)
+        return dict(zip(PROGRAM_COUNTERS, np.asarray(nxt)[S:].tolist()))
+
+    plain = read(False)["kv_pages_read_sum"]
+    assert attention.last_decode_program().counts() == (0, 2, "backend cpu")
+    kernel = read(True)["kv_pages_read_sum"]
+    assert attention.last_decode_program().counts() == (2, 0, None)
+    assert kernel == 2 * sum(p // 4 + 1 for p in (0, 150, 22)) == 90
+    assert plain == 2 * 3 * 2 * 32
 
 
 # -- the cell's size, with nothing allocated --------------------------------
