@@ -517,10 +517,11 @@ SPAN_CATALOG: Dict[str, Tuple[str, Tuple[str, ...]]] = {
     # the device where a spec names counters (PagedGenerativeSpec.
     # program_counters: the expert layers' moe_layer_steps,
     # moe_experts_touched_sum, moe_tokens_routed_sum,
-    # moe_peak_expert_tokens_sum and, for a sigmoid router with a
-    # correction bias, moe_bias_moved_sum; a two-store cache's
-    # kv_rows_attended_sum), packed behind the next tokens; ``turns``:
-    # lanes whose tumbling window was given back at this step's boundary;
+    # moe_peak_expert_tokens_sum, moe_stream_layer_steps and, for a
+    # sigmoid router with a correction bias, moe_bias_moved_sum; a
+    # two-store cache's kv_rows_attended_sum), packed behind the next
+    # tokens; ``turns``: lanes whose tumbling window was given back at
+    # this step's boundary;
     # ``table_entries``: the table entries a lane the step was sent, over
     # the tiers on the ladder, and ``table_entries.<tier>`` each named
     # tier's own (every tier takes the rung of its own longest lane)

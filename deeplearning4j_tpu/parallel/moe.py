@@ -19,11 +19,17 @@ TPU framework expresses it:
 Beside the Switch layer, which drops what overflows an expert's
 capacity, stands a DROPLESS top-k layer for models whose reference drops
 nothing (:func:`topk_route` or :func:`sigmoid_bias_route`, then
-:func:`dropless_topk_ffn`): the (token, choice) pairs are sorted by
-expert and go through one grouped product (``jax.lax.ragged_dot``, or
-:func:`tiled_grouped_dot` where the caller names it) per weight, so its
-shapes are static too (``tokens x k`` rows whatever the load of an
-expert) and only the experts that have a token are read. It is told
+:func:`dropless_topk_ffn`). Its shapes are static too whatever the load
+of an expert, and only the experts that have a token are read, by one of
+two products chosen from the call's shape. A FEW tokens (a decode step,
+up to :data:`STREAM_TOKENS`) stream the experts
+(:func:`expert_stream_ffn`): every touched expert multiplies every row,
+its weights read once at the HBM's rate, which costs nothing while the
+rows are below the chip's ridge (FLOPs a byte of weights). MANY tokens
+(a prefill chunk) sort the (token, choice) pairs by expert into one
+grouped product a weight (``jax.lax.ragged_dot``, or
+:func:`tiled_grouped_dot` where the caller names it), ``tokens x k``
+rows, each pair's row multiplied by its own expert alone. It is told
 which experts it holds, routes over all of them, and returns the part of
 the result its own experts give: a chip that holds a share of the
 experts runs the same function, and the shares add up to the whole
@@ -33,6 +39,7 @@ which is added once.
 """
 from __future__ import annotations
 
+import functools
 from typing import Callable, Optional, Tuple
 
 import jax
@@ -195,13 +202,12 @@ def tiled_grouped_dot(lhs, rhs, group_sizes):
     K and ``GROUPED_TILE_COLS`` columns: a group's weights are read once a
     tile of rows that holds one of its rows, where ``ragged_dot`` at 2,048
     rows reads every group's (0.66 ms against 1.35 a product of the shape
-    above; at a decode step's 32 rows ``ragged_dot`` reads only the groups
-    that have a row, and is what :func:`dropless_topk_ffn` takes where a
-    caller names nothing). M fills whole tiles of rows and N whole tiles
-    of columns (ValueError). Rows past the last group belong to nobody and
-    their result is not one (the kernel wants every row in a group: they ride
-    with the last). The kernel is a TPU's: on another backend it is
-    interpreted (the tests' path)."""
+    above; a decode step's few rows are streamed instead,
+    :func:`expert_stream_ffn`). M fills whole tiles of rows and N whole
+    tiles of columns (ValueError). Rows past the last group belong to
+    nobody and their result is not one (the kernel wants every row in a
+    group: they ride with the last). The kernel is a TPU's: on another
+    backend it is interpreted (the tests' path)."""
     from jax.experimental.pallas.ops.tpu.megablox import gmm
     m, k = lhs.shape
     if m % GROUPED_TILE_ROWS or rhs.shape[2] % GROUPED_TILE_COLS:
@@ -213,6 +219,159 @@ def tiled_grouped_dot(lhs, rhs, group_sizes):
     return gmm(lhs, rhs, sizes, jnp.float32,
                (GROUPED_TILE_ROWS, k, GROUPED_TILE_COLS),
                interpret=jax.default_backend() != "tpu")
+
+
+#: tokens of a call up to which :func:`dropless_topk_ffn` streams the
+#: experts (:func:`expert_stream_ffn`): every touched expert multiplies
+#: every row, N FLOPs a byte of bfloat16 weights, and a v5e's ridge is
+#: 197e12 / 819e9 = 240 FLOPs a byte (``benchmark/peaks.json``), so at
+#: 128 rows the MXU's work is at most about half the weights' stream and
+#: hides under it. A decode step's 8 to 64 lanes stream; a 512-token
+#: prefill chunk would pass the ridge and keeps the grouped product. On a
+#: v5e at the four MoE families' decode calls the streamed layer read
+#: its experts at 84-90% of the HBM's rate where ``ragged_dot`` read them
+#: at 46-81%, GLM-4.7-Flash's 8 x 4 of 64 (81%) included, so no call
+#: below the constant is better sorted (``experiments/expert_stream.py``)
+STREAM_TOKENS = 128
+
+#: the largest tile of an expert's width :func:`expert_stream_ffn` reads
+#: a grid step, and the VMEM its three weight tiles may fill, double
+#: buffered: at those calls tiles of 128 to 1,024 read within 2% of one
+#: another but for GLM-4.7-Flash's 128 (5% slower)
+STREAM_TILE, STREAM_TILE_BYTES = 512, 24 << 20
+
+
+def streams_experts(tokens: int) -> bool:
+    """Whether :func:`dropless_topk_ffn` over a call of ``tokens`` tokens
+    streams the experts (:data:`STREAM_TOKENS`); a decode program counts
+    its expert layers by this (``moe_stream_layer_steps``)."""
+    return int(tokens) <= STREAM_TOKENS
+
+
+def _stream_tile(width: int, d: int, itemsize: int) -> int:
+    """The tile of an expert's ``width`` a grid step reads: the largest
+    multiple of 128 that divides it, is at most :data:`STREAM_TILE` and
+    whose three tiles of ``d`` fit :data:`STREAM_TILE_BYTES` double
+    buffered; the whole width where no multiple of 128 divides it."""
+    for t in range(min(STREAM_TILE, width) // 128 * 128, 0, -128):
+        if width % t == 0 and 2 * 3 * d * t * itemsize <= STREAM_TILE_BYTES:
+            return t
+    return width
+
+
+def _stream_kernel(src_ref, fixed_ref, x_ref, w_ref, g_ref, u_ref, dn_ref,
+                   y_ref, acc_ref, *, activation, tiles: int):
+    from jax.experimental import pallas as pl
+    e, j = pl.program_id(0), pl.program_id(1)
+
+    @pl.when((e == 0) & (j == 0))
+    def _():
+        y_ref[...] = jnp.zeros_like(y_ref)
+
+    @pl.when(fixed_ref[e] < 0)                  # the expert has a token
+    def _():
+        x = x_ref[...]
+        g = jnp.dot(x, g_ref[...], preferred_element_type=jnp.float32)
+        u = jnp.dot(x, u_ref[...], preferred_element_type=jnp.float32)
+        h = (activation(g) * u).astype(dn_ref.dtype)
+        part = jnp.dot(h, dn_ref[...], preferred_element_type=jnp.float32)
+
+        @pl.when(j == 0)
+        def _():
+            acc_ref[...] = part
+
+        @pl.when(j > 0)
+        def _():
+            acc_ref[...] += part
+
+        @pl.when(j == tiles - 1)
+        def _():
+            # the routing weights' column of this expert
+            w = w_ref[...]
+            lane = jax.lax.broadcasted_iota(jnp.int32, w.shape, 1)
+            col = jnp.sum(jnp.where(lane == e, w, 0.0), axis=1,
+                          keepdims=True)
+            y_ref[...] += acc_ref[...] * col
+
+
+def expert_stream_ffn(x, w, tokens, w_gate, w_up, w_down,
+                      activation: Callable = jax.nn.relu):
+    """The gated experts over a FEW tokens, expert by expert: ``x (N,
+    d)``, ``w (N, held)`` float32 each token's routing weight for each
+    held expert (0 where it did not choose it), ``tokens (held,)`` the
+    tokens each expert serves; the weights as :func:`dropless_topk_ffn`
+    takes them. Returns ``y (N, d)`` float32, ``sum_e w[:, e] *
+    (activation(x @ gate_e) * (x @ up_e)) @ down_e``.
+
+    A Pallas kernel over the grid (held expert, tile of its width): the
+    call's rows stay in VMEM and every expert with a token multiplies all
+    of them, its three matrices read tile by tile exactly once a call. An
+    expert without a token is read not at all: its steps name the block
+    of the next expert that has one (behind the last, the last's), so no
+    copy is issued for them and the next expert's first tile is on its
+    way while they pass, and its body does not run. Operands in the
+    weights' dtype, products accumulated in float32; the activation's
+    product goes into ``down`` in the weights' dtype, as the grouped
+    products take it; a step reads :func:`_stream_tile` of the width. The
+    kernel is a TPU's: on another backend it is interpreted (the tests'
+    path)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    n, d = x.shape
+    held, _, width = w_gate.shape
+    item = jnp.dtype(w_gate.dtype).itemsize
+    tf = _stream_tile(width, d, item)
+    tiles = width // tf
+    # rows up to whole sublane tiles of the narrowest operand
+    rows = -(-n // 16) * 16
+    x = jnp.pad(x.astype(w_gate.dtype), ((0, rows - n), (0, 0)))
+    w = jnp.pad(w.astype(jnp.float32), ((0, rows - n), (0, 0)))
+    # each expert's block: its own tiles where it has a token (``fixed``
+    # -1); else the first tile of the next expert that has one, or behind
+    # the last such the last's last tile, the block already there
+    touched = tokens > 0
+    e = jnp.arange(held, dtype=jnp.int32)
+    nxt = jax.lax.cummin(jnp.where(touched, e, held), axis=0, reverse=True)
+    last = jax.lax.cummax(jnp.where(touched, e, -1), axis=0)
+    src = jnp.where(nxt < held, nxt, jnp.maximum(last, 0))
+    fixed = jnp.where(touched, -1, jnp.where(nxt < held, 0, tiles - 1))
+
+    def part(e, j, src, fixed):
+        return src[e], jnp.where(fixed[e] < 0, j, fixed[e])
+
+    def wide(e, j, *s):                         # gate, up: (d, tf)
+        at, t = part(e, j, *s)
+        return at, 0, t
+
+    def tall(e, j, *s):                         # down: (tf, d)
+        at, t = part(e, j, *s)
+        return at, t, 0
+
+    whole = lambda *_: (0, 0)                              # noqa: E731
+    # the weights' tiles, x, w and y double-buffered, the expert's sums
+    # and the activations
+    vmem = 2 * (3 * d * tf * item + rows * d * item + rows * held * 4
+                + rows * d * 4) + rows * d * 4 + 3 * rows * tf * 4
+    return pl.pallas_call(
+        functools.partial(_stream_kernel, activation=activation,
+                          tiles=tiles),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(held, tiles),
+            in_specs=[pl.BlockSpec((rows, d), whole),
+                      pl.BlockSpec((rows, held), whole),
+                      pl.BlockSpec((None, d, tf), wide),
+                      pl.BlockSpec((None, d, tf), wide),
+                      pl.BlockSpec((None, tf, d), tall)],
+            out_specs=pl.BlockSpec((rows, d), whole),
+            scratch_shapes=[pltpu.VMEM((rows, d), jnp.float32)]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=max(32 << 20, int(vmem * 1.25))),
+        out_shape=jax.ShapeDtypeStruct((rows, d), jnp.float32),
+        interpret=jax.default_backend() != "tpu",
+        name="expert_stream_ffn",
+    )(src.astype(jnp.int32), fixed.astype(jnp.int32), x, w, w_gate, w_up,
+      w_down)[:n]
 
 
 def dropless_topk_ffn(x, idx, weights, w_gate, w_up, w_down,
@@ -230,14 +389,21 @@ def dropless_topk_ffn(x, idx, weights, w_gate, w_up, w_down,
     (held, f, d) are experts ``first_expert .. first_expert + held - 1``.
     A pair routed to an expert held elsewhere adds nothing here, and
     neither does a token where ``valid`` (N,) is false (padding, an idle
-    decode lane): such pairs sort behind every group and no expert is
-    read for them. Returns ``(y (N, d) float32, tokens (held,) int32)``,
-    the second the number of tokens each held expert served.
+    decode lane): such pairs weigh nothing and no expert is read for
+    them. Returns ``(y (N, d) float32, tokens (held,) int32)``, the
+    second the number of tokens each held expert served.
 
     Operands go into the products in the weights' dtype and accumulate
-    in float32. ``grouped(lhs, rhs, group_sizes)`` is the grouped product
+    in float32. Which product runs follows the call's shape
+    (:func:`streams_experts`): up to :data:`STREAM_TOKENS` tokens (a
+    decode step) the experts are STREAMED (:func:`expert_stream_ffn`),
+    each touched expert's weights read once at the HBM's rate and every
+    row multiplied, which costs nothing below the chip's ridge; a longer
+    call (a prefill chunk, past the ridge) sorts its pairs by expert and
+    runs ``grouped(lhs, rhs, group_sizes)``, the grouped product
     (``jax.lax.ragged_dot`` where none is named; a caller that knows its
-    run is long names :func:`tiled_grouped_dot`)."""
+    run is long names :func:`tiled_grouped_dot`), which multiplies each
+    pair's row alone."""
     n, k = idx.shape
     held = w_gate.shape[0]
     local = idx - jnp.int32(first_expert)
@@ -246,6 +412,60 @@ def dropless_topk_ffn(x, idx, weights, w_gate, w_up, w_down,
         mine = mine & valid[:, None]
     flat = jnp.where(mine, local, held).reshape(-1)        # (N k,)
     tokens = jnp.zeros(held + 1, jnp.int32).at[flat].add(1)[:held]
+    w = jnp.where(mine, weights, 0.0).astype(jnp.float32)
+    if streams_experts(n):
+        return _streamed(x, flat, w, tokens, w_gate, w_up, w_down,
+                         activation), tokens
+    return _sorted_grouped(x, flat, w, tokens, w_gate, w_up, w_down,
+                           activation, grouped), tokens
+
+
+def stream_kernel() -> bool:
+    """Whether :func:`dropless_topk_ffn` streams the experts through the
+    kernel, :func:`expert_stream_ffn`: on a TPU. Elsewhere (the CPU) it
+    computes the kernel's sums in XLA, every held expert over every row
+    (:func:`_expert_major`): the kernel interpreted would cost a CPU
+    program some 0.5 ms a layer (tests put the kernel in its place)."""
+    return jax.default_backend() == "tpu"
+
+
+def _expert_major(x, w, tokens, w_gate, w_up, w_down, activation):
+    """:func:`expert_stream_ffn`'s result in XLA: every held expert over
+    every row, each weighed by its column of ``w`` (``tokens`` unread).
+    The operands are rounded to the weights' dtype and multiplied in
+    float32, which is exact for them (XLA's CPU takes no batched
+    bfloat16 product into float32)."""
+    def f32(a, dtype=w_gate.dtype):
+        return a.astype(dtype).astype(jnp.float32)
+
+    x = f32(x)
+    g = jnp.einsum("nd,edf->enf", x, f32(w_gate))
+    u = jnp.einsum("nd,edf->enf", x, f32(w_up))
+    out = jnp.einsum("enf,efd->end", f32(activation(g) * u, w_down.dtype),
+                     f32(w_down))
+    return jnp.einsum("ne,end->nd", w.astype(jnp.float32), out)
+
+
+def _streamed(x, flat, w, tokens, w_gate, w_up, w_down, activation):
+    """:func:`dropless_topk_ffn`'s experts streamed: each pair's weight
+    ``w (N, k)`` put in its expert's column (``flat``, the pairs' held
+    expert or ``held`` for none, weighs nothing there)."""
+    n, k = w.shape
+    held = w_gate.shape[0]
+    cols = jnp.zeros((n, held + 1), jnp.float32).at[
+        jnp.arange(n)[:, None], flat.reshape(n, k)].add(w)
+    product = expert_stream_ffn if stream_kernel() else _expert_major
+    return product(x, cols[:, :held], tokens, w_gate, w_up, w_down,
+                   activation)
+
+
+def _sorted_grouped(x, flat, w, tokens, w_gate, w_up, w_down, activation,
+                    grouped=None):
+    """:func:`dropless_topk_ffn`'s pairs sorted by expert through a
+    grouped product (``jax.lax.ragged_dot`` where ``grouped`` is None);
+    the pairs of no held expert sort behind every group and read
+    nothing."""
+    n, k = w.shape
     order = jnp.argsort(flat, stable=True)
     rows = x[order // k].astype(w_gate.dtype)              # (N k, d)
 
@@ -262,9 +482,7 @@ def dropless_topk_ffn(x, idx, weights, w_gate, w_up, w_down,
     out = jnp.where((jnp.arange(n * k) < jnp.sum(tokens))[:, None], out, 0.0)
     back = jnp.zeros(n * k, jnp.int32).at[order].set(
         jnp.arange(n * k, dtype=jnp.int32))
-    w = jnp.where(mine, weights, 0.0).astype(jnp.float32)
-    y = jnp.sum(out[back].reshape(n, k, -1) * w[:, :, None], axis=1)
-    return y, tokens
+    return jnp.sum(out[back].reshape(n, k, -1) * w[:, :, None], axis=1)
 
 
 def init_moe_params(rng, d_model: int, d_ff: int, n_experts: int,

@@ -66,7 +66,8 @@ import numpy as np
 from deeplearning4j_tpu.compilecache.cache import COMPILE_STATS
 from deeplearning4j_tpu.monitor import attention
 from deeplearning4j_tpu.parallel.moe import (dropless_topk_ffn,
-                                             sigmoid_bias_route)
+                                             sigmoid_bias_route,
+                                             streams_experts)
 from deeplearning4j_tpu.zoo import paged_attend
 from deeplearning4j_tpu.zoo.paged_attend import (NEG, over_spans,
                                                  softmax_merge)
@@ -209,15 +210,18 @@ class Cohere2MoeConfig:
 #: SmallThinker's four under their names (layers run, HELD experts with a
 #: token of an active lane, the (token, expert) pairs the routers chose
 #: over ALL the router's experts, the fullest held expert's tokens), the
-#: pairs whose expert is held here, and the pages the active lanes'
+#: pairs whose expert is held here, the pages the active lanes'
 #: attention read (the kernel: each lane's own, from the rows it was
-#: handed; the plain path: every entry of the table it gathered);
-#: ``block_size`` x the last over the server's ``kv_rows_gathered_sum``
-#: is the share of what the tables sent that was read (docs/serving.md
-#: says what an operator reads from each)
+#: handed; the plain path: every entry of the table it gathered) and the
+#: layers whose held experts were streamed
+#: (``parallel.moe.streams_experts``); ``block_size`` x the pages over
+#: the server's ``kv_rows_gathered_sum`` is the share of what the tables
+#: sent that was read (docs/serving.md says what an operator reads from
+#: each)
 PROGRAM_COUNTERS = ("moe_layer_steps", "moe_experts_touched_sum",
                     "moe_tokens_routed_sum", "moe_peak_expert_tokens_sum",
-                    "moe_held_pairs_sum", "kv_pages_read_sum")
+                    "moe_held_pairs_sum", "kv_pages_read_sum",
+                    "moe_stream_layer_steps")
 
 #: table entries a prefill run reads its cached rows through at a time,
 #: as GLM-4.7-Flash's (``glm_moe_lite.PREFILL_SPAN``): the scores of a
@@ -449,15 +453,17 @@ def _programs(cfg: Cohere2MoeConfig, block_size: int,
             read = jnp.sum(jnp.any(valid, axis=1), dtype=jnp.int32) \
                 * table.shape[1]
         # the EXPERTS, on the same n: the router over all its experts,
-        # the held experts' part, the shared experts' mean. The grouped
-        # products are ragged_dot's at every run, a prefill chunk's too:
-        # the held pairs are about k / router_experts of a run's rows, and
-        # ragged_dot multiplies those alone, where the tiled kernel
-        # (parallel.moe.tiled_grouped_dot) would multiply every row, the
-        # pairs routed elsewhere riding behind the last held expert, and
-        # at the published widths XLA copies each held expert stack into
-        # the kernel's layout first (a 512-token chunk then needs 18.4
-        # GiB of a v5e's 15.75, by a compile for the chip; PERF.md §4)
+        # the held experts' part, the shared experts' mean. A decode
+        # step's lanes stream the held experts (parallel.moe
+        # .streams_experts); a prefill chunk's grouped products are
+        # ragged_dot's: the held pairs are about k / router_experts of a
+        # run's rows, and ragged_dot multiplies those alone, where the
+        # tiled kernel (parallel.moe.tiled_grouped_dot) would multiply
+        # every row, the pairs routed elsewhere riding behind the last
+        # held expert, and at the published widths XLA copies each held
+        # expert stack into the kernel's layout first (a 512-token chunk
+        # then needs 18.4 GiB of a v5e's 15.75, by a compile for the
+        # chip; PERF.md §4)
         idx, wts, _ = sigmoid_bias_route(flat, lp["/router"], None, K)
         y, served = dropless_topk_ffn(
             flat, idx, wts, lp["/experts/gate"], lp["/experts/up"],
@@ -547,7 +553,8 @@ def _programs(cfg: Cohere2MoeConfig, block_size: int,
             jnp.int32(L), jnp.sum(served > 0, dtype=jnp.int32),
             jnp.sum(did[:, -1], dtype=jnp.int32),
             jnp.sum(jnp.max(served, axis=1), dtype=jnp.int32),
-            jnp.sum(served, dtype=jnp.int32), read])
+            jnp.sum(served, dtype=jnp.int32), read,
+            jnp.int32(L if streams_experts(tokens.shape[0]) else 0)])
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         return kc, vc, jnp.concatenate([nxt, counted]), logits
 

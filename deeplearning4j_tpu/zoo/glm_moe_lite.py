@@ -64,6 +64,7 @@ from deeplearning4j_tpu.parallel.moe import (GROUPED_TILE_COLS,
                                              GROUPED_TILE_ROWS,
                                              dropless_topk_ffn,
                                              sigmoid_bias_route,
+                                             streams_experts,
                                              tiled_grouped_dot)
 from deeplearning4j_tpu.zoo.paged_attend import (NEG, over_spans,
                                                  softmax_merge)
@@ -175,21 +176,22 @@ PREFILL_SPAN = 128
 #: tokens of a run from which the experts' grouped products are the tiled
 #: kernel (``parallel.moe.tiled_grouped_dot``): a prefill CHUNK, the one
 #: long run the paged path has. Measured at 512 tokens (2,048 sorted rows:
-#: 0.66 ms a product against ``ragged_dot``'s 1.35, a chunk 41 -> 28.6 ms)
-#: and at a decode step's 8 (32 rows, where ``ragged_dot`` reads only the
-#: experts that have a token); a chat prompt's 16 to 64 tokens stay with
-#: ``ragged_dot`` and nothing between was measured (PERF.md section 6)
+#: 0.66 ms a product against ``ragged_dot``'s 1.35, a chunk 41 -> 28.6 ms);
+#: up to ``parallel.moe.STREAM_TOKENS`` (a decode step, a chat prompt) the
+#: experts are streamed, and between that and this the pairs go through
+#: ``ragged_dot``: nothing between was measured (PERF.md section 6)
 TILED_RUN_TOKENS = 512
 
 #: what the decode program counts a step, summed over its expert layers:
 #: SmallThinker's four under their names (layers run, experts with a token
 #: of an active lane, (token, expert) pairs, the fullest expert's tokens)
-#: and the (token, expert) choices the correction bias changed against
-#: the largest bare sigmoids (docs/serving.md says what an operator reads
-#: from each)
+#: the (token, expert) choices the correction bias changed against the
+#: largest bare sigmoids, and the layers whose experts were streamed
+#: (``parallel.moe.streams_experts``; docs/serving.md says what an
+#: operator reads from each)
 PROGRAM_COUNTERS = ("moe_layer_steps", "moe_experts_touched_sum",
                     "moe_tokens_routed_sum", "moe_peak_expert_tokens_sum",
-                    "moe_bias_moved_sum")
+                    "moe_bias_moved_sum", "moe_stream_layer_steps")
 
 
 def glm_moe_lite_param_shapes(cfg: GlmMoeLiteConfig) -> Dict[str, tuple]:
@@ -488,7 +490,9 @@ def glm_moe_lite_paged_decode_fns(cfg: GlmMoeLiteConfig, block_size: int,
             jnp.int32(did.shape[0]), jnp.sum(served > 0, dtype=jnp.int32),
             jnp.sum(served, dtype=jnp.int32),
             jnp.sum(jnp.max(served, axis=1), dtype=jnp.int32),
-            jnp.sum(did[:, -1], dtype=jnp.int32)])
+            jnp.sum(did[:, -1], dtype=jnp.int32),
+            jnp.int32(did.shape[0] if streams_experts(tokens.shape[0])
+                      else 0)])
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         return kc, vc, jnp.concatenate([nxt, counted]), logits
 

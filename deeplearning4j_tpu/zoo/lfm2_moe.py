@@ -64,7 +64,8 @@ import numpy as np
 from deeplearning4j_tpu.compilecache.cache import COMPILE_STATS
 from deeplearning4j_tpu.monitor import attention
 from deeplearning4j_tpu.parallel.moe import (dropless_topk_ffn,
-                                             sigmoid_bias_route)
+                                             sigmoid_bias_route,
+                                             streams_experts)
 from deeplearning4j_tpu.zoo import paged_attend
 from deeplearning4j_tpu.zoo.paged_attend import (NEG, over_spans,
                                                  softmax_merge)
@@ -195,10 +196,13 @@ class Lfm2MoeConfig:
 #: sigmoids), and over its attention layers the pages the active lanes
 #: read, as Command A+ counts them (the kernel: each lane's own, from the
 #: rows it was handed; the plain path: every entry of the spans it
-#: gathered); docs/serving.md says what an operator reads from each
+#: gathered), and the expert layers whose experts were streamed
+#: (``parallel.moe.streams_experts``); docs/serving.md says what an
+#: operator reads from each
 PROGRAM_COUNTERS = ("moe_layer_steps", "moe_experts_touched_sum",
                     "moe_tokens_routed_sum", "moe_peak_expert_tokens_sum",
-                    "moe_bias_moved_sum", "kv_pages_read_sum")
+                    "moe_bias_moved_sum", "kv_pages_read_sum",
+                    "moe_stream_layer_steps")
 
 #: table entries a prefill run, and a decode step that does not take the
 #: kernel, reads its cached rows through at a time, where they divide the
@@ -480,7 +484,8 @@ def _programs(cfg: Lfm2MoeConfig, block_size: int,
         idx, wts, moved = sigmoid_bias_route(
             flat, lp["/router"], lp["/router_bias"], K, cfg.routed_scale,
             eps=ROUTER_EPS)
-        # ragged_dot at every run, a prefill chunk's too: the tiled
+        # a decode step's lanes stream the experts (parallel.moe
+        # .streams_experts); a prefill chunk takes ragged_dot: the tiled
         # kernel (parallel.moe.tiled_grouped_dot) wants whole tiles of 512
         # output columns, and an expert of 1,792 is none
         y, served = dropless_topk_ffn(
@@ -573,7 +578,8 @@ def _programs(cfg: Lfm2MoeConfig, block_size: int,
             jnp.int32(n_moe), jnp.sum(served > 0, dtype=jnp.int32),
             jnp.sum(served, dtype=jnp.int32),
             jnp.sum(jnp.max(served, axis=1), dtype=jnp.int32),
-            jnp.sum(did[:, -1], dtype=jnp.int32), read])
+            jnp.sum(did[:, -1], dtype=jnp.int32), read,
+            jnp.int32(n_moe if streams_experts(S) else 0)])
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         return kc, vc, jnp.concatenate([nxt, counted]), logits, (conv,)
 

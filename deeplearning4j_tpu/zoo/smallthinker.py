@@ -46,7 +46,8 @@ from typing import Dict, Tuple
 import numpy as np
 
 from deeplearning4j_tpu.compilecache.cache import COMPILE_STATS
-from deeplearning4j_tpu.parallel.moe import dropless_topk_ffn, topk_route
+from deeplearning4j_tpu.parallel.moe import (dropless_topk_ffn,
+                                             streams_experts, topk_route)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -119,10 +120,12 @@ class SmallThinkerConfig:
 
 #: what the decode program counts a step, summed over its layers: layers
 #: run, experts with a token of an active lane, (token, expert) pairs,
-#: and the fullest expert's tokens (docs/serving.md says what an
-#: operator reads from each)
+#: the fullest expert's tokens, and the layers whose experts were
+#: streamed (``parallel.moe.streams_experts``; docs/serving.md says what
+#: an operator reads from each)
 PROGRAM_COUNTERS = ("moe_layer_steps", "moe_experts_touched_sum",
-                    "moe_tokens_routed_sum", "moe_peak_expert_tokens_sum")
+                    "moe_tokens_routed_sum", "moe_peak_expert_tokens_sum",
+                    "moe_stream_layer_steps")
 
 
 def smallthinker_param_shapes(cfg: SmallThinkerConfig) -> Dict[str, tuple]:
@@ -369,7 +372,8 @@ def smallthinker_paged_decode_fns(cfg: SmallThinkerConfig, block_size: int,
         counted = jnp.stack([                  # PROGRAM_COUNTERS' order
             jnp.int32(L), jnp.sum(served > 0, dtype=jnp.int32),
             jnp.sum(served, dtype=jnp.int32),
-            jnp.sum(jnp.max(served, axis=1), dtype=jnp.int32)])
+            jnp.sum(jnp.max(served, axis=1), dtype=jnp.int32),
+            jnp.int32(L if streams_experts(tokens.shape[0]) else 0)])
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         return kc, vc, jnp.concatenate([nxt, counted]), logits
 

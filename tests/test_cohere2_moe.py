@@ -340,7 +340,9 @@ def test_the_program_counts_what_a_count_by_hand_gives(spec):
         "moe_held_pairs_sum": int(served.sum()),
         # the plain path (the CPU) reads every entry it gathered: the
         # global table's 16 and the ring's 3 on three layers, a lane
-        "kv_pages_read_sum": 2 * (16 + 3 * 3)}
+        "kv_pages_read_sum": 2 * (16 + 3 * 3),
+        # three lanes stream the held experts of every layer
+        "moe_stream_layer_steps": 4}
     # layer 0 by hand in float64: the router reads the norm of the
     # embedding row, which program and reference compute alike
     emb = np.asarray(params["embed"], np.float64)

@@ -447,7 +447,9 @@ def test_the_program_counts_what_a_count_by_hand_gives(spec, pc):
         "moe_tokens_routed_sum": 2 * 2 * 6,          # k x lanes x layers
         "moe_peak_expert_tokens_sum": int(served.max(axis=1).sum()),
         "moe_bias_moved_sum": moved,
-        "kv_pages_read_sum": 2 * 16 * 2}      # lanes x entries x layers
+        "kv_pages_read_sum": 2 * 16 * 2,      # lanes x entries x layers
+        # three lanes stream the experts of every expert layer
+        "moe_stream_layer_steps": 6}
     assert served.sum() == 2 * 2 * 6
 
 

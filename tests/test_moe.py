@@ -570,3 +570,223 @@ def test_the_divisors_epsilon_leaves_the_routers_that_were_bit_for_bit(
     np.testing.assert_allclose(
         got, scale * picked / (picked.sum(1, keepdims=True) + 1e-6),
         rtol=1e-5)
+
+
+# ----------------------------------------------------------------------
+# the experts streamed at a decode call's size (parallel/moe.py
+# expert_stream_ffn; the kernel interpreted here)
+import importlib
+
+from deeplearning4j_tpu.parallel import moe as moe_mod
+
+
+def _sorted_path(monkeypatch):
+    """The layer as it ran before the streamed product: every call sorts
+    its pairs through ``ragged_dot``."""
+    monkeypatch.setattr(moe_mod, "streams_experts", lambda tokens: False)
+
+
+def _through_kernel(monkeypatch):
+    """Streamed experts through the kernel, as on a TPU (interpreted
+    here), where the CPU computes its sums in XLA."""
+    monkeypatch.setattr(moe_mod, "stream_kernel", lambda: True)
+
+
+#: scaled-down decode calls: (tokens, width d, expert width f, routed
+#: experts, k, first held, held, activation, how the pairs are drawn)
+STREAM_CASES = {
+    # SmallThinker: 8 lanes, top-6 of 64, ReGLU
+    "smallthinker": (8, 256, 768, 16, 6, 0, 16, jax.nn.relu, "router"),
+    # GLM-4.7-Flash: 8 lanes, top-4 of 64, SwiGLU
+    "glm": (8, 256, 384, 16, 4, 0, 16, jax.nn.silu, "router"),
+    # Command A+: 32 lanes, top-8 of 128, this chip holds 4 from the 8th
+    "cmda": (32, 256, 256, 32, 8, 8, 4, jax.nn.silu, "router"),
+    # LFM2-8B-A1B: 64 lanes, top-4 of 32; a width of 7 tiles of 128
+    "lfm2": (64, 128, 896, 8, 4, 0, 8, jax.nn.silu, "router"),
+    "one_token": (1, 128, 256, 8, 2, 0, 8, jax.nn.silu, "router"),
+    "one_expert_takes_every_token": (24, 128, 256, 8, 2, 0, 8,
+                                     jax.nn.relu, "first_is_0"),
+    "half_the_experts_untouched": (16, 128, 256, 8, 2, 0, 8, jax.nn.silu,
+                                   "even_only"),
+    # narrower than a lane tile: the whole width a step
+    "narrow_expert": (8, 32, 48, 8, 2, 0, 8, jax.nn.silu, "router"),
+}
+
+
+def _stream_case(name, dtype):
+    n, d, f, e, k, first, held, act, draw = STREAM_CASES[name]
+    rng = np.random.default_rng(30 + sorted(STREAM_CASES).index(name))
+    gate, up, down = (jnp.asarray(a, dtype)
+                      for a in _reglu_experts(rng, d, f, held))
+    x = jnp.asarray(rng.normal(size=(n, d)), jnp.float32)
+    idx, w = topk_route(x, jnp.asarray(rng.normal(size=(d, e)),
+                                       jnp.float32), k)
+    idx = np.asarray(idx).copy()
+    if draw == "first_is_0":
+        idx[:, 1] = np.where(idx[:, 1] == 0, idx[:, 0], idx[:, 1])
+        idx[:, 0] = 0
+        idx[:, 1] = np.where(idx[:, 1] == 0, 1, idx[:, 1])
+    elif draw == "even_only":
+        idx = 2 * (np.argsort(rng.random((n, e // 2)), axis=1)[:, :k])
+    valid = jnp.asarray(rng.random(n) > 0.25).at[0].set(True)
+    return (x, jnp.asarray(idx, jnp.int32), w, gate, up, down), \
+        dict(first_expert=first, valid=valid, activation=act)
+
+
+@pytest.mark.parametrize("product", ["kernel", "xla"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("name", sorted(STREAM_CASES))
+def test_the_streamed_experts_give_the_sorted_grouped_products(
+        name, dtype, product, monkeypatch):
+    """A decode-sized call streams the experts, through the kernel (a
+    TPU's; interpreted here) or its sums in XLA (the CPU's), and gives
+    what the pairs sorted through ``ragged_dot`` gave, to the order of
+    float32 sums (in bfloat16 the activation's product rounds to the
+    weights' dtype on both paths): held experts from ``first_expert``,
+    idle lanes, experts nobody chose, one expert taking every token, one
+    token; the tokens each expert served are the same count."""
+    args, kw = _stream_case(name, dtype)
+    assert moe_mod.streams_experts(args[0].shape[0])
+    if product == "kernel":
+        _through_kernel(monkeypatch)
+    streamed, served = jax.jit(
+        lambda *a: dropless_topk_ffn(*a, **kw))(*args)
+    text = str(jax.make_jaxpr(
+        lambda *a: dropless_topk_ffn(*a, **kw))(*args))
+    assert ("expert_stream_ffn" in text) == (product == "kernel")
+    assert "ragged_dot" not in text
+    _sorted_path(monkeypatch)
+    want, served_sorted = dropless_topk_ffn(*args, **kw)
+    np.testing.assert_array_equal(np.asarray(served),
+                                  np.asarray(served_sorted))
+    idx, valid = np.asarray(args[1]), np.asarray(kw["valid"])
+    local = idx - kw["first_expert"]
+    held = args[3].shape[0]
+    mine = (local >= 0) & (local < held) & valid[:, None]
+    np.testing.assert_array_equal(
+        np.asarray(served), np.bincount(local[mine], minlength=held))
+    if name == "half_the_experts_untouched":
+        assert not np.asarray(served)[1::2].any()
+    if name == "one_expert_takes_every_token":
+        assert int(served[0]) == int(valid.sum())
+    scale = float(np.abs(np.asarray(want)).max())
+    assert scale > 0
+    tol = 1e-6 if dtype == jnp.float32 else 2e-3
+    np.testing.assert_allclose(np.asarray(streamed), np.asarray(want),
+                               rtol=0, atol=tol * scale)
+    assert not np.asarray(streamed)[~valid].any()
+
+
+@pytest.mark.parametrize("tile", [128, 896])
+def test_any_tile_of_the_width_gives_the_same_experts(tile, monkeypatch):
+    """The width read a grid step is the kernel's own business: an
+    expert of 896 read in 7 tiles or in one gives the same sums to
+    float32's order."""
+    args, kw = _stream_case("lfm2", jnp.float32)
+    x, idx, w, gate, up, down = args
+    held, n, k = gate.shape[0], x.shape[0], idx.shape[1]
+    mine = kw["valid"][:, None] & (idx < held)
+    flat = jnp.where(mine, idx, held).reshape(-1)
+    tokens = jnp.zeros(held + 1, jnp.int32).at[flat].add(1)[:held]
+    cols = jnp.zeros((n, held + 1), jnp.float32).at[
+        jnp.arange(n)[:, None], flat.reshape(n, k)].add(
+            jnp.where(mine, w, 0.0))[:, :held]
+    want = moe_mod._expert_major(x, cols, tokens, gate, up, down,
+                                 jax.nn.silu)
+    monkeypatch.setattr(moe_mod, "STREAM_TILE", tile)
+    assert moe_mod._stream_tile(896, x.shape[1], 4) == tile
+    got = moe_mod.expert_stream_ffn(x, cols, tokens, gate, up, down,
+                                    jax.nn.silu)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=0,
+                               atol=1e-6 * float(np.abs(want).max()))
+
+
+def test_the_call_shape_picks_the_product(monkeypatch):
+    """Up to ``STREAM_TOKENS`` tokens (a decode step's lanes) the experts
+    stream (through the kernel on a TPU); a 512-token prefill chunk keeps
+    ``ragged_dot``, and the tiled product where its caller names it; the
+    tile of the width fills the VMEM it is given and divides the width."""
+    assert not moe_mod.stream_kernel()          # the CPU: its sums in XLA
+    _through_kernel(monkeypatch)
+    assert all(moe_mod.streams_experts(n) for n in (1, 8, 32, 64, 128))
+    assert not any(moe_mod.streams_experts(n) for n in (129, 512, 3072))
+    rng = np.random.default_rng(20)
+    d, e, k = 512, 4, 2
+    gate, up, down = (jnp.asarray(a, jnp.bfloat16)
+                      for a in _reglu_experts(rng, d, 512, e))
+
+    def text(n, grouped=None):
+        x = jnp.zeros((n, d), jnp.bfloat16)
+        idx = jnp.zeros((n, k), jnp.int32).at[:, 1].set(1)
+        return str(jax.make_jaxpr(lambda *a: dropless_topk_ffn(
+            *a, grouped=grouped))(x, idx, jnp.ones((n, k)), gate, up, down))
+    for n, stream, ragged, tiled in (
+            (64, True, False, False), (128, True, False, False),
+            (512, False, True, False)):
+        t = text(n)
+        assert ("expert_stream_ffn" in t, "ragged_dot" in t,
+                "gmm" in t) == (stream, ragged, tiled)
+    t = text(512, tiled_grouped_dot)
+    assert "gmm" in t and "ragged_dot" not in t \
+        and "expert_stream_ffn" not in t
+    assert "expert_stream_ffn" in text(64, tiled_grouped_dot)
+    # the four families' widths, bfloat16: (d, f) -> the tile
+    for (dd, f), want in (((2560, 768), 384), ((2048, 1536), 512),
+                          ((4096, 4096), 512), ((2048, 1792), 256),
+                          ((32, 48), 48)):
+        t = moe_mod._stream_tile(f, dd, 2)
+        assert t == want and f % t == 0
+        assert t == f or 2 * 3 * dd * t * 2 <= moe_mod.STREAM_TILE_BYTES
+
+
+#: each MoE family: its test module (the tiny configuration) and its
+#: paged spec
+FAMILY_SPECS = {
+    "smallthinker": ("test_smallthinker", "smallthinker",
+                     "smallthinker_paged_spec"),
+    "glm_moe_lite": ("test_glm_moe_lite", "glm_moe_lite",
+                     "glm_moe_lite_paged_spec"),
+    "cohere2_moe": ("test_cohere2_moe", "cohere2_moe",
+                    "cohere2_moe_paged_spec"),
+    "lfm2_moe": ("test_lfm2_moe", "lfm2_moe", "lfm2_moe_paged_spec"),
+}
+
+
+@pytest.mark.parametrize("family", sorted(FAMILY_SPECS))
+def test_a_family_counts_streamed_layers_at_decode_and_not_at_a_chunk(
+        family, monkeypatch):
+    """A request of 200 tokens through the family's server: its prefill
+    chunk of 256 tokens sorts its pairs (traced with 256 rows), every
+    decode step of 3 lanes streams the experts of every expert layer
+    (through the kernel, as on a TPU; interpreted here), and
+    ``moe_stream_layer_steps`` counts them: as many as
+    ``moe_layer_steps``."""
+    from deeplearning4j_tpu.serving.paged import PagedGenerativeServer
+    _through_kernel(monkeypatch)
+    tests, zoo, build = FAMILY_SPECS[family]
+    mod = importlib.import_module(tests)
+    cfg = dict(mod.CFG, max_position_embeddings=1024)
+    spec = getattr(importlib.import_module("deeplearning4j_tpu.zoo." + zoo),
+                   build)(mod.adapter.program_config(cfg),
+                          mod.adapter.program_params(cfg, mod.SEED))
+    assert "moe_stream_layer_steps" in spec.program_counters
+    # the rows each path was traced with
+    rows = {"_streamed": [], "_sorted_grouped": []}
+    for path in rows:
+        real = getattr(moe_mod, path)
+        monkeypatch.setattr(moe_mod, path,
+                            lambda x, *a, real=real, path=path: (
+                                rows[path].append(x.shape[0]),
+                                real(x, *a))[1])
+    prompt = np.random.default_rng(1).integers(
+        0, cfg["vocab_size"], 200).astype(np.int32)
+    with PagedGenerativeServer(spec, max_slots=3, block_size=4,
+                               max_seq_len=256, buckets=[8, 256],
+                               warmup=False) as srv:
+        srv.submit(prompt, max_new_tokens=4).result(timeout=300)
+        c = dict(srv.metrics.counters)
+    assert c["decode_steps"] > 0
+    assert c["moe_stream_layer_steps"] == c["moe_layer_steps"] > 0
+    assert set(rows["_streamed"]) == {3}
+    assert set(rows["_sorted_grouped"]) == {256}
