@@ -39,19 +39,19 @@ dtype. A layer caches ONE row a token, its K and V heads interleaved
 (``[k0, v0, k1, v1, ...]``, each ``head_dim`` wide: one
 ``serving.paged.KVLeaf`` without heads), and the rows of window and
 global layers live in two tiers of the paged pool
-(``serving.paged.KVTier``), as SmallThinker's do. The decode program
-writes a step's fresh rows first and then reads each lane's own pages
-in place through a Pallas kernel with the contract of JAX's ragged
-paged-attention kernel (``zoo.paged_attend.paged_decode``), where the
-chip can take it
-(``paged_attend.kernel_refusal``; ``monitor.attention
-.last_decode_program()`` says which way the program went). Elsewhere,
-and in the prefill program, the block is handed a cache that reads its
-layer's rows a span of table entries at a time into a running softmax
-(``zoo.paged_attend``), so that a prefill chunk of 512 queries over 128
-heads never holds the scores of a whole context; fresh rows are there
-attended to as they will lie in the cache (rounded to its dtype) and
-written afterwards.
+(``serving.paged.KVTier``), as SmallThinker's do. The attention is the
+zoo's one grouped-query attention over a ``zoo.paged_attend.PagedLeaf``.
+The decode program writes a step's fresh rows first and then reads each
+lane's own pages in place through a Pallas kernel with the contract of
+JAX's ragged paged-attention kernel (``paged_attend.attend_pages``),
+where the chip can take it (``paged_attend.decode_reads_pages``;
+``monitor.attention.last_decode_program()`` says which way the program
+went). Elsewhere, and in the prefill program, the layer's rows are read
+a span of table entries at a time into a running softmax
+(``paged_attend.attend``), so that a prefill chunk of 512 queries over
+128 heads never holds the scores of a whole context; fresh rows are
+there attended to as they will lie in the cache (rounded to its dtype)
+and written afterwards.
 
 There is no training graph for this block; :func:`cohere2_moe_paged_spec`
 serves parameters handed over by name.
@@ -64,13 +64,15 @@ from typing import Dict, Tuple
 import numpy as np
 
 from deeplearning4j_tpu.compilecache.cache import COMPILE_STATS
-from deeplearning4j_tpu.monitor import attention
 from deeplearning4j_tpu.parallel.moe import (dropless_topk_ffn,
                                              sigmoid_bias_route,
                                              streams_experts)
 from deeplearning4j_tpu.zoo import paged_attend
-from deeplearning4j_tpu.zoo.paged_attend import (NEG, over_spans,
-                                                 softmax_merge)
+from deeplearning4j_tpu.zoo.blocks import (ein, global_and_window_tiers,
+                                           layer_norm, layer_params, mm,
+                                           pull_params, rope_angles,
+                                           rope_freqs, rotate_pairs, swiglu,
+                                           tier_tables)
 
 
 class Cohere2MoeUnsupportedError(ValueError):
@@ -195,15 +197,7 @@ class Cohere2MoeConfig:
     def kv_tiers(self):
         """The pool's tiers: the global layers, then the window layers
         (a tier with no layer is left out)."""
-        from deeplearning4j_tpu.serving.paged import KVTier
-        glob = tuple(i for i, w in enumerate(self.window_layout) if not w)
-        win = tuple(i for i, w in enumerate(self.window_layout) if w)
-        tiers = []
-        if glob:
-            tiers.append(KVTier("global", glob, None))
-        if win:
-            tiers.append(KVTier("window", win, self.window))
-        return tuple(tiers)
+        return global_and_window_tiers(self.window_layout, self.window)
 
 
 #: what the decode program counts a step, summed over its layers:
@@ -256,56 +250,6 @@ def cohere2_moe_param_names(cfg: Cohere2MoeConfig):
     return list(cohere2_moe_param_shapes(cfg))
 
 
-class _PagedCache:
-    """What a block sees of the paged pool: its layer's leaf ``kv``
-    ``[num_blocks, block_size, 2 * kv_heads * head_dim]`` (K and V
-    interleaved head by head), its tier's ``table [R, entries]`` (R
-    requests in the program), ``hist`` [R], how many positions each
-    request has cached, and where the N fresh rows (request-major) go:
-    ``(write_block [N], write_off [N])``."""
-
-    def __init__(self, kv, table, write_block, hist, write_off,
-                 block_size):
-        self.kv, self.table = kv, table
-        self.write_block, self.write_off = write_block, write_off
-        self.hist, self.BS = hist, int(block_size)
-
-    def read(self, first, entries: int, counted_from):
-        """The rows of ``entries`` entries of every request's table from
-        entry ``first`` (which may be traced): ``[R, T, 2 * kv_heads *
-        head_dim]``, the position of each row ``[R, T]`` and whether it
-        is one of the request's cached positions ``[R, T]`` (an entry
-        below ``counted_from``, which an earlier span read, counts as
-        none). Entry ``e`` of a table of E entries holds block ``u = e
-        (mod E)``, the one such ``u`` among the last E blocks up to the
-        block of position ``hist - 1``: for a table that holds every block
-        up to that one (however much wider) that is ``u = e``, for a
-        window tier's ring the block that was written there last."""
-        import jax
-        import jax.numpy as jnp
-        R, E = self.table.shape
-        n = int(entries)
-        part = jax.lax.dynamic_slice_in_dim(self.table, first, n, axis=1)
-        e = first + jnp.arange(n, dtype=jnp.int32)
-        last = jnp.floor_divide(self.hist - 1, self.BS)[:, None]    # [R, 1]
-        u = last - jnp.mod(last - e[None], E)                       # [R, n]
-        pos = (u[:, :, None] * self.BS
-               + jnp.arange(self.BS, dtype=jnp.int32)[None, None])
-        held = (u >= 0)[:, :, None] & (pos < self.hist[:, None, None]) \
-            & (e >= counted_from)[None, :, None]
-        rows = self.kv[part].reshape(R, n * self.BS, -1)
-        return rows, pos.reshape(R, -1), held.reshape(R, -1)
-
-    def write(self, k, v):
-        """The fresh rows ``k, v [N, kv_heads, head_dim]``, interleaved,
-        in place."""
-        import jax.numpy as jnp
-        N = k.shape[0]
-        row = jnp.stack([k, v], axis=2).reshape(N, -1)
-        self.kv = self.kv.at[self.write_block, self.write_off].set(
-            row.astype(self.kv.dtype))
-
-
 def _programs(cfg: Cohere2MoeConfig, block_size: int,
               max_blocks_per_req: int) -> dict:
     """``prefill_fn``, ``decode_fn`` and ``forward``, all over one
@@ -316,109 +260,12 @@ def _programs(cfg: Cohere2MoeConfig, block_size: int,
 
     H, L = cfg.hidden_size, cfg.num_layers
     A, KV, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    G, K = A // KV, cfg.experts_per_token
+    K = cfg.experts_per_token
     BS, W = int(block_size), cfg.window
-    scale = np.float32(1.0 / np.sqrt(D))
+    inv_freq = rope_freqs(cfg.rope_theta, D)
     shared_scale = np.float32(1.0 / cfg.num_shared_experts)
-    inv_freq = jnp.asarray(
-        cfg.rope_theta ** (-np.arange(0, D, 2, dtype=np.float64) / D),
-        jnp.float32)
-    tier_of = {i: t for t in cfg.kv_tiers() for i in t.layers}
     widest = max(t.table_blocks(BS, max_blocks_per_req)
                  for t in cfg.kv_tiers())
-
-    def _layernorm(x, g):
-        x = x.astype(jnp.float32)
-        c = x - jnp.mean(x, axis=-1, keepdims=True)
-        var = jnp.mean(c * c, axis=-1, keepdims=True)
-        return c * jax.lax.rsqrt(var + cfg.norm_eps) * g.astype(jnp.float32)
-
-    def _mm(x, w):
-        return jnp.matmul(x.astype(w.dtype), w,
-                          preferred_element_type=jnp.float32)
-
-    def _ein(eq, a, b):
-        return jnp.einsum(eq, a, b, preferred_element_type=jnp.float32)
-
-    def _rope(x, pos):
-        # x [..., heads, D] float32, pos [...]: GPT-J's rotation of the
-        # interleaved pairs (2i, 2i + 1) by pos * theta^(-2i / D)
-        ang = pos[..., None, None].astype(jnp.float32) * inv_freq
-        cos, sin = jnp.cos(ang), jnp.sin(ang)
-        pair = x.reshape(x.shape[:-1] + (D // 2, 2))
-        x1, x2 = pair[..., 0], pair[..., 1]
-        return jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
-                         axis=-1).reshape(x.shape)
-
-    def _attend(window, q, k, v, qpos, valid, cache, span):
-        """q [R, Q, A, D], k/v [R, Q, KV, D] (the fresh rows), qpos/valid
-        [R, Q]: each query over its request's cached rows and the fresh
-        rows up to itself, one softmax over both. The cached rows are read
-        ``span`` table entries at a time (the last span of a table that is
-        no whole number of them ends at the table's end, and an entry an
-        earlier span read is not counted again), each merged into a
-        running softmax; 0 is the whole table in one read. Returns ``[R,
-        Q, A * D]``."""
-        R, Q = qpos.shape
-        dt = cache.kv.dtype
-        qg = q.reshape(R, Q, KV, G, D).astype(dt)
-        E = cache.table.shape[1]
-        span = E if not span else min(int(span), E)
-        neg = jnp.float32(NEG)
-
-        def near(kpos):
-            """Which keys at ``kpos [R, T]`` each query's window holds
-            ``[R, Q, T]``."""
-            return kpos[:, None, :] > qpos[:, :, None] - W
-
-        def over_cached(i, carry):
-            first = jnp.minimum(i * span, E - span)
-            rows, cpos, held = cache.read(first, span, i * span)
-            T = cpos.shape[1]
-            rows = rows.reshape(R, T, KV, 2, D)
-            Kc, Vc = rows[:, :, :, 0], rows[:, :, :, 1]
-            seen = held[:, None, :]
-            if window:
-                seen = seen & near(cpos)
-            return softmax_merge(
-                carry, _ein("rqhgd,rthd->rhgqt", qg, Kc) * scale,
-                seen[:, None, None],
-                lambda e: _ein("rhgqt,rthd->rhgqd", e.astype(dt), Vc), neg)
-
-        carry = (jnp.full((R, KV, G, Q), neg),
-                 jnp.zeros((R, KV, G, Q), jnp.float32),
-                 jnp.zeros((R, KV, G, Q, D), jnp.float32))
-        carry = over_spans(over_cached, carry, -(-E // span), span * BS,
-                           cache.hist, cap=E * BS)
-        # a row sees itself whether or not it is valid, so that an idle
-        # lane or a padded row has a finite result (it lands in the null
-        # block, which every table's unused entries point at)
-        see_f = (qpos[:, None, :] <= qpos[:, :, None]) \
-            & (valid[:, None, :] | jnp.eye(Q, dtype=bool)[None])
-        if window:
-            see_f = see_f & near(qpos)
-        k, v = k.astype(dt), v.astype(dt)
-        _, total, o = softmax_merge(
-            carry, _ein("rqhgd,rphd->rhgqp", qg, k) * scale,
-            see_f[:, None, None],
-            lambda e: _ein("rhgqp,rphd->rhgqd", e.astype(dt), v), neg)
-        o = o / total[..., None]                           # [R, KV, G, Q, D]
-        return jnp.transpose(o, (0, 3, 1, 2, 4)).reshape(R, Q, A * D)
-
-    def _attend_paged(window, q, qpos, valid, cache):
-        """One query a request, ``q [R, 1, A, D]``, over its own pages
-        in place, its fresh row already written: ``([R, 1, A * D], the
-        pages the active requests read)``."""
-        R = q.shape[0]
-        pages, rows = paged_attend.decode_pages(
-            cache.table, qpos[:, 0], valid[:, 0], cache.write_block, BS,
-            ring=bool(window))
-        o = paged_attend.paged_decode(
-            q[:, 0].astype(cache.kv.dtype), cache.kv, pages, rows, scale,
-            W if window else None, width=widest)
-        read = jnp.sum(jnp.where(valid[:, 0], -(-rows // BS), 0),
-                       dtype=jnp.int32)
-        return o.reshape(R, 1, A * D), read
 
     def _block(lp, x, qpos, valid, kv, table, wb, hist, write_off,
                window, span, paged):
@@ -434,22 +281,24 @@ def _programs(cfg: Cohere2MoeConfig, block_size: int,
         own (``window``, ``span`` and ``paged`` static), so that a
         program's trace and lowering hold each kind of layer once and
         call it: the decode program is built once a table width."""
-        cache = _PagedCache(kv, table, wb, hist, write_off, BS)
+        cache = paged_attend.PagedLeaf(kv, table, wb, write_off, hist, BS,
+                                       ring=window)
         R, Q, _ = x.shape
-        n = _layernorm(x, lp["/norm"])
+        n = layer_norm(x, lp["/norm"], cfg.norm_eps)
         flat, ok = n.reshape(R * Q, H), valid.reshape(R * Q)
         # ATTENTION, on n
-        q = _mm(n, lp["/attn/q"]).reshape(R, Q, A, D)
-        k = _mm(n, lp["/attn/k"]).reshape(R, Q, KV, D)
-        v = _mm(n, lp["/attn/v"]).reshape(R, Q, KV, D)
+        q = mm(n, lp["/attn/q"]).reshape(R, Q, A, D)
+        k = mm(n, lp["/attn/k"]).reshape(R, Q, KV, D)
+        v = mm(n, lp["/attn/v"]).reshape(R, Q, KV, D)
         if window:
-            q, k = _rope(q, qpos), _rope(k, qpos)
+            q = rotate_pairs(q, rope_angles(qpos, inv_freq))
+            k = rotate_pairs(k, rope_angles(qpos, inv_freq))
         if paged:
-            cache.write(k.reshape(R * Q, KV, D), v.reshape(R * Q, KV, D))
-            att, read = _attend_paged(window, q, qpos, valid, cache)
+            att, read = paged_attend.attend_pages(
+                q, k, v, qpos, valid, cache, widest, W if window else None)
         else:
-            att = _attend(window, q, k, v, qpos, valid, cache, span)
-            cache.write(k.reshape(R * Q, KV, D), v.reshape(R * Q, KV, D))
+            att = paged_attend.attend(q, k, v, qpos, valid, cache, span,
+                                      W if window else None)
             read = jnp.sum(jnp.any(valid, axis=1), dtype=jnp.int32) \
                 * table.shape[1]
         # the EXPERTS, on the same n: the router over all its experts,
@@ -469,13 +318,12 @@ def _programs(cfg: Cohere2MoeConfig, block_size: int,
             flat, idx, wts, lp["/experts/gate"], lp["/experts/up"],
             lp["/experts/down"], first_expert=cfg.first_expert, valid=ok,
             activation=jax.nn.silu)
-        y = y + _mm(jax.nn.silu(_mm(flat, lp["/shared/gate"]))
-                    * _mm(flat, lp["/shared/up"]),
-                    lp["/shared/down"]) * shared_scale
+        y = y + swiglu(flat, lp["/shared/gate"], lp["/shared/up"],
+                       lp["/shared/down"]) * shared_scale
         did = jnp.concatenate(
             [served, (jnp.sum(ok, dtype=jnp.int32) * K)[None]])
-        x = x + _mm(att, lp["/attn/o"]) + y.reshape(R, Q, H)
-        return x, did, read, cache.kv
+        x = x + mm(att, lp["/attn/o"]) + y.reshape(R, Q, H)
+        return x, did, read, cache.leaf
 
     block = jax.jit(_block, static_argnames=("window", "span", "paged"))
 
@@ -490,11 +338,9 @@ def _programs(cfg: Cohere2MoeConfig, block_size: int,
         x = jnp.take(p["embed"], tokens, axis=0).astype(jnp.float32)
         did, read = [], jnp.int32(0)
         for i in range(L):
-            sc = f"h{i}"
-            lp = {n[len(sc):]: a for n, a in p.items()
-                  if n.startswith(sc + "/")}
             x, d, r, kc[i] = block(
-                lp, x, qpos, valid, kc[i], *tiers[i], hist, write_off,
+                layer_params(p, i), x, qpos, valid, kc[i], *tiers[i], hist,
+                write_off,
                 window=bool(cfg.window_layout[i]), span=span, paged=paged)
             did.append(d)
             read = read + r
@@ -503,23 +349,12 @@ def _programs(cfg: Cohere2MoeConfig, block_size: int,
     def _head(p, x):
         """The last norm and the tied head on the stream ``x [..., H]``."""
         emb = p["embed"]
-        h = _layernorm(x, p["norm_f"]).astype(emb.dtype)
-        return _ein("...h,vh->...v", h, emb) * np.float32(cfg.logit_scale)
+        h = layer_norm(x, p["norm_f"], cfg.norm_eps).astype(emb.dtype)
+        return ein("...h,vh->...v", h, emb) * np.float32(cfg.logit_scale)
 
     def _tiers(io, table_key, lift):
-        per_tier = {}
-        for t in cfg.kv_tiers():
-            table = lift(io[t.key(table_key)])
-            entries = t.table_blocks(BS, max_blocks_per_req)
-            # a ring is addressed u % entries: exact. A table that keeps
-            # every block may come cut to the blocks its lanes hold
-            if table.shape[1] > entries or (
-                    t.window is not None and table.shape[1] != entries):
-                raise ValueError(
-                    f"{t.key(table_key)} has {table.shape[1]} entries, the "
-                    f"tier's table {entries}")
-            per_tier[t.name] = (table, io[t.key("write_block")])
-        return {i: per_tier[t.name] for i, t in tier_of.items()}
+        return tier_tables(cfg.kv_tiers(), io, table_key, lift, BS,
+                           max_blocks_per_req)
 
     def prefill_fn(params, kc, vc, io):
         tokens, length, hist = io["tokens"], io["length"], io["hist"]
@@ -537,16 +372,10 @@ def _programs(cfg: Cohere2MoeConfig, block_size: int,
 
     def decode_fn(params, kc, vc, io):
         tokens, pos, active = io["tokens"], io["positions"], io["active"]
-        # the way the context is read, decided once a traced program from
-        # what it can see (the backend, the leaf's dtype, the heads)
-        why = paged_attend.kernel_refusal(kc[0].dtype, A, KV, D)
-        sites = attention.open_decode_program()
-        for _ in range(L):
-            sites.note(why)
         x, did, read, kc = _stack(
             params, tokens[:, None], pos[:, None], active[:, None], kc,
             _tiers(io, "tables", lambda t: t), pos, io["write_off"], 0,
-            paged=why is None)
+            paged=paged_attend.decode_reads_pages(kc[0].dtype, A, KV, D, L))
         logits = _head(params, x[:, 0])
         served = did[:, :-1]
         counted = jnp.stack([                  # PROGRAM_COUNTERS' order
@@ -635,15 +464,7 @@ def cohere2_moe_paged_spec(cfg: Cohere2MoeConfig, params):
     int8 rows and a dense draft are refused
     (``serving.paged.KVLeafUnsupportedError``)."""
     from deeplearning4j_tpu.serving.paged import KVLeaf, PagedGenerativeSpec
-    pull = params if callable(params) else (lambda: params)
-    got, want = pull(), cohere2_moe_param_shapes(cfg)
-    if set(got) != set(want):
-        raise ValueError(f"parameter names differ: "
-                         f"{sorted(set(got) ^ set(want))[:4]}")
-    for n, shape in want.items():
-        if tuple(np.shape(got[n])) != shape:
-            raise ValueError(f"{n}: shape {tuple(np.shape(got[n]))}, "
-                             f"the configuration gives {shape}")
+    pull, got = pull_params(params, cohere2_moe_param_shapes(cfg))
     return PagedGenerativeSpec(
         params=pull,
         make_fns=lambda bs, maxb: cohere2_moe_paged_decode_fns(cfg, bs, maxb),

@@ -53,6 +53,9 @@ from typing import Dict
 import numpy as np
 
 from deeplearning4j_tpu.compilecache.cache import COMPILE_STATS
+from deeplearning4j_tpu.zoo.blocks import (ein, layer_params, mm, pull_params,
+                                           rope_angles, rope_freqs,
+                                           rotate_half, swiglu)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -233,29 +236,17 @@ def _functions(cfg: EvaByteConfig):
 
     A, D, c, W = cfg.num_heads, cfg.head_dim, cfg.chunk, cfg.window
     scale = 1.0 / np.sqrt(D)
-    inv_freq = jnp.asarray(
-        cfg.rope_theta ** (-np.arange(0, D, 2, dtype=np.float64) / D),
-        jnp.float32)
+    inv_freq = rope_freqs(cfg.rope_theta, D)
     neg = jnp.float32(-1e30)
 
     def rmsnorm(x, g):
+        # the gain is 1 + g where the norm has a unit offset: an order of
+        # operations of its own (blocks.rms_norm takes the gain whole)
         x = x.astype(jnp.float32)
         ms = jnp.mean(x * x, axis=-1, keepdims=True)
         g = g.astype(jnp.float32)
         return x * jax.lax.rsqrt(ms + cfg.rms_eps) * (
             1.0 + g if cfg.unit_offset else g)
-
-    def mm(x, w):
-        return jnp.matmul(x.astype(w.dtype), w,
-                          preferred_element_type=jnp.float32)
-
-    def rope(x, pos):
-        # x [..., heads, D] float32, pos [...]: rotate-half over all of D
-        ang = pos[..., None, None].astype(jnp.float32) * inv_freq
-        cos = jnp.concatenate([jnp.cos(ang), jnp.cos(ang)], axis=-1)
-        sin = jnp.concatenate([jnp.sin(ang), jnp.sin(ang)], axis=-1)
-        x1, x2 = jnp.split(x, 2, axis=-1)
-        return x * cos + jnp.concatenate([-x2, x1], axis=-1) * sin
 
     def summarise(phi, mu, kb, vb, mb):
         """Chunks of rows ``kb, vb [R, n, c, A, D]`` (float32 values of
@@ -281,17 +272,15 @@ def _functions(cfg: EvaByteConfig):
         if Q == 1:
             return attend_one(q, groups, dt), seen
         qd = q.astype(dt)
-        ss = [jnp.where(see[:, None], scale * jnp.einsum(
-            "rqad,rtad->raqt", qd, K.astype(dt),
-            preferred_element_type=jnp.float32), neg)
+        ss = [jnp.where(see[:, None], scale * ein(
+            "rqad,rtad->raqt", qd, K.astype(dt)), neg)
             for K, _, see in groups]
         top = ss[0].max(axis=-1)
         for sg in ss[1:]:
             top = jnp.maximum(top, sg.max(axis=-1))
         es = [jnp.exp(sg - top[..., None]) for sg in ss]
         den = sum(e.sum(axis=-1) for e in es)              # [R, A, Q]
-        out = sum(jnp.einsum("raqt,rtad->rqad", e.astype(dt), V.astype(dt),
-                             preferred_element_type=jnp.float32)
+        out = sum(ein("raqt,rtad->rqad", e.astype(dt), V.astype(dt))
                   for e, (_, V, _) in zip(es, groups))
         out = out / jnp.transpose(den, (0, 2, 1))[..., None]
         return out.reshape(R, Q, A * D), seen
@@ -314,16 +303,14 @@ def _functions(cfg: EvaByteConfig):
         rows = [(K.reshape(R, -1, A * D).astype(dt),
                  V.reshape(R, -1, A * D).astype(dt), see[:, 0, :, None])
                 for K, V, see in groups]
-        ss = [jnp.where(see, scale * jnp.einsum(
-            "rtw,rwa->rta", K, qw, preferred_element_type=jnp.float32), neg)
+        ss = [jnp.where(see, scale * ein("rtw,rwa->rta", K, qw), neg)
             for K, _, see in rows]                          # [R, T, A]
         top = ss[0].max(axis=1)
         for sg in ss[1:]:
             top = jnp.maximum(top, sg.max(axis=1))
         es = [jnp.exp(sg - top[:, None]) for sg in ss]
         den = sum(e.sum(axis=1) for e in es)                # [R, A]
-        wide = sum(jnp.einsum("rta,rtw->raw", e.astype(dt), V,
-                              preferred_element_type=jnp.float32)
+        wide = sum(ein("rta,rtw->raw", e.astype(dt), V)
                    for e, (_, V, _) in zip(es, rows))       # [R, A, A * D]
         out = jnp.einsum("raad->rad", wide.reshape(R, A, A, D))
         return (out / den[..., None]).reshape(R, 1, A * D)
@@ -340,8 +327,10 @@ def _functions(cfg: EvaByteConfig):
         R, Q, _ = x.shape
         dt = lp["/attn/q"].dtype
         a = rmsnorm(x, lp["/norm_1"])
-        q = rope(mm(a, lp["/attn/q"]).reshape(R, Q, A, D), qpos)
-        k = rope(mm(a, lp["/attn/k"]).reshape(R, Q, A, D), qpos)
+        q = rotate_half(mm(a, lp["/attn/q"]).reshape(R, Q, A, D),
+                        rope_angles(qpos, inv_freq))
+        k = rotate_half(mm(a, lp["/attn/k"]).reshape(R, Q, A, D),
+                        rope_angles(qpos, inv_freq))
         v = mm(a, lp["/attn/v"]).reshape(R, Q, A, D)
         # the rows as they are cached
         kr = k.astype(dt).astype(jnp.float32)
@@ -397,16 +386,10 @@ def _functions(cfg: EvaByteConfig):
                                   index.reshape(R * n))
         x = x + mm(att, lp["/attn/o"])
         b = rmsnorm(x, lp["/norm_2"])
-        y = mm(jax.nn.silu(mm(b, lp["/mlp/gate"])) * mm(b, lp["/mlp/up"]),
-               lp["/mlp/down"])
+        y = swiglu(b, lp["/mlp/gate"], lp["/mlp/up"], lp["/mlp/down"])
         return x + y, jnp.sum(jnp.where(valid, seen, 0), dtype=jnp.int32)
 
-    return rmsnorm, mm, layer
-
-
-def _layer_params(p, i):
-    sc = f"h{i}"
-    return {n[len(sc):]: a for n, a in p.items() if n.startswith(sc + "/")}
+    return rmsnorm, layer
 
 
 def forward(cfg: EvaByteConfig, params, tokens):
@@ -415,14 +398,14 @@ def forward(cfg: EvaByteConfig, params, tokens):
     once, each chunk's summary made from the sequence's own rows. Head
     ``j`` is ``[:, vocab * j : vocab * (j + 1)]``."""
     import jax.numpy as jnp
-    rmsnorm, mm, layer = _functions(cfg)
+    rmsnorm, layer = _functions(cfg)
     tokens = jnp.asarray(tokens, jnp.int32)
     T = tokens.shape[0]
     qpos = jnp.arange(T, dtype=jnp.int32)[None]
     valid = jnp.ones((1, T), bool)
     x = jnp.take(params["embed"], tokens, axis=0).astype(jnp.float32)[None]
     for i in range(cfg.num_layers):
-        x, _ = layer(_layer_params(params, i), x, qpos, valid, None,
+        x, _ = layer(layer_params(params, i), x, qpos, valid, None,
                      jnp.zeros(1, jnp.int32), False)
     return mm(rmsnorm(x[0], params["norm_f"]), params["lm_head"])
 
@@ -463,7 +446,7 @@ def evabyte_paged_decode_fns(cfg: EvaByteConfig, block_size: int,
     # the ring's entries are the configuration's (window // block_size
     # under the pool's geometry), whatever width its table is handed in
     ring = exact.table_blocks(BS, max_blocks_per_req)
-    rmsnorm, mm, layer = _functions(cfg)
+    rmsnorm, layer = _functions(cfg)
 
     def _block(lp, x, qpos, valid, kl, vl, sl, ul, table, wb, stable, swb,
                hist, write_off, tail):
@@ -497,7 +480,7 @@ def evabyte_paged_decode_fns(cfg: EvaByteConfig, block_size: int,
         seen = jnp.int32(0)
         for i in range(L):
             x, n, kx[i], vx[i], ks[i], vs[i] = block(
-                _layer_params(p, i), x, qpos, valid, kx[i], vx[i], ks[i],
+                layer_params(p, i), x, qpos, valid, kx[i], vx[i], ks[i],
                 vs[i], *tables, hist, write_off, tail=tail)
             seen = seen + n
         return rmsnorm(x, p["norm_f"]), seen, \
@@ -535,15 +518,7 @@ def evabyte_paged_spec(cfg: EvaByteConfig, params):
     that gives one: ``update_model`` calls it again). Rows are cached in
     the dtype of the parameters; the vocabulary served is head 0's."""
     from deeplearning4j_tpu.serving.paged import PagedGenerativeSpec
-    pull = params if callable(params) else (lambda: params)
-    got, want = pull(), evabyte_param_shapes(cfg)
-    if set(got) != set(want):
-        raise ValueError(f"parameter names differ: "
-                         f"{sorted(set(got) ^ set(want))[:4]}")
-    for n, shape in want.items():
-        if tuple(np.shape(got[n])) != shape:
-            raise ValueError(f"{n}: shape {tuple(np.shape(got[n]))}, "
-                             f"the configuration gives {shape}")
+    pull, got = pull_params(params, evabyte_param_shapes(cfg))
     return PagedGenerativeSpec(
         params=pull,
         make_fns=lambda bs, maxb: evabyte_paged_decode_fns(cfg, bs, maxb),

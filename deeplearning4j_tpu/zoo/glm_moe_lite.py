@@ -66,7 +66,10 @@ from deeplearning4j_tpu.parallel.moe import (GROUPED_TILE_COLS,
                                              sigmoid_bias_route,
                                              streams_experts,
                                              tiled_grouped_dot)
-from deeplearning4j_tpu.zoo.paged_attend import (NEG, over_spans,
+from deeplearning4j_tpu.zoo.blocks import (ein, layer_params, mm, pull_params,
+                                           rms_norm, rope_freqs, rotate_half,
+                                           swiglu)
+from deeplearning4j_tpu.zoo.paged_attend import (NEG, PagedLeaf, over_spans,
                                                  softmax_merge)
 
 
@@ -238,39 +241,6 @@ def glm_moe_lite_param_names(cfg: GlmMoeLiteConfig):
     return list(glm_moe_lite_param_shapes(cfg))
 
 
-class _LatentCache:
-    """What a block sees of the paged pool: its layer's one leaf
-    ``[num_blocks, block_size, leaf_width]``, the requests' ``table [R,
-    entries]`` (entry ``e`` holds block ``e``; any width that holds every
-    block a request has cached), ``hist`` [R], how many positions each
-    request has cached, and where the N fresh rows (request-major) go:
-    ``(write_block [N], write_off [N])``."""
-
-    def __init__(self, leaf, table, write_block, write_off, hist,
-                 block_size):
-        self.leaf, self.table, self.hist = leaf, table, hist
-        self.write_block, self.write_off = write_block, write_off
-        self.BS = int(block_size)
-
-    def read(self, first, entries: int):
-        """The cached rows of every request through ``entries`` entries
-        of its table from entry ``first`` (which may be traced), ``[R, T,
-        leaf_width]``, and which of them hold a position of the request
-        ``[R, T]``."""
-        import jax
-        import jax.numpy as jnp
-        R, E = self.table.shape[0], int(entries)
-        part = jax.lax.dynamic_slice_in_dim(self.table, first, E, axis=1)
-        rows = self.leaf[part].reshape(R, E * self.BS, -1)
-        pos = first * self.BS + jnp.arange(E * self.BS, dtype=jnp.int32)
-        return rows, pos[None] < self.hist[:, None]
-
-    def write(self, rows):
-        """The fresh rows ``[N, leaf_width]``, in place."""
-        self.leaf = self.leaf.at[(self.write_block, self.write_off)].set(
-            rows.astype(self.leaf.dtype))
-
-
 @COMPILE_STATS.model_build("glm_moe_lite")
 def glm_moe_lite_paged_decode_fns(cfg: GlmMoeLiteConfig, block_size: int,
                                   max_blocks_per_req: int):
@@ -303,28 +273,7 @@ def glm_moe_lite_paged_decode_fns(cfg: GlmMoeLiteConfig, block_size: int,
     BS, W = int(block_size), cfg.leaf_width
     PAD = W - cfg.row_width       # zero columns behind [c, kr]
     scale = np.float32(1.0 / np.sqrt(DN + DR))
-    inv_freq = jnp.asarray(
-        cfg.rope_theta ** (-np.arange(0, DR, 2, dtype=np.float64) / DR),
-        jnp.float32)
-
-    def _rmsnorm(x, g):
-        x = x.astype(jnp.float32)
-        ms = jnp.mean(x * x, axis=-1, keepdims=True)
-        return x * jax.lax.rsqrt(ms + cfg.rms_eps) * g.astype(jnp.float32)
-
-    def _mm(x, w):
-        return jnp.matmul(x.astype(w.dtype), w,
-                          preferred_element_type=jnp.float32)
-
-    def _ein(eq, a, b):
-        return jnp.einsum(eq, a, b, preferred_element_type=jnp.float32)
-
-    def _rope(x, ang):
-        # x [..., DR] float32, ang [..., DR / 2]: rotate-half over DR
-        cos = jnp.concatenate([jnp.cos(ang), jnp.cos(ang)], axis=-1)
-        sin = jnp.concatenate([jnp.sin(ang), jnp.sin(ang)], axis=-1)
-        x1, x2 = jnp.split(x, 2, axis=-1)
-        return x * cos + jnp.concatenate([-x2, x1], axis=-1) * sin
+    eps, inv_freq = cfg.rms_eps, rope_freqs(cfg.rope_theta, DR)
 
     def _attend(q_nope, q_rope, fresh, w_kvb, qpos, valid, cache, span):
         """q_nope [R, Q, A, DN], q_rope [R, Q, A, DR] (rotated), fresh
@@ -342,7 +291,7 @@ def glm_moe_lite_paged_decode_fns(cfg: GlmMoeLiteConfig, block_size: int,
         R, Q = qpos.shape
         dt = cache.leaf.dtype
         w3 = w_kvb.reshape(C, A, DN + DV)
-        q_lat = _ein("rqad,cad->rqac", q_nope.astype(dt), w3[..., :DN])
+        q_lat = ein("rqad,cad->rqac", q_nope.astype(dt), w3[..., :DN])
         qf = jnp.concatenate(
             [q_lat, q_rope, jnp.zeros(q_rope.shape[:-1] + (PAD,))],
             axis=-1).astype(dt)
@@ -353,11 +302,11 @@ def glm_moe_lite_paged_decode_fns(cfg: GlmMoeLiteConfig, block_size: int,
             T, W]`` into the running softmax (``paged_attend``)."""
             return softmax_merge(
                 carry, s, seen,
-                lambda e: _ein("raqt,rtw->raqw", e.astype(dt), rows), neg)
+                lambda e: ein("raqt,rtw->raqw", e.astype(dt), rows), neg)
 
         def over_cached(i, carry):
-            rows, held = cache.read(i * span, span)
-            return merge(carry, _ein("rqaw,rtw->raqt", qf, rows) * scale,
+            rows, _, held = cache.read(i * span, span)
+            return merge(carry, ein("rqaw,rtw->raqt", qf, rows) * scale,
                          held[:, None, None, :], rows)
 
         carry = (jnp.full((R, A, Q), neg), jnp.zeros((R, A, Q), jnp.float32),
@@ -370,10 +319,10 @@ def glm_moe_lite_paged_decode_fns(cfg: GlmMoeLiteConfig, block_size: int,
         see_f = (qpos[:, None, :] <= qpos[:, :, None]) \
             & (valid[:, None, :] | jnp.eye(Q, dtype=bool)[None])
         _, total, o_lat = merge(
-            carry, _ein("rqaw,rpw->raqp", qf, fresh) * scale,
+            carry, ein("rqaw,rpw->raqp", qf, fresh) * scale,
             see_f[:, None], fresh)
         o_lat = o_lat[..., :C] / total[..., None]
-        out = _ein("raqc,cav->rqav", o_lat.astype(dt), w3[..., DN:])
+        out = ein("raqc,cav->rqav", o_lat.astype(dt), w3[..., DN:])
         return out.reshape(R, Q, A * DV)
 
     def chunk_tiles(tokens: int) -> bool:
@@ -382,9 +331,6 @@ def glm_moe_lite_paged_decode_fns(cfg: GlmMoeLiteConfig, block_size: int,
         return tokens >= TILED_RUN_TOKENS and not (
             tokens * cfg.experts_per_token % GROUPED_TILE_ROWS
             or H % GROUPED_TILE_COLS or cfg.expert_width % GROUPED_TILE_COLS)
-
-    def _gated(m, gate, up, down):
-        return _mm(jax.nn.silu(_mm(m, gate)) * _mm(m, up), down)
 
     def _block(lp, x, qpos, valid, leaf, table, wb, hist, write_off, dense,
                span):
@@ -396,24 +342,24 @@ def glm_moe_lite_paged_decode_fns(cfg: GlmMoeLiteConfig, block_size: int,
         the leaf. Jitted on its own (``dense`` and ``span`` static),
         so that a program's trace and lowering hold each kind of layer once
         and call it: the decode program is built once a table width."""
-        cache = _LatentCache(leaf, table, wb, write_off, hist, BS)
+        cache = PagedLeaf(leaf, table, wb, write_off, hist, BS)
         R, Q, _ = x.shape
-        a = _rmsnorm(x, lp["/norm_1"])
-        q = _mm(_rmsnorm(_mm(a, lp["/attn/q_a"]), lp["/attn/q_norm"]),
-                lp["/attn/q_b"]).reshape(R, Q, A, DN + DR)
-        ckr = _mm(a, lp["/attn/kv_a"])                      # [R, Q, C + DR]
+        a = rms_norm(x, lp["/norm_1"], eps)
+        q = mm(rms_norm(mm(a, lp["/attn/q_a"]), lp["/attn/q_norm"], eps),
+               lp["/attn/q_b"]).reshape(R, Q, A, DN + DR)
+        ckr = mm(a, lp["/attn/kv_a"])                      # [R, Q, C + DR]
         ang = qpos[..., None].astype(jnp.float32) * inv_freq
         fresh = jnp.concatenate(
-            [_rmsnorm(ckr[..., :C], lp["/attn/kv_norm"]),
-             _rope(ckr[..., C:], ang), jnp.zeros((R, Q, PAD))],
+            [rms_norm(ckr[..., :C], lp["/attn/kv_norm"], eps),
+             rotate_half(ckr[..., C:], ang), jnp.zeros((R, Q, PAD))],
             axis=-1).astype(leaf.dtype)
-        att = _attend(q[..., :DN], _rope(q[..., DN:], ang[:, :, None]),
+        att = _attend(q[..., :DN], rotate_half(q[..., DN:], ang[:, :, None]),
                       fresh, lp["/attn/kv_b"], qpos, valid, cache, span)
-        cache.write(fresh.reshape(R * Q, W))
-        x = x + _mm(att, lp["/attn/o"])
-        m = _rmsnorm(x, lp["/norm_2"])
+        cache.write_rows(fresh.reshape(R * Q, W))
+        x = x + mm(att, lp["/attn/o"])
+        m = rms_norm(x, lp["/norm_2"], eps)
         if dense:
-            y = _gated(m, lp["/mlp/gate"], lp["/mlp/up"], lp["/mlp/down"])
+            y = swiglu(m, lp["/mlp/gate"], lp["/mlp/up"], lp["/mlp/down"])
             return x + y, None, cache.leaf
         flat, ok = m.reshape(R * Q, H), valid.reshape(R * Q)
         idx, wts, moved = sigmoid_bias_route(
@@ -425,7 +371,7 @@ def glm_moe_lite_paged_decode_fns(cfg: GlmMoeLiteConfig, block_size: int,
             grouped=tiled_grouped_dot if chunk_tiles(R * Q) else None)
         if "/shared/gate" in lp:
             # the shared expert: every token passes it, added once
-            y = y + _gated(flat, lp["/shared/gate"], lp["/shared/up"],
+            y = y + swiglu(flat, lp["/shared/gate"], lp["/shared/up"],
                            lp["/shared/down"])
         did = jnp.concatenate(
             [served, jnp.sum(jnp.where(ok, moved, 0), dtype=jnp.int32)[None]])
@@ -446,15 +392,12 @@ def glm_moe_lite_paged_decode_fns(cfg: GlmMoeLiteConfig, block_size: int,
         x = jnp.take(p["embed"], tokens, axis=0).astype(jnp.float32)
         did = []
         for i in range(L):
-            sc = f"h{i}"
-            lp = {n[len(sc):]: a for n, a in p.items()
-                  if n.startswith(sc + "/")}
-            x, d, kc[i] = block(lp, x, qpos, valid, kc[i], table, wb, hist,
-                                write_off, dense=cfg.is_dense(i),
-                                span=span)
+            x, d, kc[i] = block(layer_params(p, i), x, qpos, valid, kc[i],
+                                table, wb, hist, write_off,
+                                dense=cfg.is_dense(i), span=span)
             if d is not None:
                 did.append(d)
-        return _rmsnorm(x, p["norm_f"]), jnp.stack(did), tuple(kc)
+        return rms_norm(x, p["norm_f"], eps), jnp.stack(did), tuple(kc)
 
     def prefill_fn(params, kc, vc, io):
         tokens, length, hist = io["tokens"], io["length"], io["hist"]
@@ -475,7 +418,7 @@ def glm_moe_lite_paged_decode_fns(cfg: GlmMoeLiteConfig, block_size: int,
                           table[None], wb, hist[None], g % BS, span)
         h_last = jax.lax.dynamic_slice_in_dim(
             x[0], jnp.maximum(length - 1, 0), 1, axis=0)
-        logits = _mm(h_last, params["lm_head"])[0]
+        logits = mm(h_last, params["lm_head"])[0]
         return kc, vc, jnp.argmax(logits).astype(jnp.int32), logits
 
     def decode_fn(params, kc, vc, io):
@@ -484,7 +427,7 @@ def glm_moe_lite_paged_decode_fns(cfg: GlmMoeLiteConfig, block_size: int,
                             active[:, None], kc, io["tables"],
                             io["write_block"], pos, io["write_off"],
                             io["tables"].shape[1])
-        logits = _mm(x[:, 0], params["lm_head"])
+        logits = mm(x[:, 0], params["lm_head"])
         served = did[:, :-1]
         counted = jnp.stack([                  # PROGRAM_COUNTERS' order
             jnp.int32(did.shape[0]), jnp.sum(served > 0, dtype=jnp.int32),
@@ -511,15 +454,7 @@ def glm_moe_lite_paged_spec(cfg: GlmMoeLiteConfig, params):
     if cfg.first_dense_layers >= cfg.num_layers:
         raise ValueError("the decode program counts its expert layers: "
                          "there is none")
-    pull = params if callable(params) else (lambda: params)
-    got, want = pull(), glm_moe_lite_param_shapes(cfg)
-    if set(got) != set(want):
-        raise ValueError(f"parameter names differ: "
-                         f"{sorted(set(got) ^ set(want))[:4]}")
-    for n, shape in want.items():
-        if tuple(np.shape(got[n])) != shape:
-            raise ValueError(f"{n}: shape {tuple(np.shape(got[n]))}, "
-                             f"the configuration gives {shape}")
+    pull, got = pull_params(params, glm_moe_lite_param_shapes(cfg))
     return PagedGenerativeSpec(
         params=pull,
         make_fns=lambda bs, maxb: glm_moe_lite_paged_decode_fns(cfg, bs, maxb),

@@ -31,6 +31,7 @@ import dataclasses
 import numpy as np
 
 from deeplearning4j_tpu.compilecache.cache import COMPILE_STATS
+from deeplearning4j_tpu.zoo.blocks import layer_params
 
 
 @dataclasses.dataclass
@@ -186,6 +187,71 @@ def gpt_param_names(cfg: GPTConfig):
     return names
 
 
+def _decode_helpers(cfg: GPTConfig, QW: bool):
+    """The seven helpers :func:`gpt_decode_fns` and
+    :func:`gpt_paged_decode_fns` share: ``(_ln, _matmul, _mlp, _tok_emb,
+    _logits, _q_store, _q_load)``, over int8 weight payloads where
+    ``QW``."""
+    import jax
+    import jax.numpy as jnp
+    eps = cfg.layer_norm_eps
+
+    def _ln(x, g, b):
+        # one-pass moments + rsqrt, exactly ops/nn_ops.py layer_norm's
+        # f32 path (x is f32 here)
+        mean = jnp.mean(x, axis=-1, keepdims=True)
+        m2 = jnp.mean(x * x, axis=-1, keepdims=True)
+        var = jnp.maximum(m2 - mean * mean, 0.0)
+        inv = jax.lax.rsqrt(var + eps)
+        return (x - mean) * inv * g + b
+
+    def _matmul(p, n, x):
+        # int8 path: matmul the raw int8 payload upcast in-register,
+        # per-output-channel scale applied to the [..., n_out] product
+        # — the dequant rides the matmul epilogue instead of
+        # materializing an f32 weight copy
+        if QW:
+            return (x @ p[n].astype(jnp.float32)) * p[n + "::scale"]
+        return x @ p[n]
+
+    def _mlp(p, sc, x):
+        y = _matmul(p, f"{sc}/mlp/fc/kernel", x) + p[f"{sc}/mlp/fc/bias"]
+        y = jax.nn.gelu(y, approximate=True)    # ops gelu default
+        return _matmul(p, f"{sc}/mlp/proj/kernel", y) \
+            + p[f"{sc}/mlp/proj/bias"]
+
+    def _tok_emb(p, tokens):
+        e = jnp.take(p["wte"], tokens, axis=0)
+        if QW:
+            e = e.astype(jnp.float32) * p["wte::scale"]
+        return e
+
+    def _logits(p, x):
+        if cfg.tie_embeddings:
+            if QW:
+                # (wte_i8 * s_h) contracted over h == wte_i8 contracted
+                # with (x * s_h): fold the per-hidden-channel scale into
+                # the small activation, keep the big operand int8
+                return jnp.einsum("...h,vh->...v", x * p["wte::scale"],
+                                  p["wte"].astype(jnp.float32))
+            return jnp.einsum("...h,vh->...v", x, p["wte"])
+        return _matmul(p, "lm_head", x)
+
+    def _q_store(x, dt, s):
+        # symmetric int8 at write: one round+clip per fresh K/V row
+        if s is None:
+            return x.astype(dt)
+        return jnp.clip(jnp.round(x / s), -127, 127).astype(dt)
+
+    def _q_load(x, s):
+        # dequant at gather, fused into the score/att matmul producers
+        if s is None:
+            return x
+        return x.astype(jnp.float32) * s
+
+    return _ln, _matmul, _mlp, _tok_emb, _logits, _q_store, _q_load
+
+
 @COMPILE_STATS.model_build("gpt")
 def gpt_decode_fns(cfg: GPTConfig, quantize_weights: bool = False,
                    kv_scales=None):
@@ -252,7 +318,6 @@ def gpt_decode_fns(cfg: GPTConfig, quantize_weights: bool = False,
 
     H, A, D, L = (cfg.hidden_size, cfg.num_heads, cfg.head_size,
                   cfg.num_layers)
-    eps = cfg.layer_norm_eps
     scale = 1.0 / np.sqrt(D)        # matches ops scaled_dot_product_attention
     QW = bool(quantize_weights)
     KQ = kv_scales is not None
@@ -260,58 +325,8 @@ def gpt_decode_fns(cfg: GPTConfig, quantize_weights: bool = False,
     ksc = np.asarray(kv_scales["k"], np.float32) if KQ else None
     vsc = np.asarray(kv_scales["v"], np.float32) if KQ else None
 
-    def _ln(x, g, b):
-        # one-pass moments + rsqrt, exactly ops/nn_ops.py layer_norm's
-        # f32 path (x is f32 here)
-        mean = jnp.mean(x, axis=-1, keepdims=True)
-        m2 = jnp.mean(x * x, axis=-1, keepdims=True)
-        var = jnp.maximum(m2 - mean * mean, 0.0)
-        inv = jax.lax.rsqrt(var + eps)
-        return (x - mean) * inv * g + b
-
-    def _matmul(p, n, x):
-        # int8 path: matmul the raw int8 payload upcast in-register,
-        # per-output-channel scale applied to the [..., n_out] product
-        # — the dequant rides the matmul epilogue instead of
-        # materializing an f32 weight copy
-        if QW:
-            return (x @ p[n].astype(jnp.float32)) * p[n + "::scale"]
-        return x @ p[n]
-
-    def _mlp(p, sc, x):
-        y = _matmul(p, f"{sc}/mlp/fc/kernel", x) + p[f"{sc}/mlp/fc/bias"]
-        y = jax.nn.gelu(y, approximate=True)    # ops gelu default
-        return _matmul(p, f"{sc}/mlp/proj/kernel", y) \
-            + p[f"{sc}/mlp/proj/bias"]
-
-    def _tok_emb(p, tokens):
-        e = jnp.take(p["wte"], tokens, axis=0)
-        if QW:
-            e = e.astype(jnp.float32) * p["wte::scale"]
-        return e
-
-    def _logits(p, x):
-        if cfg.tie_embeddings:
-            if QW:
-                # (wte_i8 * s_h) contracted over h == wte_i8 contracted
-                # with (x * s_h): fold the per-hidden-channel scale into
-                # the small activation, keep the big operand int8
-                return jnp.einsum("...h,vh->...v", x * p["wte::scale"],
-                                  p["wte"].astype(jnp.float32))
-            return jnp.einsum("...h,vh->...v", x, p["wte"])
-        return _matmul(p, "lm_head", x)
-
-    def _q_store(x, dt, s):
-        # symmetric int8 at write: one round+clip per fresh K/V row
-        if s is None:
-            return x.astype(dt)
-        return jnp.clip(jnp.round(x / s), -127, 127).astype(dt)
-
-    def _q_load(x, s):
-        # dequant at gather, fused into the score/att matmul producers
-        if s is None:
-            return x
-        return x.astype(jnp.float32) * s
+    _ln, _matmul, _mlp, _tok_emb, _logits, _q_store, _q_load = \
+        _decode_helpers(cfg, QW)
 
     def prefill_fn(params, kc, vc, io):
         p = params
@@ -568,54 +583,14 @@ def gpt_paged_decode_fns(cfg: GPTConfig, block_size: int,
     BS = int(block_size)
     MAXB = int(max_blocks_per_req)
     T = MAXB * BS                   # gathered context length per request
-    eps = cfg.layer_norm_eps
     scale = 1.0 / np.sqrt(D)
     QW = bool(quantize_weights)
     KQ = kv_scales is not None
     ksc = np.asarray(kv_scales["k"], np.float32) if KQ else None
     vsc = np.asarray(kv_scales["v"], np.float32) if KQ else None
 
-    def _ln(x, g, b):
-        mean = jnp.mean(x, axis=-1, keepdims=True)
-        m2 = jnp.mean(x * x, axis=-1, keepdims=True)
-        var = jnp.maximum(m2 - mean * mean, 0.0)
-        inv = jax.lax.rsqrt(var + eps)
-        return (x - mean) * inv * g + b
-
-    def _matmul(p, n, x):
-        if QW:
-            return (x @ p[n].astype(jnp.float32)) * p[n + "::scale"]
-        return x @ p[n]
-
-    def _mlp(p, sc, x):
-        y = _matmul(p, f"{sc}/mlp/fc/kernel", x) + p[f"{sc}/mlp/fc/bias"]
-        y = jax.nn.gelu(y, approximate=True)
-        return _matmul(p, f"{sc}/mlp/proj/kernel", y) \
-            + p[f"{sc}/mlp/proj/bias"]
-
-    def _tok_emb(p, tokens):
-        e = jnp.take(p["wte"], tokens, axis=0)
-        if QW:
-            e = e.astype(jnp.float32) * p["wte::scale"]
-        return e
-
-    def _logits(p, x):
-        if cfg.tie_embeddings:
-            if QW:
-                return jnp.einsum("...h,vh->...v", x * p["wte::scale"],
-                                  p["wte"].astype(jnp.float32))
-            return jnp.einsum("...h,vh->...v", x, p["wte"])
-        return _matmul(p, "lm_head", x)
-
-    def _q_store(x, dt, s):
-        if s is None:
-            return x.astype(dt)
-        return jnp.clip(jnp.round(x / s), -127, 127).astype(dt)
-
-    def _q_load(x, s):
-        if s is None:
-            return x
-        return x.astype(jnp.float32) * s
+    _ln, _matmul, _mlp, _tok_emb, _logits, _q_store, _q_load = \
+        _decode_helpers(cfg, QW)
 
     def _leaves(side):
         # one array a layer, each taken as [num_blocks, block_size, H]:
@@ -635,11 +610,8 @@ def gpt_paged_decode_fns(cfg: GPTConfig, block_size: int,
     # its leaves, and last ``ks``/``vs``, its K/V scales or None.
     def _layers(layer, p, x, kc, vc, *args):
         for i in range(L):
-            sc = f"h{i}"
-            lp = {n[len(sc):]: a for n, a in p.items()
-                  if n.startswith(sc + "/")}
             x, kc[i], vc[i] = layer(
-                lp, x, kc[i], vc[i], *args,
+                layer_params(p, i), x, kc[i], vc[i], *args,
                 ksc[i] if KQ else None, vsc[i] if KQ else None)
         return x
 

@@ -41,15 +41,17 @@ ONE row a token and attention layer, the heads' K and V interleaved
 the attention layers alone, in order, on one tier that keeps every block.
 The convolution's ``v`` is rounded to the state's dtype where it is made,
 so that a run that reads its first taps from the store computes what one
-run over the whole prompt would. A prefill run reads its cached rows
-:data:`READ_SPAN` table entries at a time into a running softmax
-(``zoo.paged_attend``). A decode step on one TPU reads each lane's own
-pages in place through ``paged_attend.paged_decode``, whose kernel takes
-a head of 64 as one 128-lane column ``[k_h | v_h]`` and pads a group of 4
-query heads to whole sublane tiles; where ``paged_attend.kernel_refusal``
-names a reason (the CPU) it reads the table it was handed the same way
-as a prefill run, span by span (``monitor.attention.last_decode_program()``
-says which way the program went).
+run over the whole prompt would. The attention is the zoo's one
+grouped-query attention over a ``zoo.paged_attend.PagedLeaf``: a prefill
+run reads its cached rows :data:`READ_SPAN` table entries at a time into
+a running softmax (``paged_attend.attend``). A decode step on one TPU
+reads each lane's own pages in place (``paged_attend.attend_pages``),
+whose kernel takes a head of 64 as one 128-lane column ``[k_h | v_h]``
+and pads a group of 4 query heads to whole sublane tiles; where
+``paged_attend.kernel_refusal`` names a reason (the CPU) it reads the
+table it was handed the same way as a prefill run, span by span
+(``monitor.attention.last_decode_program()`` says which way the program
+went).
 
 There is no training graph for this block; :func:`lfm2_moe_paged_spec`
 serves parameters handed over by name.
@@ -62,13 +64,13 @@ from typing import Dict, Tuple
 import numpy as np
 
 from deeplearning4j_tpu.compilecache.cache import COMPILE_STATS
-from deeplearning4j_tpu.monitor import attention
 from deeplearning4j_tpu.parallel.moe import (dropless_topk_ffn,
                                              sigmoid_bias_route,
                                              streams_experts)
 from deeplearning4j_tpu.zoo import paged_attend
-from deeplearning4j_tpu.zoo.paged_attend import (NEG, over_spans,
-                                                 softmax_merge)
+from deeplearning4j_tpu.zoo.blocks import (ein, layer_params, mm, pull_params,
+                                           rms_norm, rope_angles, rope_freqs,
+                                           rotate_half, swiglu)
 
 
 class Lfm2MoeUnsupportedError(ValueError):
@@ -253,43 +255,6 @@ def lfm2_moe_param_names(cfg: Lfm2MoeConfig):
     return list(lfm2_moe_param_shapes(cfg))
 
 
-class _PagedCache:
-    """What an attention layer sees of the paged pool: its leaf
-    ``[num_blocks, block_size, 2 * kv_heads * head_dim]`` (K and V
-    interleaved head by head), the requests' ``table [R, entries]``
-    (entry ``e`` holds block ``e``; any width that holds every block a
-    request has cached), ``hist`` [R], how many positions each request has
-    cached, and where the N fresh rows (request-major) go: ``(write_block
-    [N], write_off [N])``."""
-
-    def __init__(self, kv, table, write_block, write_off, hist,
-                 block_size):
-        self.kv, self.table, self.hist = kv, table, hist
-        self.write_block, self.write_off = write_block, write_off
-        self.BS = int(block_size)
-
-    def read(self, first, entries: int):
-        """The cached rows of every request through ``entries`` entries
-        of its table from entry ``first`` (which may be traced), ``[R, T,
-        2 * kv_heads * head_dim]``, and which of them hold a position of
-        the request ``[R, T]``."""
-        import jax
-        import jax.numpy as jnp
-        R, E = self.table.shape[0], int(entries)
-        part = jax.lax.dynamic_slice_in_dim(self.table, first, E, axis=1)
-        rows = self.kv[part].reshape(R, E * self.BS, -1)
-        pos = first * self.BS + jnp.arange(E * self.BS, dtype=jnp.int32)
-        return rows, pos[None] < self.hist[:, None]
-
-    def write(self, k, v):
-        """The fresh rows ``k, v [N, kv_heads, head_dim]``, interleaved,
-        in place."""
-        import jax.numpy as jnp
-        row = jnp.stack([k, v], axis=2).reshape(k.shape[0], -1)
-        self.kv = self.kv.at[self.write_block, self.write_off].set(
-            row.astype(self.kv.dtype))
-
-
 class _ConvState:
     """What a conv layer sees of the state store: its array ``[rows,
     L - 1, hidden]``, the row each of R requests reads (``read_rows
@@ -333,124 +298,49 @@ def _programs(cfg: Lfm2MoeConfig, block_size: int,
 
     H, L = cfg.hidden_size, cfg.num_layers
     A, KV, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    G, K, LC = A // KV, cfg.experts_per_token, cfg.conv_width
+    K, LC = cfg.experts_per_token, cfg.conv_width
     BS = int(block_size)
-    scale = np.float32(1.0 / np.sqrt(D))
-    inv_freq = jnp.asarray(
-        cfg.rope_theta ** (-np.arange(0, D, 2, dtype=np.float64) / D),
-        jnp.float32)
+    eps, inv_freq = cfg.norm_eps, rope_freqs(cfg.rope_theta, D)
     n_moe = L - cfg.num_dense_layers
-
-    def _rmsnorm(x, g):
-        x = x.astype(jnp.float32)
-        ms = jnp.mean(x * x, axis=-1, keepdims=True)
-        return x * jax.lax.rsqrt(ms + cfg.norm_eps) * g.astype(jnp.float32)
-
-    def _mm(x, w):
-        return jnp.matmul(x.astype(w.dtype), w,
-                          preferred_element_type=jnp.float32)
-
-    def _ein(eq, a, b):
-        return jnp.einsum(eq, a, b, preferred_element_type=jnp.float32)
-
-    def _rope(x, pos):
-        # x [..., heads, D] float32, pos [...]: rotate-half by pos *
-        # theta^(-2i / D)
-        ang = pos[..., None, None].astype(jnp.float32) * inv_freq
-        cos = jnp.concatenate([jnp.cos(ang)] * 2, axis=-1)
-        sin = jnp.concatenate([jnp.sin(ang)] * 2, axis=-1)
-        x1, x2 = jnp.split(x, 2, axis=-1)
-        return x * cos + jnp.concatenate([-x2, x1], axis=-1) * sin
-
-    def _attend(q, k, v, qpos, valid, cache, span):
-        """q [R, Q, A, D], k/v [R, Q, KV, D] (the fresh rows), qpos/valid
-        [R, Q]: each query over its request's cached rows, read ``span``
-        table entries at a time into a running softmax, and the fresh
-        rows up to itself, one softmax over both. Returns ``[R, Q, A *
-        D]``."""
-        R, Q = qpos.shape
-        dt = cache.kv.dtype
-        qg = q.reshape(R, Q, KV, G, D).astype(dt)
-        neg = jnp.float32(NEG)
-
-        def over_cached(i, carry):
-            rows, held = cache.read(i * span, span)
-            rows = rows.reshape(R, rows.shape[1], KV, 2, D)
-            Kc, Vc = rows[:, :, :, 0], rows[:, :, :, 1]
-            return softmax_merge(
-                carry, _ein("rqhgd,rthd->rhgqt", qg, Kc) * scale,
-                held[:, None, None, None, :],
-                lambda e: _ein("rhgqt,rthd->rhgqd", e.astype(dt), Vc), neg)
-
-        carry = (jnp.full((R, KV, G, Q), neg),
-                 jnp.zeros((R, KV, G, Q), jnp.float32),
-                 jnp.zeros((R, KV, G, Q, D), jnp.float32))
-        E = cache.table.shape[1]
-        # as many spans as the longest request's cached rows fill (an
-        # idle lane's position counts no further than its table)
-        carry = over_spans(over_cached, carry, E // span, span * BS,
-                           cache.hist, cap=E * BS)
-        # a row sees itself whether or not it is valid, so that an idle
-        # lane or a padded row has a finite result (it lands in the null
-        # block, which every table's unused entries point at)
-        see_f = (qpos[:, None, :] <= qpos[:, :, None]) \
-            & (valid[:, None, :] | jnp.eye(Q, dtype=bool)[None])
-        k, v = k.astype(dt), v.astype(dt)
-        _, total, o = softmax_merge(
-            carry, _ein("rqhgd,rphd->rhgqp", qg, k) * scale,
-            see_f[:, None, None],
-            lambda e: _ein("rhgqp,rphd->rhgqd", e.astype(dt), v), neg)
-        o = o / total[..., None]                           # [R, KV, G, Q, D]
-        return jnp.transpose(o, (0, 3, 1, 2, 4)).reshape(R, Q, A * D)
 
     def _attention(lp, u, qpos, valid, cache, span, paged):
         """The attention mix on ``u [R, Q, H]`` and the pages the
         requests with a valid row read: their own through the kernel,
         every entry of the spans gathered on the plain path."""
         R, Q, _ = u.shape
-        q = _mm(u, lp["/attn/q"]).reshape(R, Q, A, D)
-        k = _mm(u, lp["/attn/k"]).reshape(R, Q, KV, D)
-        v = _mm(u, lp["/attn/v"]).reshape(R, Q, KV, D)
+        q = mm(u, lp["/attn/q"]).reshape(R, Q, A, D)
+        k = mm(u, lp["/attn/k"]).reshape(R, Q, KV, D)
+        v = mm(u, lp["/attn/v"]).reshape(R, Q, KV, D)
         # the per-head norms, then the rotation
-        q = _rope(_rmsnorm(q, lp["/attn/q_norm"]), qpos)
-        k = _rope(_rmsnorm(k, lp["/attn/k_norm"]), qpos)
+        q = rotate_half(rms_norm(q, lp["/attn/q_norm"], eps),
+                        rope_angles(qpos, inv_freq))
+        k = rotate_half(rms_norm(k, lp["/attn/k_norm"], eps),
+                        rope_angles(qpos, inv_freq))
         if paged:
-            # one query a request, over its own pages in place, its fresh
-            # row written first
-            cache.write(k.reshape(R * Q, KV, D), v.reshape(R * Q, KV, D))
-            pages, rows = paged_attend.decode_pages(
-                cache.table, qpos[:, 0], valid[:, 0], cache.write_block, BS,
-                ring=False)
-            att = paged_attend.paged_decode(
-                q[:, 0].astype(cache.kv.dtype), cache.kv, pages, rows, scale,
-                width=int(max_blocks_per_req)).reshape(R, Q, A * D)
-            read = jnp.sum(jnp.where(valid[:, 0], -(-rows // BS), 0),
-                           dtype=jnp.int32)
+            # one query a request, over its own pages in place
+            att, read = paged_attend.attend_pages(
+                q, k, v, qpos, valid, cache, int(max_blocks_per_req))
         else:
-            att = _attend(q, k, v, qpos, valid, cache, span)
-            cache.write(k.reshape(R * Q, KV, D), v.reshape(R * Q, KV, D))
+            att = paged_attend.attend(q, k, v, qpos, valid, cache, span)
             E = cache.table.shape[1]
             read = jnp.sum(jnp.any(valid, axis=1), dtype=jnp.int32) * span \
                 * paged_attend.spans_held(E // span, span * BS, cache.hist,
                                           cap=E * BS)
-        return _mm(att, lp["/attn/o"]), read
+        return mm(att, lp["/attn/o"]), read
 
     def _conv(lp, u, state):
         """The gated short convolution on ``u [R, Q, H]``, its first taps
         from the state (:class:`_ConvState`), which it leaves holding the
         run's last ``L - 1`` real ``v``."""
         Q = u.shape[1]
-        bcx = _mm(u, lp["/conv/in"])
+        bcx = mm(u, lp["/conv/in"])
         b, c, xt = bcx[..., :H], bcx[..., H:2 * H], bcx[..., 2 * H:]
         ext = state.extend(b * xt)             # [R, L - 1 + Q, H]
         w = lp["/conv/w"].astype(jnp.float32)
         z = sum(w[:, j] * ext[:, j:j + Q].astype(jnp.float32)
                 for j in range(LC))
         state.write(ext)
-        return _mm(c * z, lp["/conv/out"])
-
-    def _gated(m, gate, up, down):
-        return _mm(jax.nn.silu(_mm(m, gate)) * _mm(m, up), down)
+        return mm(c * z, lp["/conv/out"])
 
     def _block(lp, x, qpos, valid, store, table, wb, hist, write_off, rows,
                conv, dense, span, paged):
@@ -465,20 +355,21 @@ def _programs(cfg: Lfm2MoeConfig, block_size: int,
         ``span`` and ``paged`` static), so that a program's trace and
         lowering hold each kind of layer once and call it."""
         R, Q, _ = x.shape
-        u = _rmsnorm(x, lp["/norm_op"])
+        u = rms_norm(x, lp["/norm_op"], eps)
         read = None
         if conv:
             cache = _ConvState(store, *rows)
             x = x + _conv(lp, u, cache)
             store = cache.store
         else:
-            cache = _PagedCache(store, table, wb, write_off, hist, BS)
+            cache = paged_attend.PagedLeaf(store, table, wb, write_off, hist,
+                                           BS)
             att, read = _attention(lp, u, qpos, valid, cache, span, paged)
             x = x + att
-            store = cache.kv
-        m = _rmsnorm(x, lp["/norm_ffn"])
+            store = cache.leaf
+        m = rms_norm(x, lp["/norm_ffn"], eps)
         if dense:
-            return x + _gated(m, lp["/mlp/gate"], lp["/mlp/up"],
+            return x + swiglu(m, lp["/mlp/gate"], lp["/mlp/up"],
                               lp["/mlp/down"]), None, read, store
         flat, ok = m.reshape(R * Q, H), valid.reshape(R * Q)
         idx, wts, moved = sigmoid_bias_route(
@@ -509,16 +400,13 @@ def _programs(cfg: Lfm2MoeConfig, block_size: int,
         x = jnp.take(p["embed"], tokens, axis=0).astype(jnp.float32)
         did, read = [], jnp.int32(0)
         for i in range(L):
-            sc = f"h{i}"
-            lp = {n[len(sc):]: a for n, a in p.items()
-                  if n.startswith(sc + "/")}
             conv = not cfg.attention_layout[i]
             store = st if conv else kc
             j = (cfg.conv_layers if conv else cfg.attention_layers).index(i)
             x, d, r, store[j] = block(
-                lp, x, qpos, valid, store[j], table, wb, hist, write_off,
-                rows, conv=conv, dense=cfg.is_dense(i), span=span,
-                paged=paged)
+                layer_params(p, i), x, qpos, valid, store[j], table, wb,
+                hist, write_off, rows, conv=conv, dense=cfg.is_dense(i),
+                span=span, paged=paged)
             if d is not None:
                 did.append(d)
             if r is not None:
@@ -528,8 +416,8 @@ def _programs(cfg: Lfm2MoeConfig, block_size: int,
     def _head(p, x):
         """The last norm and the tied head on the stream ``x [..., H]``."""
         emb = p["embed"]
-        return _ein("...h,vh->...v", _rmsnorm(x, p["norm_f"]).astype(
-            emb.dtype), emb)
+        return ein("...h,vh->...v",
+                   rms_norm(x, p["norm_f"], eps).astype(emb.dtype), emb)
 
     def prefill_fn(params, kc, vc, io, state):
         tokens, length, hist = io["tokens"], io["length"], io["hist"]
@@ -556,12 +444,8 @@ def _programs(cfg: Lfm2MoeConfig, block_size: int,
         tokens, pos, active = io["tokens"], io["positions"], io["active"]
         S = tokens.shape[0]
         lanes = jnp.arange(S, dtype=jnp.int32)
-        # the way the context is read, decided once a traced program from
-        # what it can see (the backend, the leaf's dtype, the heads)
-        why = paged_attend.kernel_refusal(kc[0].dtype, A, KV, D)
-        sites = attention.open_decode_program()
-        for _ in cfg.attention_layers:
-            sites.note(why)
+        paged = paged_attend.decode_reads_pages(
+            kc[0].dtype, A, KV, D, len(cfg.attention_layers))
         # an idle lane's state is written to the spare row behind the
         # slots', which nothing reads
         rows = (lanes, jnp.where(active, lanes, S), pos == 0,
@@ -571,7 +455,7 @@ def _programs(cfg: Lfm2MoeConfig, block_size: int,
             params, tokens[:, None], pos[:, None], active[:, None], kc,
             state[0], io["tables"], io["write_block"], pos, io["write_off"],
             rows, READ_SPAN if width % READ_SPAN == 0 else width,
-            paged=why is None)
+            paged=paged)
         logits = _head(params, x[:, 0])
         served = did[:, :-1]
         counted = jnp.stack([                  # PROGRAM_COUNTERS' order
@@ -662,15 +546,7 @@ def lfm2_moe_paged_spec(cfg: Lfm2MoeConfig, params):
     from deeplearning4j_tpu.serving.paged import (KVLeaf,
                                                   PagedGenerativeSpec,
                                                   StateLeaf)
-    pull = params if callable(params) else (lambda: params)
-    got, want = pull(), lfm2_moe_param_shapes(cfg)
-    if set(got) != set(want):
-        raise ValueError(f"parameter names differ: "
-                         f"{sorted(set(got) ^ set(want))[:4]}")
-    for n, shape in want.items():
-        if tuple(np.shape(got[n])) != shape:
-            raise ValueError(f"{n}: shape {tuple(np.shape(got[n]))}, "
-                             f"the configuration gives {shape}")
+    pull, got = pull_params(params, lfm2_moe_param_shapes(cfg))
     return PagedGenerativeSpec(
         params=pull,
         make_fns=lambda bs, maxb: lfm2_moe_paged_decode_fns(cfg, bs, maxb),

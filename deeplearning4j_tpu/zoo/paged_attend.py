@@ -1,16 +1,18 @@
-"""The running softmax the paged programs read a long context through: the
-cached rows come a span of table entries at a time, and each span's
-scores are merged into the softmax so far, so that no array of scores
-is as long as the context. GLM-4.7-Flash's latent attention and Command
-A+'s grouped-query attention both attend this way; each gives the
-product of the weights with its own rows (``weighted``).
+"""The paged attention of the serving zoo's blocks, written once.
+:class:`PagedLeaf` is what a layer sees of one leaf of the paged pool. A
+grouped-query layer (Command A+, LFM2-8B-A1B) caches ONE row a token, its
+K and V heads interleaved (``[k0, v0, k1, v1, ...]``, each ``head_dim``
+wide), and reads it through :func:`attend`, a span of table entries at a
+time merged into a running softmax (:func:`softmax_merge`) so that no
+array of scores is as long as the context, or, one query a lane on one
+TPU, through :func:`attend_pages`, each lane's own pages IN PLACE
+(:func:`paged_decode`, a Pallas kernel); :func:`decode_reads_pages`
+decides which. GLM-4.7-Flash's latent attention reads its leaf through
+the same :class:`PagedLeaf` and merges its own rows the same way.
 
-A decode step of heads over K and V rows may instead read each lane's
-own pages IN PLACE (:func:`paged_decode`, a Pallas kernel for the TPU):
-the leaf is then ONE array a layer ``[num_blocks, block_size, 2 *
-kv_heads * head_dim]`` whose row interleaves the heads' K and V (``[k0,
-v0, k1, v1, ...]``, each ``head_dim`` wide). The kernel has the contract
-of JAX's ragged paged-attention kernel
+The kernel reads the leaf as ONE array a layer ``[num_blocks,
+block_size, 2 * kv_heads * head_dim]``. It has the contract of JAX's
+ragged paged-attention kernel
 (``jax.experimental.pallas.ops.tpu.ragged_paged_attention``) at one
 query a lane: the same pages, rows, scale and window give the same
 result as its reference over ``leaf.reshape(num_blocks, block_size, 2 *
@@ -38,6 +40,10 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+
+from deeplearning4j_tpu.monitor import attention
+from deeplearning4j_tpu.zoo.blocks import ein
 
 #: a score no row that is seen ever has
 NEG = -1e30
@@ -89,6 +95,133 @@ def over_spans(over, carry, spans: int, span_rows: int, hist, cap=None):
         return over(0, carry)
     return jax.lax.fori_loop(0, spans_held(spans, span_rows, hist, cap),
                              over, carry)
+
+
+class PagedLeaf:
+    """What a layer sees of one leaf of the paged pool: ``leaf
+    [num_blocks, block_size, width]``, its tier's ``table [R, entries]``
+    (R requests in the program), ``hist [R]``, how many positions each
+    request has cached, and where the N fresh rows (request-major) go:
+    ``(write_block [N], write_off [N])``. The table of a tier with a
+    window is a ``ring``: entry ``e`` of its E holds block ``u = e (mod
+    E)``, the one such ``u`` among the last E blocks up to the block of
+    position ``hist - 1`` (the block written there last). Any other table
+    holds block ``e`` in entry ``e``, at any width that holds every block
+    a request has cached."""
+
+    def __init__(self, leaf, table, write_block, write_off, hist,
+                 block_size: int, ring: bool = False):
+        self.leaf, self.table, self.hist = leaf, table, hist
+        self.write_block, self.write_off = write_block, write_off
+        self.BS, self.ring = int(block_size), bool(ring)
+
+    def read(self, first, entries: int, counted_from=None):
+        """The rows of ``entries`` entries of every request's table from
+        entry ``first`` (which may be traced): ``[R, T, width]``, the
+        position of each row (``[R, T]``; ``[1, T]`` where the table is
+        no ring) and whether it is one of the request's cached positions
+        ``[R, T]`` (an entry below ``counted_from``, which an earlier span
+        read, counts as none)."""
+        R, E = self.table.shape
+        n, BS = int(entries), self.BS
+        part = jax.lax.dynamic_slice_in_dim(self.table, first, n, axis=1)
+        if not self.ring:
+            rows = self.leaf[part].reshape(R, n * BS, -1)
+            pos = (first * BS + jnp.arange(n * BS, dtype=jnp.int32))[None]
+            held = pos < self.hist[:, None]
+            if counted_from is not None:
+                held = held & (pos >= counted_from * BS)
+            return rows, pos, held
+        e = first + jnp.arange(n, dtype=jnp.int32)
+        last = jnp.floor_divide(self.hist - 1, BS)[:, None]         # [R, 1]
+        u = last - jnp.mod(last - e[None], E)                       # [R, n]
+        pos = (u[:, :, None] * BS
+               + jnp.arange(BS, dtype=jnp.int32)[None, None])
+        held = (u >= 0)[:, :, None] & (pos < self.hist[:, None, None])
+        if counted_from is not None:
+            held = held & (e >= counted_from)[None, :, None]
+        rows = self.leaf[part].reshape(R, n * BS, -1)
+        return rows, pos.reshape(R, -1), held.reshape(R, -1)
+
+    def write_rows(self, rows):
+        """The fresh rows ``[N, width]``, in place."""
+        self.leaf = self.leaf.at[(self.write_block, self.write_off)].set(
+            rows.astype(self.leaf.dtype))
+
+    def write(self, k, v):
+        """The fresh rows ``k, v [N, kv_heads, head_dim]``, interleaved,
+        in place."""
+        self.write_rows(jnp.stack([k, v], axis=2).reshape(k.shape[0], -1))
+
+
+def attend(q, k, v, qpos, valid, cache: PagedLeaf, span: int,
+           window: Optional[int] = None):
+    """Grouped-query attention of a run over an interleaved leaf: ``q [R,
+    Q, heads, D]``, ``k, v [R, Q, kv_heads, D]`` (the fresh rows, written
+    to ``cache`` afterwards), ``qpos``/``valid`` ``[R, Q]``. Each query
+    reads its request's cached rows and the fresh rows up to itself, one
+    softmax over both (scores ``/ sqrt(D)``), and with a ``window`` only
+    the rows ``j > qpos - window``. The cached rows are read ``span``
+    table entries at a time (0: the whole table in one read), as many
+    spans as the longest request's rows fill, each merged into a running
+    softmax; where the spans do not tile the table, the last ends at the
+    table's end and an entry an earlier span read is not counted again.
+    Fresh rows are attended to as they will lie in the cache (rounded to
+    its dtype). Returns ``[R, Q, heads * D]``."""
+    R, Q = qpos.shape
+    A, (KV, D) = q.shape[2], k.shape[2:]
+    G, BS = A // KV, cache.BS
+    scale = np.float32(1.0 / np.sqrt(D))
+    dt = cache.leaf.dtype
+    qg = q.reshape(R, Q, KV, G, D).astype(dt)
+    E = cache.table.shape[1]
+    span = min(int(span), E) if span else E
+    neg = jnp.float32(NEG)
+
+    def near(kpos):
+        """Which keys at ``kpos [R, T]`` each query's window holds ``[R,
+        Q, T]``."""
+        return kpos[:, None, :] > qpos[:, :, None] - window
+
+    def over_cached(i, carry):
+        if E % span:
+            rows, cpos, held = cache.read(jnp.minimum(i * span, E - span),
+                                          span, i * span)
+        else:
+            rows, cpos, held = cache.read(i * span, span)
+        rows = rows.reshape(R, rows.shape[1], KV, 2, D)
+        Kc, Vc = rows[:, :, :, 0], rows[:, :, :, 1]
+        if window:
+            held = held[:, None, :] & near(cpos)                # [R, Q, T]
+        # seen: [R, 1, 1, Q, T] in a window, else [R, 1, 1, 1, T]
+        return softmax_merge(
+            carry, ein("rqhgd,rthd->rhgqt", qg, Kc) * scale,
+            held[:, None, None] if window else held[:, None, None, None, :],
+            lambda e: ein("rhgqt,rthd->rhgqd", e.astype(dt), Vc), neg)
+
+    carry = (jnp.full((R, KV, G, Q), neg),
+             jnp.zeros((R, KV, G, Q), jnp.float32),
+             jnp.zeros((R, KV, G, Q, D), jnp.float32))
+    # as many spans as the longest request's cached rows fill (an idle
+    # lane's position counts no further than its table)
+    carry = over_spans(over_cached, carry, -(-E // span), span * BS,
+                       cache.hist, cap=E * BS)
+    # a row sees itself whether or not it is valid, so that an idle lane
+    # or a padded row has a finite result (it lands in the null block,
+    # which every table's unused entries point at)
+    see_f = (qpos[:, None, :] <= qpos[:, :, None]) \
+        & (valid[:, None, :] | jnp.eye(Q, dtype=bool)[None])
+    if window:
+        see_f = see_f & near(qpos)
+    kf, vf = k.astype(dt), v.astype(dt)
+    _, total, o = softmax_merge(
+        carry, ein("rqhgd,rphd->rhgqp", qg, kf) * scale,
+        see_f[:, None, None],
+        lambda e: ein("rhgqp,rphd->rhgqd", e.astype(dt), vf), neg)
+    o = o / total[..., None]                               # [R, KV, G, Q, D]
+    o = jnp.transpose(o, (0, 3, 1, 2, 4)).reshape(R, Q, A * D)
+    cache.write(k.reshape(R * Q, KV, D), v.reshape(R * Q, KV, D))
+    return o
 
 
 def kernel_refusal(dtype, heads: int, kv_heads: int,
@@ -328,6 +461,41 @@ def paged_decode(q, leaf, pages, rows, scale: float,
                           per_block=min(KV_PAGES_PER_BLOCK, pages.shape[1]))
 
 
-__all__ = ["NEG", "softmax_merge", "spans_held", "over_spans",
+def attend_pages(q, k, v, qpos, valid, cache: PagedLeaf, width: int,
+                 window: Optional[int] = None):
+    """One query a request, ``q [R, 1, heads, D]``, over its own pages of
+    an interleaved leaf, read in place through :func:`paged_decode` once
+    the fresh rows ``k, v [R, 1, kv_heads, D]`` are written (``width``
+    the widest table of the program's tiers). Returns ``([R, 1, heads *
+    D], the pages the requests with a valid row read)``."""
+    R, _, A, D = q.shape
+    cache.write(k.reshape(R, *k.shape[2:]), v.reshape(R, *v.shape[2:]))
+    pages, rows = decode_pages(
+        cache.table, qpos[:, 0], valid[:, 0], cache.write_block, cache.BS,
+        ring=cache.ring)
+    o = paged_decode(q[:, 0].astype(cache.leaf.dtype), cache.leaf, pages,
+                     rows, np.float32(1.0 / np.sqrt(D)), window, width=width)
+    read = jnp.sum(jnp.where(valid[:, 0], -(-rows // cache.BS), 0),
+                   dtype=jnp.int32)
+    return o.reshape(R, 1, A * D), read
+
+
+def decode_reads_pages(dtype, heads: int, kv_heads: int, head_dim: int,
+                       layers: int) -> bool:
+    """Whether a decode program whose ``layers`` attention layers have
+    leaves in ``dtype`` and ``heads`` query heads over ``kv_heads`` K/V
+    heads of ``head_dim`` reads its pages through :func:`attend_pages`
+    (else :func:`attend`), decided once a traced program from what it can
+    see (:func:`kernel_refusal`); the program's sites are noted in
+    ``monitor.attention``, which ``last_decode_program()`` then gives."""
+    why = kernel_refusal(dtype, heads, kv_heads, head_dim)
+    sites = attention.open_decode_program()
+    for _ in range(layers):
+        sites.note(why)
+    return why is None
+
+
+__all__ = ["NEG", "softmax_merge", "spans_held", "over_spans", "PagedLeaf",
+           "attend", "attend_pages", "decode_reads_pages",
            "kernel_refusal", "window_start", "decode_pages", "paged_kernel",
            "paged_decode", "KV_PAGES_PER_BLOCK"]

@@ -48,6 +48,10 @@ import numpy as np
 from deeplearning4j_tpu.compilecache.cache import COMPILE_STATS
 from deeplearning4j_tpu.parallel.moe import (dropless_topk_ffn,
                                              streams_experts, topk_route)
+from deeplearning4j_tpu.zoo.blocks import (ein, global_and_window_tiers,
+                                           layer_params, mm, pull_params,
+                                           rms_norm, rope_angles, rope_freqs,
+                                           rotate_half, tier_tables)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -107,15 +111,7 @@ class SmallThinkerConfig:
     def kv_tiers(self):
         """The pool's tiers: the global layers, then the window layers
         (a tier with no layer is left out)."""
-        from deeplearning4j_tpu.serving.paged import KVTier
-        glob = tuple(i for i, w in enumerate(self.window_layout) if not w)
-        win = tuple(i for i, w in enumerate(self.window_layout) if w)
-        tiers = []
-        if glob:
-            tiers.append(KVTier("global", glob, None))
-        if win:
-            tiers.append(KVTier("window", win, self.window))
-        return tuple(tiers)
+        return global_and_window_tiers(self.window_layout, self.window)
 
 
 #: what the decode program counts a step, summed over its layers: layers
@@ -225,27 +221,7 @@ def smallthinker_paged_decode_fns(cfg: SmallThinkerConfig, block_size: int,
     BS = int(block_size)
     W = cfg.window
     scale = 1.0 / np.sqrt(D)
-    inv_freq = jnp.asarray(
-        cfg.rope_theta ** (-np.arange(0, D, 2, dtype=np.float64) / D),
-        jnp.float32)
-    tier_of = {i: t for t in cfg.kv_tiers() for i in t.layers}
-
-    def _rmsnorm(x, g):
-        x = x.astype(jnp.float32)
-        ms = jnp.mean(x * x, axis=-1, keepdims=True)
-        return x * jax.lax.rsqrt(ms + cfg.rms_eps) * g.astype(jnp.float32)
-
-    def _mm(x, w):
-        return jnp.matmul(x.astype(w.dtype), w,
-                          preferred_element_type=jnp.float32)
-
-    def _rope(x, pos):
-        # x [..., heads, D] float32, pos [...]: rotate-half over all of D
-        ang = pos[..., None, None].astype(jnp.float32) * inv_freq
-        cos = jnp.concatenate([jnp.cos(ang), jnp.cos(ang)], axis=-1)
-        sin = jnp.concatenate([jnp.sin(ang), jnp.sin(ang)], axis=-1)
-        x1, x2 = jnp.split(x, 2, axis=-1)
-        return x * cos + jnp.concatenate([-x2, x1], axis=-1) * sin
+    eps, inv_freq = cfg.rms_eps, rope_freqs(cfg.rope_theta, D)
 
     def _attend(window, q, k, v, qpos, valid, cache):
         """q [R, Q, A, D], k/v [R, Q, KV, D] (the fresh rows), qpos/valid
@@ -265,20 +241,16 @@ def smallthinker_paged_decode_fns(cfg: SmallThinkerConfig, block_size: int,
             see_f = see_f & (qpos[:, None, :] > qpos[:, :, None] - W)
         qg = q.reshape(R, Q, KV, G, D).astype(dt)
         neg = jnp.float32(-1e30)
-        s_c = jnp.einsum("rqhgd,rthd->rhgqt", qg, Kc.astype(dt),
-                         preferred_element_type=jnp.float32) * scale
-        s_f = jnp.einsum("rqhgd,rphd->rhgqp", qg, k.astype(dt),
-                         preferred_element_type=jnp.float32) * scale
+        s_c = ein("rqhgd,rthd->rhgqt", qg, Kc.astype(dt)) * scale
+        s_f = ein("rqhgd,rphd->rhgqp", qg, k.astype(dt)) * scale
         s_c = jnp.where(see_c[:, None, None], s_c, neg)
         s_f = jnp.where(see_f[:, None, None], s_f, neg)
         top = jnp.maximum(jnp.max(s_c, axis=-1), jnp.max(s_f, axis=-1))
         e_c = jnp.exp(s_c - top[..., None])
         e_f = jnp.exp(s_f - top[..., None])
         den = jnp.sum(e_c, axis=-1) + jnp.sum(e_f, axis=-1)    # [R,KV,G,Q]
-        out = jnp.einsum("rhgqt,rthd->rqhgd", e_c.astype(dt), Vc.astype(dt),
-                         preferred_element_type=jnp.float32) \
-            + jnp.einsum("rhgqp,rphd->rqhgd", e_f.astype(dt), v.astype(dt),
-                         preferred_element_type=jnp.float32)
+        out = ein("rhgqt,rthd->rqhgd", e_c.astype(dt), Vc.astype(dt)) \
+            + ein("rhgqp,rphd->rqhgd", e_f.astype(dt), v.astype(dt))
         out = out / jnp.transpose(den, (0, 3, 1, 2))[..., None]
         return out.reshape(R, Q, A * D)
 
@@ -295,19 +267,20 @@ def smallthinker_paged_decode_fns(cfg: SmallThinkerConfig, block_size: int,
         each."""
         cache = _PagedCache(kl, vl, table, wb, hist, write_off, BS)
         R, Q, _ = x.shape
-        a = _rmsnorm(x, lp["/norm_1"])
+        a = rms_norm(x, lp["/norm_1"], eps)
         # the router is placed before attention
         idx, wts = topk_route(a.reshape(R * Q, H), lp["/router"],
                               cfg.experts_per_token)
-        q = _mm(a, lp["/attn/q"]).reshape(R, Q, A, D)
-        k = _mm(a, lp["/attn/k"]).reshape(R, Q, KV, D)
-        v = _mm(a, lp["/attn/v"]).reshape(R, Q, KV, D)
+        q = mm(a, lp["/attn/q"]).reshape(R, Q, A, D)
+        k = mm(a, lp["/attn/k"]).reshape(R, Q, KV, D)
+        v = mm(a, lp["/attn/v"]).reshape(R, Q, KV, D)
         if rope:
-            q, k = _rope(q, qpos), _rope(k, qpos)
+            q = rotate_half(q, rope_angles(qpos, inv_freq))
+            k = rotate_half(k, rope_angles(qpos, inv_freq))
         att = _attend(window, q, k, v, qpos, valid, cache)
         cache.write(k.reshape(R * Q, KV * D), v.reshape(R * Q, KV * D))
-        x = x + _mm(att, lp["/attn/o"])
-        m = _rmsnorm(x, lp["/norm_2"])
+        x = x + mm(att, lp["/attn/o"])
+        m = rms_norm(x, lp["/norm_2"], eps)
         y, served = dropless_topk_ffn(
             m.reshape(R * Q, H), idx, wts, lp["/experts/gate"],
             lp["/experts/up"], lp["/experts/down"],
@@ -324,31 +297,17 @@ def smallthinker_paged_decode_fns(cfg: SmallThinkerConfig, block_size: int,
         x = jnp.take(p["embed"], tokens, axis=0).astype(jnp.float32)
         served = []
         for i in range(L):
-            sc = f"h{i}"
-            lp = {n[len(sc):]: a for n, a in p.items()
-                  if n.startswith(sc + "/")}
             x, n, kc[i], vc[i] = block(
-                lp, x, qpos, valid, kc[i], vc[i], *tiers[i], hist,
-                write_off, rope=bool(cfg.rope_layout[i]),
+                layer_params(p, i), x, qpos, valid, kc[i], vc[i], *tiers[i],
+                hist, write_off, rope=bool(cfg.rope_layout[i]),
                 window=bool(cfg.window_layout[i]))
             served.append(n)
-        return _rmsnorm(x, p["norm_f"]), jnp.stack(served), \
+        return rms_norm(x, p["norm_f"], eps), jnp.stack(served), \
             tuple(kc), tuple(vc)
 
     def _tiers(io, table_key, lift):
-        per_tier = {}
-        for t in cfg.kv_tiers():
-            table = lift(io[t.key(table_key)])
-            entries = t.table_blocks(BS, max_blocks_per_req)
-            # a ring is addressed u % entries: exact. A table that keeps
-            # every block may come cut to the blocks its lanes hold
-            if table.shape[1] > entries or (
-                    t.window is not None and table.shape[1] != entries):
-                raise ValueError(
-                    f"{t.key(table_key)} has {table.shape[1]} entries, the "
-                    f"tier's table {entries}")
-            per_tier[t.name] = (table, io[t.key("write_block")])
-        return {i: per_tier[t.name] for i, t in tier_of.items()}
+        return tier_tables(cfg.kv_tiers(), io, table_key, lift, BS,
+                           max_blocks_per_req)
 
     def prefill_fn(params, kc, vc, io):
         tokens, length, hist = io["tokens"], io["length"], io["hist"]
@@ -360,7 +319,7 @@ def smallthinker_paged_decode_fns(cfg: SmallThinkerConfig, block_size: int,
             _tiers(io, "table", lambda t: t[None]), hist[None], g % BS)
         h_last = jax.lax.dynamic_slice_in_dim(
             x[0], jnp.maximum(length - 1, 0), 1, axis=0)
-        logits = _mm(h_last, params["lm_head"])[0]
+        logits = mm(h_last, params["lm_head"])[0]
         return kc, vc, jnp.argmax(logits).astype(jnp.int32), logits
 
     def decode_fn(params, kc, vc, io):
@@ -368,7 +327,7 @@ def smallthinker_paged_decode_fns(cfg: SmallThinkerConfig, block_size: int,
         x, served, kc, vc = _stack(
             params, tokens[:, None], pos[:, None], active[:, None], kc, vc,
             _tiers(io, "tables", lambda t: t), pos, io["write_off"])
-        logits = _mm(x[:, 0], params["lm_head"])
+        logits = mm(x[:, 0], params["lm_head"])
         counted = jnp.stack([                  # PROGRAM_COUNTERS' order
             jnp.int32(L), jnp.sum(served > 0, dtype=jnp.int32),
             jnp.sum(served, dtype=jnp.int32),
@@ -387,15 +346,7 @@ def smallthinker_paged_spec(cfg: SmallThinkerConfig, params):
     callable that gives one: ``update_model`` calls it again). K and V
     are cached in the dtype of the parameters."""
     from deeplearning4j_tpu.serving.paged import PagedGenerativeSpec
-    pull = params if callable(params) else (lambda: params)
-    got, want = pull(), smallthinker_param_shapes(cfg)
-    if set(got) != set(want):
-        raise ValueError(f"parameter names differ: "
-                         f"{sorted(set(got) ^ set(want))[:4]}")
-    for n, shape in want.items():
-        if tuple(np.shape(got[n])) != shape:
-            raise ValueError(f"{n}: shape {tuple(np.shape(got[n]))}, "
-                             f"the configuration gives {shape}")
+    pull, got = pull_params(params, smallthinker_param_shapes(cfg))
     return PagedGenerativeSpec(
         params=pull,
         make_fns=lambda bs, maxb: smallthinker_paged_decode_fns(cfg, bs, maxb),
